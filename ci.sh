@@ -194,18 +194,15 @@ python -m pytest tests/test_temporal_reuse.py tests/test_multicam.py \
     --continue-on-collection-errors \
     -p no:cacheprovider -p no:xdist -p no:randomly
 
-echo "== bench diff (optional shard: fresh bench vs BENCH_LOCAL.json) =="
-# perf-regression gate: compares a freshly produced bench results file
-# (BENCH_FRESH=<results.json>, written by a perf/ script on real
-# hardware) against the committed BENCH_LOCAL.json and fails on a >10%
-# throughput, MFU, or host_gap_ratio (served fps / device ceiling)
-# regression. Skipped — loudly — when no fresh row
-# exists: CI containers have no accelerator to produce one.
-if [[ -n "${BENCH_FRESH:-}" && -f "${BENCH_FRESH}" ]]; then
-    python perf/bench_diff.py "${BENCH_FRESH}" --baseline BENCH_LOCAL.json
-else
-    echo "no fresh bench results (set BENCH_FRESH=<results.json>); skipping"
-fi
+echo "== chip_smoke rehearsal (serve path end to end, tiny, CPU) =="
+# the chip smoke's own phases — kernel checks, threshold calibration,
+# serve over loopback gRPC, fused-vs-XLA comparison, /snapshot asserts,
+# SIGTERM drain — at tiny sizes with interpreted kernels, then the
+# --chips 4 mesh path on four virtual devices. Control flow only: the
+# real thing is `python chip_smoke.py` through the chip tool.
+JAX_PLATFORMS=cpu python chip_smoke.py --rehearse
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+    python chip_smoke.py --rehearse --chips 4
 
 echo "== tier-1 pytest =="
 exec python -m pytest tests/ -q -m 'not slow' \

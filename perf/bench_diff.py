@@ -1,8 +1,8 @@
-"""Bench regression gate: a fresh result row vs the committed baseline.
+"""Bench regression gate: a fresh result row vs a baseline file.
 
 Compares the ``results`` rows of a freshly produced bench JSON (any of
-the perf/ scripts' output, same shape as BENCH_LOCAL.json) against the
-committed BENCH_LOCAL.json, matched by ``metric`` name, and exits
+the perf/ scripts' output, the shape bench.py writes) against a
+baseline file of the same shape, matched by ``metric`` name, and exits
 nonzero when either
 
   * throughput (``value``, frames/scans per sec per chip) regressed by
@@ -22,16 +22,16 @@ Two comparisons are reported but never gated: rows measured under the
 Pallas INTERPRETER (``interpret: true`` — correctness-true,
 performance-false) and rows whose ``fused_stages`` route changed
 between fresh and baseline (a different code path, not a regression).
-ci.sh runs this as an OPTIONAL shard: only when a fresh row exists
-(``BENCH_FRESH=<results.json>``), because producing one needs the
-actual accelerator; the committed baseline alone proves nothing.
+The repository commits no baseline: both files come from runs on the
+same chip (parent and change in one call of the chip tool), so
+``--baseline`` is required.
 
 Improvements never fail; metrics present on only one side are reported
 but not gated (a new bench row has no baseline yet, a retired one no
 fresh measurement).
 
 Usage:
-    python perf/bench_diff.py FRESH.json [--baseline BENCH_LOCAL.json]
+    python perf/bench_diff.py FRESH.json --baseline PARENT.json
                               [--threshold 0.10]
 """
 
@@ -154,9 +154,9 @@ def main(argv=None) -> None:
     )
     p.add_argument("fresh", help="freshly produced bench results JSON")
     p.add_argument(
-        "--baseline",
-        default=os.path.join(_REPO_ROOT, "BENCH_LOCAL.json"),
-        help="committed baseline (default: repo BENCH_LOCAL.json)",
+        "--baseline", required=True,
+        help="baseline results JSON from the same chip (the parent "
+        "commit's run)",
     )
     p.add_argument(
         "--threshold", type=float, default=0.10,

@@ -90,8 +90,6 @@ def main() -> None:
             backbone_filters=(256, 512), middle_filters=(32, 64, 128),
         )),
     ]
-    rtt = bench._tunnel_rtt_ms()
-    print(f"tunnel rtt {rtt:.2f} ms", file=sys.stderr)
     configs = []
     for label, factory in variants:
         try:
@@ -106,7 +104,7 @@ def main() -> None:
         for c in configs:
             c.run_trial()
     for c in configs:
-        row = c.result(rtt, with_latency=False)
+        row = c.result(with_latency=False)
         print(json.dumps({
             "variant": c.name,
             "scans_per_sec": row["value"],

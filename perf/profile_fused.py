@@ -54,7 +54,7 @@ from triton_client_tpu.ops.voxelize import VoxelConfig
 
 STAGES = ("voxelize_scatter", "decode_nms_2d", "decode_nms_3d")
 
-# KITTI-shaped SECOND grid (the BASELINE.md 5 ms/scan scatter victim)
+# KITTI-shaped SECOND grid (the scatter-bound front)
 KITTI_VOXEL = VoxelConfig(
     point_cloud_range=(0.0, -40.0, -3.0, 70.4, 40.0, 1.0),
     voxel_size=(0.05, 0.05, 0.1),

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from _harness import compile_looped, run_trials
 
 from triton_client_tpu.models.yolov5 import YoloV5
-from triton_client_tpu.obs.roofline import V5E_PEAK_FLOPS, classify
+from triton_client_tpu.obs.roofline import classify, peak_flops
 from triton_client_tpu.ops.detect_postprocess import extract_boxes
 from triton_client_tpu.ops.preprocess import normalize_image
 
@@ -82,8 +82,7 @@ def main():
         "v5m_b32": lambda: make_case(
             YoloV5, variant="m", batch=32, dtype=jnp.bfloat16
         ),
-        # the peak-per-chip A/B (BASELINE.md: 15.80 -> 14.26 ms,
-        # 4,050 -> 4,490 fps): run `... b64 b64_mxu_bf16`
+        # the peak-per-chip A/B: run `... b64 b64_mxu_bf16`
         "b64": lambda: make_case(YoloV5, batch=64),
         "b64_mxu_bf16": lambda: make_case(
             YoloV5, batch=64, s2d=True, ch_floor=32, dtype=jnp.bfloat16
@@ -112,9 +111,12 @@ def main():
             flops[name] = 0.0
             nbytes[name] = 0.0
     out = run_trials(cases, inner=inner, trials=8)
-    # v5e bf16 MXU peak (fp32 runs the MXU at bf16 rate under jax's
-    # default precision) — single source of truth in obs.roofline
-    peak = V5E_PEAK_FLOPS
+    # the live device's bf16 MXU peak (fp32 runs the MXU at bf16 rate
+    # under jax's default precision) — single source of truth in
+    # obs.roofline; a device it does not list has no MFU
+    peak = peak_flops("bf16")
+    if peak is None:
+        raise SystemExit("no peaks listed for this device: nothing to divide by")
     print("\n== results ==")
     for name, ms in out.items():
         fps = units[name] / (ms / 1e3)

@@ -11,7 +11,7 @@ meshes of 1/2/4/8 devices; per width the harness reports
     params, no collectives), so the number is independent of how the
     harness host schedules virtual devices;
   * ``per_chip_frames_per_sec`` — aggregate / width, comparable to
-    BENCH_LOCAL.json's ``*_per_chip`` rows;
+    bench.py's ``*_per_chip`` rows;
   * ``e2e_frames_per_sec`` — measured wall through the full channel
     (stage -> sharded launch -> readback) on THIS host. On virtual
     host-platform devices every "device" time-shares the same cores, so
@@ -24,8 +24,9 @@ meshes of 1/2/4/8 devices; per width the harness reports
 
 Self-provisioning: run under any backend; when fewer than ``--devices``
 devices are live the script re-execs itself in a virtual CPU mesh
-(``--xla_force_host_platform_device_count``, same pattern as
-``__graft_entry__.py dryrun_multichip``).
+(``--xla_force_host_platform_device_count``) — from a parent that
+has not touched jax, so no chip is held across the re-exec. The
+on-chip mesh check is ``python chip_smoke.py --chips 4``.
 
 Usage: python perf/profile_serving_sharded.py [--devices 8]
        [--widths 1,2,4,8] [--batch 8] [--rounds 6] [--hw 256]

@@ -99,8 +99,8 @@ def serve_and_search(args) -> dict:
         slo_ms = args.slo_ms
         if not slo_ms:
             # auto-SLO: 3x the lightly-loaded p50 — honest on any rig
-            # (a fixed wall-clock SLO would read 0 capacity through the
-            # ~100 ms tunnel RTT and hide regressions on fast hosts)
+            # (a fixed wall-clock SLO would read 0 capacity over a
+            # ~100 ms WAN link and hide regressions on fast hosts)
             calib = run_open_loop(
                 addr, scenarios, rate_qps=4.0, duration_s=3.0,
                 seed=args.seed, deadline_s=120.0,

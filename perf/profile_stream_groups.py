@@ -1,10 +1,10 @@
-"""Multi-frame stream groups under a simulated tunnel RTT (ISSUE 13).
+"""Multi-frame stream groups under a simulated WAN RTT (ISSUE 13).
 
-The paper's remote rig pays ~93 ms of tunnel RTT per gRPC message; the
+A robot on a WAN link pays ~100 ms of RTT per gRPC message; the
 multi-frame stream protocol packs G frames into ONE ModelStreamInfer
 message so that cost is paid once per group instead of once per frame.
 On loopback the RTT is ~0 and the win is invisible, so this harness
-SIMULATES the tunnel: a closed-loop stream client sleeps ``--rtt-ms``
+SIMULATES the link: a closed-loop stream client sleeps ``--rtt-ms``
 once per message boundary (exactly the cost model of one in-flight
 message on a long fat pipe), then measures served fps per group size.
 
@@ -52,7 +52,7 @@ def drive(chan, model, frame, group, rtt_s, duration_s) -> dict:
         i = 0
         while time.perf_counter() < t_end:
             if rtt_s > 0 and i % group == 0:
-                # one simulated tunnel round trip per MESSAGE: the
+                # one simulated WAN round trip per MESSAGE: the
                 # whole point of packing G frames into one
                 time.sleep(rtt_s)
             sent.put(1)  # closed loop: at most `group` frames in flight

@@ -3,7 +3,7 @@ detect CLI --repo (trained weights) -> mAP report.
 
 These are SMOKE tests (few steps, tiny shapes) proving the loop's
 plumbing end to end; the convergence runs with real step counts live in
-perf/closed_loop.py and their numbers in BASELINE.md.
+perf/closed_loop.py.
 """
 
 import json

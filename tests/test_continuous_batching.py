@@ -13,7 +13,7 @@ Four contract planes of ``ContinuousBatchingChannel``:
     single-device channel and shard-major across the 8-device mesh;
   * **the padding tax** — under a seeded open-loop mixed drive the
     served pad fraction stays under the 5% acceptance bar (the window
-    batcher's static buckets sat at ~32% in BENCH_r05).
+    batcher's static buckets padded up to a third of device rows).
 """
 
 import concurrent.futures

@@ -180,8 +180,8 @@ def test_examples_yolov5_base_keeps_continuity_layout():
 
 
 def test_examples_yolov5l_capacity_entry_builds():
-    """The capacity-is-free recommendation (v5l at 35% MFU, ~1,000 fps
-    b8 — BASELINE.md MFU study) is servable out of the box, not just
+    """The capacity-is-free recommendation (serve the largest variant
+    the accuracy budget wants) is servable out of the box, not just
     prose: the repo entry builds and serves the same contract."""
     rm = dr.build_model("examples/yolov5l_crop", version="1")
     assert rm.spec.name == "yolov5l_crop"

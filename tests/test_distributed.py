@@ -182,7 +182,7 @@ assert is_coordinator() == (pid == 0)
 import numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 mesh = global_mesh(MeshConfig(data=4, model=1))
 # host-major: the data axis walks process 0's devices first, then

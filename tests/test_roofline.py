@@ -9,17 +9,23 @@ import numpy as np
 import pytest
 
 from triton_client_tpu.obs.roofline import (
-    POLICY_PEAK_FLOPS,
-    V5E_PEAK_FLOPS,
-    V5E_PEAK_HBM_BPS,
+    DEVICE_PEAKS,
+    V5E,
     classify,
     hlo_module_for,
     launcher_name,
     measure_launch_cost,
     model_row,
     name_launcher,
+    peak_bytes_per_s,
+    peak_flops,
     record_launch_cost,
 )
+
+# the tests below state the device they compute a ceiling for: the live
+# one is a CPU, which has no peaks (test_unlisted_device_gets_no_figure)
+V5E_PEAK_FLOPS = DEVICE_PEAKS[V5E]["flops"]
+V5E_PEAK_HBM_BPS = DEVICE_PEAKS[V5E]["hbm_bytes_per_s"]
 
 
 def _model(name="m", version="1", extra=None):
@@ -34,7 +40,7 @@ def _model(name="m", version="1", extra=None):
 
 def test_compute_bound_when_intensity_above_knee():
     # I = 1e12/1e9 = 1000 flop/B >> knee (~240): the MXU ceiling binds
-    row = classify(1e12, 1e9, precision="bf16", batch=8)
+    row = classify(1e12, 1e9, precision="bf16", batch=8, device_kind=V5E)
     assert row.bound == "compute"
     assert row.intensity == pytest.approx(1000.0)
     assert row.knee == pytest.approx(V5E_PEAK_FLOPS / V5E_PEAK_HBM_BPS)
@@ -44,35 +50,37 @@ def test_compute_bound_when_intensity_above_knee():
 
 def test_bandwidth_bound_when_intensity_below_knee():
     # I = 1 flop/B << knee: HBM binds; ceiling = peak_bw / bytes
-    row = classify(1e9, 1e9, precision="f32", batch=1)
+    row = classify(1e9, 1e9, precision="f32", batch=1, device_kind=V5E)
     assert row.bound == "bandwidth"
     assert row.attainable_calls_per_s == pytest.approx(V5E_PEAK_HBM_BPS / 1e9)
 
 
 def test_int8_activations_double_the_flops_ceiling():
-    f32 = classify(1e12, 1e6, precision="f32")
-    int8 = classify(1e12, 1e6, precision="int8")
-    assert POLICY_PEAK_FLOPS["int8"] == 2 * V5E_PEAK_FLOPS
+    f32 = classify(1e12, 1e6, precision="f32", device_kind=V5E)
+    int8 = classify(1e12, 1e6, precision="int8", device_kind=V5E)
+    assert peak_flops("int8", V5E) == 2 * V5E_PEAK_FLOPS
     assert int8.attainable_calls_per_s == pytest.approx(
         2 * f32.attainable_calls_per_s
     )
     # int8-WEIGHT policies run the MXU at the bf16 MAC rate
     assert classify(
-        1e12, 1e6, precision="int8w"
+        1e12, 1e6, precision="int8w", device_kind=V5E
     ).attainable_calls_per_s == pytest.approx(f32.attainable_calls_per_s)
 
 
 def test_zero_cost_is_unknown_and_zero_bytes_is_compute():
-    empty = classify(0, 0)
+    empty = classify(0, 0, device_kind=V5E)
     assert empty.bound == "unknown"
     assert empty.attainable_fps == 0.0
-    no_bytes = classify(1e9, 0)
+    no_bytes = classify(1e9, 0, device_kind=V5E)
     assert no_bytes.bound == "compute"
     assert no_bytes.intensity == float("inf")
 
 
 def test_as_dict_round_trips_the_row():
-    d = classify(2e12, 1e9, precision="bf16", batch=4).as_dict()
+    d = classify(
+        2e12, 1e9, precision="bf16", batch=4, device_kind=V5E
+    ).as_dict()
     assert d["bound"] == "compute"
     assert set(d) == {
         "flops", "bytes", "precision", "batch", "intensity", "knee",
@@ -138,14 +146,30 @@ def test_model_row_reports_attained_fraction():
         "precision": "bf16",
         "analytic_flops_per_call": 9e11,
     }
-    row = model_row(extra, measured_fps=100.0)
+    row = model_row(extra, measured_fps=100.0, device_kind=V5E)
     assert row["bound"] == "compute"
     assert row["analytic_flops_per_call"] == 9e11
     assert row["measured_fps"] == 100.0
     assert row["attained_fraction"] == pytest.approx(
         100.0 / row["attainable_fps"]
     )
-    assert "measured_fps" not in model_row(extra)
+    assert "measured_fps" not in model_row(extra, device_kind=V5E)
+
+
+def test_unlisted_device_gets_no_figure():
+    """A device that is not in DEVICE_PEAKS (the CPU these tests run
+    on, by default) yields the measured intensity and nothing that
+    needs a peak — never v5e's ceiling under another device's name."""
+    assert "cpu" not in DEVICE_PEAKS
+    assert peak_flops("bf16", "cpu") is None
+    assert peak_bytes_per_s("cpu") is None
+    for row in (
+        classify(1e12, 1e9, precision="bf16", batch=8, device_kind="cpu"),
+        classify(1e12, 1e9, precision="bf16", batch=8),  # the live device
+    ):
+        assert row.intensity == pytest.approx(1000.0)
+        assert (row.bound, row.knee, row.attainable_fps) == ("unknown", 0.0, 0.0)
+    assert DEVICE_PEAKS[V5E]["source"]
 
 
 # -- channel integration ------------------------------------------------------
@@ -190,9 +214,15 @@ def test_first_launch_records_measured_cost_into_spec_extra():
         getattr(chan, "close", lambda: None)()
 
 
-def test_collector_model_rows_gain_roofline_after_measurement():
+def test_collector_model_rows_gain_roofline_after_measurement(monkeypatch):
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
+
+    # the collector reads the LIVE device's peaks, and this CPU has
+    # none: list it (with v5e's numbers) so the row gains a bound
+    monkeypatch.setitem(
+        DEVICE_PEAKS, jax.devices()[0].device_kind, DEVICE_PEAKS[V5E]
+    )
 
     from triton_client_tpu.channel.base import InferRequest
     from triton_client_tpu.channel.tpu_channel import TPUChannel

@@ -38,9 +38,9 @@ import pytest
 
 from triton_client_tpu.channel.base import InferRequest, InferResponse
 from triton_client_tpu.obs.device_time import (
-    POLICY_PEAK_FLOPS,
     DeviceTimeLedger,
 )
+from triton_client_tpu.obs.roofline import V5E, peak_flops
 from triton_client_tpu.obs.trace import (
     SUMMARY_PARAM_KEY,
     RequestTrace,
@@ -240,7 +240,7 @@ class TestDeviceTimeLedger:
         assert 0.0 < snap["window"]["utilization"] <= 1.0
 
     def test_mfu_from_flops_metadata(self):
-        led = DeviceTimeLedger(window_s=60.0)
+        led = DeviceTimeLedger(window_s=60.0, device_kind=V5E)
         extra = {"flops_per_call": 1e12, "precision": "int8"}
         for _ in range(4):
             led.record("m", 0.01, extra)
@@ -252,7 +252,12 @@ class TestDeviceTimeLedger:
         for _ in range(4):
             led.record("m", 0.01, extra)
         assert led.mfu()["m"] > before
-        assert POLICY_PEAK_FLOPS["int8"] == 2 * POLICY_PEAK_FLOPS["bf16"]
+        assert peak_flops("int8", V5E) == 2 * peak_flops("bf16", V5E)
+        # a device with no listed peak accounts seconds, reports no MFU
+        unlisted = DeviceTimeLedger(window_s=60.0, device_kind="cpu")
+        unlisted.record("m", 0.01, extra)
+        assert unlisted.mfu() == {}
+        assert unlisted.snapshot()["window"]["mfu"] == {}
         # models without metadata still account seconds, no MFU row
         led.record("bare", 0.01)
         assert "bare" not in led.mfu()
@@ -377,8 +382,8 @@ class TestRouterTracing:
     def test_propagation_is_effectively_free(self):
         """Acceptance: trace propagation adds ~0% measurable cost. On a
         fake fleet whose RPC is microseconds, the traced router must
-        stay within 2 ms/request of the untraced one — at the ~100 ms
-        e2e latencies of BENCH_LOCAL.json that bounds the tax at <2%,
+        stay within 2 ms/request of the untraced one — at ~100 ms e2e
+        latencies that bounds the tax at <2%,
         and the real tax (a uuid, a dict, a few spans) is microseconds."""
         script = lambda ep, req: _ok_response(req)  # noqa: E731
         n = 50
@@ -461,8 +466,17 @@ class TestLiveJoinedTrace:
             for _c, server in stacks:
                 server.stop()
 
-    def test_ledger_reconciles_and_metrics_scrape_nonzero(self):
+    def test_ledger_reconciles_and_metrics_scrape_nonzero(self, monkeypatch):
+        import jax
+
         from triton_client_tpu.channel.grpc_channel import GRPCChannel
+        from triton_client_tpu.obs.roofline import DEVICE_PEAKS
+
+        # the server's ledger reads the LIVE device's peak, and this
+        # CPU has none (no MFU gauge): list it with v5e's numbers
+        monkeypatch.setitem(
+            DEVICE_PEAKS, jax.devices()[0].device_kind, DEVICE_PEAKS[V5E]
+        )
 
         repo, _ = _repo(sleep_s=0.0)
         chan, server = _stack(repo)

@@ -1,6 +1,6 @@
 """Host transport: negotiation, UDS, region pool, stream groups.
 
-The tentpole behind these tests (ROADMAP item 1 / BENCH_r05): a
+The tentpole behind these tests: a
 same-host client must not pay the protobuf serialize/frame/parse tax
 per 786 KB frame. The pieces under test:
 

@@ -3,7 +3,7 @@
 XLA compiles one executable per (shape, dtype, static-arg) signature;
 anything that makes the traced Python non-deterministic per call either
 fails at trace time or silently retraces — and on the serving path a
-retrace is a multi-second stall (BASELINE.md measured compile bills).
+retrace is a multi-second stall.
 These rules find the three shapes of that bug this codebase has
 actually grown:
 

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from triton_client_tpu.channel.staged import (
     SEGMENT_IDS_KEY,
@@ -50,6 +51,7 @@ from triton_client_tpu.channel.staged import (
 )
 from triton_client_tpu.obs.roofline import name_launcher
 from triton_client_tpu.parallel.mesh import (
+    DATA_AXIS,
     data_axis_size,
     replicate_params,
     serving_shardings,
@@ -248,10 +250,33 @@ class ShardedTPUChannel(StagedChannel):
             # nothing is donated, hence the distinct parameter names)
             outer.lower = lambda db, kb: jitted.lower(placed, db, kb)
             return outer, donate_names, out_dtype
+        def body(donated, kept):
+            return device_fn({**donated, **kept})
+
+        if batched and model.spec.extra.get("fused_stages"):
+            # A Pallas fusion inside the body: the SPMD partitioner
+            # refuses it ("Mosaic kernels cannot be automatically
+            # partitioned. Please wrap the call in a shard_map"), so
+            # run the body per shard — each device executes the
+            # single-device program on its own rows, which is what the
+            # data axis means here anyway. Every output of a model
+            # with batched inputs is batch-leading (the slice-back in
+            # _host_outputs relies on the same fact).
+            mesh, whole = self._mesh, body
+
+            def spec(names):
+                return {
+                    k: P(DATA_AXIS) if k in batched else P() for k in names
+                }
+
+            def body(donated, kept):
+                return jax.shard_map(
+                    whole, mesh=mesh, in_specs=(spec(donated), spec(kept)),
+                    out_specs=P(DATA_AXIS), check_vma=False,
+                )(donated, kept)
+
         launcher = jax.jit(
-            name_launcher(
-                lambda donated, kept: device_fn({**donated, **kept}), model
-            ),
+            name_launcher(body, model),
             in_shardings=(batch_s, None),
             donate_argnums=(0,),
         )

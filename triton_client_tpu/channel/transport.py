@@ -1,9 +1,9 @@
 """Endpoint transport negotiation for the host serving path.
 
-BENCH_r05 put the problem in one row: yolov5n runs 1,685 fps/chip on
-the device but 12.0 fps served over loopback gRPC — the host transport
-is ~1% of the device ceiling, and the expensive part is not the
-network, it is serializing a 786 KB frame into protobuf, copying it
+The problem in one line: a model that runs three orders of magnitude
+above camera rate on the device can serve at a small fraction of that
+over loopback gRPC, and the expensive part is not the network, it is
+serializing a 786 KB frame into protobuf, copying it
 through HTTP/2 framing, and deserializing it in the server process.
 The fix (ROADMAP item 1) is to stop paying that tax whenever both ends
 share a kernel: same-host endpoints ride POSIX shared memory, with the

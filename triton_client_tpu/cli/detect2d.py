@@ -330,6 +330,11 @@ def build(args):
 
 def main(argv=None) -> None:
     args = parse_args(argv)
+    from triton_client_tpu.utils.compilation_cache import (
+        enable_persistent_cache,
+    )
+
+    enable_persistent_cache()  # before the first compile
     if args.sink == "bag":
         raise SystemExit(
             "--sink bag is 3D-only (the output bag carries point clouds + "

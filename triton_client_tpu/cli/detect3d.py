@@ -128,6 +128,11 @@ def _build_pose_lookup(args):
 
 def main(argv=None) -> None:
     args = parse_args(argv)
+    from triton_client_tpu.utils.compilation_cache import (
+        enable_persistent_cache,
+    )
+
+    enable_persistent_cache()  # before the first compile
     if args.sink == "images":
         raise SystemExit(
             "--sink images is 2D-only (3D results are box arrays, not "

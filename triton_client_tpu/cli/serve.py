@@ -20,7 +20,10 @@ log = logging.getLogger(__name__)
 _timeout_warned = False
 
 
-def main(argv=None) -> None:
+def make_parser() -> argparse.ArgumentParser:
+    """The ``serve`` argv contract — split from :func:`main` so an
+    embedder (tests, ``chip_smoke.py``) parses exactly the flags the
+    CLI takes and hands the namespace to :func:`build_server`."""
     p = argparse.ArgumentParser(description="TPU inference server")
     p.add_argument(
         "-r", "--model-repository", required=True,
@@ -98,10 +101,12 @@ def main(argv=None) -> None:
         "compiles log2(max-merge)+1 batch shapes instead of every size",
     )
     p.add_argument(
-        "--metrics-port", type=int, default=8002,
+        "--metrics-port", default=8002,
+        type=lambda v: v if v == "auto" else int(v),
         help="telemetry endpoint: Prometheus metrics on /metrics (Triton "
         ":8002 parity), Chrome-trace JSON on /traces, raw collector "
-        "state on /snapshot (0 disables)",
+        "state on /snapshot (0 disables, 'auto' binds a free port and "
+        "prints it)",
     )
     p.add_argument(
         "--op-sample-interval", type=float, default=0.0,
@@ -300,7 +305,11 @@ def main(argv=None) -> None:
         help="compile every registered model before accepting requests",
     )
     p.add_argument("-v", "--verbose", action="store_true")
-    args = p.parse_args(argv)
+    return p
+
+
+def main(argv=None) -> None:
+    args = make_parser().parse_args(argv)
     logging.basicConfig(level=logging.DEBUG if args.verbose else logging.INFO)
     server = build_server(args)
     server.start()
@@ -315,18 +324,27 @@ def main(argv=None) -> None:
             "(/metrics /traces /snapshot /profile /history)", flush=True,
         )
 
+    drain_on_sigterm(server, args.drain_timeout)
+    try:
+        server.wait()
+    except KeyboardInterrupt:
+        server.stop()
+
+
+def drain_on_sigterm(server, drain_timeout: float) -> None:
+    """Install ``serve``'s SIGTERM behaviour on the calling (main)
+    thread: drain instead of dropping in-flight work on the floor."""
     import signal
 
     def _sigterm(signum, frame):
-        # orchestrator shutdown: drain instead of dropping in-flight
-        # work on the floor. The handler interrupts wait() on the main
-        # thread; drain() flips not-ready, waits out the building, and
-        # stops the transport — wait() then returns and main exits.
+        # orchestrator shutdown. The handler interrupts wait() on the
+        # main thread; drain() flips not-ready, waits out the building,
+        # and stops the transport — wait() then returns and main exits.
         print(
-            f"SIGTERM: draining (timeout {args.drain_timeout:.1f}s)",
+            f"SIGTERM: draining (timeout {drain_timeout:.1f}s)",
             flush=True,
         )
-        drained = server.drain(timeout_s=args.drain_timeout)
+        drained = server.drain(timeout_s=drain_timeout)
         print(
             "drain complete" if drained
             else "drain timeout: stragglers cancelled",
@@ -334,10 +352,6 @@ def main(argv=None) -> None:
         )
 
     signal.signal(signal.SIGTERM, _sigterm)
-    try:
-        server.wait()
-    except KeyboardInterrupt:
-        server.stop()
 
 
 def build_server(args):
@@ -347,9 +361,20 @@ def build_server(args):
     from triton_client_tpu.channel.sharded_channel import ShardedTPUChannel
     from triton_client_tpu.channel.tpu_channel import TPUChannel
     from triton_client_tpu.cli.common import parse_mesh
+    from triton_client_tpu.obs.roofline import device_info
     from triton_client_tpu.runtime.disk_repository import scan_disk
     from triton_client_tpu.runtime.server import InferenceServer
+    from triton_client_tpu.utils.compilation_cache import (
+        enable_persistent_cache,
+    )
 
+    cache_dir = enable_persistent_cache()  # before the first compile
+    device = device_info()
+    print(
+        f"device: {device['platform']} ({device['kind']}) x{device['count']}; "
+        f"compile cache: {cache_dir or 'off'}",
+        flush=True,
+    )
     repo = scan_disk(
         args.model_repository,
         precision=getattr(args, "precision", "") or None,
@@ -392,18 +417,31 @@ def build_server(args):
         breaker_threshold=getattr(args, "breaker_threshold", 5),
         breaker_reset_s=getattr(args, "breaker_reset_s", 10.0),
     )
-    mesh_config = parse_mesh(args.mesh)
     if args.mesh:
         # explicit --mesh: serve the whole mesh data-parallel — params
         # replicated, request batches sharded over the data axis
-        channel = ShardedTPUChannel(repo, mesh_config=mesh_config, **chan_kw)
+        channel = ShardedTPUChannel(
+            repo, mesh_config=parse_mesh(args.mesh), **chan_kw
+        )
         print(
             f"mesh serving: {channel.stats()['mesh_devices']} devices, "
             f"data axis {channel.batch_multiple} "
             f"(batches shard over 'data'; params replicated)", flush=True,
         )
     else:
-        channel = TPUChannel(repo, mesh_config=mesh_config, **chan_kw)
+        # no --mesh: ONE device. TPUChannel's default all-devices mesh
+        # would shard any input whose leading dim divides the device
+        # count — a 3D model's points axis included — which nobody
+        # asked for; spreading over chips is what --mesh says.
+        import jax
+
+        first = jax.devices()[:1]
+        channel = TPUChannel(repo, devices=first, **chan_kw)
+        print(
+            f"single-device serving on {first[0]} "
+            f"({device['count']} visible; --mesh data=N shards batches "
+            "over N)", flush=True,
+        )
     base_channel = channel
 
     # multi-tenant model lifecycle: HBM-budgeted paging + tenant policy

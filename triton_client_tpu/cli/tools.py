@@ -375,9 +375,11 @@ def roofline(argv=None) -> None:
     import urllib.request
 
     rows = []
+    device = None
     if os.path.exists(args.source):
         with open(args.source) as f:
             doc = json.load(f)
+        device = doc.get("device")
         # bench.py results: rows carry the roofline columns directly
         for r in doc.get("rows") or doc.get("results") or []:
             if not r.get("roofline_bound"):
@@ -403,6 +405,7 @@ def roofline(argv=None) -> None:
         url = args.source.rstrip("/") + "/snapshot"
         with urllib.request.urlopen(url, timeout=args.timeout) as resp:
             snap = json.load(resp)
+        device = snap.get("device")
         for m in snap.get("models") or []:
             roof = m.get("roofline")
             if not roof:
@@ -421,7 +424,7 @@ def roofline(argv=None) -> None:
                 }
             )
     if args.json:
-        print(json.dumps({"rows": rows}, indent=2))
+        print(json.dumps({"device": device, "rows": rows}, indent=2))
         return
     if not rows:
         raise SystemExit(
@@ -429,6 +432,19 @@ def roofline(argv=None) -> None:
             "their first launch (serve a request, then retry), and "
             "bench JSON needs the roofline columns (rerun bench.py)"
         )
+    if device:
+        from triton_client_tpu.obs.roofline import DEVICE_PEAKS
+
+        print(
+            f"device: {device.get('platform')} ({device.get('kind')}) "
+            f"x{device.get('count')}"
+        )
+        if device.get("kind") not in DEVICE_PEAKS:
+            print(
+                f"no peaks known for device_kind {device.get('kind')!r}: "
+                "bound and ceiling are not computed (flop/B is the "
+                "measured intensity only)"
+            )
     hdr = (
         f"{'model':<40} {'prec':<6} {'GF/call':>9} {'MB/call':>9} "
         f"{'flop/B':>8} {'bound':<10} {'ceiling fps':>12} {'attained':>9}"

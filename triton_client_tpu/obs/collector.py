@@ -29,6 +29,8 @@ from __future__ import annotations
 import logging
 import threading
 
+from triton_client_tpu.obs.roofline import device_info, model_row
+
 log = logging.getLogger(__name__)
 
 # Every family the collector always exports, name -> prometheus type.
@@ -70,7 +72,7 @@ METRIC_TYPES: dict[str, str] = {
     "tpu_serving_dispatcher_last_progress_seconds": "gauge",
     # padding-tax plane (ISSUE 8): pad_fraction is the headline share
     # of device rows that were padding; batch_occupancy is the merge
-    # occupancy as a real histogram (the BENCH_r05 smear, live);
+    # occupancy as a real histogram, live;
     # ragged_* count the packed-batch path where padding is replaced by
     # a segment table (pad rows there are alignment slack only)
     "tpu_serving_pad_fraction": "gauge",
@@ -486,6 +488,8 @@ class RuntimeCollector:
             "errors": errors,
             "compile": self._compile.snapshot(),
             "memory": self._memory(),
+            # what every number in this snapshot was measured on
+            "device": device_info(),
         }
         # one shed ledger across the whole pipeline: admission-door
         # sheds (recorded here) + the queue/merge/launch/breaker stages
@@ -558,17 +562,16 @@ class RuntimeCollector:
                 "version": version,
                 "precision": str(extra.get("precision", "f32")),
                 "param_bytes": int(extra.get("param_bytes", 0) or 0),
+                # which Pallas fusions this model's launcher routes
+                # (ops/fused; empty = the plain XLA route)
+                "fused_stages": list(extra.get("fused_stages") or ()),
             }
             # roofline placement once the channel has recorded the
             # XLA-measured launch cost (obs.roofline.record_launch_cost
             # at first launch; absent until then / without a cost model)
             if extra.get("measured_flops_per_call") is not None:
-                try:
-                    from triton_client_tpu.obs.roofline import model_row
-
-                    row["roofline"] = model_row(extra)
-                except Exception:
-                    pass
+                row["roofline"] = model_row(extra)
+                row["pallas_kernels"] = int(extra.get("pallas_kernels", 0))
             rows.append(row)
         return rows
 

@@ -16,7 +16,7 @@ construction) accrues into
     (``tpu_serving_device_utilization_ratio``),
   * live per-model MFU — achieved flops over the window against the
     precision policy's peak (``tpu_serving_mfu{model}``), using the
-    same analytic flops / POLICY_PEAK accounting the bench records
+    same analytic flops / per-device peak accounting the bench records
     (``spec.extra["flops_per_call"]`` + ``extra["precision"]``).
 
 ``record`` runs on the resolve() readback path (executor threads,
@@ -30,13 +30,10 @@ import collections
 import threading
 import time
 
-# Re-exported from the roofline module — the single home of the
-# per-chip peaks (bench.py imports the same table), so served MFU,
-# bench MFU, and the roofline ceiling all divide by one denominator.
-from triton_client_tpu.obs.roofline import (  # noqa: F401
-    POLICY_PEAK_FLOPS,
-    V5E_PEAK_FLOPS,
-)
+# the single home of the per-chip peaks (bench.py reads the same
+# table), so served MFU, bench MFU, and the roofline ceiling all
+# divide by one denominator
+from triton_client_tpu.obs.roofline import peak_flops
 
 
 class DeviceTimeLedger:
@@ -51,9 +48,11 @@ class DeviceTimeLedger:
     Flops metadata is learned lazily per model from the ``spec_extra``
     mapping the channel passes on each record (first one wins):
     ``flops_per_call`` — analytic flops of one launch at its serving
-    batch — and ``precision`` — the policy name keying
-    :data:`POLICY_PEAK_FLOPS`. Models without flops metadata still
-    account device-seconds; their MFU is simply not reported.
+    batch — and ``precision`` — the policy name ``peak_flops`` scales
+    the device's peak by. Models without flops metadata still account
+    device-seconds; their MFU is simply not reported — and neither is
+    anyone's on a ``device_kind`` (default: the live device's) that
+    ``obs.roofline.DEVICE_PEAKS`` does not list.
     """
 
     def __init__(
@@ -62,8 +61,10 @@ class DeviceTimeLedger:
         devices: int = 1,
         window_s: float = 60.0,
         buckets: int = 12,
+        device_kind: str | None = None,
     ) -> None:
         self._tenants = tenants
+        self._device_kind = device_kind
         self._devices = max(1, int(devices))
         self._window_s = float(window_s)
         self._n_buckets = max(2, int(buckets))
@@ -121,8 +122,8 @@ class DeviceTimeLedger:
                 flops = 0.0
             self._flops_per_call[model] = flops
             precision = str(spec_extra.get("precision") or "f32")
-            self._peak_flops[model] = POLICY_PEAK_FLOPS.get(
-                precision, V5E_PEAK_FLOPS
+            self._peak_flops[model] = (
+                peak_flops(precision, self._device_kind) or 0.0
             )
         now = time.perf_counter()
         idx = int(now / self._bucket_w)

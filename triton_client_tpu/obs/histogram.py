@@ -39,9 +39,9 @@ import math
 import threading
 
 # Upper bounds in seconds. Spans from 250us (a fast device_execute on a
-# warm small model) to 60s (a tunnel-degraded e2e); the +Inf overflow
-# bucket is implicit. Matches the spirit of profiling._BUCKETS but
-# extends both ends so per-stage spans and tunnel e2e both resolve.
+# warm small model) to 60s (an e2e over a degraded WAN link); the +Inf
+# overflow bucket is implicit. Matches the spirit of profiling._BUCKETS
+# but extends both ends so per-stage spans and WAN e2e both resolve.
 DEFAULT_BUCKETS: tuple[float, ...] = (
     0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,

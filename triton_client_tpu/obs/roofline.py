@@ -34,15 +34,22 @@ few ms of tracing the launcher already paid) and are recorded into
                                                       seed, kept as a
                                                       labeled
                                                       comparison only
+  pallas_kernels                                      Mosaic custom
+                                                      calls in the
+                                                      lowered launcher
+                                                      (0 = none, or
+                                                      interpreted)
   hlo_module                                          the jit module
                                                       name opstats maps
                                                       device ops back
                                                       to this model by
 
-This module is also the single home of the per-chip peaks: bench.py
-and obs/device_time.py used to carry duplicate POLICY_PEAK_FLOPS
-tables; both now import from here so served MFU, bench MFU, and the
-roofline all divide by the same denominator.
+This module is also the single home of the per-chip peaks, ONE table
+keyed by the ``device_kind`` jax reports: served MFU
+(obs/device_time.py), bench MFU and the roofline all divide by the same
+denominator, and a device that is not in the table gets NO figure — the
+MFU gauge stays absent and the ``roofline`` CLI says so — instead of
+another chip's peak.
 """
 
 from __future__ import annotations
@@ -50,35 +57,63 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-#: v5e per-chip peaks. The MXU runs f32 inputs at the bf16 MAC rate
+#: the ``device_kind`` jax reports for one TPU v5e chip
+V5E = "TPU v5 lite"
+
+#: Per-chip peaks keyed by ``jax.devices()[0].device_kind``. ``flops``
+#: is the bf16 MXU rate; the MXU runs f32 inputs at the bf16 MAC rate
 #: under jax's default precision, so f32/bf16/int8-weight policies all
-#: see the same flops ceiling; int8 activations double the MAC rate.
-V5E_PEAK_FLOPS = 197e12
-#: v5e HBM2 bandwidth per chip (bytes/s) — the roofline's memory slope.
-V5E_PEAK_HBM_BPS = 819e9
-
-POLICY_PEAK_FLOPS = {
-    "f32": V5E_PEAK_FLOPS,
-    "bf16": V5E_PEAK_FLOPS,
-    "int8w": V5E_PEAK_FLOPS,
-    "int8": 2 * V5E_PEAK_FLOPS,
-}
-#: HBM bandwidth is precision-independent (the bytes themselves shrink
-#: with narrower dtypes — that is already in the measured byte count).
-POLICY_PEAK_BYTES = {
-    "f32": V5E_PEAK_HBM_BPS,
-    "bf16": V5E_PEAK_HBM_BPS,
-    "int8w": V5E_PEAK_HBM_BPS,
-    "int8": V5E_PEAK_HBM_BPS,
+#: see the same ceiling and int8 activations double it
+#: (_POLICY_MXU_RATE). HBM bandwidth is precision-independent — the
+#: bytes themselves shrink with narrower dtypes, which is already in
+#: the measured byte count.
+DEVICE_PEAKS = {
+    V5E: {
+        "flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+        "bf16, 16 GB HBM2e at 819 GB/s per chip",
+    },
 }
 
-
-def peak_flops(precision: str | None) -> float:
-    return POLICY_PEAK_FLOPS.get(str(precision or "f32"), V5E_PEAK_FLOPS)
+_POLICY_MXU_RATE = {"f32": 1.0, "bf16": 1.0, "int8w": 1.0, "int8": 2.0}
 
 
-def peak_bytes_per_s(precision: str | None) -> float:
-    return POLICY_PEAK_BYTES.get(str(precision or "f32"), V5E_PEAK_HBM_BPS)
+def device_info() -> dict:
+    """The device this process computes on, as jax reports it —
+    ``serve`` prints it first, ``/snapshot["device"]`` carries it and
+    ``chip_smoke.py`` ends with it, so no reading can be mistaken for
+    another device's."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def _peaks(device_kind: str | None) -> dict | None:
+    if device_kind is None:
+        device_kind = device_info()["kind"]
+    return DEVICE_PEAKS.get(device_kind)
+
+
+def peak_flops(precision: str | None, device_kind: str | None = None):
+    """Peak flop/s of ``device_kind`` (default: the live device) under
+    a precision policy; ``None`` for a device not in DEVICE_PEAKS."""
+    peaks = _peaks(device_kind)
+    if peaks is None:
+        return None
+    return peaks["flops"] * _POLICY_MXU_RATE.get(str(precision or "f32"), 1.0)
+
+
+def peak_bytes_per_s(device_kind: str | None = None):
+    """Peak HBM bytes/s of ``device_kind`` (default: the live device);
+    ``None`` for a device not in DEVICE_PEAKS."""
+    peaks = _peaks(device_kind)
+    return None if peaks is None else peaks["hbm_bytes_per_s"]
 
 
 @dataclass
@@ -89,7 +124,8 @@ class RooflineRow:
     bytes: float
     precision: str = "f32"
     batch: int = 1
-    #: derived
+    #: derived — knee/bound/attainable stay at their defaults for a
+    #: device that is not in DEVICE_PEAKS
     intensity: float = 0.0
     knee: float = 0.0
     bound: str = "unknown"
@@ -115,24 +151,32 @@ def classify(
     bytes_accessed: float,
     precision: str = "f32",
     batch: int = 1,
+    device_kind: str | None = None,
 ) -> RooflineRow:
     """Roofline position of one launch: arithmetic intensity against
     the machine knee, the binding ceiling, and the attainable call/fps
     rate if ONLY that ceiling bound (the ideal-overlap upper bound an
-    actual serving rate is compared to)."""
+    actual serving rate is compared to). ``device_kind`` defaults to
+    the live device; one that is not in DEVICE_PEAKS yields the
+    intensity alone (``bound == "unknown"``, no knee, no ceiling)."""
     flops = max(0.0, float(flops or 0.0))
     bytes_accessed = max(0.0, float(bytes_accessed or 0.0))
     batch = max(1, int(batch or 1))
-    pf, pb = peak_flops(precision), peak_bytes_per_s(precision)
     row = RooflineRow(
         flops=flops, bytes=bytes_accessed, precision=str(precision or "f32"),
-        batch=batch, knee=pf / pb,
+        batch=batch,
     )
+    if flops > 0 or bytes_accessed > 0:
+        row.intensity = (
+            flops / bytes_accessed if bytes_accessed > 0 else float("inf")
+        )
+    pf = peak_flops(precision, device_kind)
+    pb = peak_bytes_per_s(device_kind)
+    if pf is None or pb is None:
+        return row
+    row.knee = pf / pb
     if flops <= 0 and bytes_accessed <= 0:
         return row
-    row.intensity = flops / bytes_accessed if bytes_accessed > 0 else float(
-        "inf"
-    )
     compute_rate = pf / flops if flops > 0 else float("inf")
     memory_rate = pb / bytes_accessed if bytes_accessed > 0 else float("inf")
     row.bound = "compute" if compute_rate <= memory_rate else "bandwidth"
@@ -184,14 +228,19 @@ def measure_launch_cost(launcher, *args, batch_rows: int = 1) -> dict:
     no backend compile, so calling this next to the first launch adds
     milliseconds to a path that is about to pay a full compile anyway.
 
-    Returns ``{"flops", "bytes", "batch"}`` (zeros when the cost model
-    reports nothing)."""
+    Returns ``{"flops", "bytes", "batch", "pallas_kernels"}`` (zeros
+    when the cost model reports nothing). ``pallas_kernels`` counts the
+    Mosaic custom calls in the lowered module: a Pallas kernel lowers
+    to one on a TPU and to plain ops under the interpreter, so the
+    count says whether the launcher the channel is about to compile
+    really holds its fused stages as kernels."""
     lowered = launcher.lower(*args)
     cost = _cost_dict(lowered.cost_analysis())
     return {
         "flops": float(cost.get("flops", 0.0) or 0.0),
         "bytes": float(cost.get("bytes accessed", 0.0) or 0.0),
         "batch": max(1, int(batch_rows or 1)),
+        "pallas_kernels": lowered.as_text().count("tpu_custom_call"),
     }
 
 
@@ -214,11 +263,16 @@ def record_launch_cost(model, launcher, *args, batch_rows: int = 1) -> dict:
     extra["measured_flops_per_call"] = measured["flops"]
     extra["measured_bytes_per_call"] = measured["bytes"]
     extra["measured_batch"] = measured["batch"]
+    extra["pallas_kernels"] = measured["pallas_kernels"]
     extra.setdefault("hlo_module", hlo_module_for(model))
     return measured
 
 
-def model_row(extra: dict, measured_fps: float | None = None) -> dict:
+def model_row(
+    extra: dict,
+    measured_fps: float | None = None,
+    device_kind: str | None = None,
+) -> dict:
     """Roofline report row from a model's ``spec.extra`` (the shape the
     collector's ``models`` snapshot section and the ``roofline`` CLI
     share). ``measured_fps`` — when known — is reported next to the
@@ -227,7 +281,7 @@ def model_row(extra: dict, measured_fps: float | None = None) -> dict:
     bytes_ = float(extra.get("measured_bytes_per_call") or 0.0)
     batch = int(extra.get("measured_batch") or 1)
     precision = str(extra.get("precision") or "f32")
-    row = classify(flops, bytes_, precision, batch).as_dict()
+    row = classify(flops, bytes_, precision, batch, device_kind).as_dict()
     analytic = extra.get("analytic_flops_per_call")
     if analytic is not None:
         row["analytic_flops_per_call"] = float(analytic)

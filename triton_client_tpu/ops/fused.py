@@ -15,10 +15,13 @@ specific wins:
     opt-out: the resolved stage list is published as
     ``spec.extra["fused_stages"]`` so remote clients and bench rows can
     see exactly which fusions a served model runs.
-  * backend — ``auto`` routes fused only on a real TPU backend (XLA is
-    faster than interpret mode on CPU); ``on`` forces the fusion
-    everywhere, running the SAME kernels under the Pallas interpreter
-    (how the tier-1 parity matrix pins kernel numerics on CPU).
+  * backend — ``auto`` routes fused only on a real TPU backend, where
+    the kernels are compiled by Mosaic and never interpreted; ``on``
+    forces the fusion everywhere, which off-TPU means the SAME kernels
+    under the Pallas interpreter (how the tier-1 parity matrix pins
+    kernel numerics on CPU). Nothing falls back: a kernel Mosaic
+    refuses fails the model's first compile, it is not swapped for
+    the interpreter or the XLA reference.
 
 Stage names are the shared vocabulary between pipelines, bench rows,
 ``obs/opstats`` per-stage attribution and ``perf/profile_fused``:
@@ -52,8 +55,10 @@ def _env_stages() -> tuple[str, ...] | None:
 
 
 def fused_interpret() -> bool:
-    """Whether fused kernels must run under the Pallas interpreter
-    (everywhere but a real TPU backend — same rule as ops.nms)."""
+    """Whether a Pallas kernel built now runs under the interpreter:
+    never on a TPU backend, always off it (where only ``fused="on"``,
+    a forced NMS mode or a test reaches a kernel at all). The one
+    backend probe every kernel call site shares."""
     import jax
 
     return jax.default_backend() != "tpu"

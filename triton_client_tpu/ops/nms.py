@@ -96,6 +96,7 @@ def nms(
     n = boxes.shape[0]
     mode = _nms_mode(n, max_det)
     if mode == "pallas":
+        from triton_client_tpu.ops import fused
         from triton_client_tpu.ops.pallas_nms import nms_pallas
 
         return nms_pallas(
@@ -103,8 +104,8 @@ def nms(
             scores,
             iou_thresh=iou_thresh,
             max_det=max_det,
-            # Off-TPU (forced via env) the kernel runs interpreted.
-            interpret=jax.default_backend() != "tpu",
+            # forced via env: compiled on a TPU, interpreted off it
+            interpret=fused.fused_interpret(),
         )
     if mode == "fixpoint":
         return _nms_fixpoint(boxes, scores, iou_thresh, max_det=max_det)

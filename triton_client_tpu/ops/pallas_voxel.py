@@ -3,8 +3,7 @@
 The XLA scatter that dominates ``second_iou`` device time is
 ``models/second._scatter_mean_volume``: a 131k-row scatter-ADD with
 duplicate indices into the (n_cells+1, f+1) accumulator — XLA lowers
-duplicate-index adds to a serialized update chain (~5 ms/scan measured,
-BASELINE.md). This module replaces the whole voxelize->scatter stage
+duplicate-index adds to a serialized update chain. This module replaces the whole voxelize->scatter stage
 with the ragged-TPU formulation (*Ragged Paged Attention*, PAPERS.md):
 
   1. XLA prologue (cheap, fully parallel): cell assignment + one
@@ -75,23 +74,36 @@ def pipeline_mode() -> str:
     return mode if mode in ("grid", "manual") else "grid"
 
 
-def _accum_block(out_ref, valsT, slots_row, base, *, window):
+def _accum_block(out_ref, valsT, slots_row, base_tile, *, window):
     """Shared reduce step: one (8, block) values block x its one-hot
     slot selector into the VMEM accumulator's 128-aligned window.
     ``slots_row``: (1, block) int32 sorted slots — lane-major, so the
     block tiles VMEM exactly (a (block, 1) column would pad 128x,
-    TPL801); ``base``: scalar 128-aligned window start. Slots outside
-    the window (the dump slot of a mixed real/pad block) compare false
-    everywhere and vanish — their value rows are pre-zeroed by the
-    validity weight anyway."""
+    TPL801); ``base_tile``: scalar window start in 128-lane tiles.
+    Slots outside the window (the dump slot of a mixed real/pad block)
+    compare false everywhere and vanish — their value rows are
+    pre-zeroed by the validity weight anyway."""
+    # The window start is a dynamic SMEM scalar; Mosaic only lowers a
+    # dynamic lane-dim slice it can PROVE 128-aligned ("cannot
+    # statically prove that index in dimension 1 is a multiple of
+    # 128"). Carrying the start in tiles makes it aligned by
+    # construction here, where the slice is taken — not by a promise
+    # about what the caller computed.
+    base = pl.multiple_of(base_tile * _LANES, _LANES)
     block = slots_row.shape[1]
     local = slots_row - base
     col = jax.lax.broadcasted_iota(jnp.int32, (window, block), 0)
     onehotT = (col == local).astype(jnp.float32)  # (window, block)
+    # HIGHEST: at the default precision the MXU rounds its f32 operands
+    # to bf16 — 8 mantissa bits, so a coordinate at 64 m lands on a
+    # 0.25 m lattice (measured on a v5e: per-voxel means off by 0.2499 m
+    # from a float64 reference; the interpreter cannot show it). The
+    # one-hot side is exact either way; the value side needs all passes.
     contrib = jax.lax.dot_general(
         valsT,
         onehotT,
         (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )  # (8, window): same contraction over the block dim as the old
     # (block, window) one-hot, elementwise-identical operands — bitwise
@@ -214,9 +226,10 @@ def sorted_segment_mean_pallas(
     v_out = ((num_slots + 1 + _WINDOW + _LANES - 1) // _LANES) * _LANES
     count_row = _SUBLANES - 1
 
-    # 128-aligned window base per block, from each block's first (lowest)
-    # slot — scalar-prefetched so both kernel forms read it from SMEM.
-    bases = (slots[::POINT_BLOCK] // _LANES) * _LANES
+    # Window start per block in 128-lane tiles, from each block's first
+    # (lowest) slot — scalar-prefetched so both kernel forms read it
+    # from SMEM and scale it back to a provably aligned lane offset.
+    bases = slots[::POINT_BLOCK] // _LANES
     slots_row = slots.reshape(1, n)
 
     if pipeline == "manual":
@@ -231,8 +244,8 @@ def sorted_segment_mean_pallas(
             num_scalar_prefetch=1,
             grid=(),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         )

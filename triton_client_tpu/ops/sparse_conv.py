@@ -3,7 +3,7 @@
 The reference's SECOND-IoU runs spconv CUDA sparse convolutions at
 0.05 m voxels (examples/second_iou/1/model.py:96-157; built at
 docker/server_3d/Dockerfile:41-55). The dense emulation tops out at
-0.1 m (the 0.05 m volume is 5.4 GB — BASELINE.md grid sweep), while
+0.1 m (the 0.05 m volume is 5.4 GB), while
 occupancy is only ~60k voxels of 90M cells, so this module implements
 the sparse stack the TPU way: static shapes everywhere, gathers +
 per-offset MXU matmuls instead of hash-table rulebooks.

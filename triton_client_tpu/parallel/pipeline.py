@@ -30,9 +30,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from triton_client_tpu.parallel._compat import shard_map
 from triton_client_tpu.parallel.mesh import PIPE_AXIS
 
 StageFn = Callable[..., jnp.ndarray]

@@ -38,9 +38,9 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from triton_client_tpu.parallel._compat import shard_map
 from triton_client_tpu.parallel.mesh import SEQ_AXIS
 
 _NEG = -1e30  # soft -inf: keeps exp() finite for fully-masked rows

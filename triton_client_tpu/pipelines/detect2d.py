@@ -29,7 +29,7 @@ from triton_client_tpu.ops.detect_postprocess import (
     extract_boxes,
     extract_boxes_scored,
 )
-from triton_client_tpu.ops.fused import fused_interpret, resolve_fused_stages
+from triton_client_tpu.ops import fused as fused_routing
 from triton_client_tpu.ops.preprocess import normalize_image
 from triton_client_tpu.runtime.precision import (
     KEEP_F32_2D,
@@ -83,7 +83,9 @@ class Detect2DPipeline:
         self.config = config
         self._forward = forward
         self.precision = PrecisionPolicy.parse(precision)
-        self.fused_stages = resolve_fused_stages(config.fused, ("decode_nms",))
+        self.fused_stages = fused_routing.resolve_fused_stages(
+            config.fused, ("decode_nms",)
+        )
         self._jit = jax.jit(self._pipeline, static_argnames=("orig_hw",))
 
     def _pipeline(
@@ -106,7 +108,7 @@ class Detect2DPipeline:
         # regardless of policy
         pred = self.precision.boundary(self._forward(x))
         fuse_tail = "decode_nms" in self.fused_stages
-        interpret = fused_interpret()
+        interpret = fused_routing.fused_interpret()
         if cfg.head_style == "scored":
             boxes_scores = pred
             dets, valid = extract_boxes_scored(

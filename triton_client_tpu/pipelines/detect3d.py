@@ -36,7 +36,7 @@ from triton_client_tpu.ops.detect3d_postprocess import (
     extract_boxes_3d,
     nms_pack_3d,
 )
-from triton_client_tpu.ops.fused import fused_interpret, resolve_fused_stages
+from triton_client_tpu.ops import fused as fused_routing
 from triton_client_tpu.ops.pallas_decode import fused_residual_decode
 from triton_client_tpu.ops.pallas_voxel import fused_mean_volume
 from triton_client_tpu.ops.voxelize import pad_points, voxelize
@@ -57,7 +57,7 @@ class Detect3DConfig:
     # NMS candidate width (top-k on raw logits before box decode).
     # 256 measured mAP-identical to 512 on the trained closed-loop
     # model while saving ~1.7 ms/scan — the rotated-IoU matrix is
-    # quadratic in this (BASELINE.md round-3 floor campaign); raise it
+    # quadratic in this; raise it
     # for scenes with hundreds of above-threshold objects
     pre_max: int = 256
     point_buckets: tuple[int, ...] = (32768, 65536, 131072)
@@ -147,7 +147,9 @@ class Detect3DPipeline:
             and hasattr(model, "from_volume")
         ):
             candidates = ("voxelize_scatter",) + candidates
-        self.fused_stages = resolve_fused_stages(config.fused, candidates)
+        self.fused_stages = fused_routing.resolve_fused_stages(
+            config.fused, candidates
+        )
         if "voxelize_scatter" in self.fused_stages:
             logger.info(
                 "fused voxelize->scatter caps occupied cells at max_voxels "
@@ -165,7 +167,7 @@ class Detect3DPipeline:
         # realize — HBM reads stay int8); voxelize below always sees the
         # f32 cloud (KEEP_F32_3D: cell coords are precision-sensitive)
         variables = realize(self.variables)
-        interpret = fused_interpret()
+        interpret = fused_routing.fused_interpret()
         if "voxelize_scatter" in self.fused_stages:
             # fused Pallas voxelize->scatter: sorted-segment mean via
             # MXU one-hot matmuls + unique-index set-scatter epilogue,

@@ -1,14 +1,14 @@
 """Continuous batching: windowless EDF admission + packed ragged batches.
 
 ISSUE 8's tentpole. The window batcher (``runtime/batching.py``) pays
-two taxes BENCH_r05 made visible:
+two taxes:
 
   * **the window barrier** — requests pool behind an admission window
     even when an execution slot is free, so under open-loop traffic the
     device idles while arrivals wait for a timer;
   * **the padding tax** — every merge group rounds up to a static
-    power-of-two bucket (229/721 served frames were padding, ~32% of
-    device work), and variable-size 3D inputs pad to the widest member
+    power-of-two bucket (up to a third of device work in one served
+    run), and variable-size 3D inputs pad to the widest member
     besides.
 
 This scheduler removes both, keeping the proven dispatch machinery

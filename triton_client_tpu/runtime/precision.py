@@ -1,7 +1,7 @@
 """Per-model serving precision policies: f32 | bf16 | int8w | int8.
 
-BENCH_r05 pinned MFU at 2.3-4.1% across yolov5n/pointpillars — the
-perception models this stack serves are HBM-bandwidth-bound, so the
+The small perception models this stack serves use a few percent of
+the MXU — they are HBM-bandwidth-bound — so the
 largest single-chip lever left (after dispatch overlap and data-parallel
 sharding) is moving fewer bytes per call. TPUs run bf16 and int8 on the
 MXU natively; production TPU serving stacks treat precision as a
