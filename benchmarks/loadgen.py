@@ -1,0 +1,229 @@
+"""Load generation: the benchmark's own copy.
+
+Copied in substance from ``triton_client_tpu/utils/loadgen.py``
+(``poisson_schedule``, ``run_open_loop``, ``co_percentile``,
+``run_pool``), which is sound: arrivals are a pure function of the
+seed (here: one fixed set of exponential gaps, permuted by the seed),
+the dispatcher never waits for a response, and latency runs from the
+SCHEDULED arrival, so a stall is charged to every request it delays.
+What is added is what a benchmark needs and the original does not
+record: how late the generator sent each request, and every response
+handed to a checker. This module never imports jax.
+
+A traffic file's ``loop`` names its kind. ``run.py`` looks up
+``<loop>_loop`` (one measured window) and ``<loop>_sample`` (every
+request of the output check's sample once, in flight together as the
+cell's traffic would have them) here by that name; all kinds take the
+same arguments.
+"""
+
+from __future__ import annotations
+
+import queue
+import threading
+import time
+
+import numpy as np
+
+
+def poisson_schedule(rate: float, duration_s: float, rng: np.random.Generator) -> np.ndarray:
+    """Arrival offsets in seconds from window start: ``rate x
+    duration_s`` exponential gaps, the SAME gaps for every seed (drawn
+    from a fixed stream and scaled to fill the window), in an order the
+    run's seed fixes. Every seed then offers the same amount of work
+    and the same bursts, somewhere else in the window; with gaps drawn
+    anew per seed the offered count alone moved by 2.5% from run to
+    run, and the latencies with it."""
+    n = max(1, int(round(rate * duration_s)))
+    gaps = np.random.default_rng(20250927).exponential(1.0 / rate, n + 1)
+    gaps *= duration_s / gaps.sum()
+    return np.cumsum(rng.permutation(gaps))[:n]
+
+
+def co_percentile(latencies_ms, scheduled: int, q: float) -> float:
+    """Percentile over ALL scheduled requests: one that never completed
+    counts as infinitely late (the coordinated-omission-safe form)."""
+    lat = np.sort(np.asarray(latencies_ms, float))
+    if scheduled <= 0:
+        return float("nan")
+    rank = int(np.ceil(q / 100.0 * scheduled)) - 1
+    return float(lat[rank]) if rank < len(lat) else float("inf")
+
+
+class Window:
+    """What one measured window recorded."""
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.attempted = 0
+        self.failed = 0
+        self.items_done = 0
+        self.latencies_ms: list[float] = []
+        self.done_at_s: list[float] = []  # completion times from window start
+        self.late_ms: list[float] = []
+        self.errors: list[str] = []
+        self.malformed = 0
+        self.t_start = 0.0
+        self.t_end = 0.0
+
+    def span_s(self) -> float:
+        """The length of the measured window."""
+        return self.t_end - self.t_start
+
+    def record(self, response, items: int, t_ref: float, check) -> None:
+        """A completed request: its latency always counts; its items
+        count as done only if it completed inside the window."""
+        t_done = time.perf_counter()
+        bad = check(response) if check is not None else None
+        with self.lock:
+            self.latencies_ms.append((t_done - t_ref) * 1e3)
+            self.done_at_s.append(t_done - self.t_start)
+            if t_done <= self.t_end:
+                self.items_done += items
+            if bad:
+                self.malformed += 1
+                if len(self.errors) < 5:
+                    self.errors.append(bad)
+
+    def timeline(self) -> list[list[float]]:
+        """Per second of the window: completions and their median
+        latency in ms (for the builder's log: when did it stall?)."""
+        sec = np.floor(np.asarray(self.done_at_s)).astype(int)
+        lat = np.asarray(self.latencies_ms)
+        return [[int(s), int((sec == s).sum()), round(float(np.median(lat[sec == s])), 1)]
+                for s in sorted(set(sec.tolist()))]
+
+    def fail(self, error) -> None:
+        with self.lock:
+            self.failed += 1
+            if len(self.errors) < 5:
+                self.errors.append(repr(error))
+
+
+def open_loop(make_channel, channel, requests, traffic: dict, seconds: float,
+              rng: np.random.Generator, check=None, rate=None, resolvers: int = 32) -> Window:
+    """One open-loop window at a FIXED offered rate (the traffic file's
+    ``rate_per_s``; ``rate`` is the builder's sweep). One thread walks
+    the schedule and issues non-blocking calls; ``resolvers`` threads
+    wait for the responses. ``requests`` is the cell's seeded pool; the
+    arrivals draw from it in an order the seed fixes. The window's
+    length is ``seconds``; a request due inside it that finishes after
+    it counts at its true latency, and completes nothing in the window."""
+    items_per_request = int(traffic["items_per_request"])
+    offsets = poisson_schedule(float(rate or traffic["rate_per_s"]), seconds, rng)
+    picks = rng.integers(0, len(requests), len(offsets))
+    win = Window()
+    win.attempted = len(offsets)
+    pending: queue.Queue = queue.Queue()
+
+    def resolve_loop() -> None:
+        while True:
+            item = pending.get()
+            if item is None:
+                return
+            t_due, future = item
+            try:
+                response = future.result()
+            except Exception as e:  # a failed request completes nothing
+                win.fail(e)
+                continue
+            win.record(response, items_per_request, t_due, check)
+
+    workers = [threading.Thread(target=resolve_loop, daemon=True) for _ in range(resolvers)]
+    for w in workers:
+        w.start()
+    win.t_start = t0 = time.perf_counter()
+    win.t_end = t0 + seconds
+    for off, pick in zip(offsets, picks):
+        due = t0 + float(off)
+        delay = due - time.perf_counter()
+        if delay > 0:
+            time.sleep(delay)
+        win.late_ms.append((time.perf_counter() - due) * 1e3)
+        pending.put((due, channel.do_inference_async(requests[pick])))
+    for _ in workers:
+        pending.put(None)
+    for w in workers:
+        w.join()
+    return win
+
+
+def open_sample(make_channel, channel, requests, traffic: dict) -> list:
+    """Every request once, issued at the cell's rate; the responses in
+    the requests' order."""
+    gap = 1.0 / float(traffic["rate_per_s"])
+    futures = []
+    for r in requests:
+        futures.append(channel.do_inference_async(r))
+        time.sleep(gap)
+    return [f.result() for f in futures]
+
+
+def closed_loop(make_channel, channel, requests, traffic: dict, seconds: float,
+                rng: np.random.Generator, check=None, rate=None) -> Window:
+    """``clients`` callers, each with a channel of its own, each sending
+    its next request when the last one answered, for ``seconds``. The
+    window runs from the start to the answer to the last request sent
+    before ``seconds`` were over, and every request sent counts: all
+    the work over all the time. (Cut at ``seconds`` sharp, a window of
+    a hundred launches of some hundred frames each reads one of two
+    values, a launch apart, by where its end falls between two
+    answers; the time to the last answer is continuous.)"""
+    items_per_request, clients = int(traffic["items_per_request"]), int(traffic["clients"])
+    win = Window()
+    orders = [rng.permutation(len(requests)) for _ in range(clients)]
+    channels = [make_channel() for _ in range(clients)]
+    start = threading.Barrier(clients + 1)
+    deadline = [0.0]
+
+    def client(channel, order) -> None:
+        start.wait()
+        i = 0
+        while True:
+            t_send = time.perf_counter()
+            if t_send >= deadline[0]:
+                return
+            request = requests[order[i % len(order)]]
+            i += 1
+            with win.lock:
+                win.attempted += 1
+            try:
+                response = channel.do_inference(request)
+            except Exception as e:
+                win.fail(e)
+                continue
+            win.record(response, items_per_request, t_send, check)
+
+    threads = [threading.Thread(target=client, args=(c, o), daemon=True) for c, o in zip(channels, orders)]
+    try:
+        for t in threads:
+            t.start()
+        win.t_start = time.perf_counter()
+        deadline[0] = win.t_start + seconds
+        win.t_end = float("inf")  # until the last answer is in
+        start.wait()
+        for t in threads:
+            t.join()
+        win.t_end = win.t_start + max(win.done_at_s, default=seconds)
+    finally:
+        for c in channels:
+            c.close()
+    return win
+
+
+def closed_sample(make_channel, channel, requests, traffic: dict) -> list:
+    """Every request once, from the cell's ``clients`` callers side by
+    side; the responses in the requests' order."""
+    responses = [None] * len(requests)
+    lanes = int(traffic["clients"])
+
+    def lane(k: int) -> None:
+        for i in range(k, len(requests), lanes):
+            responses[i] = channel.do_inference(requests[i])
+
+    threads = [threading.Thread(target=lane, args=(k,)) for k in range(lanes)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return responses

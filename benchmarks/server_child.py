@@ -1,0 +1,451 @@
+#!/usr/bin/env python3
+"""The one process that holds the chip.
+
+Started by ``run.py`` (which never touches jax). It makes the seeded
+weights and writes them as ``weights.msgpack`` into a temporary model
+repository, stands the server up through the code ``python -m
+triton_client_tpu serve`` runs (argv parser -> ``build_server`` ->
+``start``), evaluates the configuration's plain reference on the seeded
+sample, compiles every launch shape the cell's traffic can form, and
+then answers the parent's commands, one JSON object a line on the
+stream it was given as stdout (everything else this process prints
+goes to stderr):
+
+    -> {"ready": ...}                      after set-up
+    <- {"cmd": "profile", "seconds": s, "after_s": a}   trace the device for s seconds, a seconds from now
+    <- {"cmd": "finish"}                   drain, report, exit
+    -> {"done": ...}
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import importlib
+import json
+import os
+import pathlib
+import sys
+import threading
+import time
+
+T0 = time.perf_counter()
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+import numpy as np  # noqa: E402
+
+
+def seeded(seed: int, stream: int) -> np.random.Generator:
+    """Stream 0: calibration input; 1: the sample and request pool;
+    2: arrivals and order. ``run.py`` draws the same streams."""
+    return np.random.default_rng([int(seed), stream])
+
+
+def load_json(path) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def apply_rehearsal(cfg: dict) -> dict:
+    """Tiny sizes for a CPU rehearsal: the configuration's own
+    ``rehearsal`` block overrides ``model``, ``weights`` and ``check``
+    keys (never used on a chip)."""
+    cfg = json.loads(json.dumps(cfg))
+    for block in ("model", "weights", "check"):
+        for key, value in cfg["rehearsal"].get(block, {}).items():
+            if isinstance(value, dict):
+                cfg[block][key] = {**cfg[block][key], **value}
+            else:
+                cfg[block][key] = value
+    return cfg
+
+
+def rehearsal_traffic(traffic: dict, cfg: dict) -> dict:
+    """The traffic mix at a CPU rehearsal's sizes: the configuration's
+    ``rehearsal.traffic`` block overrides the mix's own keys (a batch
+    that fills a quarter of a chip is minutes of interpreted kernels)."""
+    return {**traffic, **cfg["rehearsal"].get("traffic", {})}
+
+
+def input_params(traffic: dict, cfg: dict, rehearse: bool) -> dict:
+    """The traffic mix's input parameters (a rehearsal shrinks them)."""
+    params = traffic["inputs"]["params"]
+    if rehearse:
+        params = {**params, **cfg["rehearsal"].get("traffic_params", {})}
+    return params
+
+
+def first_items(batch: dict, n: int) -> dict:
+    """The first ``n`` items of a stacked sample."""
+    return {k: v[:n] for k, v in batch.items()}
+
+
+def entry_doc(cfg: dict, rehearse: bool, precision: str | None) -> dict:
+    """The committed entry's config.yaml as served. A rehearsal shrinks
+    it and asks for the fused kernels (interpreted off a TPU); the
+    output check's control serves it at a lower precision."""
+    from triton_client_tpu.dataset_config import load_yaml
+
+    doc = load_yaml(str(ROOT / cfg["entry"] / "config.yaml"))
+    pipeline = dict(doc.get("pipeline", {}))
+    if "class_names_file" in pipeline:
+        pipeline["class_names_file"] = str(ROOT / pipeline["class_names_file"])
+    if rehearse:
+        if "dataset" in doc:  # inline the dataset yaml so it can shrink
+            dataset = load_yaml(str(ROOT / doc.pop("dataset")))
+            dataset.pop("model")
+            pipeline = {**dict(dataset.pop("pipeline", {})), **pipeline}
+            dataset["voxel"] = {**dataset["voxel"], **cfg["rehearsal"]["model"]["voxel"]}
+            doc["model"] = dataset
+            pipeline["point_buckets"] = [cfg["rehearsal"]["model"]["point_bucket"]]
+        else:
+            doc["model"] = {**doc["model"], "input_hw": cfg["rehearsal"]["model"]["input_hw"]}
+        pipeline["fused"] = "on"
+    elif "dataset" in doc:
+        doc["dataset"] = str(ROOT / doc["dataset"])
+    if pipeline:
+        doc["pipeline"] = pipeline
+    if precision:
+        doc["model"] = {**dict(doc.get("model", {})), "precision": precision}
+    doc["max_batch_size"] = int(cfg["max_batch_size"])  # the configuration's, where it departs from the entry's
+    return doc
+
+
+def calibration_input(generator, traffic: dict, params: dict, cfg: dict, seed: int) -> dict:
+    """The seeded input the weights' batch-norm statistics are taken
+    on: ``weights.calibration_items`` items of the cell's own traffic
+    (``weights.calibration_params`` overrides the mix's input
+    parameters, so that eight frames are not cut from 768 drawn)."""
+    items = int(cfg["weights"]["calibration_items"])
+    params = {**params, **cfg["weights"].get("calibration_params", {})}
+    made = generator.make(seeded(seed, 0), items, params, cfg)  # at most one item a request too many
+    return first_items(stack_requests(made, cfg), items)
+
+
+def make_weights(reference, cfg: dict, seed: int, calibration: dict):
+    """Seeded weights on the device, in one jitted call whose program
+    does not depend on the seed (so the compile cache holds it)."""
+    import jax
+
+    key = jax.random.wrap_key_data(
+        np.asarray([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
+    )
+    return jax.jit(lambda k, c: reference.init_params(k, c, cfg))(key, calibration)
+
+
+def write_repository(root: pathlib.Path, cfg: dict, tree, rehearse: bool,
+                     precision: str | None = None) -> str:
+    """``<root>/<entry name>/config.yaml`` + ``1/weights.msgpack``: the
+    program's normal loading path. Returns the served model's name.
+    Without a ``tree`` the entry has no weights and loads at its own
+    initialisation (``program_temp_bytes``)."""
+    import flax.serialization
+    import jax
+    import yaml
+
+    name = pathlib.Path(cfg["entry"]).name
+    version = root / name / "1"
+    version.mkdir(parents=True)
+    with open(root / name / "config.yaml", "w") as f:
+        yaml.safe_dump(entry_doc(cfg, rehearse, precision), f, sort_keys=False)
+    if tree is not None:
+        host = jax.tree_util.tree_map(np.asarray, tree)
+        (version / "weights.msgpack").write_bytes(flax.serialization.to_bytes(host))
+    return name
+
+
+def stack_requests(requests: list[dict], cfg: dict) -> dict:
+    """The sample as one batch for the reference: requests that carry
+    a batch axis (the configuration's ``request_batch_axis``) are
+    concatenated, the others stacked."""
+    join = np.concatenate if cfg["request_batch_axis"] else np.stack
+    return {k: join([r[k] for r in requests]) for k in requests[0]}
+
+
+def run_reference(reference, cfg: dict, tree, requests: list[dict], out_path: pathlib.Path) -> dict:
+    """The plain float32 reference over the sample's first
+    ``check.sample_items`` items in one call (a request of the replay
+    cells holds more frames than that: the limits were read on this
+    many, and the reference's memory stays far under the served
+    path's); its rows go to ``out_path`` for the parent's comparison.
+
+    The same program is run once more with every parameter rounded to
+    bfloat16 and back (no new compile: the weights are an argument).
+    How far that moves the scores is this seed's ``sensitivity``: some
+    seeds' weights pass a rounding error on at twice the size others
+    do, and the comparison divides by it so that one limit fits all."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import compare
+
+    batch = first_items(stack_requests(requests, cfg), int(cfg["check"]["sample_items"]))
+    forward = jax.jit(lambda t, x: reference.forward(t, x, cfg))
+    rounded = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16).astype(x.dtype), tree)
+    items, moved = (
+        reference.detections(jax.tree_util.tree_map(np.asarray, forward(t, batch)), cfg)
+        for t in (tree, rounded)
+    )
+    pipe = cfg["pipeline"]
+    shift = compare.compare(
+        [it["rows"] for it in moved], [it["rows"] for it in items], "boxes", reference.BOX_COLS,
+        10**9, pipe.get("conf_thresh", pipe.get("score_thresh")), cfg["check"],
+    )
+    np.savez(
+        out_path,
+        **{f"rows_{i}": it["rows"] for i, it in enumerate(items)},
+        gated=np.asarray([it["gated"] for it in items]),
+        sensitivity=np.asarray(shift["score_err_rms"]),
+    )
+    return {
+        "items": len(items),
+        "boxes": [len(it["rows"]) for it in items],
+        "gated_max": int(max(it["gated"] for it in items)),
+        "sensitivity": shift["score_err_rms"],
+    }
+
+
+@contextlib.contextmanager
+def cache_subdirectory(cache_dir: str, name: str):
+    """Compile inside ``<cache_dir>/<name>`` (no-op where the cache is off)."""
+    if not cache_dir:
+        yield
+        return
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    sub = os.path.join(cache_dir, name)
+    os.makedirs(sub, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", sub)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        compilation_cache.reset_cache()
+
+
+def start_server(repo: pathlib.Path, argv: list[str]):
+    from triton_client_tpu.cli import serve
+
+    full = ["-r", str(repo), "-a", "127.0.0.1:0", "--metrics-port", "auto", *argv]
+    print("serve " + " ".join(full), flush=True)
+    args = serve.make_parser().parse_args(full)
+    server = serve.build_server(args)
+    server.start()
+    return server, args
+
+
+def device_channel(server):
+    channel = server.channel
+    while hasattr(channel, "inner"):
+        channel = channel.inner
+    return channel
+
+
+def compile_launch_shapes(server, name: str, request: dict, batch_sizes: list[int]) -> float:
+    """Compile every launch shape the batcher can form for this cell:
+    one request's rows repeated to each batch size, sent straight to
+    the device channel under the batcher (the front door cannot ask for
+    a merge size). The launches run side by side so the compiles do."""
+    from triton_client_tpu.channel.base import InferRequest
+
+    channel = device_channel(server)
+    t0 = time.perf_counter()
+    errors = []
+
+    def launch(b: int) -> None:
+        try:
+            if b:
+                inputs = {k: np.resize(v, (b, *v.shape[1:])) for k, v in request.items()}
+            else:  # the request as it is (3D: one scan, no batch axis)
+                inputs = request
+            channel.do_inference(InferRequest(name, inputs))
+        except Exception as e:  # reported, then raised on the main thread
+            errors.append(f"b{b}: {e!r}")
+
+    threads = [threading.Thread(target=launch, args=(b,)) for b in batch_sizes]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    if errors:
+        raise RuntimeError("; ".join(errors))
+    return time.perf_counter() - t0
+
+
+def program_temp_bytes(root: pathlib.Path, cfg: dict, request: dict, batch_sizes: list[int], kept_dir: str) -> int:
+    """The temporaries of the served device program at the cell's
+    largest launch shape, as XLA states them for this chip
+    (``memory_analysis().temp_size_in_bytes``). The allocator's
+    ``peak_bytes_in_use`` leaves a running program's temporaries out
+    (PERF.md section 2: a b8 launch raised it by 19 MB where one
+    layer's activations are 33 MB), so a chip's peak is that statistic
+    plus this. The entry is built by the program's own loading path at
+    its own initialisation: buffer sizes follow from shapes, not from
+    weights, and a program that does not change with the seed stays in
+    the compile cache. Building the entry and loading that program
+    took 21 s of every set-up, so the number is kept beside the compile
+    cache under a key of everything it can depend on: the
+    configuration, the shapes, the chip, the installation and every
+    source file of the program."""
+    import hashlib
+
+    import jax
+    import jaxlib
+    from triton_client_tpu.runtime.disk_repository import build_model
+
+    digest = hashlib.sha256(json.dumps(
+        [cfg, batch_sizes, {k: (v.shape, str(v.dtype)) for k, v in request.items()},
+         jax.devices()[0].device_kind, jax.__version__, jaxlib.__version__], sort_keys=True, default=str).encode())
+    for source in sorted((ROOT / "triton_client_tpu").rglob("*.py")):
+        digest.update(source.read_bytes())
+    kept = pathlib.Path(kept_dir) / f"program_temp_{digest.hexdigest()[:24]}.json" if kept_dir else None
+    if kept is not None and kept.exists():
+        return int(load_json(kept)["temp_size_in_bytes"])
+
+    argv = cfg["serve_argv"]
+    precision = argv[argv.index("--precision") + 1] if "--precision" in argv else None
+    name = write_repository(root, cfg, None, False, precision)
+    model = build_model(root / name)
+    temp = 0
+    for b in batch_sizes:
+        shapes = {
+            k: jax.ShapeDtypeStruct((b, *v.shape[1:]) if b else v.shape, v.dtype) for k, v in request.items()
+        }
+        analysis = jax.jit(model.device_fn).lower(shapes).compile().memory_analysis()
+        temp = max(temp, int(analysis.temp_size_in_bytes))
+    if kept is not None:
+        kept.parent.mkdir(parents=True, exist_ok=True)
+        kept.write_text(json.dumps({"temp_size_in_bytes": temp, "config": cfg["name"], "batch_sizes": batch_sizes}))
+    return temp
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser()
+    p.add_argument("--config", required=True)
+    p.add_argument("--traffic", required=True)
+    p.add_argument("--seed", type=int, required=True)
+    p.add_argument("--work", required=True)
+    p.add_argument("--chips", type=int, default=1)
+    p.add_argument("--trace", type=int, default=0)
+    p.add_argument("--rehearse", action="store_true")
+    p.add_argument("--record-trace", default="")
+    args = p.parse_args(argv)
+
+    # the protocol owns the original stdout; every other print -> stderr
+    proto = os.fdopen(os.dup(1), "w")
+    os.dup2(2, 1)
+    sys.stdout = sys.stderr
+
+    def say(obj) -> None:
+        proto.write(json.dumps(obj) + "\n")
+        proto.flush()
+
+    from triton_client_tpu.utils.compilation_cache import enable_persistent_cache
+
+    cache_dir = enable_persistent_cache()  # <checkout>/.jax_cache unless set outside
+    import jax
+
+    devices = jax.devices()
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    if not args.rehearse and (device["platform"] != "tpu" or len(devices) < args.chips):
+        print(f"benchmark needs {args.chips} TPU chip(s); jax found {device}", file=sys.stderr)
+        return 3
+    marks = {"jax_s": time.perf_counter() - T0}
+
+    cfg = load_json(ROOT / args.config)
+    traffic = load_json(ROOT / args.traffic)
+    if args.rehearse:
+        cfg = apply_rehearsal(cfg)
+        traffic = rehearsal_traffic(traffic, cfg)
+    reference = importlib.import_module(f"benchmarks.references.{cfg['reference']}")
+    generator = importlib.import_module(f"benchmarks.inputs.{traffic['inputs']['generator']}")
+    params = input_params(traffic, cfg, args.rehearse)
+
+    work = pathlib.Path(args.work)
+    n_requests = max(1, cfg["check"]["sample_items"] // traffic["items_per_request"])
+    if args.rehearse:
+        n_requests = min(n_requests, cfg["rehearsal"].get("sample_requests", 4))
+    sample = generator.make(seeded(args.seed, 1), n_requests, params, cfg)
+    # the benchmark's own two programs do not depend on the seed; they
+    # live in a subdirectory of the cache, where the served launchers
+    # (new constants, so new entries, with every seed) cannot evict them
+    with cache_subdirectory(cache_dir, "benchmark"):
+        tree = make_weights(reference, cfg, args.seed, calibration_input(generator, traffic, params, cfg, args.seed))
+        marks["weights_s"] = time.perf_counter() - T0
+        ref_stats = run_reference(reference, cfg, tree, sample, work / "reference.npz")
+        marks["reference_s"] = time.perf_counter() - T0
+        temp_bytes = 0 if args.rehearse else program_temp_bytes(
+            work / "shapes", cfg, sample[0], traffic["launch_batch_sizes"],
+            os.path.join(cache_dir, "benchmark") if cache_dir else "")
+        marks["program_temp_s"] = time.perf_counter() - T0
+    # what the yardstick itself took of the device, before the server holds anything
+    marks["peak_bytes_before_server"] = int((devices[0].memory_stats() or {}).get("peak_bytes_in_use", 0))
+    name = write_repository(work / "repo", cfg, tree, args.rehearse)
+
+    trace_argv = ["--trace-capacity", "4096" if args.trace else "0"]
+    server, serve_args = start_server(work / "repo", [*cfg["serve_argv"], *trace_argv])
+    marks["server_s"] = time.perf_counter() - T0
+
+    written = []  # the persistent cache records a miss when it writes a newly compiled program
+    jax.monitoring.register_event_listener(
+        lambda event, **kw: written.append(event) if event.endswith("/cache_misses") else None
+    )
+    compile_s = compile_launch_shapes(server, name, sample[0], traffic["launch_batch_sizes"])
+    marks["compiled_s"] = time.perf_counter() - T0
+
+    say({
+        "ready": True, "device": device, "port": server.port,
+        "metrics_port": server.metrics_port, "model": name,
+        "reference": str(work / "reference.npz"), "reference_stats": ref_stats,
+        "compile_cache_dir": cache_dir or "off", "launch_compile_s": compile_s,
+        "compiled_at_unix": time.time(), "compiled_anew": bool(written) or not cache_dir,
+        "marks": marks,
+    })
+
+    traced = False
+    log_dir = work / "profile"
+    for line in sys.stdin:
+        cmd = json.loads(line)
+        if cmd["cmd"] == "profile":
+            # the device's lines only. With the host tracer at level 1
+            # (let alone the Python tracer) the host's staging work
+            # slowed 60-fold while traced (XlaLinearize of a b8 uint8
+            # batch 300 ms for 5) and the device read 96% idle for 34%
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 0
+            options.enable_hlo_proto = False
+            time.sleep(float(cmd.get("after_s", 0.0)))  # past the callers' start, where the mix says so
+            jax.profiler.start_trace(str(log_dir), profiler_options=options)
+            time.sleep(float(cmd["seconds"]))
+            jax.profiler.stop_trace()
+            traced = True
+            say({"profiled": True})
+        elif cmd["cmd"] == "finish":
+            break
+
+    # the reduction is Python and holds this process's interpreter lock
+    # for seconds: it waits for the window to end, or the server's own
+    # threads starve (a traced replay fell from 106 to 18 scans/s)
+    profile = None
+    if traced:
+        from benchmarks import trace_reduce
+
+        profile = trace_reduce.reduce_dir(log_dir, args.chips, args.record_trace or None)
+    stats = [d.memory_stats() or {} for d in devices[: args.chips]]
+    peak = max((s.get("peak_bytes_in_use", 0) for s in stats), default=0)
+    drained = server.drain(timeout_s=serve_args.drain_timeout)
+    say({"done": True, "memory_peak_bytes": int(peak) + temp_bytes, "memory_buffers_peak_bytes": int(peak),
+         "memory_program_temp_bytes": temp_bytes, "profile": profile, "drained": bool(drained)})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
