@@ -394,16 +394,17 @@ class TestRouterTracing:
                 router.do_inference(InferRequest("m", {"x": X}))
             return time.perf_counter() - t0
 
+        # the smallest of several alternated repeats of each loop: what
+        # the loop costs, not what five other xdist workers took from it
         plain = _router(["r0", "r1"], script)
-        try:
-            t_plain = drive(plain)
-        finally:
-            plain.close()
         traced = _router(["r0", "r1"], script, tracer=Tracer(capacity=256))
         try:
-            t_traced = drive(traced)
+            pairs = [(drive(plain), drive(traced)) for _ in range(5)]
         finally:
+            plain.close()
             traced.close()
+        t_plain = min(p for p, _ in pairs)
+        t_traced = min(t for _, t in pairs)
         assert (t_traced - t_plain) / n < 0.002
 
 

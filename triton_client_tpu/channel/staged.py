@@ -115,7 +115,7 @@ class StagedRequest:
 
     __slots__ = (
         "model", "device_inputs", "request", "t_stage", "meta",
-        "lifecycle_key",
+        "lifecycle_key", "trace_state",
     )
 
     def __init__(self, model, device_inputs, request, t_stage, meta=None) -> None:
@@ -129,6 +129,22 @@ class StagedRequest:
         # the request resolves or fails, so eviction can never reclaim
         # a model whose batch is still staged/executing
         self.lifecycle_key = None
+        # traced requests only, (ids, h2d start, arrival marker, h2d
+        # attrs): ``ids`` is the attrs dict of every span of this launch
+        # but h2d, into which launch() writes ``launch_id`` once the
+        # ordinal is known; the open ``h2d`` span is closed by resolve()
+        self.trace_state = None
+
+
+@jax.jit
+def _arrival_marker(device_inputs):
+    """One element of every staged array, as a device program of its
+    own: it runs once the arrays are on the device and, the device
+    taking its programs in order, after the launch before it — and it
+    survives launch(), which donates the arrays themselves."""
+    return jax.tree_util.tree_map(
+        lambda x: x[(slice(0, 1),) * x.ndim], device_inputs
+    )
 
 
 class _Inflight:
@@ -488,9 +504,11 @@ class StagedChannel(BaseChannel):
             if tr is not None:
                 tr.add("lifecycle", t_p0, time.perf_counter())
         if tr is not None:
+            ids = {}  # launch() fills in launch_id: the ordinal is its to give
             t_w0 = time.perf_counter()
             self._acquire_slot()
-            tr.add("slot_wait", t_w0, time.perf_counter())
+            t_h0 = time.perf_counter()
+            tr.add("slot_wait", t_w0, t_h0, ids)
         else:
             self._acquire_slot()
         try:
@@ -506,11 +524,29 @@ class StagedChannel(BaseChannel):
         with self._slot_cv:
             self._stats["staged"] += 1
         t_staged = time.perf_counter()
-        if tr is not None:
-            # the whole stage phase: validate + slot admission + H2D
-            tr.add("stage", t_s0, t_staged)
         staged = StagedRequest(model, device_inputs, request, t_staged, meta)
         staged.lifecycle_key = lifecycle_key
+        if tr is not None:
+            # the stage phase: validate + slot admission + the ENQUEUE of
+            # the H2D copy (device_put returns before the bytes moved)
+            tr.add("stage", t_s0, t_staged, ids)
+            # h2d is closed by resolve(), on the thread that waits for
+            # the outputs anyway, when the arrival marker is ready: at
+            # the later of "frames on the device" and "previous launch
+            # done". Waiting HERE for the arrays themselves held the
+            # executor 0.14 s a launch off its next request and cost a
+            # traced server 4-10% of its rate (PERF.md, PR 26). Traced
+            # only: the untraced path dispatches nothing more and no
+            # path waits for the device in stage().
+            h2d = {
+                "bytes": sum(
+                    np.asarray(a).nbytes for a in request.inputs.values()
+                ),
+                "rows": _batch_rows(device_inputs),
+            }
+            staged.trace_state = (
+                ids, t_h0, _arrival_marker(device_inputs), h2d
+            )
         return staged
 
     def _acquire_slot(self) -> None:
@@ -666,33 +702,49 @@ class StagedChannel(BaseChannel):
                 return InferFuture.failed(e)
         rec = _Inflight(outputs)
         t_launched = time.perf_counter()
-        if tr is not None:
-            tr.add("launch", t0, t_launched)
         with self._slot_cv:
             self._inflight.append(rec)
             self._stats["launched"] += 1
+            launch_id = self._stats["launched"]
             if donate_names:
                 self._stats["donated_launches"] += 1
             if deadline is not None and t_launched > deadline:
                 self._stats["deadline_expired_launches"] += 1
             self._slot_occupancy[len(self._inflight)] += 1
+        h2d = None
+        if tr is not None:
+            # the channel's launch ordinal on every span of this launch
+            # (the same dicts on every member of a merged launch), so
+            # spans group by launch and the k-th launch can be laid
+            # beside the k-th jit_mdl_* module of a device trace
+            ids, *h2d = staged.trace_state
+            ids["launch_id"] = h2d[2]["launch_id"] = launch_id
+            tr.add("launch", t0, t_launched, ids)
 
         ledger = self._device_time
 
         def resolve() -> InferResponse:
             try:
                 if tr is not None or ledger is not None:
+                    if h2d is not None:
+                        t_h0, marker, h2d_attrs = h2d
+                        jax.block_until_ready(marker)
+                        tr.add("h2d", t_h0, time.perf_counter(), h2d_attrs)
                     # device window: enqueue -> execution complete.
                     # block_until_ready is what np.asarray would wait on
                     # anyway; forcing it here splits execute from the
                     # device->host copy in the request timeline. The
                     # ledger accrues the SAME window the trace spans, so
                     # its totals reconcile with the device_execute
-                    # histogram by construction.
+                    # histogram by construction. A host wait, not device
+                    # time: it holds what is left of the transfer, the
+                    # queueing behind the previous launch and the
+                    # compute; the end of h2d, inside it, says where the
+                    # compute can have begun.
                     jax.block_until_ready(outputs)
                     t_ready = time.perf_counter()
                     if tr is not None:
-                        tr.add("device_execute", t_launched, t_ready)
+                        tr.add("device_execute", t_launched, t_ready, ids)
                     if ledger is not None:
                         # session frames accrue under a per-stream
                         # tenant, so the ledger's tenant axis answers
@@ -706,7 +758,7 @@ class StagedChannel(BaseChannel):
                 faults.probe("readback", name)
                 host = self._host_outputs(outputs, out_dtype, staged.meta)
                 if tr is not None:
-                    tr.add("readback", t_ready, time.perf_counter())
+                    tr.add("readback", t_ready, time.perf_counter(), ids)
             except Exception:
                 # readback failure belongs to THIS batch's futures only
                 # (the batcher fans it to the members); the breaker

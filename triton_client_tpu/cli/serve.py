@@ -172,7 +172,8 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--trace-capacity", type=int, default=256,
         help="recent request traces kept for /traces export "
-        "(`trace-dump`); 0 disables request-scoped spans",
+        "(`trace-dump`); 0 disables request-scoped spans, and with them "
+        "the one-element marker program a launch that times `h2d`",
     )
     p.add_argument(
         "--slo-ms", type=float, default=0.0,

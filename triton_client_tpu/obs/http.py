@@ -14,10 +14,15 @@ traces and the raw collector snapshot:
   GET /snapshot  RuntimeCollector.snapshot() as JSON (debug/automation)
   GET /profile   on-demand jax.profiler capture (?seconds=N, default 1,
                  capped at 60; ?top_k=K bounds the op rows): blocks for
-                 the window, writes the XLA + device timeline into a
-                 server-local directory, and returns its path PLUS the
-                 parsed per-op summary (obs.opstats: op, kind, model,
-                 occurrences, device time) as JSON. One capture at a
+                 the window, writes the device timeline into a
+                 server-local directory (on a TPU the device's lines
+                 only: obs.profiling.device_trace, so the capture does
+                 not slow the host path it looks at), and returns its
+                 path PLUS the parsed per-op summary (obs.opstats: op,
+                 kind, model, occurrences, device time) and, with a
+                 tracer attached, ``launch_timeline``: the gaps between
+                 launches split by what the host was doing in them
+                 (obs.launch_timeline) as JSON. One capture at a
                  time — a concurrent request gets 409 (jax.profiler is
                  a process-global singleton; overlapping captures
                  abort). A trace that fails to parse still returns the
@@ -203,12 +208,13 @@ class TelemetryServer:
             )
             return
         try:
+            from triton_client_tpu.obs.profiling import device_trace
+
             log_dir = tempfile.mkdtemp(prefix="tpu_serving_profile_")
-            jax.profiler.start_trace(log_dir)
-            try:
+            t_capture = time.perf_counter()
+            with device_trace(log_dir):
                 time.sleep(seconds)
-            finally:
-                jax.profiler.stop_trace()
+            t_captured = time.perf_counter()
         except Exception as e:
             log.exception("profile capture failed")
             self._send(req, 500, f"profile capture failed: {e}\n".encode())
@@ -230,6 +236,25 @@ class TelemetryServer:
         except Exception as e:
             log.exception("profile trace parse failed")
             doc["op_summary_error"] = str(e)
+        if self._tracer is not None:
+            try:
+                from triton_client_tpu.obs import launch_timeline
+
+                # the requests that lived inside the capture; the
+                # profiler session's zero is near its start
+                doc["launch_timeline"] = launch_timeline.timeline(
+                    [
+                        t for t in self._tracer.recent()
+                        if t.t_end is not None
+                        and t.t_end >= t_capture
+                        and t.t_start <= t_captured
+                    ],
+                    launch_timeline.module_events(log_dir),
+                    near_s=t_capture,
+                )
+            except Exception as e:
+                log.exception("launch timeline failed")
+                doc["launch_timeline_error"] = str(e)
         self._send(req, 200, json.dumps(doc).encode(), "application/json")
 
     @staticmethod

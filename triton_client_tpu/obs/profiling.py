@@ -126,15 +126,40 @@ class StageProfiler:
         return "\n".join(lines)
 
 
-@contextlib.contextmanager
-def device_trace(log_dir: str) -> Iterator[None]:
-    """jax.profiler trace window: captures XLA compilation + TPU device
-    timeline into ``log_dir`` (open with TensorBoard's profile plugin or
-    Perfetto). Complements StageProfiler: host timers see walls, this
-    sees what the chip did inside them."""
+def _device_only_options():
+    """Profiler options for a capture that must not slow what it
+    measures, chosen from the platform: on a TPU the device's own lines
+    only. With the host tracer at its default level (let alone the
+    Python tracer) the host's staging slowed 18-60x while a capture ran
+    and the device read 96% idle where 34% was true (PERF.md section
+    6). Elsewhere None, the defaults: on the CPU backend the ops ARE
+    host events."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    if jax.default_backend() != "tpu":
+        return None
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 0
+    options.python_tracer_level = 0
+    options.enable_hlo_proto = False
+    return options
+
+
+@contextlib.contextmanager
+def device_trace(log_dir: str) -> Iterator[None]:
+    """jax.profiler trace window: captures the device timeline into
+    ``log_dir`` (open with TensorBoard's profile plugin or Perfetto).
+    Complements StageProfiler: host timers see walls, this sees what
+    the chip did inside them. The one capture helper of the program:
+    ``/profile`` and the continuous sampler go through it, so no
+    capture of a live TPU server runs the host tracers."""
+    import jax
+
+    options = _device_only_options()
+    if options is None:
+        jax.profiler.start_trace(log_dir)
+    else:
+        jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:

@@ -104,12 +104,11 @@ class ContinuousSampler:
         t0 = time.perf_counter()
         try:
             log_dir = tempfile.mkdtemp(prefix="tpu_serving_sample_")
-            jax.profiler.start_trace(log_dir)
-            try:
-                time.sleep(self.window_s)
-            finally:
-                jax.profiler.stop_trace()
             from triton_client_tpu.obs import opstats
+            from triton_client_tpu.obs.profiling import device_trace
+
+            with device_trace(log_dir):
+                time.sleep(self.window_s)
 
             modules = {}
             if self._hlo_modules is not None:

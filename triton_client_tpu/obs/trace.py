@@ -252,9 +252,13 @@ class MultiTrace:
     def __init__(self, members) -> None:
         self.members = [m for m in members if m is not None]
 
-    def add(self, name: str, t0: float, t1: float) -> None:
+    def add(
+        self, name: str, t0: float, t1: float, attrs: dict | None = None
+    ) -> None:
+        # one attrs dict shared by every member: the channel fills in
+        # ``launch_id`` once for the whole merged launch
         for m in self.members:
-            m.add(name, t0, t1)
+            m.add(name, t0, t1, attrs)
 
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:
@@ -309,6 +313,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._ids = itertools.count(1)
         self._finished = 0
+        # one (perf_counter, time_ns) pair, taken together: what lets a
+        # reader lay this process's spans beside another clock's events
+        self._clock_anchor = (time.perf_counter(), time.time_ns())
 
     def start(
         self,
@@ -365,21 +372,31 @@ class Tracer:
             }
 
     def chrome_trace(self, n: int = 0) -> dict:
-        return chrome_trace(self.recent(n))
+        return chrome_trace(self.recent(n), clock_anchor=self._clock_anchor)
 
 
-def chrome_trace(traces) -> dict:
+def chrome_trace(traces, clock_anchor=None) -> dict:
     """Chrome-trace ('Trace Event Format') JSON for a list of traces.
 
     Loadable in Perfetto / chrome://tracing: complete ('X') events with
     microsecond timestamps, one tid (row) per request, the whole
     request as a parent event so the per-phase spans nest visually
     inside it. Timestamps rebase onto the earliest trace start so the
-    viewer opens at t=0."""
+    viewer opens at t=0; with ``clock_anchor`` (the Tracer's
+    ``(perf_counter, time_ns)`` pair) a top-level ``clock`` object says
+    which ``perf_counter`` value that zero is, so ``ts`` can be put
+    back on the process's clock (and, through the pair, on wall time)."""
     traces = [t for t in traces if t is not None]
+    base = min((t.t_start for t in traces), default=None)
+    clock = {}
+    if clock_anchor is not None:
+        clock["clock"] = {
+            "base_perf_counter_s": base,
+            "anchor_perf_counter_s": clock_anchor[0],
+            "anchor_time_ns": clock_anchor[1],
+        }
     if not traces:
-        return {"traceEvents": [], "displayTimeUnit": "ms"}
-    base = min(t.t_start for t in traces)
+        return {"traceEvents": [], "displayTimeUnit": "ms", **clock}
 
     def us(t: float) -> float:
         return round((t - base) * 1e6, 3)
@@ -440,7 +457,7 @@ def chrome_trace(traces) -> dict:
                 ev["args"] = dict(s.attrs)
             events.append(ev)
     events.sort(key=lambda e: (e.get("ts", -1.0), e["tid"]))
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
+    return {"traceEvents": events, "displayTimeUnit": "ms", **clock}
 
 
 def dump_chrome_trace(traces, path: str) -> None:

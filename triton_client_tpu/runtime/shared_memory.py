@@ -33,11 +33,24 @@ import collections
 import mmap
 import os
 import threading
+import time
 from dataclasses import dataclass
 
 import numpy as np
 
 _SHM_DIR = "/dev/shm"
+
+# the last copies INTO a region, (t0, t1, nbytes) on time.perf_counter:
+# the one host copy on the way in that no span can cover (the caller
+# makes it before the request exists). Always on: two clock reads and
+# an append beside a copy of up to hundreds of megabytes.
+_WRITE_LOG: collections.deque = collections.deque(maxlen=4096)
+
+
+def write_log() -> list[tuple[float, float, int]]:
+    """This process's most recent ``SharedMemoryRegion.write`` calls,
+    oldest first: ``(t0, t1, nbytes)`` on ``time.perf_counter``."""
+    return list(_WRITE_LOG)
 
 
 def _shm_path(key: str) -> str:
@@ -99,6 +112,7 @@ class SharedMemoryRegion:
 
     def write(self, arr: np.ndarray, offset: int = 0) -> int:
         """Copy ``arr``'s bytes into the region; returns bytes written."""
+        t0 = time.perf_counter()
         arr = np.ascontiguousarray(arr)
         n = arr.nbytes
         if offset < 0 or offset + n > self.size:
@@ -111,6 +125,7 @@ class SharedMemoryRegion:
         # host overlap their memcpys
         dst = np.frombuffer(self._mm, np.uint8, count=n, offset=offset)
         np.copyto(dst, arr.view(np.uint8).reshape(-1))
+        _WRITE_LOG.append((t0, time.perf_counter(), n))
         return n
 
     def read(self, offset: int, byte_size: int) -> memoryview:
