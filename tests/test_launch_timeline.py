@@ -250,6 +250,14 @@ GAP_CASES = {
         {"gap_s": 0.5, "busy_s": 0.2, "parse": 0.05, "batch_queue": 0.05, "batch_merge": 0.1,
          "slot_wait": 0.1, "h2d": 0.1, "launch": 0.05, "other": 0.05, "h2d_overlap_s": 0.0},
     ),
+    # a lone request that needed no pad rows was not copied (PR 27): its member has no
+    # batch_merge span, and the gap falls to the states that are there
+    "no_batch_merge_span": (
+        _rec(2, 0.7, (0.9, 1.1), (1.1, 1.15), 1.35, parse=(0.7, 0.75),
+             batch_queue=(0.75, 0.85), batch_merge=None, slot_wait=(0.85, 0.9)),
+        {"gap_s": 0.45, "busy_s": 0.2, "parse": 0.05, "batch_queue": 0.1, "slot_wait": 0.05,
+         "h2d": 0.2, "launch": 0.05, "h2d_overlap_s": 0.0},
+    ),
 }
 
 

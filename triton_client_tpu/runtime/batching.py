@@ -23,6 +23,18 @@ every batch size (the role Triton's preferred_batch_size plays), and
 ``max_merge`` lets the device batch grow past the admission size —
 the measured b8->b64 dispatch-amortization win, applied to serving.
 
+A group of ONE member is never copied unless it needs pad rows. Without
+``pad_to_buckets`` it runs solo. With it (the continuous scheduler
+always sets it) the group still takes the dense path, so its size is
+seen by the pad table, but when the pad comes out 0 (the rows already
+are a launch size, or the request is wider than ``max_merge``) the
+inner channel is handed the request's OWN arrays: for an shm request
+the zero-copy view of the caller's region, read in place until the
+answer leaves. Only a lone request that needs pad rows, and every group
+of two or more, is built into a new buffer (``np.concatenate``, span
+``batch_merge``). ``stats()`` counts both: ``passthrough_groups`` and
+``merged_bytes``.
+
 BatchingChannel is itself a BaseChannel, so it stacks under the gRPC
 façade or above TPUChannel unchanged. Requests are only merged when
 model, version and non-batch input shapes match; mismatches run solo.
@@ -108,7 +120,9 @@ class BatchingChannel(BaseChannel):
         ``pad_to_buckets``: pad each merged batch to the next power of
         two with replicated rows (outputs for the pad rows are
         discarded). Keeps the set of batch shapes the inner channel —
-        and therefore XLA — ever sees to log2(max_merge)+1 sizes.
+        and therefore XLA — ever sees to log2(max_merge)+1 sizes. A
+        lone request that needs no pad rows is passed on uncopied
+        (module docstring).
 
         ``merge_hold_us``: when a slot frees onto a SHALLOW queue (the
         formed group is under max_merge and nothing else is staged),
@@ -191,6 +205,10 @@ class BatchingChannel(BaseChannel):
         self._merge_stats = {
             "merges": 0, "merged_frames": 0, "padded_frames": 0,
             "launch_frees": 0,
+            # dense groups handed to the inner channel without a copy
+            # (one member, no pad rows), and the bytes of the buffers
+            # the batcher did build for all the others
+            "passthrough_groups": 0, "merged_bytes": 0,
         }
         # padding-tax attribution (ISSUE 8 satellite): pad frames per
         # MODEL, so the Prometheus counter can carry a model label and
@@ -655,20 +673,39 @@ class BatchingChannel(BaseChannel):
                 if self._pad_to_buckets and rounded <= self._max_merge
                 else 0
             )
+            # ONE member whose rows already are the launch: there is
+            # nothing to merge, so its own arrays go down as they came
+            # (for an shm request the zero-copy view of the caller's
+            # region; it is answered only after the device has read
+            # them). Concatenating one part into a fresh buffer cost
+            # 0.95 s for 604 MB and kept the device waiting two thirds
+            # of the time (PERF.md, PR 27).
+            passthrough = len(requests) == 1 and pad == 0
             t_stage0 = time.perf_counter()
             merged = {}
             arena_held = []
             for name in requests[0].inputs:
                 parts = [np.asarray(r.inputs[name]) for r in requests]
+                if passthrough:
+                    merged[name] = parts[0]
+                    continue
                 if pad:
                     # replicate a real row: zeros can steer a model
                     # down numerically different paths, a copy cannot
                     parts = pad_rows(parts, pad)
                 merged[name] = self._merge_parts(name, parts, arena_held)
             t_disp = time.perf_counter()
-            for tr in traces:
-                if tr is not None:
-                    tr.add("batch_merge", t_stage0, t_disp)
+            if passthrough:
+                # and no span where nothing was copied: one of zero
+                # length would still read as a state in
+                # obs/launch_timeline.py
+                with self._ready_cv:
+                    self._merge_stats["passthrough_groups"] += 1
+            else:
+                self._count_merged(merged)
+                for tr in traces:
+                    if tr is not None:
+                        tr.add("batch_merge", t_stage0, t_disp)
             if self._shed_expired:
                 # second deadline pass AFTER the pack (ISSUE 8
                 # satellite): the host merge build above takes real
@@ -768,6 +805,14 @@ class BatchingChannel(BaseChannel):
                     request_id=request.request_id,
                     latency_s=resp.latency_s,
                 )
+            )
+
+    def _count_merged(self, merged: dict) -> None:
+        """``merged_bytes``: the bytes of a device batch this batcher
+        built by copying its members' rows."""
+        with self._ready_cv:
+            self._merge_stats["merged_bytes"] += sum(
+                a.nbytes for a in merged.values()
             )
 
     def _merge_parts(self, name: str, parts: list, arena_held: list) -> np.ndarray:

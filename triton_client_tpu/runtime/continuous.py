@@ -459,6 +459,7 @@ class ContinuousBatchingChannel(BatchingChannel):
                         np.stack(parts), layout.seg_bucket
                     )
             t_disp = time.perf_counter()
+            self._count_merged(merged)
             for tr in traces:
                 if tr is not None:
                     tr.add("batch_merge", t_stage0, t_disp)
