@@ -71,13 +71,12 @@ def main(argv=None) -> int:
     child = run.Child(["--config", config_file, "--traffic", traffic_file, "--seed", str(args.seed), "--work", str(work),
                        "--chips", str(cell["chips"]), "--trace", "1", *(["--rehearse"] if args.rehearse else [])])
     try:
-        from triton_client_tpu.channel.base import InferRequest
         from triton_client_tpu.channel.grpc_channel import GRPCChannel
 
         generator = importlib.import_module(f"benchmarks.inputs.{traffic['inputs']['generator']}")
         inputs = generator.make(seeded(args.seed, 1), 1, input_params(traffic, cfg, args.rehearse), cfg)
         ready = child.read("ready")
-        requests = [InferRequest(ready["model"], x) for x in inputs]
+        requests = getattr(loadgen, f"{traffic['loop']}_requests")(ready["model"], inputs)
         make_channel = lambda: GRPCChannel(f"127.0.0.1:{ready['port']}", timeout_s=120.0, retries=0)
         loop = getattr(loadgen, f"{traffic['loop']}_loop")
         channel = make_channel()

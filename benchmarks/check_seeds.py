@@ -22,21 +22,24 @@ import json
 import pathlib
 import sys
 import tempfile
+import types
 
 import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from benchmarks import compare, server_child as sc  # noqa: E402
+from benchmarks import loadgen, server_child as sc  # noqa: E402
 
 
 @functools.lru_cache(maxsize=None)
 def _parts(config: str, traffic: str, full: bool):
+    """``traffic`` names a mix under ``traffic/``, or is the path of a
+    mix's file (the tests keep mixes that are in no cell)."""
     cfg = sc.load_json(ROOT / f"benchmarks/configs/{config}.json")
     if not full:
         cfg = sc.apply_rehearsal(cfg)
-    mix = sc.load_json(ROOT / f"benchmarks/traffic/{traffic}.json")
+    mix = sc.load_json(ROOT / (traffic if traffic.endswith(".json") else f"benchmarks/traffic/{traffic}.json"))
     if not full:
         mix = sc.rehearsal_traffic(mix, cfg)
     reference = importlib.import_module(f"benchmarks.references.{cfg['reference']}")
@@ -46,46 +49,35 @@ def _parts(config: str, traffic: str, full: bool):
 
 def numbers(config: str, traffic: str, seed: int, precision: str | None = None,
             perturb: float = 0.0, full: bool = False) -> dict:
-    """The output check's numbers for one seed. ``precision`` serves
-    the entry at a lower precision (the control); ``perturb`` adds that
-    much to the last Detect/class head's bias in the SERVED weights."""
+    """The output check's numbers for one seed, by the configuration's
+    check module. ``precision`` serves the entry at a lower precision
+    (the control); ``perturb`` hands the check module's ``perturbed``
+    that amount for the SERVED weights."""
     import jax
     from triton_client_tpu.runtime.disk_repository import build_model
 
     cfg, mix, reference, generator = _parts(config, traffic, full)
+    check = sc.check_module(cfg)
     if precision is None and "--precision" in cfg["serve_argv"]:  # as the cell serves it
         precision = cfg["serve_argv"][cfg["serve_argv"].index("--precision") + 1]
     params = sc.input_params(mix, cfg, not full)
     tree = sc.make_weights(reference, cfg, seed, sc.calibration_input(generator, mix, params, cfg, seed))
-    served_tree = tree
-    if perturb:
-        served_tree = jax.tree_util.tree_map(lambda x: x, tree)
-        head = sorted(k for k in served_tree["params"] if "detect" in k or k == "cls_head")[-1]
-        served_tree["params"][head] = {
-            **served_tree["params"][head], "bias": served_tree["params"][head]["bias"] + perturb,
-        }
+    served_tree = check.perturbed(tree, perturb) if perturb else tree
     with tempfile.TemporaryDirectory() as tmp:
         work = pathlib.Path(tmp)
         name = sc.write_repository(work / "repo", cfg, served_tree, not full, precision)
         model = build_model(work / "repo" / name, weights=work / "repo" / name / "1" / "weights.msgpack")
-        n = max(1, cfg["check"]["sample_items"] // mix["items_per_request"])
-        if not full:
-            n = min(n, cfg["rehearsal"]["sample_requests"])
-        sample = generator.make(sc.seeded(seed, 1), n, params, cfg)
-        stats = sc.run_reference(reference, cfg, tree, sample, work / "reference.npz")
-        ref = np.load(work / "reference.npz")
-        got = []
-        for request in sample:
-            out = model.infer_fn({k: jax.numpy.asarray(v) for k, v in request.items()})
-            got += compare.live_rows(np.asarray(out[cfg["outputs"]["rows"]]), np.asarray(out[cfg["outputs"]["valid"]]))
-        want = [ref[f"rows_{i}"] for i in range(len(ref["gated"]))]
-    result = compare.compare(got[: len(want)], want, reference.COMPARE, reference.BOX_COLS,
-                             cfg["pipeline"]["max_det"],
-                             cfg["pipeline"].get("conf_thresh", cfg["pipeline"].get("score_thresh")),
-                             cfg["check"], float(ref["sensitivity"]))
-    result["correct"], _ = compare.verdict(result, cfg["check"])
-    result.pop("pairs")
-    result["reference_boxes"] = stats["boxes"]
+        sample = generator.make(sc.seeded(seed, 1), sc.sample_size(cfg, mix, not full), params, cfg)
+        stats = check.expected(reference, cfg, tree, sample, work / "reference.npz")
+
+        def answer(request: dict):
+            out = model.infer_fn({k: jax.numpy.asarray(v) for k, v in loadgen.split_items(request)[0].items()})
+            return types.SimpleNamespace(outputs={k: np.asarray(v) for k, v in out.items()})
+
+        responses = [[answer(r) for r in s] if isinstance(s, list) else answer(s) for s in sample]  # streams or requests
+        ok, _, result = check.served(responses, work / "reference.npz", cfg)
+    result["correct"] = ok
+    result["reference"] = stats
     return result
 
 

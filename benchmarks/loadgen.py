@@ -11,17 +11,23 @@ record: how late the generator sent each request, and every response
 handed to a checker. This module never imports jax.
 
 A traffic file's ``loop`` names its kind. ``run.py`` looks up
-``<loop>_loop`` (one measured window) and ``<loop>_sample`` (every
-request of the output check's sample once, in flight together as the
-cell's traffic would have them) here by that name; all kinds take the
-same arguments.
+``<loop>_requests`` (the input generator's draw as what the kind
+sends), ``<loop>_loop`` (one measured window) and ``<loop>_sample``
+(everything of the output check's sample once, in flight together as
+the cell's traffic would have it) here by that name; all kinds take
+the same arguments. ``open`` and ``closed`` send single requests from a
+pool; ``sessions`` sends STREAMS: ordered requests under one
+``sequence_id``, each when the last one answered.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import itertools
 import queue
 import threading
 import time
+import uuid
 
 import numpy as np
 
@@ -50,8 +56,66 @@ def co_percentile(latencies_ms, scheduled: int, q: float) -> float:
     return float(lat[rank]) if rank < len(lat) else float("inf")
 
 
+def split_items(request: dict) -> tuple[dict, int | None]:
+    """A generated request -> (its input arrays, the items it states).
+    A request of a stream may state its own item count under ``items``
+    (a plain int beside the arrays); None means the traffic file's
+    ``items_per_request``."""
+    items = request.get("items")
+    if isinstance(items, int):
+        return {k: v for k, v in request.items() if k != "items"}, items
+    return request, None
+
+
+def flat(sample: list) -> list:
+    """A sample's requests (or its responses) one after another,
+    whether it is a list of them or a list of streams."""
+    return [r for stream in sample for r in stream] if sample and isinstance(sample[0], list) else list(sample)
+
+
+def first_request(sample: list) -> dict:
+    """The input arrays of a sample's first request."""
+    return split_items(flat(sample)[0])[0]
+
+
+def stacked(sample: list, cfg: dict) -> dict:
+    """A sample's requests as one batch (for a reference, or for the
+    weights' calibration): requests that carry a batch axis (the
+    configuration's ``request_batch_axis``) are concatenated, the
+    others stacked."""
+    requests = [split_items(r)[0] for r in flat(sample)]
+    join = np.concatenate if cfg["request_batch_axis"] else np.stack
+    return {k: join([r[k] for r in requests]) for k in requests[0]}
+
+
+def first_items(batch: dict, n: int) -> dict:
+    """The first ``n`` items of a stacked sample."""
+    return {k: v[:n] for k, v in batch.items()}
+
+
+def closed_requests(model: str, inputs: list[dict]) -> list:
+    """The pool of an ``open`` or ``closed`` mix: one request a draw."""
+    from triton_client_tpu.channel.base import InferRequest
+
+    return [InferRequest(model, x) for x in inputs]
+
+
+open_requests = closed_requests
+
+
+def sessions_requests(model: str, streams: list[list[dict]]) -> list:
+    """The pool of a ``sessions`` mix: each stream a list of
+    ``(request, items)``, in the order it is sent."""
+    from triton_client_tpu.channel.base import InferRequest
+
+    return [[(InferRequest(model, inputs), items) for inputs, items in map(split_items, stream)]
+            for stream in streams]
+
+
 class Window:
     """What one measured window recorded."""
+
+    scheduled = False  # an open loop's: latencies run from a fixed schedule
 
     def __init__(self) -> None:
         self.lock = threading.Lock()
@@ -85,6 +149,16 @@ class Window:
                 if len(self.errors) < 5:
                     self.errors.append(bad)
 
+    def end_to_end(self) -> dict:
+        """What the window says end to end: items completed in it over
+        its length and, where the arrivals were scheduled, the latency
+        percentiles over all scheduled requests."""
+        values = {"throughput": self.items_done / self.span_s()}
+        if self.scheduled:
+            values["latency_p50_ms"] = co_percentile(self.latencies_ms, self.attempted, 50)
+            values["latency_p95_ms"] = co_percentile(self.latencies_ms, self.attempted, 95)
+        return values
+
     def timeline(self) -> list[list[float]]:
         """Per second of the window: completions and their median
         latency in ms (for the builder's log: when did it stall?)."""
@@ -113,6 +187,7 @@ def open_loop(make_channel, channel, requests, traffic: dict, seconds: float,
     offsets = poisson_schedule(float(rate or traffic["rate_per_s"]), seconds, rng)
     picks = rng.integers(0, len(requests), len(offsets))
     win = Window()
+    win.scheduled = True
     win.attempted = len(offsets)
     pending: queue.Queue = queue.Queue()
 
@@ -211,19 +286,111 @@ def closed_loop(make_channel, channel, requests, traffic: dict, seconds: float,
     return win
 
 
-def closed_sample(make_channel, channel, requests, traffic: dict) -> list:
-    """Every request once, from the cell's ``clients`` callers side by
-    side; the responses in the requests' order."""
-    responses = [None] * len(requests)
-    lanes = int(traffic["clients"])
+def _in_lanes(lanes: int, pool: list, send) -> list:
+    """``send`` of every member of ``pool`` once, ``lanes`` callers
+    side by side; what it returned, in the pool's order."""
+    answers = [None] * len(pool)
 
     def lane(k: int) -> None:
-        for i in range(k, len(requests), lanes):
-            responses[i] = channel.do_inference(requests[i])
+        for i in range(k, len(pool), lanes):
+            answers[i] = send(pool[i])
 
     threads = [threading.Thread(target=lane, args=(k,)) for k in range(lanes)]
     for t in threads:
         t.start()
     for t in threads:
         t.join()
+    return answers
+
+
+def closed_sample(make_channel, channel, requests, traffic: dict) -> list:
+    """Every request once, from the cell's ``clients`` callers side by
+    side; the responses in the requests' order."""
+    return _in_lanes(int(traffic["clients"]), requests, channel.do_inference)
+
+
+def _send_stream(channel, stream, deadline=None, on_send=None, on_answer=None, on_error=None) -> list:
+    """One stream under a fresh ``sequence_id``: its requests in order,
+    each when the last one answered, ``sequence_start`` on the first
+    and ``sequence_end`` on the last. Past ``deadline`` (a one-element
+    list holding a ``perf_counter`` time) nothing more is sent, and a
+    failed request ends the stream. The responses, in order."""
+    sequence_id = f"bench-{uuid.uuid4().hex[:16]}"  # a stream opened is a new sequence id, in every round
+    responses = []
+    for k, (request, items) in enumerate(stream):
+        t_send = time.perf_counter()
+        if deadline is not None and t_send >= deadline[0]:
+            break
+        if on_send is not None:
+            on_send()
+        request = dataclasses.replace(request, sequence_id=sequence_id, sequence_start=k == 0,
+                                      sequence_end=k == len(stream) - 1)
+        try:
+            response = channel.do_inference(request)
+        except Exception as e:
+            if on_error is None:
+                raise
+            on_error(e)
+            break
+        responses.append(response)
+        if on_answer is not None:
+            on_answer(response, items, t_send)
     return responses
+
+
+def sessions_loop(make_channel, channel, requests, traffic: dict, seconds: float,
+                  rng: np.random.Generator, check=None, rate=None) -> Window:
+    """``clients`` callers, each with a channel of its own. A caller
+    opens a stream from the seeded pool under a fresh ``sequence_id``,
+    sends its requests IN ORDER, each when the last one answered, then
+    opens the next, until ``seconds`` are over. The window closes as
+    the closed loop's does: at the answer to the last request sent in
+    time, and every request sent counts. A request completes the items
+    it states, else the traffic file's ``items_per_request`` (a
+    stream's first request can carry thousands, the later ones one
+    each). A failed request ends its stream and counts as failed; the
+    caller opens the next. A stream that the deadline cuts is left
+    open: the server reclaims it."""
+    items_per_request, clients = int(traffic["items_per_request"]), int(traffic["clients"])
+    win = Window()
+    orders = [rng.permutation(len(requests)) for _ in range(clients)]
+    channels = [make_channel() for _ in range(clients)]
+    start = threading.Barrier(clients + 1)
+    deadline = [0.0]
+
+    def on_send() -> None:
+        with win.lock:
+            win.attempted += 1
+
+    def on_answer(response, items, t_send) -> None:
+        win.record(response, items_per_request if items is None else items, t_send, check)
+
+    def client(channel, order) -> None:
+        start.wait()
+        for i in itertools.count():
+            if time.perf_counter() >= deadline[0]:
+                return
+            _send_stream(channel, requests[order[i % len(order)]], deadline, on_send, on_answer, win.fail)
+
+    threads = [threading.Thread(target=client, args=(c, o), daemon=True) for c, o in zip(channels, orders)]
+    try:
+        for t in threads:
+            t.start()
+        win.t_start = time.perf_counter()
+        deadline[0] = win.t_start + seconds
+        win.t_end = float("inf")  # until the last answer is in
+        start.wait()
+        for t in threads:
+            t.join()
+        win.t_end = win.t_start + max(win.done_at_s, default=seconds)
+    finally:
+        for c in channels:
+            c.close()
+    return win
+
+
+def sessions_sample(make_channel, channel, requests, traffic: dict) -> list[list]:
+    """Every stream once and whole, from the cell's ``clients`` callers
+    side by side; each stream's responses in its own order, the streams
+    in the pool's."""
+    return _in_lanes(int(traffic["clients"]), requests, lambda stream: _send_stream(channel, stream))

@@ -10,11 +10,13 @@ module, so it pins itself to the CPU platform to make sure). The child
 (``server_child.py``) holds the chip: seeded weights, the real
 ``serve`` entry, the plain reference, the profiler.
 
-Nothing here names a configuration, a traffic mix, a model family or
-a per-layer metric: the cell names its configuration and traffic files,
-those name the reference, the input generator and the operation count,
-and each per-layer metric of BENCHMARK.json is read by the module of
-its own name under ``layer_metrics/``.
+Nothing here names a configuration, a traffic mix, a model family, a
+kind of loop or a per-layer metric: the cell names its configuration
+and traffic files, those name the reference, the check module
+(``checks/<check.kind>.py``: what is compared and how), the input
+generator, the loop kind (``loadgen.<loop>_requests/_loop/_sample``)
+and the operation count, and each per-layer metric of BENCHMARK.json is
+read by the module of its own name under ``layer_metrics/``.
 """
 
 from __future__ import annotations
@@ -96,30 +98,18 @@ class Child:
         self.proc.wait()
 
 
-def output_check(reference, cfg: dict, traffic: dict, ready: dict, make_channel, requests) -> dict:
+def output_check(check, cfg: dict, traffic: dict, ready: dict, make_channel, requests) -> dict:
     """The sample through the served path with the cell's own
-    concurrency (``loadgen.<loop>_sample``), held against the
-    reference's rows."""
-    from benchmarks import compare, loadgen
+    concurrency (``loadgen.<loop>_sample``), held against what the
+    reference gave by the configuration's check module."""
+    from benchmarks import loadgen
 
     channel = make_channel()
     try:
         responses = getattr(loadgen, f"{traffic['loop']}_sample")(make_channel, channel, requests, traffic)
     finally:
         channel.close()
-    got = []
-    ref = np.load(ready["reference"])
-    rows, valid = cfg["outputs"]["rows"], cfg["outputs"]["valid"]
-    for response in responses:
-        got += compare.live_rows(response.outputs[rows], response.outputs[valid])
-    want = [ref[f"rows_{i}"] for i in range(len(ref["gated"]))]  # the sample's first check.sample_items items
-    pipe = cfg["pipeline"]
-    numbers = compare.compare(
-        got[: len(want)], want, reference.COMPARE, reference.BOX_COLS, pipe["max_det"], pipe.get("conf_thresh", pipe.get("score_thresh")), cfg["check"],
-        float(ref["sensitivity"]),
-    )
-    ok, lines = compare.verdict(numbers, cfg["check"])
-    numbers.pop("pairs")
+    ok, lines, numbers = check.served(responses, ready["reference"], cfg)
     return {"ok": ok, "lines": lines, "numbers": numbers}
 
 
@@ -135,10 +125,13 @@ def main(argv=None) -> int:
                    "first events of the profiler trace at this path (the tests' fixture)")
     p.add_argument("--rehearse", action="store_true", help="CPU rehearsal at tiny sizes: "
                    "set-up and output check only; prints no metric")
+    p.add_argument("--traffic-file", default="", help="with --rehearse: the cell's configuration under "
+                   "this traffic file instead of the cell's own (the tests' mixes, which are in no cell)")
     args = p.parse_args(argv)
 
     os.environ["JAX_PLATFORMS"] = "cpu"  # this process only; the child gets its own
-    from benchmarks.server_child import apply_rehearsal, input_params, load_json, rehearsal_traffic, seeded
+    from benchmarks.server_child import (apply_rehearsal, check_module, input_params, load_json, rehearsal_traffic,
+                                         sample_size, seeded)
 
     bench = load_json(ROOT / "BENCHMARK.json")
     cell = next((w for w in bench["workloads"] if w["name"] == args.workload), None)
@@ -147,6 +140,11 @@ def main(argv=None) -> int:
         return 2
     config_file = next(c["file"] for c in bench["configs"] if c["name"] == cell["config"])
     traffic_file = f"{bench['paths'][0]}/traffic/{cell['traffic']}.json"
+    if args.traffic_file:
+        if not args.rehearse:
+            print("--traffic-file is for a rehearsal: a measured run takes the cell's own mix", file=sys.stderr)
+            return 2
+        traffic_file = args.traffic_file
     cfg, traffic = load_json(ROOT / config_file), load_json(ROOT / traffic_file)
     if args.rehearse:
         cfg = apply_rehearsal(cfg)
@@ -162,26 +160,21 @@ def main(argv=None) -> int:
     try:
         from triton_client_tpu.channel.grpc_channel import GRPCChannel
 
-        from benchmarks import compare, loadgen
+        from benchmarks import loadgen
 
-        reference = importlib.import_module(f"benchmarks.references.{cfg['reference']}")
+        check = check_module(cfg)
         generator = importlib.import_module(f"benchmarks.inputs.{traffic['inputs']['generator']}")
         params = input_params(traffic, cfg, args.rehearse)
-        n_requests = max(1, cfg["check"]["sample_items"] // traffic["items_per_request"])
-        if args.rehearse:
-            n_requests = min(n_requests, cfg["rehearsal"].get("sample_requests", 4))
         # the same draw the child makes for the reference's sample
-        inputs = generator.make(seeded(args.seed, 1), n_requests, params, cfg)
+        inputs = generator.make(seeded(args.seed, 1), sample_size(cfg, traffic, args.rehearse), params, cfg)
 
         ready = child.read("ready")
         device = ready["device"]
-        from triton_client_tpu.channel.base import InferRequest
-
-        requests = [InferRequest(ready["model"], x) for x in inputs]
+        requests = getattr(loadgen, f"{traffic['loop']}_requests")(ready["model"], inputs)  # what the kind sends
         make_channel = lambda: GRPCChannel(f"127.0.0.1:{ready['port']}", timeout_s=120.0, retries=0)
         log(ready=ready)
 
-        checked = output_check(reference, cfg, traffic, ready, make_channel, requests)
+        checked = output_check(check, cfg, traffic, ready, make_channel, requests)
         for line in checked["lines"]:
             log(compared=line["number"], value=line["value"], limit=line["limit"])
         log(output_check=checked["numbers"], reference=ready["reference_stats"])
@@ -192,8 +185,7 @@ def main(argv=None) -> int:
                               "numbers": checked["numbers"], "device": device}), flush=True)
             return 0
 
-        pipe = cfg["pipeline"]
-        well_formed = lambda resp: compare.malformed(resp.outputs, cfg["outputs"], pipe["max_det"], pipe["row_width"])
+        well_formed = lambda resp: check.well_formed(resp, cfg)
         loop = getattr(loadgen, f"{traffic['loop']}_loop")  # the traffic file names its kind
         channel = make_channel()
         try:
@@ -252,13 +244,7 @@ def main(argv=None) -> int:
         child.send(cmd="finish")
         done = child.read("done")
 
-        values = {
-            "throughput": win.items_done / win.span_s(),
-            "setup_s": setup_s,
-        }
-        if traffic["loop"] == "open":
-            values["latency_p50_ms"] = loadgen.co_percentile(win.latencies_ms, win.attempted, 50)
-            values["latency_p95_ms"] = loadgen.co_percentile(win.latencies_ms, win.attempted, 95)
+        values = {**win.end_to_end(), "setup_s": setup_s}
         in_cell = lambda m: "workloads" not in m or cell["name"] in m["workloads"]
         metrics = {}
         if args.trace:
@@ -296,7 +282,15 @@ def main(argv=None) -> int:
         # temporaries, which that statistic leaves out (server_child.program_temp_bytes)
         device_out = {**device, "memory_peak_bytes": done["memory_peak_bytes"],
                       "memory_buffers_peak_bytes": done["memory_buffers_peak_bytes"],
-                      "memory_program_temp_bytes": done["memory_program_temp_bytes"]}
+                      "memory_program_temp_bytes": done["memory_program_temp_bytes"],
+                      "memory_peak_before_server_bytes": done["memory_peak_before_server_bytes"],
+                      "memory_peak_phase": done["memory_peak_phase"]}
+        if done["memory_peak_phase"] == "yardstick":
+            # an error in set-up, not a larger cell: no result line
+            print(f"memory_peak_bytes was set before the server started ({done['memory_peak_before_server_bytes']} "
+                  "bytes of the yardstick's own buffers: the reference, a second copy of the weights); the served "
+                  f"program never passed it ({done['memory_buffers_peak_bytes']})", file=sys.stderr)
+            return 4
         result = {"correct": correct, "attempted": win.attempted, "failed": win.failed,
                   "metrics": metrics, "device": device_out}
         if args.trace and done["profile"]:
@@ -304,6 +298,9 @@ def main(argv=None) -> int:
             device_out["window_s"] = done["profile"]["window_s"]
             result["breakdown"] = done["profile"]["breakdown"]
         print(json.dumps(result), flush=True)
+        for line in checked["lines"]:  # each number compared beside its limit, at the end of standard error too
+            print(json.dumps({"compared": line["number"], "value": line["value"], "limit": line["limit"]}),
+                  file=sys.stderr, flush=True)
         return 0
     finally:
         child.stop()

@@ -2,14 +2,15 @@
 """The one process that holds the chip.
 
 Started by ``run.py`` (which never touches jax). It makes the seeded
-weights and writes them as ``weights.msgpack`` into a temporary model
-repository, stands the server up through the code ``python -m
-triton_client_tpu serve`` runs (argv parser -> ``build_server`` ->
-``start``), evaluates the configuration's plain reference on the seeded
-sample, compiles every launch shape the cell's traffic can form, and
-then answers the parent's commands, one JSON object a line on the
-stream it was given as stdout (everything else this process prints
-goes to stderr):
+weights, has the configuration's check module (``checks/<check.kind>.py``)
+evaluate the plain reference on the seeded sample, writes the weights
+as ``weights.msgpack`` into a temporary model repository, DROPS every
+device buffer of its own, stands the server up through the code
+``python -m triton_client_tpu serve`` runs (argv parser ->
+``build_server`` -> ``start``), compiles every launch shape the cell's
+traffic can form, and then answers the parent's commands, one JSON
+object a line on the stream it was given as stdout (everything else
+this process prints goes to stderr):
 
     -> {"ready": ...}                      after set-up
     <- {"cmd": "profile", "seconds": s, "after_s": a}   trace the device for s seconds, a seconds from now
@@ -21,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import gc
 import importlib
 import json
 import os
@@ -34,6 +36,8 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 import numpy as np  # noqa: E402
+
+from benchmarks import loadgen  # noqa: E402
 
 
 def seeded(seed: int, stream: int) -> np.random.Generator:
@@ -76,51 +80,48 @@ def input_params(traffic: dict, cfg: dict, rehearse: bool) -> dict:
     return params
 
 
-def first_items(batch: dict, n: int) -> dict:
-    """The first ``n`` items of a stacked sample."""
-    return {k: v[:n] for k, v in batch.items()}
+def sample_size(cfg: dict, traffic: dict, rehearse: bool) -> int:
+    """How many draws the output check's sample (and the window's pool)
+    asks of the input generator: requests, or streams where the mix
+    sends streams. The mix's ``sample_requests`` where it states one,
+    else as many requests as hold ``check.sample_items`` items."""
+    n = int(traffic.get("sample_requests") or max(1, cfg["check"]["sample_items"] // traffic["items_per_request"]))
+    if rehearse:
+        n = min(n, cfg["rehearsal"].get("sample_requests", 4))
+    return n
+
+
+def check_module(cfg: dict):
+    """What the configuration's family decides (``checks/<kind>.py``)."""
+    return importlib.import_module(f"benchmarks.checks.{cfg['check']['kind']}")
 
 
 def entry_doc(cfg: dict, rehearse: bool, precision: str | None) -> dict:
-    """The committed entry's config.yaml as served. A rehearsal shrinks
-    it and asks for the fused kernels (interpreted off a TPU); the
-    output check's control serves it at a lower precision."""
+    """The committed entry's config.yaml as served: what the check
+    module makes of it (paths, a rehearsal's sizes), then the output
+    check's control (a lower precision) and the configuration's batch."""
     from triton_client_tpu.dataset_config import load_yaml
 
-    doc = load_yaml(str(ROOT / cfg["entry"] / "config.yaml"))
-    pipeline = dict(doc.get("pipeline", {}))
-    if "class_names_file" in pipeline:
-        pipeline["class_names_file"] = str(ROOT / pipeline["class_names_file"])
-    if rehearse:
-        if "dataset" in doc:  # inline the dataset yaml so it can shrink
-            dataset = load_yaml(str(ROOT / doc.pop("dataset")))
-            dataset.pop("model")
-            pipeline = {**dict(dataset.pop("pipeline", {})), **pipeline}
-            dataset["voxel"] = {**dataset["voxel"], **cfg["rehearsal"]["model"]["voxel"]}
-            doc["model"] = dataset
-            pipeline["point_buckets"] = [cfg["rehearsal"]["model"]["point_bucket"]]
-        else:
-            doc["model"] = {**doc["model"], "input_hw": cfg["rehearsal"]["model"]["input_hw"]}
-        pipeline["fused"] = "on"
-    elif "dataset" in doc:
-        doc["dataset"] = str(ROOT / doc["dataset"])
-    if pipeline:
-        doc["pipeline"] = pipeline
+    doc = check_module(cfg).entry(load_yaml(str(ROOT / cfg["entry"] / "config.yaml")), cfg, rehearse)
     if precision:
         doc["model"] = {**dict(doc.get("model", {})), "precision": precision}
     doc["max_batch_size"] = int(cfg["max_batch_size"])  # the configuration's, where it departs from the entry's
     return doc
 
 
-def calibration_input(generator, traffic: dict, params: dict, cfg: dict, seed: int) -> dict:
+def calibration_input(generator, traffic: dict, params: dict, cfg: dict, seed: int) -> dict | None:
     """The seeded input the weights' batch-norm statistics are taken
     on: ``weights.calibration_items`` items of the cell's own traffic
     (``weights.calibration_params`` overrides the mix's input
-    parameters, so that eight frames are not cut from 768 drawn)."""
-    items = int(cfg["weights"]["calibration_items"])
+    parameters, so that eight frames are not cut from 768 drawn). None
+    where the configuration states no such count: its weights are drawn
+    from the key alone."""
+    items = int(cfg["weights"].get("calibration_items", 0))
+    if not items:
+        return None
     params = {**params, **cfg["weights"].get("calibration_params", {})}
     made = generator.make(seeded(seed, 0), items, params, cfg)  # at most one item a request too many
-    return first_items(stack_requests(made, cfg), items)
+    return loadgen.first_items(loadgen.stacked(made, cfg), items)
 
 
 def make_weights(reference, cfg: dict, seed: int, calibration: dict):
@@ -134,14 +135,35 @@ def make_weights(reference, cfg: dict, seed: int, calibration: dict):
     return jax.jit(lambda k, c: reference.init_params(k, c, cfg))(key, calibration)
 
 
+def write_msgpack(path: pathlib.Path, tree) -> None:
+    """``flax.serialization.to_bytes(tree)`` written leaf by leaf, byte
+    for byte the same file: msgpack is compositional, so a map is its
+    header and then each key and value. One leaf at a time is on the
+    host, not the whole tree beside a ``bytes`` of the whole tree."""
+    import flax.serialization
+    import msgpack
+
+    packer = msgpack.Packer()
+
+    def put(f, node) -> None:
+        if isinstance(node, dict):
+            f.write(packer.pack_map_header(len(node)))
+            for key in sorted(node):  # the order jax's tree_map gave the whole-tree copy
+                f.write(packer.pack(key))
+                put(f, node[key])
+        else:
+            f.write(flax.serialization.msgpack_serialize(np.asarray(node)))
+
+    with open(path, "wb") as f:
+        put(f, flax.serialization.to_state_dict(tree))
+
+
 def write_repository(root: pathlib.Path, cfg: dict, tree, rehearse: bool,
                      precision: str | None = None) -> str:
     """``<root>/<entry name>/config.yaml`` + ``1/weights.msgpack``: the
     program's normal loading path. Returns the served model's name.
     Without a ``tree`` the entry has no weights and loads at its own
     initialisation (``program_temp_bytes``)."""
-    import flax.serialization
-    import jax
     import yaml
 
     name = pathlib.Path(cfg["entry"]).name
@@ -150,60 +172,8 @@ def write_repository(root: pathlib.Path, cfg: dict, tree, rehearse: bool,
     with open(root / name / "config.yaml", "w") as f:
         yaml.safe_dump(entry_doc(cfg, rehearse, precision), f, sort_keys=False)
     if tree is not None:
-        host = jax.tree_util.tree_map(np.asarray, tree)
-        (version / "weights.msgpack").write_bytes(flax.serialization.to_bytes(host))
+        write_msgpack(version / "weights.msgpack", tree)
     return name
-
-
-def stack_requests(requests: list[dict], cfg: dict) -> dict:
-    """The sample as one batch for the reference: requests that carry
-    a batch axis (the configuration's ``request_batch_axis``) are
-    concatenated, the others stacked."""
-    join = np.concatenate if cfg["request_batch_axis"] else np.stack
-    return {k: join([r[k] for r in requests]) for k in requests[0]}
-
-
-def run_reference(reference, cfg: dict, tree, requests: list[dict], out_path: pathlib.Path) -> dict:
-    """The plain float32 reference over the sample's first
-    ``check.sample_items`` items in one call (a request of the replay
-    cells holds more frames than that: the limits were read on this
-    many, and the reference's memory stays far under the served
-    path's); its rows go to ``out_path`` for the parent's comparison.
-
-    The same program is run once more with every parameter rounded to
-    bfloat16 and back (no new compile: the weights are an argument).
-    How far that moves the scores is this seed's ``sensitivity``: some
-    seeds' weights pass a rounding error on at twice the size others
-    do, and the comparison divides by it so that one limit fits all."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchmarks import compare
-
-    batch = first_items(stack_requests(requests, cfg), int(cfg["check"]["sample_items"]))
-    forward = jax.jit(lambda t, x: reference.forward(t, x, cfg))
-    rounded = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16).astype(x.dtype), tree)
-    items, moved = (
-        reference.detections(jax.tree_util.tree_map(np.asarray, forward(t, batch)), cfg)
-        for t in (tree, rounded)
-    )
-    pipe = cfg["pipeline"]
-    shift = compare.compare(
-        [it["rows"] for it in moved], [it["rows"] for it in items], "boxes", reference.BOX_COLS,
-        10**9, pipe.get("conf_thresh", pipe.get("score_thresh")), cfg["check"],
-    )
-    np.savez(
-        out_path,
-        **{f"rows_{i}": it["rows"] for i, it in enumerate(items)},
-        gated=np.asarray([it["gated"] for it in items]),
-        sensitivity=np.asarray(shift["score_err_rms"]),
-    )
-    return {
-        "items": len(items),
-        "boxes": [len(it["rows"]) for it in items],
-        "gated_max": int(max(it["gated"] for it in items)),
-        "sensitivity": shift["score_err_rms"],
-    }
 
 
 @contextlib.contextmanager
@@ -244,28 +214,31 @@ def device_channel(server):
     return channel
 
 
-def compile_launch_shapes(server, name: str, request: dict, batch_sizes: list[int]) -> float:
+def served_model(server, name: str):
+    """The model as the server registered it, read from the device
+    channel's repository (the program has no public handle on it)."""
+    return device_channel(server)._repository.get(name)
+
+
+def compile_launch_shapes(server, name: str, launches: list[dict]) -> float:
     """Compile every launch shape the batcher can form for this cell:
-    one request's rows repeated to each batch size, sent straight to
-    the device channel under the batcher (the front door cannot ask for
-    a merge size). The launches run side by side so the compiles do."""
+    the check module's ``launch_request`` for each of the mix's
+    ``launch_batch_sizes``, sent straight to the device channel under
+    the batcher (the front door cannot ask for a merge size). The
+    launches run side by side so the compiles do."""
     from triton_client_tpu.channel.base import InferRequest
 
     channel = device_channel(server)
     t0 = time.perf_counter()
     errors = []
 
-    def launch(b: int) -> None:
+    def launch(k: int, inputs: dict) -> None:
         try:
-            if b:
-                inputs = {k: np.resize(v, (b, *v.shape[1:])) for k, v in request.items()}
-            else:  # the request as it is (3D: one scan, no batch axis)
-                inputs = request
             channel.do_inference(InferRequest(name, inputs))
         except Exception as e:  # reported, then raised on the main thread
-            errors.append(f"b{b}: {e!r}")
+            errors.append(f"launch shape {k}: {e!r}")
 
-    threads = [threading.Thread(target=launch, args=(b,)) for b in batch_sizes]
+    threads = [threading.Thread(target=launch, args=(k, inputs)) for k, inputs in enumerate(launches)]
     for t in threads:
         t.start()
     for t in threads:
@@ -275,19 +248,41 @@ def compile_launch_shapes(server, name: str, request: dict, batch_sizes: list[in
     return time.perf_counter() - t0
 
 
-def program_temp_bytes(root: pathlib.Path, cfg: dict, request: dict, batch_sizes: list[int], kept_dir: str) -> int:
+def launch_temp_bytes(model, shapes: dict) -> int:
+    """XLA's statement of one launch's temporaries for a built model's
+    device program. Where the model carries its weights as ``params``
+    (``device_fn(inputs, params)``) they are lowered ABSTRACT, as
+    shapes and types: no second tree on the device and no gigabytes of
+    constants in the module. A model without ``params`` has them as
+    closure constants of ``device_fn(inputs)``."""
+    import jax
+
+    program = jax.jit(model.device_fn)
+    if model.params is None:
+        lowered = program.lower(shapes)
+    else:
+        abstract = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), model.params)
+        lowered = program.lower(shapes, abstract)
+    return int(lowered.compile().memory_analysis().temp_size_in_bytes)
+
+
+def program_temp_bytes(root: pathlib.Path, cfg: dict, launches: list[dict], served, kept_dir: str) -> int:
     """The temporaries of the served device program at the cell's
     largest launch shape, as XLA states them for this chip
     (``memory_analysis().temp_size_in_bytes``). The allocator's
     ``peak_bytes_in_use`` leaves a running program's temporaries out
     (PERF.md section 2: a b8 launch raised it by 19 MB where one
     layer's activations are 33 MB), so a chip's peak is that statistic
-    plus this. The entry is built by the program's own loading path at
-    its own initialisation: buffer sizes follow from shapes, not from
-    weights, and a program that does not change with the seed stays in
-    the compile cache. Building the entry and loading that program
-    took 21 s of every set-up, so the number is kept beside the compile
-    cache under a key of everything it can depend on: the
+    plus this. ``served`` is the model the server registered. Where it
+    carries ``params`` its own device program is lowered with them
+    abstract (``launch_temp_bytes``) and nothing is built. Where it
+    does not, the entry is built once more by the program's own loading
+    path at its own initialisation: buffer sizes follow from shapes,
+    not from weights, and a program that does not change with the seed
+    stays in the compile cache (such weights are constants of the
+    module, so they are megabytes). Building the entry and loading that
+    program took 21 s of every set-up, so the number is kept beside the
+    compile cache under a key of everything it can depend on: the
     configuration, the shapes, the chip, the installation and every
     source file of the program."""
     import hashlib
@@ -296,8 +291,9 @@ def program_temp_bytes(root: pathlib.Path, cfg: dict, request: dict, batch_sizes
     import jaxlib
     from triton_client_tpu.runtime.disk_repository import build_model
 
+    shapes = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in launch.items()} for launch in launches]
     digest = hashlib.sha256(json.dumps(
-        [cfg, batch_sizes, {k: (v.shape, str(v.dtype)) for k, v in request.items()},
+        [cfg, [{k: (v.shape, str(v.dtype)) for k, v in launch.items()} for launch in shapes],
          jax.devices()[0].device_kind, jax.__version__, jaxlib.__version__], sort_keys=True, default=str).encode())
     for source in sorted((ROOT / "triton_client_tpu").rglob("*.py")):
         digest.update(source.read_bytes())
@@ -305,21 +301,23 @@ def program_temp_bytes(root: pathlib.Path, cfg: dict, request: dict, batch_sizes
     if kept is not None and kept.exists():
         return int(load_json(kept)["temp_size_in_bytes"])
 
-    argv = cfg["serve_argv"]
-    precision = argv[argv.index("--precision") + 1] if "--precision" in argv else None
-    name = write_repository(root, cfg, None, False, precision)
-    model = build_model(root / name)
-    temp = 0
-    for b in batch_sizes:
-        shapes = {
-            k: jax.ShapeDtypeStruct((b, *v.shape[1:]) if b else v.shape, v.dtype) for k, v in request.items()
-        }
-        analysis = jax.jit(model.device_fn).lower(shapes).compile().memory_analysis()
-        temp = max(temp, int(analysis.temp_size_in_bytes))
+    model = served
+    if served.params is None:
+        argv = cfg["serve_argv"]
+        precision = argv[argv.index("--precision") + 1] if "--precision" in argv else None
+        model = build_model(root / write_repository(root, cfg, None, False, precision))
+    temp = max(launch_temp_bytes(model, launch) for launch in shapes)
     if kept is not None:
         kept.parent.mkdir(parents=True, exist_ok=True)
-        kept.write_text(json.dumps({"temp_size_in_bytes": temp, "config": cfg["name"], "batch_sizes": batch_sizes}))
+        kept.write_text(json.dumps({"temp_size_in_bytes": temp, "config": cfg["name"],
+                                    "weights": "closure constants" if served.params is None else "abstract"}))
     return temp
+
+
+def bytes_on_device(devices, key: str) -> int:
+    """The allocator's ``key`` on the fullest of ``devices`` (0 where
+    the backend keeps no statistics)."""
+    return max((int((d.memory_stats() or {}).get(key, 0)) for d in devices), default=0)
 
 
 def main(argv=None) -> int:
@@ -368,36 +366,46 @@ def main(argv=None) -> int:
     generator = importlib.import_module(f"benchmarks.inputs.{traffic['inputs']['generator']}")
     params = input_params(traffic, cfg, args.rehearse)
 
+    check = check_module(cfg)
     work = pathlib.Path(args.work)
-    n_requests = max(1, cfg["check"]["sample_items"] // traffic["items_per_request"])
-    if args.rehearse:
-        n_requests = min(n_requests, cfg["rehearsal"].get("sample_requests", 4))
-    sample = generator.make(seeded(args.seed, 1), n_requests, params, cfg)
+    sample = generator.make(seeded(args.seed, 1), sample_size(cfg, traffic, args.rehearse), params, cfg)
+    first = loadgen.first_request(sample)
+    launches = [check.launch_request(first, b) for b in traffic["launch_batch_sizes"]]
     # the benchmark's own two programs do not depend on the seed; they
     # live in a subdirectory of the cache, where the served launchers
     # (new constants, so new entries, with every seed) cannot evict them
     with cache_subdirectory(cache_dir, "benchmark"):
-        tree = make_weights(reference, cfg, args.seed, calibration_input(generator, traffic, params, cfg, args.seed))
+        calibration = calibration_input(generator, traffic, params, cfg, args.seed)
+        tree = make_weights(reference, cfg, args.seed, calibration)
         marks["weights_s"] = time.perf_counter() - T0
-        ref_stats = run_reference(reference, cfg, tree, sample, work / "reference.npz")
+        ref_stats = check.expected(reference, cfg, tree, sample, work / "reference.npz")
         marks["reference_s"] = time.perf_counter() - T0
-        temp_bytes = 0 if args.rehearse else program_temp_bytes(
-            work / "shapes", cfg, sample[0], traffic["launch_batch_sizes"],
-            os.path.join(cache_dir, "benchmark") if cache_dir else "")
-        marks["program_temp_s"] = time.perf_counter() - T0
-    # what the yardstick itself took of the device, before the server holds anything
-    marks["peak_bytes_before_server"] = int((devices[0].memory_stats() or {}).get("peak_bytes_in_use", 0))
     name = write_repository(work / "repo", cfg, tree, args.rehearse)
+    # the weights are on the device once: the yardstick lets go of everything it holds there (the
+    # tree, the calibration input, the reference's programs and outputs) before the server loads them
+    del tree, calibration
+    gc.collect()
+    jax.clear_caches()
+    before = {"bytes_in_use_before_server": bytes_on_device(devices[: args.chips], "bytes_in_use"),
+              "peak_bytes_before_server": bytes_on_device(devices[: args.chips], "peak_bytes_in_use")}
+    marks.update(before)
+    print(json.dumps(before), flush=True)
 
     trace_argv = ["--trace-capacity", "4096" if args.trace else "0"]
     server, serve_args = start_server(work / "repo", [*cfg["serve_argv"], *trace_argv])
     marks["server_s"] = time.perf_counter() - T0
+    with cache_subdirectory(cache_dir, "benchmark"):
+        temp_bytes = 0 if args.rehearse else program_temp_bytes(
+            work / "shapes", cfg, launches, served_model(server, name),
+            os.path.join(cache_dir, "benchmark") if cache_dir else "")
+    marks["program_temp_s"] = time.perf_counter() - T0
 
     written = []  # the persistent cache records a miss when it writes a newly compiled program
     jax.monitoring.register_event_listener(
         lambda event, **kw: written.append(event) if event.endswith("/cache_misses") else None
     )
-    compile_s = compile_launch_shapes(server, name, sample[0], traffic["launch_batch_sizes"])
+    compile_s = compile_launch_shapes(server, name, launches)
+    del launches
     marks["compiled_s"] = time.perf_counter() - T0
 
     say({
@@ -439,11 +447,14 @@ def main(argv=None) -> int:
         from benchmarks import trace_reduce
 
         profile = trace_reduce.reduce_dir(log_dir, args.chips, args.record_trace or None)
-    stats = [d.memory_stats() or {} for d in devices[: args.chips]]
-    peak = max((s.get("peak_bytes_in_use", 0) for s in stats), default=0)
+    peak = bytes_on_device(devices[: args.chips], "peak_bytes_in_use")
     drained = server.drain(timeout_s=serve_args.drain_timeout)
-    say({"done": True, "memory_peak_bytes": int(peak) + temp_bytes, "memory_buffers_peak_bytes": int(peak),
-         "memory_program_temp_bytes": temp_bytes, "profile": profile, "drained": bool(drained)})
+    # the allocator's peak is the process's and never falls: where it has not risen since the server
+    # started, the yardstick's own buffers (the reference, the second copy of the weights) set it
+    phase = "not stated" if not peak else "server" if peak > marks["peak_bytes_before_server"] else "yardstick"
+    say({"done": True, "memory_peak_bytes": peak + temp_bytes, "memory_buffers_peak_bytes": peak,
+         "memory_program_temp_bytes": temp_bytes, "memory_peak_before_server_bytes": marks["peak_bytes_before_server"],
+         "memory_peak_phase": phase, "profile": profile, "drained": bool(drained)})
     return 0
 
 

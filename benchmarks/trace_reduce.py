@@ -26,7 +26,14 @@ device's own lines, not by a host event.
     last round of warm-up, so what is left lies in the window). NOT the host
     plane's span: the host tracer starts before and stops after the
     device's, and its events do not say when the device was traced;
-  * launches: per module name, count and summed device time;
+  * launches: per module name, count and summed device time, of the
+    modules that lie INSIDE the window: one that touches its head
+    (begun before the head left out ended, or at the trace's own first
+    event where the trace is read whole) or its end (nothing on the
+    device's lines ends after it) is left out of both. The profiler
+    stops inside a launch, and a module cut there, counted as a whole
+    launch with part of its time, read 197.6 ms a launch where the host
+    clock said 208.5 (16-17 modules in the trace; PERF.md section 6);
   * breakdown: the ten ops with most device time, and the idle time
     by where the gap lies: ``inside <module>`` (a program is running
     and no op is: it waits for memory or a transfer) or ``between
@@ -111,10 +118,15 @@ def reduce(planes: dict, chips: int) -> dict:
         op_events = lines.get(OPS_LINE)
         if op_events is None:
             raise ValueError(f"{name}: no {OPS_LINE!r} line among {sorted(lines)}")
+        module_events = lines.get(MODULES_LINE, [])
+        first = min(s for _, s, _ in op_events + module_events)  # the trace's own edges on this chip
+        last = max(s + d for _, s, d in op_events + module_events)
         head = min(s for _, s, _ in op_events) + int(HEAD_LEFT_OUT_S * 1e9)
         if any(s >= head for _, s, _ in op_events):  # a trace shorter than the head is read whole
             op_events = [e for e in op_events if e[1] >= head]
-            lines = {**lines, MODULES_LINE: [e for e in lines.get(MODULES_LINE, []) if e[1] >= head]}
+            module_events = [e for e in module_events if e[1] >= head]  # traced from their start
+            first = head - 1
+        whole = [e for e in module_events if first < e[1] and e[1] + e[2] < last]  # touch neither edge
         spans = [(s, s + d) for _, s, d in op_events]
         covered, merged = union_ns(spans)
         busy.append(covered)
@@ -123,11 +135,11 @@ def reduce(planes: dict, chips: int) -> dict:
         for op, _, d in op_events:
             op = op_name(op)
             ops[op] = ops.get(op, 0) + d
-        for mod, _, d in lines.get(MODULES_LINE, []):
+        for mod, _, d in whole:
             row = launches.setdefault(module_name(mod), [0, 0])
             row[0] += 1
             row[1] += d
-        modules = sorted((s, s + d, module_name(n)) for n, s, d in lines.get(MODULES_LINE, []))
+        modules = sorted((s, s + d, module_name(n)) for n, s, d in module_events)
         module_starts = [m[0] for m in modules]
         for a, b in zip(merged, merged[1:]):
             i = bisect.bisect_right(module_starts, a[1]) - 1  # the program running when the gap opens
