@@ -147,12 +147,12 @@ def test_examples_tree_parses():
 
     root = pathlib.Path("examples")
     dirs = sorted(p for p in root.iterdir() if (p / "config.yaml").exists())
-    assert len(dirs) == 14
+    assert len(dirs) == 15
     for d in dirs:
         doc = load_yaml(str(d / "config.yaml"))
         if doc["family"] == "ensemble":
             continue  # validated by scan_disk against member specs
-        assert doc["family"] in dr._families_2d() + dr._families_3d(), d
+        assert doc["family"] in dr._families_2d() + dr._families_3d() + dr._families_lm(), d
         assert not set(doc) - dr._TOP_KEYS, d
         for key in ("dataset",):
             if key in doc:

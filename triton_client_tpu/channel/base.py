@@ -70,6 +70,13 @@ class InferRequest:
     sequence_end: bool = dataclasses.field(
         default=False, repr=False, compare=False
     )
+    # a launch the batcher merged from the requests of SEVERAL sessions
+    # of a model that declares mergeable sessions (runtime/sessions.py
+    # TokenSessions): row by row, (sequence_id, start, end). None on
+    # every other request, whose own three fields above say it all.
+    sequence_rows: tuple | None = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
 
 @dataclasses.dataclass

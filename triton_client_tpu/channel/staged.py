@@ -46,6 +46,7 @@ from triton_client_tpu.channel.base import (
     InferResponse,
 )
 from triton_client_tpu.config import ModelSpec
+from triton_client_tpu.obs.roofline import name_launcher
 from triton_client_tpu.parallel.mesh import MeshConfig, make_mesh
 from triton_client_tpu.runtime import faults
 from triton_client_tpu.runtime.admission import (
@@ -115,7 +116,7 @@ class StagedRequest:
 
     __slots__ = (
         "model", "device_inputs", "request", "t_stage", "meta",
-        "lifecycle_key", "trace_state",
+        "lifecycle_key", "trace_state", "session",
     )
 
     def __init__(self, model, device_inputs, request, t_stage, meta=None) -> None:
@@ -134,6 +135,140 @@ class StagedRequest:
         # but h2d, into which launch() writes ``launch_id`` once the
         # ordinal is known; the open ``h2d`` span is closed by resolve()
         self.trace_state = None
+        # (state, ticket) of the session state this launch is bracketed
+        # by (runtime/sessions.py: the model's own, else the server's
+        # tracker for a request under a sequence_id), or None
+        self.session = None
+
+
+def _row_major(x):
+    """A device array as the launch programs take their state: row-major.
+
+    A fresh array has the layout the compiler likes for its shape, which
+    on a TPU need not be row-major (bfloat16 ``[6, 40, 4352, 576]`` comes
+    with the 4,352 minor, to save padding 576 to 640 lanes). Such an
+    array is moved once, at load; but the move cannot be relied on: JAX's
+    persistent compile cache forgets a pinned output layout of a program
+    it loads (honoured in a first process, not in a second: my chip
+    runs, PR 29), so a state that still is not row-major is refused, with
+    the cure: give it a shape whose natural layout is row-major (rows of
+    whole 128-lane tiles, as models/axk1.py does)."""
+    from jax.experimental.layout import Format, Layout
+
+    if not isinstance(x, jax.Array):
+        return x  # shapes and types alone (a lowering ahead of any array)
+    want = tuple(range(x.ndim))
+    if tuple(x.format.layout.major_to_minor) != want:
+        x = jax.device_put(x, Format(Layout(major_to_minor=want), x.sharding))
+        if tuple(x.format.layout.major_to_minor) != want:
+            raise ValueError(
+                f"device state {x.shape} {x.dtype} has layout {x.format.layout} "
+                "on this device and could not be moved to row-major: pad its "
+                "last dimension to whole 128-lane tiles"
+            )
+    return x
+
+
+class ParamLauncher:
+    """The launcher of a model that registers ``params``: its weights
+    are ARGUMENTS of the jitted device program, not constants of its
+    module (gigabytes cannot be, and a module without them stays in the
+    compile cache whatever the weights). Where the model names a device
+    state (``spec.extra["device_state"]``: a key of ``params`` and of
+    the program's outputs, such as a language model's cache), that
+    subtree is DONATED into every launch and the one the launch returns
+    is kept in its place: it never leaves the device and no launch
+    copies it. Launches of one model are dispatched one at a time (the
+    state threads through them in order); each launch shape is compiled
+    ahead of the lock, so the shapes of a warm-up compile side by side.
+    """
+
+    def __init__(self, model, body) -> None:
+        self._model = model
+        self._state_key = key = model.spec.extra.get("device_state")
+
+        def run(donated, kept, weights, state):
+            params = weights if key is None else {**weights, key: state}
+            out = dict(body({**donated, **kept}, params))
+            return out, (out.pop(key) if key is not None else None)
+
+        self._run = run
+        if key is not None:
+            model.params[key] = jax.tree_util.tree_map(
+                _row_major, model.params[key]
+            )
+        self._programs: dict = {}  # launch kind -> its jitted program
+        self._lock = threading.Lock()
+        self._compiled: dict = {}
+
+    def _program(self, inputs: dict):
+        """The jitted program for these inputs. A model whose session
+        state names its launches' kinds (``launch_kind(inputs)``: a
+        language model's step and prefill) gets one named module a kind,
+        ``jit_mdl_<name>_<version>_<kind>``, so that a device trace tells
+        them apart; any other model has the one ``jit_mdl_<name>_<version>``.
+        The state keeps ONE layout, row-major, on its way in and out: the
+        compiler is otherwise free to give each launch shape's result a
+        layout of its own, and every launch then begins and ends with a
+        copy of the whole state."""
+        kind_of = getattr(self._model.sessions, "launch_kind", None)
+        kind = kind_of(inputs) if kind_of is not None else ""
+        program = self._programs.get(kind)
+        if program is None:
+            fn = lambda *args: self._run(*args)
+            name_launcher(fn, self._model)
+            if kind:
+                fn.__name__ = fn.__qualname__ = f"{fn.__name__}_{kind}"
+            key, pinned = self._state_key, {}
+            if key is not None:
+                pinned = {
+                    "in_shardings": (None, None, None, self._state_format()),
+                    "out_shardings": (None, self._state_format()),
+                }
+            program = self._programs[kind] = jax.jit(
+                fn, donate_argnums=(0,) if key is None else (0, 3), **pinned
+            )
+        return program
+
+    def _state_format(self):
+        """Row-major for every leaf of the state, on the device it is on."""
+        from jax.experimental.layout import Format, Layout
+
+        return jax.tree_util.tree_map(
+            lambda x: Format(Layout(major_to_minor=tuple(range(x.ndim))), x.sharding),
+            self._model.params[self._state_key],
+        )
+
+    def _params(self):
+        params, key = self._model.params, self._state_key
+        if key is None:
+            return params, None
+        return {k: v for k, v in params.items() if k != key}, params[key]
+
+    def lower(self, donated, kept):
+        """The launch's program for these inputs' shapes, the weights
+        and the state as shapes and types (nothing read or donated)."""
+        abstract = lambda tree: jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree
+        )
+        weights, state = self._params()
+        return self._program({**donated, **kept}).lower(
+            donated, kept, abstract(weights), abstract(state)
+        )
+
+    def __call__(self, donated, kept):
+        shape_key = tuple(
+            sorted((k, v.shape, str(v.dtype)) for k, v in {**donated, **kept}.items())
+        )
+        compiled = self._compiled.get(shape_key)
+        if compiled is None:
+            compiled = self._compiled[shape_key] = self.lower(donated, kept).compile()
+        with self._lock:
+            weights, state = self._params()
+            out, state = compiled(donated, kept, weights, state)
+            if self._state_key is not None:
+                self._model.params[self._state_key] = state
+        return out
 
 
 @jax.jit
@@ -248,6 +383,7 @@ class StagedChannel(BaseChannel):
         # output wire dtypes); rebuilt when the repository reloads the
         # model (identity mismatch)
         self._launch_cache: dict = {}
+        self._launcher_build = threading.Lock()
         # models whose measured flops/bytes (obs/roofline.py) were
         # already recorded into spec.extra — one attempt per model
         # identity, success or not, so a cost-model failure cannot
@@ -485,6 +621,21 @@ class StagedChannel(BaseChannel):
                         f"{sorted(request.inputs)}"
                     )
                 tensor_spec.validate(np.asarray(request.inputs[tensor_spec.name]))
+        # the session state this launch belongs to: the model's own
+        # (a token model: its rows get their cache slots and positions
+        # here, and the request becomes the launch's plain arrays), else
+        # the server's tracker for a request under a sequence_id
+        state = model.sessions
+        if state is None and request.sequence_id:
+            state = self._sessions
+        session = None
+        if state is not None:
+            try:
+                request, ticket = state.open(request)
+            except Exception:
+                self._count_shed(model.spec.name, request.priority, "session")
+                raise
+            session = (state, ticket)
         lifecycle_key = None
         if self._lifecycle is not None:
             # block until the model is WARM (a cold model promotes on
@@ -500,6 +651,8 @@ class StagedChannel(BaseChannel):
                 )
             except Exception:
                 self._count_shed(model.spec.name, request.priority, "lifecycle")
+                if session is not None:
+                    session[0].abort(session[1])
                 raise
             if tr is not None:
                 tr.add("lifecycle", t_p0, time.perf_counter())
@@ -520,12 +673,15 @@ class StagedChannel(BaseChannel):
             self._release_slot()
             if lifecycle_key is not None:
                 self._lifecycle.release(*lifecycle_key)
+            if session is not None:
+                session[0].abort(session[1])
             raise
         with self._slot_cv:
             self._stats["staged"] += 1
         t_staged = time.perf_counter()
         staged = StagedRequest(model, device_inputs, request, t_staged, meta)
         staged.lifecycle_key = lifecycle_key
+        staged.session = session
         if tr is not None:
             # the stage phase: validate + slot admission + the ENQUEUE of
             # the H2D copy (device_put returns before the bytes moved)
@@ -609,6 +765,7 @@ class StagedChannel(BaseChannel):
             # instead of burning a device slot on work nobody can use
             self._release_slot()
             self._release_lifecycle(staged)
+            self._abort_session(staged)
             self._count_shed(name, request.priority, "launch")
             return InferFuture.failed(
                 DeadlineExpiredError(
@@ -619,6 +776,7 @@ class StagedChannel(BaseChannel):
         if self._breaker is not None and not self._breaker.allow(name, t0):
             self._release_slot()
             self._release_lifecycle(staged)
+            self._abort_session(staged)
             self._count_shed(name, request.priority, "breaker")
             return InferFuture.failed(
                 CircuitOpenError(
@@ -683,21 +841,25 @@ class StagedChannel(BaseChannel):
             # itself needs a timeout)
             self._release_slot()
             self._release_lifecycle(staged)
+            self._abort_session(staged)
             self._record_launch_failure(name)
             return InferFuture.failed(e)
-        sessions = self._sessions
-        session_id = request.sequence_id if sessions is not None else ""
-        if session_id:
-            # append the stream's device-resident tracking step to this
-            # launch: async jit dispatch over arrays already in HBM —
-            # the track tensors join the outputs, the state pytree
-            # stays on device inside the session slot. The slot ref
-            # advance() takes is dropped in resolve's finally.
+        session = staged.session
+        session_id = request.sequence_id if session is not None else ""
+        if session is not None:
+            # the session state's step on the launched outputs: the
+            # tracker appends the stream's device-resident tracking step
+            # (async jit dispatch over arrays already in HBM; the track
+            # tensors join the outputs, the state pytree stays on device
+            # inside the session slot, and the slot ref advance() takes
+            # is dropped by close() in resolve's finally); a token model
+            # runs nothing more (close() cuts the logits' pad rows on the host)
             try:
-                outputs = sessions.advance(request, outputs)
+                outputs = session[0].advance(session[1], outputs)
             except Exception as e:
                 self._release_slot()
                 self._release_lifecycle(staged)
+                self._abort_session(staged)
                 self._count_shed(name, request.priority, "session")
                 return InferFuture.failed(e)
         rec = _Inflight(outputs)
@@ -722,8 +884,11 @@ class StagedChannel(BaseChannel):
             tr.add("launch", t0, t_launched, ids)
 
         ledger = self._device_time
+        # a token launch names its span: lm_prefill or lm_step
+        launch_span = getattr(session[1], "span", None) if session else None
 
         def resolve() -> InferResponse:
+            host = None
             try:
                 if tr is not None or ledger is not None:
                     if h2d is not None:
@@ -745,6 +910,11 @@ class StagedChannel(BaseChannel):
                     t_ready = time.perf_counter()
                     if tr is not None:
                         tr.add("device_execute", t_launched, t_ready, ids)
+                        if launch_span is not None:
+                            tr.add(
+                                launch_span[0], t_launched, t_ready,
+                                {**ids, **launch_span[1]},
+                            )
                     if ledger is not None:
                         # session frames accrue under a per-stream
                         # tenant, so the ledger's tenant axis answers
@@ -768,8 +938,8 @@ class StagedChannel(BaseChannel):
             finally:
                 self._retire(rec)
                 self._release_lifecycle(staged)
-                if session_id:
-                    sessions.release(session_id)
+                if session is not None:
+                    session[0].close(session[1], host)
             if self._breaker is not None:
                 self._breaker.record_success(name)
             return InferResponse(
@@ -794,9 +964,18 @@ class StagedChannel(BaseChannel):
             cached = self._launch_cache.get(key)
             if cached is not None and cached[0] is model:
                 return cached[1], cached[2], cached[3]
-        launcher, donate_names, out_dtype = self._make_launcher(model)
-        with self._slot_cv:
-            self._launch_cache[key] = (model, launcher, donate_names, out_dtype)
+        # one launcher a model: a model that registers params hands its
+        # launcher the device state to thread through its launches, and
+        # two launchers built side by side (the first requests of a
+        # warm-up arrive together) would each donate the other's state
+        with self._launcher_build:
+            with self._slot_cv:
+                cached = self._launch_cache.get(key)
+                if cached is not None and cached[0] is model:
+                    return cached[1], cached[2], cached[3]
+            launcher, donate_names, out_dtype = self._make_launcher(model)
+            with self._slot_cv:
+                self._launch_cache[key] = (model, launcher, donate_names, out_dtype)
         return launcher, donate_names, out_dtype
 
     def _ensure_launch_cost(
@@ -864,6 +1043,32 @@ class StagedChannel(BaseChannel):
     @property
     def sessions(self):
         return self._sessions
+
+    def _abort_session(self, staged: StagedRequest) -> None:
+        """A staged launch that never reached the device: its session
+        state takes back what ``open`` gave."""
+        if staged.session is not None:
+            staged.session[0].abort(staged.session[1])
+
+    def session_stats(self) -> dict | None:
+        """The tracker sessions' counters (where a manager is attached)
+        and, under ``models``, those of every registered model that
+        declares a session state of its own."""
+        out = self._sessions.stats() if self._sessions is not None else None
+        models = {}
+        for name, version in self._repository.list_models():
+            state = self._repository.get(name, version).sessions
+            if state is not None:
+                models[name] = state.stats()
+        if models:
+            out = {**(out or {}), "models": models}
+        return out
+
+    def served_model(self, name: str, version: str = ""):
+        """The model as this channel serves it (its registered entry:
+        ``spec``, ``device_fn``, ``params`` as they stand on the
+        device): the handle a memory statement or a warm-up lowers."""
+        return self._repository.get(name, version)
 
     def _warm_model(self, name: str, version: str) -> None:
         """Lifecycle page-in hook: build + cache the jitted launcher (the

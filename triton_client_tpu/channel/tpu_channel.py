@@ -34,6 +34,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from triton_client_tpu.channel.staged import (  # noqa: F401 — re-exported
+    ParamLauncher,
     StagedChannel,
     StagedRequest,
     _Inflight,
@@ -73,6 +74,13 @@ class TPUChannel(StagedChannel):
             frozenset(model.spec.donatable_inputs()) if self._donate else frozenset()
         )
         device_fn = self._device_body(model)
+        out_dtype = {
+            t.name: config_dtypes().get(t.dtype) for t in model.spec.outputs
+        }
+        if model.params is not None:
+            # weights (and a declared device state) as launcher
+            # arguments: staged.ParamLauncher
+            return ParamLauncher(model, device_fn), donate_names, out_dtype
         # the launcher carries the model's name so its HLO module is
         # jit_mdl_<name>_<version> — profiler op events then attribute
         # back to the model by module name (obs/opstats.py)
@@ -82,7 +90,4 @@ class TPUChannel(StagedChannel):
             ),
             donate_argnums=(0,),
         )
-        out_dtype = {
-            t.name: config_dtypes().get(t.dtype) for t in model.spec.outputs
-        }
         return launcher, donate_names, out_dtype

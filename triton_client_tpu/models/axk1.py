@@ -1,0 +1,341 @@
+"""A.X-K1 (``model_type`` ``axk1``): latent attention and routed experts,
+served as one chip's share of a wider deployment.
+
+The published block (https://huggingface.co/skt/A.X-K1): RMSNorm,
+multi-head latent attention (ops/latent_attention.py) with YaRN rotary
+embeddings (ops/rope.py), one leading dense SwiGLU layer, then layers
+of sigmoid-routed experts (ops/experts.py) beside a shared one. This
+chip holds ``experts_here`` of the ``router_experts`` routed experts of
+each layer, ``vocab_size`` rows of embedding and head and
+``num_hidden_layers`` layers; every width is the published one.
+
+Functional: parameters are a pytree (bfloat16 matrices ``[in, out]``,
+float32 norm scales), the cache is an array ``[layers, slots, slot_len,
+kv_rank + rope]`` that a launch takes in and gives back. One operation,
+*extend*: a launch appends ``lengths[b]`` tokens to the session in slot
+``slots[b]`` from position ``positions[b]`` on and answers the logits
+of each row's last appended position. A launch of one row with many
+tokens (a prompt, a further turn) expands the slot's latents per head;
+a launch of many rows with one token each (decoded steps of different
+sessions) runs the absorbed form. The router, norms, softmax and logits
+are float32; everything a matrix product reads is bfloat16.
+
+The layers after the dense ones run under ONE ``lax.scan`` (their
+weights stacked on a leading axis by :func:`stack_layers`), so each
+kernel is one op name in a device trace.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from triton_client_tpu.ops import experts as experts_op
+from triton_client_tpu.ops import latent_attention, rope
+
+#: the keys an entry's ``model`` block may hold beside ``rope_scaling`` and ``precision``
+_PUBLISHED = {
+    "hidden_size", "intermediate_size", "moe_intermediate_size",
+    "num_attention_heads", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+    "qk_rope_head_dim", "v_head_dim", "router_experts", "experts_here",
+    "expert_offset", "n_shared_experts", "num_experts_per_tok",
+    "norm_topk_prob", "routed_scaling_factor", "num_hidden_layers",
+    "first_k_dense_replace", "vocab_size", "rms_norm_eps", "rope_theta",
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class AXK1Config:
+    """The published sizes (defaults) and this chip's share."""
+
+    hidden_size: int = 7168
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    router_experts: int = 192  # the router's width: every expert of the model
+    experts_here: int = 12  # held on this chip ...
+    expert_offset: int = 0  # ... from this one on
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    num_hidden_layers: int = 6
+    first_k_dense_replace: int = 1
+    vocab_size: int = 20480
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    yarn: rope.YarnConfig = rope.YarnConfig()
+
+    @classmethod
+    def from_dict(cls, doc: dict) -> "AXK1Config":
+        doc = dict(doc)
+        scaling = dict(doc.pop("rope_scaling", {}))
+        doc.pop("precision", None)  # the serving policy's, not a size
+        unknown = set(doc) - _PUBLISHED
+        if unknown:
+            raise KeyError(f"axk1 model config: unknown keys {sorted(unknown)}")
+        cfg = cls(**doc)
+        yarn = rope.YarnConfig(
+            dim=cfg.qk_rope_head_dim,
+            theta=float(cfg.rope_theta),
+            factor=float(scaling.get("factor", 32.0)),
+            original_max_position=int(
+                scaling.get("original_max_position_embeddings", 4096)
+            ),
+            beta_fast=float(scaling.get("beta_fast", 32.0)),
+            beta_slow=float(scaling.get("beta_slow", 1.0)),
+            mscale=float(scaling.get("mscale", 1.0)),
+            mscale_all_dim=float(scaling.get("mscale_all_dim", 1.0)),
+        )
+        return dataclasses.replace(cfg, yarn=yarn)
+
+    @property
+    def cache_width(self) -> int:
+        """Values a cached position holds: the latent and the rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_row(self) -> int:
+        """A cache row as it is allocated: ``cache_width`` rounded up to
+        whole 128-lane tiles (576 -> 640), the tail zero. The chip pads a
+        576-wide row to 640 lanes anyway, or, left to choose, lays the
+        array out with the POSITIONS minor; a row of whole tiles has one
+        natural layout, row-major, which every launch program and a fresh
+        array agree on without being told (channel/staged.py)."""
+        return -(-self.cache_width // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * (
+            rope.attention_mscale(self.yarn) ** 2
+        )
+
+
+def _normal(key, shape, std):
+    if len(shape) >= 3:
+        return jax.lax.map(
+            lambda k: _normal(k, shape[1:], std), jax.random.split(key, shape[0])
+        )
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(jnp.bfloat16)
+
+
+def _mlp(key, d, f, lead=()):
+    k = jax.random.split(key, 3)
+    return {
+        "gate": _normal(k[0], (*lead, d, f), d**-0.5),
+        "up": _normal(k[1], (*lead, d, f), d**-0.5),
+        "down": _normal(k[2], (*lead, f, d), f**-0.5),
+    }
+
+
+def init_params(key, cfg: AXK1Config) -> dict:
+    """The program's own initialisation (an entry without a weights
+    file serves it): the layout a ``weights.msgpack`` has, layer by
+    layer under ``layers/<i>``."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    keys = jax.random.split(key, cfg.num_hidden_layers + 2)
+    layers = {}
+    for i in range(cfg.num_hidden_layers):
+        k = jax.random.split(keys[i], 10)
+        layer = {
+            "norm1": jnp.ones((d,), jnp.float32),
+            "norm2": jnp.ones((d,), jnp.float32),
+            "attn": {
+                "q_a": _normal(k[0], (d, cfg.q_lora_rank), d**-0.5),
+                "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
+                "q_b": _normal(k[1], (cfg.q_lora_rank, h * qk), cfg.q_lora_rank**-0.5),
+                "kv_a": _normal(k[2], (d, cfg.cache_width), d**-0.5),
+                "kv_norm": jnp.ones((cfg.kv_lora_rank,), jnp.float32),
+                "kv_b": _normal(
+                    k[3],
+                    (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                    cfg.kv_lora_rank**-0.5,
+                ),
+                "o": _normal(k[4], (h * cfg.v_head_dim, d), 0.5 * (h * cfg.v_head_dim) ** -0.5),
+            },
+        }
+        if i < cfg.first_k_dense_replace:
+            layer["mlp"] = _mlp(k[5], d, cfg.intermediate_size)
+        else:
+            layer["router"] = _normal(k[6], (d, cfg.router_experts), 1.5 * d**-0.5)
+            layer["shared"] = _mlp(
+                k[7], d, cfg.moe_intermediate_size * cfg.n_shared_experts
+            )
+            layer["experts"] = _mlp(
+                k[8], d, cfg.moe_intermediate_size, (cfg.experts_here,)
+            )
+        layers[str(i)] = layer
+    return {
+        "embed": _normal(keys[-2], (cfg.vocab_size, d), 1.0),
+        "head": _normal(keys[-1], (d, cfg.vocab_size), 2.0 * d**-0.5),
+        "final_norm": jnp.ones((d,), jnp.float32),
+        "layers": layers,
+    }
+
+
+def abstract_params(cfg: AXK1Config):
+    """The tree's shapes and types, nothing built."""
+    return jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+
+
+def stack_layers(tree: dict, cfg: AXK1Config) -> dict:
+    """The served form of a loaded tree: the dense layers as they are,
+    the expert layers stacked leaf by leaf on a leading axis for the
+    scan. TAKES the per-layer leaves out of ``tree`` (its ``layers`` is
+    left empty) and drops each group of them as its stack is built, so
+    at most one stacked leaf exists twice: beside 8.33 GB of weights a
+    second copy of the expert layers (6.75 GB) does not fit a chip."""
+    layers = tree["layers"]
+    n_dense = cfg.first_k_dense_replace
+    dense = [layers.pop(str(i)) for i in range(n_dense)]
+    rest = [layers.pop(str(i)) for i in range(n_dense, cfg.num_hidden_layers)]
+    stacked = None
+    if rest:
+        flat = [jax.tree_util.tree_flatten(layer) for layer in rest]
+        treedef = flat[0][1]
+        columns = [list(leaves) for leaves, _ in flat]
+        del rest, flat  # the columns alone hold the per-layer leaves now
+        out = []
+        for j in range(len(columns[0])):
+            out.append(jnp.stack([col[j] for col in columns]))
+            for col in columns:
+                col[j] = None
+        stacked = jax.tree_util.tree_unflatten(treedef, out)
+    return {
+        "embed": tree["embed"], "head": tree["head"],
+        "final_norm": tree["final_norm"], "dense": dense, "moe": stacked,
+    }
+
+
+def empty_cache(cfg: AXK1Config, slots: int, slot_len: int):
+    return jnp.zeros(
+        (cfg.num_hidden_layers, slots, slot_len, cfg.cache_row), jnp.bfloat16
+    )
+
+
+# -- the forward pass -----------------------------------------------------------
+
+
+def _rms(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def _swiglu(x, p):
+    return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]
+
+
+def _attention(cfg, p, x, kv, layer, slots, positions, valid, cos, sin):
+    """``x [B, n, D]`` bfloat16 normalised. Writes the new tokens'
+    ``(c, kr)`` into ``kv[layer]`` (pad tokens are dropped), then
+    attends. Returns the attention output ``[B, n, D]`` and ``kv``."""
+    b, n, _ = x.shape
+    h, nope, rp = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    rank, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
+    bf = jnp.bfloat16
+    q = (_rms(x @ p["q_a"], p["q_norm"], eps).astype(bf) @ p["q_b"]).reshape(b, n, h, nope + rp)
+    q_nope = q[..., :nope]
+    q_rope = rope.apply_rope(
+        q[..., nope:].astype(jnp.float32), cos[:, :, None], sin[:, :, None]
+    ).astype(bf)
+    ckr = x @ p["kv_a"]
+    c = _rms(ckr[..., :rank], p["kv_norm"], eps)
+    kr = rope.apply_rope(ckr[..., rank:].astype(jnp.float32), cos, sin)
+    tail = jnp.zeros((b, n, kv.shape[-1] - cfg.cache_width), jnp.float32)
+    new = jnp.concatenate([c, kr, tail], axis=-1).astype(bf)
+    slot_len = kv.shape[2]
+    # a pad token's position is past the slot: dropped, so a launch of
+    # pad rows alone (a compile) writes nothing and disturbs no session
+    where = jnp.where(valid, positions, slot_len)
+    kv = kv.at[layer, slots[:, None], where].set(new, mode="drop")
+    kv_b = p["kv_b"].reshape(rank, h, -1)
+    if n == 1:
+        out = latent_attention.absorbed_attention(
+            q_nope[:, 0], q_rope[:, 0], kv, layer, slots, positions[:, 0],
+            kv_b, cfg.softmax_scale, nope,
+        )[:, None]
+    else:
+        # many tokens: ONE session a launch (pipelines/lm.py forms it so)
+        assert b == 1, "a launch of many tokens a row holds one session"
+        rows = jax.lax.dynamic_index_in_dim(
+            jax.lax.dynamic_index_in_dim(kv, layer, 0, keepdims=False),
+            slots[0], 0, keepdims=False,
+        )
+        out = latent_attention.expanded_attention(
+            q_nope[0], q_rope[0], rows, positions[0], kv_b,
+            cfg.softmax_scale, nope,
+        )[None]
+    return out.reshape(b, n, -1) @ p["o"], kv
+
+
+def _layer(cfg, p, hidden, kv, layer, slots, positions, valid, cos, sin):
+    """One layer; returns the stream, the cache and the rows each held
+    expert saw (zeros for a dense layer)."""
+    bf = jnp.bfloat16
+    eps = cfg.rms_norm_eps
+    a, kv = _attention(
+        cfg, p["attn"], _rms(hidden, p["norm1"], eps).astype(bf), kv, layer,
+        slots, positions, valid, cos, sin,
+    )
+    hidden = hidden + a.astype(jnp.float32)
+    x32 = _rms(hidden, p["norm2"], eps)
+    x = x32.astype(bf)
+    if "mlp" in p:
+        return hidden + _swiglu(x, p["mlp"]).astype(jnp.float32), kv, None
+    b, n, d = x.shape
+    idx, gates = experts_op.route(
+        x32.reshape(b * n, d), p["router"], cfg.num_experts_per_tok,
+        cfg.routed_scaling_factor, cfg.norm_topk_prob,
+    )
+    y, rows = experts_op.routed_experts(
+        x.reshape(b * n, d), valid.reshape(-1), idx, gates, p["experts"],
+        cfg.expert_offset,
+    )
+    shared = _swiglu(x, p["shared"]).astype(jnp.float32)
+    return hidden + y.reshape(b, n, d) + shared, kv, rows
+
+
+def extend(cfg: AXK1Config, weights: dict, kv, tokens, slots, positions, lengths):
+    """Append ``lengths[b]`` of ``tokens [B, n]`` to the session in slot
+    ``slots[b]`` from ``positions[b]`` on. Returns ``logits [B, V]``
+    float32 of each row's last appended position, ``expert_rows
+    [expert layers, experts_here]`` int32 and the cache."""
+    b, n = tokens.shape
+    offsets = jnp.arange(n, dtype=jnp.int32)[None, :]
+    valid = offsets < lengths[:, None]
+    pos = positions[:, None] + offsets
+    cos, sin = rope.rope_tables(pos, cfg.yarn)
+    hidden = weights["embed"][tokens].astype(jnp.float32)
+    layer = 0
+    for p in weights["dense"]:
+        hidden, kv, _ = _layer(cfg, p, hidden, kv, layer, slots, pos, valid, cos, sin)
+        layer += 1
+    n_moe = cfg.num_hidden_layers - cfg.first_k_dense_replace
+    expert_rows = jnp.zeros((0, cfg.experts_here), jnp.int32)
+    if n_moe:
+
+        def body(carry, xs):
+            hidden, kv = carry
+            p, i = xs
+            hidden, kv, rows = _layer(cfg, p, hidden, kv, i, slots, pos, valid, cos, sin)
+            return (hidden, kv), rows
+
+        (hidden, kv), expert_rows = jax.lax.scan(
+            body, (hidden, kv),
+            (weights["moe"], jnp.arange(layer, layer + n_moe, dtype=jnp.int32)),
+        )
+    last = jnp.clip(lengths - 1, 0, n - 1)
+    final = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]
+    logits = jnp.dot(
+        _rms(final, weights["final_norm"], cfg.rms_norm_eps).astype(jnp.bfloat16),
+        weights["head"], preferred_element_type=jnp.float32,
+    )
+    return logits, expert_rows, kv

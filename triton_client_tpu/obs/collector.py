@@ -508,16 +508,15 @@ class RuntimeCollector:
             snap["tracer"] = self._tracer.stats()
         if self._device_time is not None:
             snap["device_time"] = self._device_time.snapshot()
-        sessions = (
-            getattr(self._tpu, "sessions", None)
-            if self._tpu is not None
-            else None
-        )
-        if sessions is not None:
-            # stats() drains the deferred device-counter folds — the
-            # only host read of tracker state, at scrape time, never on
-            # the frame path
-            snap["sessions"] = sessions.stats()
+        session_stats = getattr(self._tpu, "session_stats", None)
+        if session_stats is not None:
+            # the tracker's stats() drains the deferred device-counter
+            # folds — the only host read of tracker state, at scrape
+            # time, never on the frame path; a model that declares its
+            # own session state (token sessions) reports under "models"
+            sessions = session_stats()
+            if sessions is not None:
+                snap["sessions"] = sessions
         snap["op_sample"] = op_sample
         if self._sampler is not None:
             snap["sampler"] = self._sampler.stats()

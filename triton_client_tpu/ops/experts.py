@@ -1,0 +1,103 @@
+"""The routed-expert layer for a chip that holds a share of the experts.
+
+The router scores every token over ALL experts of the model; this chip
+holds ``experts_here`` of them from ``expert_offset`` on and computes
+their part of the result for the tokens routed to them. Tokens routed
+elsewhere add nothing here (their experts' chips add it in the
+deployment; on one chip nothing stands in for them), and no token is
+dropped whatever the imbalance: the token-slots routed here are sorted
+by expert and go through a grouped product (``jax.lax.ragged_dot``, one
+group an expert) in chunks of :data:`CHUNK_ROWS` rows, as many chunks
+as the launch's routing needs. A launch of a few tokens (a step launch)
+runs every held expert over every token and weights by the gates.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+CHUNK_ROWS = 1024
+#: launches of at most this many tokens (a step launch) run every held
+#: expert over every token instead: such a launch is bound by reading the
+#: experts' weights whichever way, and a plain product reads a layer's
+#: experts in place where the grouped one has them copied out of the
+#: layers' stack first (5.3 ms a matrix a launch: my chip run, PR 29)
+DENSE_TOKENS = 64
+
+
+def route(x, router, top_k: int, scale: float, normalise: bool = True):
+    """Sigmoid scores in float32 over all experts: the ``top_k``
+    largest and their gates ``scale * s_i / sum s_j``."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,  # a TPU's float32 product is bfloat16 passes unless told
+        )
+    )
+    top, idx = jax.lax.top_k(scores, top_k)
+    if normalise:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return idx, top * scale
+
+
+def _swiglu_grouped(rows, experts, sizes):
+    dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
+    act = jax.nn.silu(dot(rows, experts["gate"])) * dot(rows, experts["up"])
+    return jax.lax.ragged_dot(
+        act, experts["down"], sizes, preferred_element_type=jnp.float32
+    )
+
+
+def routed_experts(x, valid, idx, gates, experts, expert_offset: int):
+    """``x [T, D]`` bfloat16, ``valid [T]`` (pad tokens route nowhere),
+    ``idx``/``gates [T, k]`` from :func:`route`, ``experts`` the held
+    experts' ``gate``/``up [E, D, F]`` and ``down [E, F, D]``. Returns
+    the held experts' sum ``[T, D]`` float32 and the rows each expert
+    saw ``[E]`` int32."""
+    t, k = idx.shape
+    held = experts["gate"].shape[0]
+    local = idx - expert_offset
+    here = (local >= 0) & (local < held) & valid[:, None]
+    if t <= DENSE_TOKENS:
+        # [T, E]: the token's gate for each held expert, 0 where it was not chosen
+        weight = jnp.sum(
+            jnp.where(
+                here[:, :, None] & (local[:, :, None] == jnp.arange(held)),
+                gates[:, :, None], 0.0,
+            ),
+            axis=1,
+        )
+        act = jax.nn.silu(jnp.einsum("td,edf->etf", x, experts["gate"])) * jnp.einsum(
+            "td,edf->etf", x, experts["up"]
+        )
+        y = jnp.einsum("etf,efd->etd", act, experts["down"]).astype(jnp.float32)
+        rows = jnp.sum(weight > 0, axis=0, dtype=jnp.int32)
+        return jnp.einsum("etd,te->td", y, weight), rows
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    token = (order // k).astype(jnp.int32)
+    gate = jnp.where(key[order] < held, gates.reshape(-1)[order], 0.0)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
+    )
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    chunk = min(t * k, CHUNK_ROWS)
+    assert (t * k) % chunk == 0, "token-slots must fill whole chunks"
+
+    def add_chunk(i, acc):
+        lo = i * chunk
+        rows = jax.lax.dynamic_slice(token, (lo,), (chunk,))
+        g = jax.lax.dynamic_slice(gate, (lo,), (chunk,))
+        # the part of each expert's run of rows that falls in this chunk
+        in_chunk = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
+        y = _swiglu_grouped(x[rows], experts, in_chunk)
+        # rows past the last group are not the product's to define
+        return acc.at[rows].add(jnp.where(g[:, None] > 0, y * g[:, None], 0.0))
+
+    acc = jnp.zeros((t, x.shape[1]), jnp.float32)
+    if t * k <= chunk:
+        return add_chunk(0, acc), sizes
+    chunks = (ends[-1] + chunk - 1) // chunk
+    return jax.lax.fori_loop(0, chunks, add_chunk, acc), sizes

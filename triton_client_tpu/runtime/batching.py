@@ -58,6 +58,7 @@ from triton_client_tpu.channel.base import BaseChannel, InferRequest, InferRespo
 from triton_client_tpu.obs.trace import MultiTrace
 from triton_client_tpu.runtime import faults
 from triton_client_tpu.runtime.admission import (
+    AdmissionRejectedError,
     DeadlineExpiredError,
     QueueFullError,
 )
@@ -629,6 +630,16 @@ class BatchingChannel(BaseChannel):
         requests = [g[1] for g in group]
         futures = [g[2] for g in group]
         traces = [r.trace for r in requests]
+        # the steps of several sessions of a model that declares
+        # mergeable sessions (the continuous scheduler keys them so):
+        # one launch, each row its own stream, no pad rows here (the
+        # model's session state forms the launch shape) and no retry of
+        # a failed launch (the cache may have moved on)
+        sequence_rows = (
+            tuple((r.sequence_id, r.sequence_start, r.sequence_end) for r in requests)
+            if requests[0].sequence_id
+            else None
+        )
         t_dispatch = time.perf_counter()
         if log.isEnabledFor(logging.DEBUG):
             # correlated dispatch line: each member's trace/request tag,
@@ -670,7 +681,9 @@ class BatchingChannel(BaseChannel):
             rounded = self._pad_target(total)
             pad = (
                 rounded - total
-                if self._pad_to_buckets and rounded <= self._max_merge
+                if self._pad_to_buckets
+                and rounded <= self._max_merge
+                and sequence_rows is None
                 else 0
             )
             # ONE member whose rows already are the launch: there is
@@ -752,6 +765,7 @@ class BatchingChannel(BaseChannel):
                         # batch is late the moment any member is
                         deadline_s=min(deadlines) if deadlines else None,
                         priority=max(r.priority for r in requests),
+                        sequence_rows=sequence_rows,
                     )
                 )
                 if free_slot is not None:
@@ -774,7 +788,15 @@ class BatchingChannel(BaseChannel):
                 with self._ready_cv:
                     self._merge_stats["padded_frames"] += pad
                     self._padded_by_model[requests[0].model_name] += pad
-        except Exception:
+        except Exception as e:
+            if sequence_rows is not None and not isinstance(
+                e, AdmissionRejectedError
+            ):
+                # (a row refused at admission took the whole launch back
+                # before it reached the device: those retry one by one)
+                for future in futures:
+                    future.set_exception(e)
+                return
             # A merged failure must not take down unrelated requests:
             # fall back to per-request execution.
             for request, future in zip(requests, futures):

@@ -280,12 +280,21 @@ class ContinuousBatchingChannel(BatchingChannel):
         ragged_names = self._ragged_names(
             request.model_name, request.model_version
         )
+        session_step = False
         if request.sequence_id:
             # session frames bypass BOTH merge paths (ragged packing
             # included): _merge_key solos them, so the tracking step
-            # sees exactly one stream's frame per launch in order
+            # sees exactly one stream's frame per launch in order —
+            # unless the model declares mergeable sessions
             ragged_names = None
-        if ragged_names:
+            session_step = self._session_step(request)
+        if session_step:
+            # one new token of a session whose state is a slot of the
+            # model's own device cache: the steps of DIFFERENT sessions
+            # merge into one launch under the model's key
+            key = ("__session_step__", request.model_name, request.model_version)
+            size = 1
+        elif ragged_names:
             # one segment per request: same-model ragged requests merge
             # regardless of their (wildly varying) row counts — that
             # variance is exactly what the packed layout absorbs
@@ -332,7 +341,16 @@ class ContinuousBatchingChannel(BatchingChannel):
         i = 0
         while i < len(self._ready) and frames < self._max_merge:
             item = self._ready[i]
-            if item[0] == first[0] and frames + item[1] <= self._max_merge:
+            if (
+                item[0] == first[0]
+                and frames + item[1] <= self._max_merge
+                # at most one request of a session in a launch: a later
+                # one keeps its place and waits its turn
+                and (
+                    not item[2].sequence_id
+                    or all(item[2].sequence_id != g[2].sequence_id for g in group)
+                )
+            ):
                 group.append(self._ready.pop(i))
                 frames += item[1]
             else:
@@ -349,6 +367,25 @@ class ContinuousBatchingChannel(BatchingChannel):
         with self._ready_cv:
             self._live_buckets.observe(total)
             return self._live_buckets.target(total)
+
+    # -- mergeable sessions ---------------------------------------------------
+
+    def _session_step(self, request: InferRequest) -> bool:
+        """Whether this session request may share a launch with other
+        sessions' requests: the model declares it
+        (``spec.extra["session_merge"]``: its session state is a slot of
+        its own device cache, runtime/sessions.py TokenSessions) and the
+        request carries ONE new token; a request of many runs alone."""
+        try:
+            spec = self._inner.get_metadata(
+                request.model_name, request.model_version
+            )
+            if not (getattr(spec, "extra", None) or {}).get("session_merge"):
+                return False
+            (tokens,) = request.inputs.values()
+            return tuple(getattr(tokens, "shape", ())) == (1, 1)
+        except Exception:
+            return False
 
     # -- ragged capability ----------------------------------------------------
 

@@ -45,6 +45,13 @@ def _families_2d() -> tuple[str, ...]:
     return tuple(BUILDERS_2D)
 
 
+def _families_lm() -> tuple[str, ...]:
+    """Token models served in sessions over a device-resident cache
+    (pipelines/lm.py): weights are launcher arguments, read from
+    ``weights.msgpack`` onto the device leaf by leaf."""
+    return ("axk1",)
+
+
 def _families_3d() -> tuple[str, ...]:
     from triton_client_tpu.pipelines.detect3d import BUILDERS_3D
 
@@ -242,6 +249,11 @@ class _Entry:
             )
         self.doc = doc
         self.family = doc.get("family")
+        if self.family in _families_lm():
+            # no pipeline object and no template tree: the builder
+            # (pipelines/lm.py) reads the whole entry itself
+            self._build = self.cfg = self._template = None
+            return
         if self.family in _families_2d():
             self._build, make_cfg = _build_2d(self.family, doc, self.model_dir)
         elif self.family in _families_3d():
@@ -249,7 +261,7 @@ class _Entry:
         else:
             raise ValueError(
                 f"{self.model_dir}: unknown family {self.family!r} "
-                f"(known: {_families_2d() + _families_3d()})"
+                f"(known: {_families_2d() + _families_3d() + _families_lm()})"
             )
         # Probe with empty variables (builders skip init when variables
         # is given; forward closures are lazy) to get the family-default
@@ -266,6 +278,12 @@ class _Entry:
     def registered(
         self, version: str, weights: str | pathlib.Path | None = None
     ) -> RegisteredModel:
+        if self._build is None:
+            from triton_client_tpu.pipelines import lm
+
+            return lm.build_registered(
+                self.doc, self.model_dir.name, version, weights
+            )
         if weights is not None:
             variables = load_weights(weights, self.family, self.template())
         else:
@@ -445,9 +463,10 @@ def scan_disk(
             rm = entry.registered(version, weights)
             repo.register(
                 rm.spec, rm.infer_fn, warmup=rm.warmup,
-                device_fn=rm.device_fn, precision=rm.precision,
+                device_fn=rm.device_fn, params=rm.params,
+                precision=rm.precision, sessions=rm.sessions,
             )
-            if entry.doc.get("warmup"):
+            if entry.doc.get("warmup") and rm.warmup is not None:
                 rm.warmup()
     if ensembles:
         from triton_client_tpu.runtime.ensemble import build_ensemble_doc

@@ -133,6 +133,21 @@ def quantize_channelwise(arr, axis: int = -1) -> QuantizedParam:
     return QuantizedParam(jnp.asarray(q), jnp.asarray(scale))
 
 
+def fake_quant_channelwise(w, contract_axis: int = -2):
+    """``w`` as symmetric per-output-channel int8 weights read it,
+    computed on the device and held in ``w``'s own type: each value
+    rounded to the 255-level grid of its channel's range (the maximum
+    over ``contract_axis`` alone, so the stacked matrices of several
+    layers or experts each keep their own scales). The numerics of
+    int8 weights without a second storage format in the model: what a
+    model served from a multi-gigabyte tree uses for ``int8`` (a leaf at
+    a time, no float32 copy on the host)."""
+    x = w.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(x), axis=contract_axis, keepdims=True)
+    scale = jnp.where(amax > 0, amax / _QMAX, 1.0)
+    return (jnp.clip(jnp.round(x / scale), -_QMAX, _QMAX) * scale).astype(w.dtype)
+
+
 def _is_quant(x) -> bool:
     return isinstance(x, QuantizedParam)
 

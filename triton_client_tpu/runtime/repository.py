@@ -72,6 +72,16 @@ class RegisteredModel:
     # ``num_segments`` (request-major). None means the model only runs
     # dense.
     ragged_fn: object | None = None
+    # Optional session state the model declares (runtime/sessions.py):
+    # an object with ``open(request) -> (request, ticket)``,
+    # ``advance(ticket, outputs) -> outputs`` and ``close(ticket,
+    # host_outputs)``, which the staged channel brackets every launch of
+    # this model with. A token model registers a ``TokenSessions`` (a
+    # slot of its device-resident cache and a length per stream; its
+    # ``params[spec.extra["device_state"]]`` is that cache, donated
+    # into each launch and taken back from its outputs). None means the
+    # server's tracker sessions apply to requests under a sequence_id.
+    sessions: object | None = None
 
 
 class ModelRepository:
@@ -105,10 +115,12 @@ class ModelRepository:
         params: object | None = None,
         precision: object | None = None,
         ragged_fn: object | None = None,
+        sessions: object | None = None,
     ) -> None:
         with self._lock:
             self._models.setdefault(spec.name, {})[spec.version] = RegisteredModel(
-                spec, infer_fn, warmup, device_fn, params, precision, ragged_fn
+                spec, infer_fn, warmup, device_fn, params, precision, ragged_fn,
+                sessions,
             )
 
     def unregister(self, name: str, version: str = "") -> None:

@@ -28,6 +28,15 @@ pool with every slot in flight rejects the new stream with
 :class:`SessionLimitError` (RESOURCE_EXHAUSTED on the wire, same
 non-retryable overload contract as admission).
 
+Two kinds of state share that pool and ladder (:class:`SlotPool`), each
+declared by the model it belongs to: the tracker pytree above (the
+default: :class:`SessionManager`, for any detector), and a slot of a
+token model's latent cache with a length (:class:`TokenSessions`, which
+a language model registers as ``RegisteredModel.sessions``). The staged
+channel drives either through the same three calls, ``open`` before the
+launch's inputs are placed, ``advance`` on the launched outputs,
+``close`` when the launch resolves.
+
 Track-id namespace: ids are int32 ``namespace(4b) | epoch(11b) |
 local(16b)`` — ``namespace`` distinguishes replicas (serve
 ``--session-id-namespace``), ``epoch`` increments on every session
@@ -78,9 +87,12 @@ def id_base_for(namespace: int, epoch: int) -> int:
 @dataclasses.dataclass
 class _Slot:
     stream_id: str
-    epoch: int
-    id_base: int
-    state: dict | None = None  # device pytree, lazily built on frame 1
+    epoch: int = 0
+    id_base: int = 0
+    # what the stream's model declares: the tracker's device pytree
+    # (lazily built on frame 1), or a token model's cache slot index
+    state: object | None = None
+    length: int = 0  # tokens held (token sessions)
     group: int = 0  # 0 single-frame; >0 synchronized-camera group size
     refs: int = 0
     frames: int = 0
@@ -92,6 +104,55 @@ class _Slot:
     step_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False
     )
+
+
+class SlotPool:
+    """A bounded pool of per-stream slots and its reclaim ladder: ended
+    slots first, then TTL-expired, then LRU, always ``refs == 0`` only.
+    Whatever a slot holds is its owner's; ``on_free`` is told of every
+    slot that leaves the pool (caller holds ``lock``)."""
+
+    def __init__(self, max_slots: int, ttl_s: float, time_fn, on_free) -> None:
+        self.max = max(1, int(max_slots))
+        self.ttl_s = float(ttl_s)
+        self.time = time_fn
+        self.on_free = on_free
+        self.lock = threading.Lock()
+        self.slots: dict[str, _Slot] = {}
+        self.expired = 0
+        self.reclaimed = 0
+        self.rejected = 0
+
+    def make_room_locked(self, now: float) -> None:
+        """Free one refs==0 slot: ended > TTL-expired > LRU. Raises
+        SessionLimitError when every slot has in-flight work."""
+        if len(self.slots) < self.max:
+            return
+        idle = [s for s in self.slots.values() if s.refs == 0]
+        victim = None
+        for s in idle:
+            if s.ended:
+                victim = s
+                break
+        if victim is None and self.ttl_s > 0:
+            for s in idle:
+                if now - s.last_used > self.ttl_s:
+                    victim = s
+                    self.expired += 1
+                    break
+        if victim is None and idle:
+            victim = min(idle, key=lambda s: s.last_used)
+            self.reclaimed += 1
+        if victim is None:
+            self.rejected += 1
+            raise SessionLimitError(
+                f"session pool full ({self.max} slots, all in flight)"
+            )
+        self.free_locked(victim)
+
+    def free_locked(self, slot: _Slot) -> None:
+        del self.slots[slot.stream_id]
+        self.on_free(slot)
 
 
 class SessionManager:
@@ -111,12 +172,16 @@ class SessionManager:
         time_fn=time.monotonic,
     ) -> None:
         self.tracker = tracker or tracking.TrackerConfig()
-        self._max = max(1, int(max_sessions))
-        self._ttl_s = float(ttl_s)
         self._namespace = int(id_namespace)
         self._time = time_fn
-        self._lock = threading.Lock()
-        self._slots: dict[str, _Slot] = {}
+        # the pool and its ladder are every session kind's; a freed
+        # tracker slot queues its device counters for the next fold
+        self._pool = SlotPool(
+            max_sessions, ttl_s, time_fn, self._fold_async_locked
+        )
+        self._max = self._pool.max
+        self._lock = self._pool.lock
+        self._slots = self._pool.slots
         # dead sessions' state pytrees awaiting a counter fold (device
         # reads deferred to scrape time — see _drain_folds)
         self._dead_states: list = []
@@ -126,9 +191,6 @@ class SessionManager:
         # the steady-state frame path)
         self._created = 0
         self._restarted = 0
-        self._expired = 0
-        self._reclaimed = 0
-        self._rejected = 0
         self._ended = 0
         self._frames = 0
         self._coasted = 0
@@ -140,34 +202,6 @@ class SessionManager:
     def _next_epoch_locked(self) -> int:
         self._epochs += 1
         return self._epochs
-
-    def _make_room_locked(self, now: float) -> None:
-        """Free one refs==0 slot: ended > TTL-expired > LRU. Raises
-        SessionLimitError when every slot has in-flight work."""
-        if len(self._slots) < self._max:
-            return
-        idle = [s for s in self._slots.values() if s.refs == 0]
-        victim = None
-        for s in idle:
-            if s.ended:
-                victim = s
-                break
-        if victim is None and self._ttl_s > 0:
-            for s in idle:
-                if now - s.last_used > self._ttl_s:
-                    victim = s
-                    self._expired += 1
-                    break
-        if victim is None and idle:
-            victim = min(idle, key=lambda s: s.last_used)
-            self._reclaimed += 1
-        if victim is None:
-            self._rejected += 1
-            raise SessionLimitError(
-                f"session pool full ({self._max} slots, all in flight)"
-            )
-        del self._slots[victim.stream_id]
-        self._fold_async_locked(victim)
 
     def _fold_async_locked(self, slot: _Slot) -> None:
         """Queue a dead slot's device counters for the next stats()
@@ -194,6 +228,19 @@ class SessionManager:
 
     # -- the frame bracket ----------------------------------------------------
 
+    def open(self, request):
+        """The channel's first call of a launch: a tracker changes
+        nothing of the request; its ticket is the request."""
+        return request, request
+
+    def abort(self, request) -> None:
+        """A launch that never reached :meth:`advance` took no ref."""
+
+    def close(self, request, host_outputs=None) -> None:
+        """The channel's last call of a launch that advanced (success
+        or failure)."""
+        self.release(request.sequence_id)
+
     def advance(self, request, outputs):
         """Run one tracking step on a detector launch's device outputs.
 
@@ -210,7 +257,7 @@ class SessionManager:
             slot = self._slots.get(sid)
             fresh = None
             if slot is None:
-                self._make_room_locked(now)
+                self._pool.make_room_locked(now)
                 slot = _Slot(
                     stream_id=sid,
                     epoch=self._next_epoch_locked(),
@@ -373,8 +420,7 @@ class SessionManager:
                 return
             slot.refs = max(0, slot.refs - 1)
             if slot.ended and slot.refs == 0:
-                del self._slots[stream_id]
-                self._fold_async_locked(slot)
+                self._pool.free_locked(slot)
 
     def end(self, stream_id: str) -> None:
         """Explicitly end a session (server drain, client abort)."""
@@ -384,8 +430,7 @@ class SessionManager:
                 return
             slot.ended = True
             if slot.refs == 0:
-                del self._slots[stream_id]
-                self._fold_async_locked(slot)
+                self._pool.free_locked(slot)
         self._drain_folds()
 
     def reset(self) -> None:
@@ -415,11 +460,275 @@ class SessionManager:
                 "created_total": self._created,
                 "restarted_total": self._restarted,
                 "ended_total": self._ended,
-                "expired_total": self._expired,
-                "reclaimed_total": self._reclaimed,
-                "rejected_total": self._rejected,
+                "expired_total": self._pool.expired,
+                "reclaimed_total": self._pool.reclaimed,
+                "rejected_total": self._pool.rejected,
                 "frames_total": self._frames,
                 "coast_frames_total": self._coasted,
                 "track_births_total": self._births_total,
                 "track_deaths_total": self._deaths_total,
+            }
+
+
+# -- token sessions: a slot of a model's device-resident cache ----------------
+
+
+@dataclasses.dataclass
+class TokenLaunch:
+    """One launch of a token model, as :meth:`TokenSessions.open`
+    admitted it: the sessions it extends row by row, and what its spans
+    and counters say of it. ``rows`` is empty for a launch that came as
+    plain arrays (a compile of a launch shape)."""
+
+    kind: str  # "lm_prefill": one session, many tokens; "lm_step": one token a session
+    rows: list  # [(stream_id, tokens, restarted, ends)]
+    tokens: int = 0
+    sessions: int = 0
+
+    @property
+    def span(self):
+        """The launch's span over its device window, and its attributes."""
+        return self.kind, {"tokens": self.tokens, "sessions": self.sessions}
+
+
+class TokenSessions:
+    """The session state a token model declares: each stream holds one
+    slot of the model's device-resident cache and a length.
+
+    The model's pipeline (pipelines/lm.py) registers one of these as
+    ``RegisteredModel.sessions``. A request under a ``sequence_id``
+    carries ``tokens [1, n]``; the batcher may merge the one-token
+    requests of DIFFERENT sessions into one launch (``tokens [B, 1]``
+    with ``InferRequest.sequence_rows`` naming each row's stream).
+    :meth:`open` gives every row its slot and position, refuses a stream
+    that would outgrow its slot or finds no room
+    (:class:`SessionLimitError`), and forms the launch's plain arrays
+    (``tokens``, ``slots``, ``positions``, ``lengths``) padded to one of
+    the model's launch shapes; pad rows have length 0 and write nothing.
+    The cache itself never passes through here: it stays on the device,
+    donated from launch to launch by the channel.
+
+    One request of a stream at a time: a later request of a stream whose
+    earlier one is still in flight waits its turn in :meth:`open`.
+    """
+
+    INPUT = "tokens"
+    LAUNCH_INPUTS = ("tokens", "slots", "positions", "lengths")
+    EXPERT_ROWS = "expert_rows"
+
+    def __init__(
+        self,
+        slots: int,
+        slot_len: int,
+        max_tokens: int,
+        token_bucket,
+        step_bucket,
+        ttl_s: float = 60.0,
+        time_fn=time.monotonic,
+        turn_timeout_s: float = 30.0,
+    ) -> None:
+        self.slot_len = int(slot_len)
+        self.max_tokens = int(max_tokens)
+        self._token_bucket = token_bucket
+        self._step_bucket = step_bucket
+        self._free = list(range(int(slots) - 1, -1, -1))
+        self._pool = SlotPool(slots, ttl_s, time_fn, self._freed_locked)
+        self._turn = threading.Condition(self._pool.lock)
+        self._turn_timeout_s = float(turn_timeout_s)
+        self._oneshots = 0
+        self._counters = {
+            "lm_tokens_prefill": 0, "lm_tokens_step": 0,
+            "lm_prefill_launches": 0, "lm_step_launches": 0,
+            "lm_step_sessions": 0, "created_total": 0, "ended_total": 0,
+            "outgrown_total": 0, "unknown_total": 0,
+        }
+        self._expert_rows = None  # [expert layers, experts held], summed over launches
+
+    def _freed_locked(self, slot: _Slot) -> None:
+        self._free.append(slot.state)
+        self._turn.notify_all()
+
+    # -- the launch bracket ---------------------------------------------------
+
+    def launch_kind(self, inputs: dict) -> str:
+        """``lm_step`` for a launch of one token a row, ``lm_prefill``
+        for one of many tokens: the launch's span, and the suffix of its
+        module's name in a device trace."""
+        return "lm_step" if inputs[self.INPUT].shape[1] == 1 else "lm_prefill"
+
+    def open(self, request):
+        """Admit one launch. Returns the request as the device program
+        takes it and the launch's :class:`TokenLaunch` ticket; pair with
+        :meth:`close` (success or failure)."""
+        inputs = request.inputs
+        if "slots" in inputs:
+            # plain arrays as the device program takes them (a launch
+            # shape compiled ahead of traffic): no stream, nothing kept
+            return request, TokenLaunch(self.launch_kind(inputs), [], 0)
+        tokens = np.asarray(inputs[self.INPUT]).astype(np.int32, copy=False)
+        if tokens.ndim != 2:
+            raise ValueError(f"tokens must be [1, n]; got shape {tokens.shape}")
+        b, n = tokens.shape
+        named = request.sequence_rows or (
+            (request.sequence_id, request.sequence_start, request.sequence_end),
+        )
+        if len(named) != b or (b > 1 and n != 1) or not 1 <= n <= self.max_tokens:
+            raise ValueError(
+                f"a request carries tokens [1, n], 1 <= n <= {self.max_tokens}; "
+                f"got {tokens.shape} for {len(named)} session(s)"
+            )
+        ticket = TokenLaunch(self.launch_kind({self.INPUT: tokens}), [], b * n, b)
+        slots = np.zeros(b, np.int32)
+        positions = np.zeros(b, np.int32)
+        try:
+            for i, (stream_id, start, end) in enumerate(named):
+                slots[i], positions[i] = self._admit(ticket, stream_id, start, end, n)
+        except Exception:
+            self.close(ticket, None, failed=True)
+            raise
+        if n == 1:
+            rows = self._step_bucket(b)
+            pad = rows - b
+            launch = {
+                "tokens": np.concatenate([tokens, np.zeros((pad, 1), np.int32)]),
+                "slots": np.concatenate([slots, np.zeros(pad, np.int32)]),
+                "positions": np.concatenate([positions, np.zeros(pad, np.int32)]),
+                "lengths": np.concatenate([np.ones(b, np.int32), np.zeros(pad, np.int32)]),
+            }
+        else:
+            width = self._token_bucket(n)
+            launch = {
+                "tokens": np.concatenate([tokens, np.zeros((1, width - n), np.int32)], axis=1),
+                "slots": slots, "positions": positions,
+                "lengths": np.full(1, n, np.int32),
+            }
+        return dataclasses.replace(request, inputs=launch), ticket
+
+    def _admit(self, ticket: TokenLaunch, stream_id: str, start: bool, end: bool, n: int):
+        """One row's slot index and start position; the stream's length
+        moves on at once (rolled back if the launch fails)."""
+        now = self._pool.time()
+        with self._turn:
+            if not stream_id:
+                # no sequence_id: a session of this one request
+                self._oneshots += 1
+                stream_id, start, end = f"__oneshot__{self._oneshots}", True, True
+            deadline = time.monotonic() + self._turn_timeout_s
+            while True:
+                slot = self._pool.slots.get(stream_id)
+                if slot is None or slot.refs == 0:
+                    break
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    raise SessionLimitError(
+                        f"stream '{stream_id}': an earlier request is still in flight"
+                    )
+                self._turn.wait(timeout=left)
+            restarted = False
+            if slot is None:
+                if not start:
+                    self._counters["unknown_total"] += 1
+                    raise SessionLimitError(
+                        f"stream '{stream_id}' holds no cache slot here (never "
+                        "started, ended, or reclaimed): send sequence_start"
+                    )
+                self._pool.make_room_locked(now)
+                slot = _Slot(stream_id=stream_id, state=self._free.pop(), created=now)
+                self._pool.slots[stream_id] = slot
+                self._counters["created_total"] += 1
+                restarted = True
+            elif start or slot.ended:
+                slot.length, slot.ended, restarted = 0, False, True
+            if slot.length + n > self.slot_len:
+                self._counters["outgrown_total"] += 1
+                if restarted:
+                    self._pool.free_locked(slot)
+                raise SessionLimitError(
+                    f"stream '{stream_id}': {slot.length} + {n} tokens outgrow "
+                    f"its cache slot of {self.slot_len} positions"
+                )
+            position = slot.length
+            slot.length += n
+            slot.refs += 1
+            slot.last_used = now
+            ticket.rows.append((stream_id, n, restarted, end))
+            return slot.state, position
+
+    def advance(self, ticket: TokenLaunch, outputs):
+        """The launch itself extended the cache: nothing more runs on the
+        device. (The pad rows of the logits are cut on the host, in
+        :meth:`close`: a slice on the device is one more program for
+        every number of sessions a launch can carry, each compiled the
+        first time a launch of that many comes by.)"""
+        return outputs
+
+    def abort(self, ticket: TokenLaunch) -> None:
+        self.close(ticket, None, failed=True)
+
+    def close(self, ticket: TokenLaunch, host_outputs=None, failed: bool = False) -> None:
+        """The launch resolved: drop its rows' references, free the
+        slots of streams that ended, count. A failed launch takes its
+        rows' lengths back (a restarted stream is freed)."""
+        failed = failed or host_outputs is None
+        with self._turn:
+            for stream_id, n, restarted, end in ticket.rows:
+                slot = self._pool.slots.get(stream_id)
+                if slot is None:
+                    continue
+                slot.refs = max(0, slot.refs - 1)
+                if failed:
+                    slot.length -= n
+                    end = end or restarted
+                if end:
+                    slot.ended = True
+                    if not failed:
+                        self._counters["ended_total"] += 1
+                if slot.ended and slot.refs == 0:
+                    self._pool.free_locked(slot)
+            ticket.rows = []
+            if not failed and ticket.tokens:
+                if ticket.kind == "lm_step":
+                    self._counters["lm_tokens_step"] += ticket.tokens
+                    self._counters["lm_step_launches"] += 1
+                    self._counters["lm_step_sessions"] += ticket.tokens
+                else:
+                    self._counters["lm_tokens_prefill"] += ticket.tokens
+                    self._counters["lm_prefill_launches"] += 1
+            self._turn.notify_all()
+        if host_outputs is not None:
+            rows = host_outputs.pop(self.EXPERT_ROWS, None)
+            if ticket.sessions:  # the real rows; the launch's pad rows end here
+                for k, v in host_outputs.items():
+                    host_outputs[k] = v[: ticket.sessions]
+            if rows is not None and ticket.tokens:
+                with self._turn:
+                    total = np.asarray(rows, np.int64)
+                    self._expert_rows = (
+                        total if self._expert_rows is None else self._expert_rows + total
+                    )
+
+    def end(self, stream_id: str) -> None:
+        """Explicitly end a session (server drain, client abort)."""
+        with self._turn:
+            slot = self._pool.slots.get(stream_id)
+            if slot is not None:
+                slot.ended = True
+                if slot.refs == 0:
+                    self._pool.free_locked(slot)
+
+    def stats(self) -> dict:
+        with self._turn:
+            slots = list(self._pool.slots.values())
+            return {
+                **self._counters,
+                "session_cache_slots": self._pool.max,
+                "session_cache_slot_len": self.slot_len,
+                "session_cache_slots_in_use": len(slots),
+                "session_cache_tokens": sum(s.length for s in slots),
+                "expired_total": self._pool.expired,
+                "reclaimed_total": self._pool.reclaimed,
+                "rejected_total": self._pool.rejected,
+                "expert_rows": (
+                    self._expert_rows.tolist() if self._expert_rows is not None else []
+                ),
             }
