@@ -29,7 +29,9 @@ def records(ctx: dict) -> list[dict]:
     """One record a launch that has ``h2d``, ``launch`` and
     ``device_execute``, in ``launch_id`` order, times in seconds: on
     ``time.perf_counter`` where the export says its clock, and then only
-    the launches that were ready inside the measured window."""
+    the launches that were ready inside the measured window (before
+    ``spans_until``, where the mix pins its device trace to the window:
+    ``_spans.undisturbed``)."""
     doc = ctx.get("traces") or {}
     base = (doc.get("clock") or {}).get("base_perf_counter_s")
     interval = lambda e: ((base or 0.0) + e["ts"] / 1e6, (base or 0.0) + (e["ts"] + e["dur"]) / 1e6)
@@ -54,7 +56,8 @@ def records(ctx: dict) -> list[dict]:
     recs = [out[k] for k in sorted(out) if all(n in out[k] for n in ("h2d", "launch", "device_execute"))]
     window = ctx.get("window")
     if base is not None and window is not None:
-        recs = [r for r in recs if window.t_start <= r["device_execute"][1] <= window.t_end]
+        until = window.t_end if ctx.get("spans_until") is None else min(window.t_end, ctx["spans_until"])
+        recs = [r for r in recs if window.t_start <= r["device_execute"][1] <= until]
     return recs
 
 

@@ -27,10 +27,18 @@ def kind_rows(ctx: dict, kind: str) -> tuple[int, float]:
     return sum(r["count"] for r in rows), sum(r["device_s"] for r in rows)
 
 
+def gauges(ctx: dict) -> list[dict]:
+    """The model's session gauges at the moments INSIDE the window that
+    ``run.py`` sampled (every two seconds of a traced window), else at
+    the window's two ends: a window of whole rounds begins and ends
+    between rounds, where the pool holds what the warm-up left."""
+    inside = [((s.get("sessions") or {}).get("models") or {}).get(ctx["model"]) for s in ctx.get("snapshots_inside") or []]
+    return [s for s in inside if s] or [s for s in (stats(ctx, "snapshot_before"), stats(ctx)) if s]
+
+
 def mean_context(ctx: dict) -> float | None:
-    """Mean positions a live session holds, from the gauges at the
-    window's two ends."""
-    ends = [s for s in (stats(ctx, "snapshot_before"), stats(ctx)) if s and s.get("session_cache_slots_in_use")]
-    if not ends:
+    """Mean positions a live session holds, over ``gauges``."""
+    live = [s for s in gauges(ctx) if s.get("session_cache_slots_in_use")]
+    if not live:
         return None
-    return sum(s["session_cache_tokens"] / s["session_cache_slots_in_use"] for s in ends) / len(ends)
+    return sum(s["session_cache_tokens"] / s["session_cache_slots_in_use"] for s in live) / len(live)

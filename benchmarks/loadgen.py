@@ -15,15 +15,16 @@ A traffic file's ``loop`` names its kind. ``run.py`` looks up
 sends), ``<loop>_loop`` (one measured window) and ``<loop>_sample``
 (everything of the output check's sample once, in flight together as
 the cell's traffic would have it) here by that name; all kinds take
-the same arguments. ``open`` and ``closed`` send single requests from a
-pool; ``sessions`` sends STREAMS: ordered requests under one
-``sequence_id``, each when the last one answered.
+the same arguments. A kind may also have ``<loop>_refused``: why a cell
+may not run a mix as its file stands. ``open`` and ``closed`` send
+single requests from a pool; ``sessions`` sends STREAMS: ordered
+requests under one ``sequence_id``, each when the last one answered,
+in whole rounds that each hold the whole pool.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import itertools
 import queue
 import threading
 import time
@@ -338,22 +339,64 @@ def _send_stream(channel, stream, deadline=None, on_send=None, on_answer=None, o
     return responses
 
 
+def round_orders(rng: np.random.Generator, clients: int, n: int, rounds: int) -> list[list[int]]:
+    """Who sends what: ``orders[c][r]`` is the pool stream caller ``c``
+    opens in round ``r``. A round's ``clients`` streams are
+    ``clients / n`` copies of the whole pool of ``n``, in an order
+    drawn anew each round, so every round is the same work whatever the
+    seed. ``clients`` is a multiple of ``n`` (``sessions_refused``)."""
+    if clients % n:
+        raise ValueError(f"{clients} callers over a pool of {n} streams: not a multiple")
+    return np.stack([rng.permutation(np.tile(np.arange(n), clients // n)) for _ in range(rounds)]).T.tolist()
+
+
+def sessions_refused(traffic: dict, pool: int) -> str | None:
+    """Why a CELL may not run this ``sessions`` mix (``run.py`` asks
+    before a measured run), or None."""
+    if float(traffic.get("round_s", 0)) <= 0:
+        return "a sessions mix states round_s, the nominal length of a round: --seconds is rounded to whole rounds"
+    if int(traffic["clients"]) % pool:
+        return (f"{traffic['clients']} callers over a pool of {pool} streams: not a multiple, "
+                "so a round would not hold the whole pool and rounds would differ")
+    return None
+
+
 def sessions_loop(make_channel, channel, requests, traffic: dict, seconds: float,
                   rng: np.random.Generator, check=None, rate=None) -> Window:
-    """``clients`` callers, each with a channel of its own. A caller
-    opens a stream from the seeded pool under a fresh ``sequence_id``,
-    sends its requests IN ORDER, each when the last one answered, then
-    opens the next, until ``seconds`` are over. The window closes as
-    the closed loop's does: at the answer to the last request sent in
-    time, and every request sent counts. A request completes the items
-    it states, else the traffic file's ``items_per_request`` (a
-    stream's first request can carry thousands, the later ones one
-    each). A failed request ends its stream and counts as failed; the
-    caller opens the next. A stream that the deadline cuts is left
-    open: the server reclaims it."""
+    """``clients`` callers, each with a channel of its own, send ROUNDS:
+    in each round a caller opens the stream ``round_orders`` gives it
+    under a fresh ``sequence_id`` and sends its requests IN ORDER, each
+    when the last one answered; then its next round (no barrier: the
+    callers stay together because the server serves them together).
+
+    The window is a WHOLE NUMBER of rounds, ``seconds`` over the
+    traffic file's ``round_s`` (the nominal length of a round) rounded
+    (a half rounds up), at least one, and ends as the closed loop's does, at the last
+    answer: the same requests and items in every window of a cell, and
+    ``throughput`` is that over the time the program took, continuous
+    in its speed. (Cut at ``seconds``, with each caller drawing its own
+    order, a window of 40 s held exactly two prompts a caller, 91% of
+    its tokens: six seeds spread 7%, and a step 2% faster would have
+    let a third burst of 32 prompts in, +45%: PERF.md section 2.) Such
+    a window that is not over after three times its nominal length
+    stops sending, and the loop raises.
+
+    ``seconds`` under half a round (a warm-up, the round that starts a
+    profiler) is ONE round cut by the deadline: nothing is sent after
+    ``seconds``, and a stream that the deadline cuts is left open (the
+    server reclaims it).
+
+    A request completes the items it states, else the traffic file's
+    ``items_per_request`` (a stream's first request can carry
+    thousands, the later ones one each). A failed request ends its
+    stream and counts as failed; the caller goes on to its next round."""
     items_per_request, clients = int(traffic["items_per_request"]), int(traffic["clients"])
+    round_s = float(traffic["round_s"])
+    whole = seconds >= round_s / 2
+    rounds = max(1, int(seconds / round_s + 0.5))  # one where the deadline cuts it
+    limit_s = 3 * rounds * round_s if whole else seconds
     win = Window()
-    orders = [rng.permutation(len(requests)) for _ in range(clients)]
+    orders = round_orders(rng, clients, len(requests), rounds)
     channels = [make_channel() for _ in range(clients)]
     start = threading.Barrier(clients + 1)
     deadline = [0.0]
@@ -367,17 +410,15 @@ def sessions_loop(make_channel, channel, requests, traffic: dict, seconds: float
 
     def client(channel, order) -> None:
         start.wait()
-        for i in itertools.count():
-            if time.perf_counter() >= deadline[0]:
-                return
-            _send_stream(channel, requests[order[i % len(order)]], deadline, on_send, on_answer, win.fail)
+        for pick in order:
+            _send_stream(channel, requests[pick], deadline, on_send, on_answer, win.fail)
 
     threads = [threading.Thread(target=client, args=(c, o), daemon=True) for c, o in zip(channels, orders)]
     try:
         for t in threads:
             t.start()
         win.t_start = time.perf_counter()
-        deadline[0] = win.t_start + seconds
+        deadline[0] = win.t_start + limit_s
         win.t_end = float("inf")  # until the last answer is in
         start.wait()
         for t in threads:
@@ -386,6 +427,9 @@ def sessions_loop(make_channel, channel, requests, traffic: dict, seconds: float
     finally:
         for c in channels:
             c.close()
+    if whole and time.perf_counter() >= deadline[0]:
+        raise RuntimeError(f"a window of {rounds} round(s), nominally {rounds * round_s:g} s, was not over after "
+                           f"{limit_s:g} s: stopped sending at {win.attempted} requests")
     return win
 
 

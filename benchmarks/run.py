@@ -35,6 +35,7 @@ import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 import urllib.request  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -56,6 +57,15 @@ def log(**row) -> None:
 def http_json(port: int, path: str):
     with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}", timeout=60.0) as r:
         return json.load(r)
+
+
+def sample_snapshots(port: int, every_s: float, stop: threading.Event, into: list) -> None:
+    """``/snapshot`` every ``every_s`` seconds until ``stop``: what the
+    server's gauges read INSIDE a traced window (at its two ends a
+    window of whole rounds is between rounds, and a gauge reads what
+    the warm-up left behind)."""
+    while not stop.wait(every_s):
+        into.append(http_json(port, "/snapshot"))
 
 
 def server_counts(snapshot: dict) -> dict:
@@ -130,8 +140,9 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     os.environ["JAX_PLATFORMS"] = "cpu"  # this process only; the child gets its own
-    from benchmarks.server_child import (apply_rehearsal, check_module, input_params, load_json, rehearsal_traffic,
-                                         sample_size, seeded)
+    from benchmarks import loadgen
+    from benchmarks.server_child import (TRACE_CAPACITY, apply_rehearsal, check_module, input_params, load_json,
+                                         rehearsal_traffic, sample_size, seeded)
 
     bench = load_json(ROOT / "BENCHMARK.json")
     cell = next((w for w in bench["workloads"] if w["name"] == args.workload), None)
@@ -149,6 +160,16 @@ def main(argv=None) -> int:
     if args.rehearse:
         cfg = apply_rehearsal(cfg)
         traffic = rehearsal_traffic(traffic, cfg)
+    trace_s, trace_at_s = float(traffic.get("trace_s", 3.0)), traffic.get("trace_at_s")
+    if not args.rehearse:  # what a measured run cannot do with the mix as its file stands, said before set-up is paid
+        refused = getattr(loadgen, f"{traffic['loop']}_refused", None)
+        why = refused(traffic, sample_size(cfg, traffic, False)) if refused else None
+        if why is None and args.trace and trace_at_s is not None and args.seconds < trace_at_s + trace_s:
+            why = (f"its trace lies {trace_at_s:g} s into the window and lasts {trace_s:g} s: "
+                   f"--trace 1 needs --seconds {trace_at_s + trace_s:g} or more")
+        if why:
+            print(f"{traffic_file}: {why}", file=sys.stderr)
+            return 2
 
     work = pathlib.Path(tempfile.mkdtemp(prefix="bench_"))
     child = Child([
@@ -159,8 +180,6 @@ def main(argv=None) -> int:
     ])
     try:
         from triton_client_tpu.channel.grpc_channel import GRPCChannel
-
-        from benchmarks import loadgen
 
         check = check_module(cfg)
         generator = importlib.import_module(f"benchmarks.inputs.{traffic['inputs']['generator']}")
@@ -191,8 +210,10 @@ def main(argv=None) -> int:
         try:
             # warm-up: the cell's own traffic until the compile count stops
             # moving and, where a launcher was compiled anew, until the
-            # runtime has handed the compiler's memory back
-            rng = seeded(args.seed, 2)
+            # runtime has handed the compiler's memory back. It draws from a
+            # stream of its own: a warm-up one round longer (a compile, a
+            # settling runtime) does not change what the window sends
+            rng, window_rng = seeded(args.seed, 2), seeded(args.seed, 3)
             compiles = http_json(ready["metrics_port"], "/snapshot")["compile"]["compiles"]
             settled_at = ready["compiled_at_unix"] + (SETTLE_AFTER_COMPILE_S if ready["compiled_anew"] else 0.0)
             for round_ in range(120):
@@ -205,7 +226,8 @@ def main(argv=None) -> int:
 
             if args.rates:  # the sweep: a window a rate, no result line
                 for rate in (float(r) for r in args.rates.split(",")):
-                    win = loop(make_channel, channel, requests, traffic, args.seconds, rng, well_formed, rate=rate)
+                    win = loop(make_channel, channel, requests, traffic, args.seconds, window_rng, well_formed,
+                               rate=rate)
                     lat = sorted(win.latencies_ms)
                     log(sweep_rate=rate, attempted=win.attempted, done=len(lat), failed=win.failed,
                         throughput=win.items_done / win.span_s(), p99=float(np.percentile(lat, 99)) if lat else None,
@@ -221,26 +243,43 @@ def main(argv=None) -> int:
 
             gc.collect()  # not inside the window
             snap0 = http_json(ready["metrics_port"], "/snapshot")
+            inside, sampled = [], threading.Event()
+            sampler = threading.Thread(target=sample_snapshots, args=(ready["metrics_port"], 2.0, sampled, inside))
             if args.trace:
-                # starting the profiler holds the launches up for some 0.6 s: it starts in one
-                # more round of warm-up, and the reduction leaves the trace's head out
                 from benchmarks import trace_reduce
 
-                # long enough for some ten launches (the traffic file's ``trace_s``, 3 s where it names
-                # none), and where the mix says so (``trace_after_s``) past the seconds in which a closed
-                # loop's callers all start at once: the held-up launches then fall into the head left out
-                child.send(cmd="profile", seconds=trace_reduce.HEAD_LEFT_OUT_S
-                           + min(float(traffic.get("trace_s", 3.0)), max(0.5, args.seconds / 3)),
-                           after_s=min(float(traffic.get("trace_after_s", 0.0)), args.seconds / 4))
-                loop(make_channel, channel, requests, traffic, 1.0, rng, None)
+                if trace_at_s is not None:
+                    # the mix pins the traced span to the WINDOW: what the reduction counts begins
+                    # ``trace_at_s`` after the window's start and lasts ``trace_s``, whatever --seconds is
+                    # (a mix whose window has phases says which one is traced; the check above keeps it inside).
+                    # The command is the last thing sent before the window (the loop's channels and barrier
+                    # are some tenths of a second); the child says when its profiler started
+                    sampler.start()
+                    child.send(cmd="profile", seconds=trace_reduce.HEAD_LEFT_OUT_S + trace_s,
+                               after_s=max(0.0, trace_at_s - trace_reduce.HEAD_LEFT_OUT_S))
+                else:
+                    # starting the profiler holds the launches up for some 0.6 s: it starts in one
+                    # more round of warm-up, and the reduction leaves the trace's head out;
+                    # long enough for some ten launches (the traffic file's ``trace_s``, 3 s where it names
+                    # none), and where the mix says so (``trace_after_s``) past the seconds in which a closed
+                    # loop's callers all start at once: the held-up launches then fall into the head left out
+                    child.send(cmd="profile", seconds=trace_reduce.HEAD_LEFT_OUT_S
+                               + min(trace_s, max(0.5, args.seconds / 3)),
+                               after_s=min(float(traffic.get("trace_after_s", 0.0)), args.seconds / 4))
+                    loop(make_channel, channel, requests, traffic, 1.0, rng, None)
+                    sampler.start()
             setup_s = time.perf_counter() - T0
-            win = loop(make_channel, channel, requests, traffic, args.seconds, rng, well_formed)
+            try:
+                win = loop(make_channel, channel, requests, traffic, args.seconds, window_rng, well_formed)
+            finally:
+                sampled.set()
             snap1 = http_json(ready["metrics_port"], "/snapshot")
-            traces = http_json(ready["metrics_port"], "/traces?n=4096") if args.trace else None
+            traces = http_json(ready["metrics_port"], f"/traces?n={TRACE_CAPACITY}") if args.trace else None
+            if args.trace:
+                sampler.join()
         finally:
             channel.close()
-        if args.trace:
-            child.read("profiled")
+        profiled = child.read("profiled") if args.trace else None
         child.send(cmd="finish")
         done = child.read("done")
 
@@ -250,7 +289,10 @@ def main(argv=None) -> int:
         if args.trace:
             ctx = {
                 "cfg": cfg, "traffic": traffic, "cell": cell, "device": device, "window": win,
-                "seconds": win.span_s(), "snapshot_before": snap0, "snapshot_after": snap1,
+                "seconds": win.span_s(), "snapshot_before": snap0, "snapshot_after": snap1, "snapshots_inside": inside,
+                # a trace pinned to the window: spans are read before the moment the child's profiler
+                # started, on the clock both processes share (layer_metrics/_spans.py)
+                "spans_until": None if trace_at_s is None else profiled["started_perf_counter_s"],
                 "traces": traces, "profile": done["profile"], "model": ready["model"],
             }
             for m in filter(in_cell, bench["per_layer"]):
@@ -271,9 +313,11 @@ def main(argv=None) -> int:
             per_launch = sum(r["device_s"] for r in rows) / max(1, sum(r["count"] for r in rows))
             launches = server_counts(snap1)["merges"] - server_counts(snap0)["merges"]
             log(idle_share_traced=1.0 - done["profile"]["busy_s"] / done["profile"]["window_s"],
-                idle_share_from_window_launches=1.0 - launches * per_launch / win.span_s())
+                idle_share_from_window_launches=1.0 - launches * per_launch / win.span_s(),
+                traced_launches=done["profile"]["launches"],
+                profiler_started_at_s=profiled["started_perf_counter_s"] - win.t_start)
         log(window={"attempted": win.attempted, "failed": win.failed, "malformed": win.malformed,
-                    "completed": len(win.latencies_ms), "errors": win.errors,
+                    "completed": len(win.latencies_ms), "items_done": win.items_done, "errors": win.errors,
                     "late_p95_ms": float(np.percentile(win.late_ms, 95)) if win.late_ms else None,
                     "span_s": win.span_s()},
             values=values, marks=ready["marks"])

@@ -40,9 +40,16 @@ import numpy as np  # noqa: E402
 from benchmarks import loadgen  # noqa: E402
 
 
+# the server's ring of request traces in a traced run: read once the window is over, it still has to hold the
+# window's FIRST requests (16,448 a window in the sessions cell, whose spans are read before its trace begins)
+TRACE_CAPACITY = 32768
+
+
 def seeded(seed: int, stream: int) -> np.random.Generator:
     """Stream 0: calibration input; 1: the sample and request pool;
-    2: arrivals and order. ``run.py`` draws the same streams."""
+    2: the warm-up's arrivals and order; 3: the measured window's (its
+    own, so that a longer warm-up does not change what the window
+    sends). ``run.py`` draws the same streams."""
     return np.random.default_rng([int(seed), stream])
 
 
@@ -391,7 +398,7 @@ def main(argv=None) -> int:
     marks.update(before)
     print(json.dumps(before), flush=True)
 
-    trace_argv = ["--trace-capacity", "4096" if args.trace else "0"]
+    trace_argv = ["--trace-capacity", str(TRACE_CAPACITY) if args.trace else "0"]
     server, serve_args = start_server(work / "repo", [*cfg["serve_argv"], *trace_argv])
     marks["server_s"] = time.perf_counter() - T0
     with cache_subdirectory(cache_dir, "benchmark"):
@@ -431,11 +438,12 @@ def main(argv=None) -> int:
             options.host_tracer_level = 0
             options.enable_hlo_proto = False
             time.sleep(float(cmd.get("after_s", 0.0)))  # past the callers' start, where the mix says so
+            started = time.perf_counter()  # the clock the load generator's window and the server's spans are on
             jax.profiler.start_trace(str(log_dir), profiler_options=options)
             time.sleep(float(cmd["seconds"]))
             jax.profiler.stop_trace()
             traced = True
-            say({"profiled": True})
+            say({"profiled": True, "started_perf_counter_s": started})
         elif cmd["cmd"] == "finish":
             break
 

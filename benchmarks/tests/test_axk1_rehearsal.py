@@ -1,4 +1,4 @@
-"""CPU rehearsal of ``axk1-ep16-l6`` under ``fleet-sessions`` at its
+"""CPU rehearsal of ``axk1-ep16-l6`` under ``fleet-rounds`` at its
 ``rehearsal`` sizes, through the real server: the harness's own set-up
 and output check (``run.py --rehearse``), and the control
 (``check_control.py``: the same entry with ``model.precision: int8``),
@@ -13,7 +13,7 @@ import subprocess
 import sys
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
-CELL = "axk1-ep16-l6-fleet-sessions"
+CELL = "axk1-ep16-l6-fleet-rounds"
 
 
 def run(script, *argv):

@@ -157,7 +157,7 @@ def test_the_configuration_keeps_every_published_width():
     assert cfg["deployment"]["chips_per_layer"] * model["experts_here"] == model["router_experts"]
     from triton_client_tpu.pipelines import lm
 
-    mix = sc.load_json(ROOT / "benchmarks/traffic/fleet-sessions.json")
+    mix = sc.load_json(ROOT / "benchmarks/traffic/fleet-rounds.json")
     shapes = mix["launch_batch_sizes"]
     assert {b["step"] for b in shapes if "step" in b} == {lm.step_bucket(n, 40) for n in range(1, 41)}
     ladder = mix["inputs"]["params"]["prompt_ladder"]  # what the mix sends is what it warms up; the program compiles a smaller extend on demand

@@ -16,7 +16,7 @@ sys.path.insert(0, str(ROOT))
 
 from benchmarks import loadgen  # noqa: E402
 from benchmarks.layer_metrics import (  # noqa: E402
-    _launches, client_write_ms, device_busy_est_ms, h2d_ms, host_gap_ms,
+    _launches, _spans, client_write_ms, device_busy_est_ms, h2d_ms, host_gap_ms,
 )
 
 BASE = 5000.0  # the perf_counter value the export's zero stands for
@@ -142,3 +142,19 @@ def test_nothing_where_no_launch_carries_an_id(reader, capsys):
     assert reader.read(ctx(traces=traces(stamped=False))) is None
     assert reader.read(ctx(traces=None)) is None
     assert capsys.readouterr().out == ""
+
+
+def test_a_trace_pinned_to_the_window_has_its_spans_read_before_the_profiler_starts():
+    """``run.py`` states ``spans_until`` for a mix with ``trace_at_s``:
+    requests and launches that ended after it (while the profiler ran,
+    and the seconds after it stopped) are left out."""
+    pinned = ctx(spans_until=BASE + 1.40)
+    assert [r["launch_id"] for r in _launches.records(pinned)] == [1, 2]  # ready at 0.70 and 1.30; 3 at 1.50
+    # requests 11 (0.00-0.71) and 12 (0.05-1.31) ended in time: parse 10 ms each
+    assert sorted(_spans.per_request_ms(pinned, ("parse",)).round(6)) == [10.0, 10.0]
+    assert len(_spans.per_request_ms(ctx(), ("parse",))) == 5  # nothing pinned: every traced request
+    assert h2d_ms.read(pinned) == pytest.approx((480.0 + 300.0) / 2)
+    late_window = ctx(spans_until=BASE + 1.40, window=window(t_start=BASE + 0.03))
+    assert len(_spans.per_request_ms(late_window, ("parse",))) == 1  # request 11 began before the window
+    no_clock = {"traces": traces(clock=False), "window": window(), "spans_until": BASE + 1.40}
+    assert len(_spans.per_request_ms(no_clock, ("parse",))) == 5  # an export that does not say its clock
