@@ -161,7 +161,9 @@ echo "== fused-kernels shard (Pallas parity matrix + profiler smoke) =="
 # runs compiled. The profile_fused smoke then proves the before/after
 # harness and the opstats per-stage split end-to-end on tiny shapes
 # (timings under interpret are correctness-true, performance-false).
-python -m pytest tests/test_fused_parity.py -q \
+# test_fused_decode_groups: the grouped 2D kernel (eight frames a grid
+# step) against nms_padded over batches 1/3/8/11/16, and its step count.
+python -m pytest tests/test_fused_parity.py tests/test_fused_decode_groups.py -q \
     --continue-on-collection-errors \
     -p no:cacheprovider -p no:xdist -p no:randomly
 python perf/profile_fused.py --stages decode_nms_2d \

@@ -96,22 +96,26 @@ def test_voxel_segment_mean_lowers(one_chip, pipeline, num_slots):
     assert "tpu_custom_call" in text
 
 
-def test_decode_nms_2d_lowers_b8(one_chip):
+def _decode_nms_2d_text(one_chip, batch):
     from triton_client_tpu.ops.pallas_decode import fused_decode_nms_2d
 
     k = 1024  # Detect2DConfig.max_nms
-
-    def tail(boxes, scores, classes, valid):
-        return jax.vmap(
-            lambda b, s, c, v: fused_decode_nms_2d(b, s, c, v, max_det=300)
-        )(boxes, scores, classes, valid)
-
-    text = _compile(
-        tail, one_chip,
-        ((8, k, 4), jnp.float32), ((8, k), jnp.float32),
-        ((8, k), jnp.int32), ((8, k), jnp.bool_),
+    return _compile(
+        lambda b, s, c, v: fused_decode_nms_2d(b, s, c, v, max_det=300),
+        one_chip,
+        ((batch, k, 4), jnp.float32), ((batch, k), jnp.float32),
+        ((batch, k), jnp.int32), ((batch, k), jnp.bool_),
     )
-    assert "tpu_custom_call" in text
+
+
+def test_decode_nms_2d_lowers_b8(one_chip):
+    """One whole group: eight frames, one a sublane, one grid step."""
+    assert "tpu_custom_call" in _decode_nms_2d_text(one_chip, 8)
+
+
+def test_decode_nms_2d_lowers_b11(one_chip):
+    """A second group padded with five frames that hold no candidate."""
+    assert "tpu_custom_call" in _decode_nms_2d_text(one_chip, 11)
 
 
 def test_decode_tail_3d_lowers_b2(one_chip):

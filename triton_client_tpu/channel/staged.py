@@ -47,6 +47,7 @@ from triton_client_tpu.channel.base import (
 )
 from triton_client_tpu.config import ModelSpec
 from triton_client_tpu.obs.roofline import name_launcher
+from triton_client_tpu.ops.fused import NMS_STEPS_KEY
 from triton_client_tpu.parallel.mesh import MeshConfig, make_mesh
 from triton_client_tpu.runtime import faults
 from triton_client_tpu.runtime.admission import (
@@ -367,6 +368,13 @@ class StagedChannel(BaseChannel):
             "deadline_expired_launches": 0,
             # launch/readback failures observed by the circuit breaker
             "launch_failures": 0,
+            # the fused 2D decode+NMS kernel (ops/pallas_decode): greedy
+            # steps its groups of eight frames ran, summed at readback,
+            # and the frames of the launches that reported them. Steps
+            # over frames: max_det = the early stop never engaged,
+            # max_det / 8 = sublane packing alone, below = both
+            "nms_steps": 0,
+            "nms_frames": 0,
         }
         self._shed_expired = bool(shed_expired)
         self._breaker = (
@@ -883,6 +891,12 @@ class StagedChannel(BaseChannel):
             ids["launch_id"] = h2d[2]["launch_id"] = launch_id
             tr.add("launch", t0, t_launched, ids)
 
+        # a fused 2D tail's step count rides with the rows; it is summed
+        # into stats() in resolve and never reaches the response
+        nms_steps = outputs.get(NMS_STEPS_KEY)
+        if nms_steps is not None:
+            outputs = {k: v for k, v in outputs.items() if k != NMS_STEPS_KEY}
+        launch_rows = _batch_rows(staged.device_inputs)
         ledger = self._device_time
         # a token launch names its span: lm_prefill or lm_step
         launch_span = getattr(session[1], "span", None) if session else None
@@ -927,6 +941,11 @@ class StagedChannel(BaseChannel):
                         )
                 faults.probe("readback", name)
                 host = self._host_outputs(outputs, out_dtype, staged.meta)
+                if nms_steps is not None:
+                    steps_run = int(np.asarray(nms_steps).sum())
+                    with self._slot_cv:
+                        self._stats["nms_steps"] += steps_run
+                        self._stats["nms_frames"] += launch_rows
                 if tr is not None:
                     tr.add("readback", t_ready, time.perf_counter(), ids)
             except Exception:

@@ -13,9 +13,13 @@ The reference's variable-length outputs and its 10 s NMS watchdog
 deterministic by construction.
 
 ``fused=True`` collapses the post-top-k tail — xywh->xyxy decode,
-class offset, suppression loop and packing — into ONE Pallas launch
-(ops/pallas_decode.fused_decode_nms_2d) instead of the nms_padded op
-chain. Bitwise-identical rows (pinned by tests/test_fused_parity.py);
+class offset, suppression loop and packing — into ONE Pallas launch for
+the whole batch (ops/pallas_decode.fused_decode_nms_2d: eight frames a
+grid step, one a sublane, each group's greedy loop over when none of
+its frames has a live candidate) instead of the per-frame nms_padded op
+chain. Gate and top-k stay per frame under ``vmap``; the tail is called
+once on the batched candidates. Bitwise-identical rows (pinned by
+tests/test_fused_parity.py and tests/test_fused_decode_groups.py);
 pipelines pick the route at trace time from ops/fused.
 """
 
@@ -31,74 +35,69 @@ from triton_client_tpu.ops.nms import nms_padded
 
 
 def _packed_nms(
-    boxes, scores, classes, valid, iou_thresh, max_det, class_agnostic,
+    candidates, iou_thresh, max_det, class_agnostic,
     box_format: str, fused: bool, interpret: bool,
 ):
-    """nms_padded vs the fused single-launch tail. ``box_format`` tells
-    the fused kernel whether decode is still pending ("xywh" — the
-    conversion the XLA path already did before top-k happens in-kernel
-    instead)."""
+    """The batch's candidates ``(boxes (B, K, 4), scores, classes,
+    valid (B, K))`` -> ``(detections, keep, steps)``: nms_padded a frame
+    vs the fused single-launch tail. ``box_format`` tells the fused
+    kernel whether decode is still pending ("xywh" — the conversion the
+    XLA path already did before top-k happens in-kernel instead).
+    ``steps``: the greedy steps each group of eight frames ran in the
+    kernel, None on the nms_padded route."""
     if fused:
         from triton_client_tpu.ops.pallas_decode import fused_decode_nms_2d
 
         return fused_decode_nms_2d(
-            boxes, scores, classes, valid,
+            *candidates,
             iou_thresh=iou_thresh, max_det=max_det, box_format=box_format,
             class_agnostic=class_agnostic, interpret=interpret,
         )
-    if box_format == "xywh":
-        boxes = xywh2xyxy(boxes)
-    return nms_padded(
-        boxes, scores, classes, valid,
-        iou_thresh=iou_thresh, max_det=max_det,
-        class_agnostic=class_agnostic,
-    )
+
+    def one_image(boxes, scores, classes, valid):
+        if box_format == "xywh":
+            boxes = xywh2xyxy(boxes)
+        return nms_padded(
+            boxes, scores, classes, valid,
+            iou_thresh=iou_thresh, max_det=max_det,
+            class_agnostic=class_agnostic,
+        )
+
+    return (*jax.vmap(one_image)(*candidates), None)
 
 
-def _gate_topk_nms(
+def _gate_topk(
     boxes: jnp.ndarray,
     scores: jnp.ndarray,
     classes: jnp.ndarray,
     conf_thresh: float,
-    iou_thresh: float,
-    max_det: int,
     max_nms: int,
-    class_agnostic: bool = False,
-    box_format: str = "xyxy",
-    fused: bool = False,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Shared single-image tail: confidence gate -> top-k prefilter ->
-    class-aware NMS -> packed (max_det, 6) rows. Invalid top-k slots
-    carry the gate's -inf in ``gated`` but 0.0 in the packed output so
-    confs stay clean. Gate + top-k stay XLA on purpose: the sort-based
-    top_k beats any in-kernel reformulation and fuses into the head."""
+):
+    """Single-image head of the tail: confidence gate -> top-k prefilter
+    -> the NMS candidates ``(boxes, scores, classes, valid)``. Invalid
+    top-k slots carry the gate's -inf in ``gated`` but 0.0 in the
+    candidates so confs stay clean. Gate + top-k stay XLA on purpose:
+    the sort-based top_k beats any in-kernel reformulation and fuses
+    into the head."""
     gated = jnp.where(scores > conf_thresh, scores, -jnp.inf)
     k = min(max_nms, gated.shape[0])
     top_scores, top_idx = jax.lax.top_k(gated, k)
     top_valid = top_scores > -jnp.inf
-    return _packed_nms(
+    return (
         boxes[top_idx],
         jnp.where(top_valid, top_scores, 0.0),
         classes[top_idx],
         top_valid,
-        iou_thresh, max_det, class_agnostic, box_format, fused, interpret,
     )
 
 
-def _multilabel_topk_nms(
+def _multilabel_topk(
     boxes: jnp.ndarray,
     per_class_scores: jnp.ndarray,
     conf_thresh: float,
-    iou_thresh: float,
-    max_det: int,
     max_nms: int,
-    class_agnostic: bool = False,
-    box_format: str = "xyxy",
-    fused: bool = False,
-    interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Single-image multi-label tail: every (box, class) pair over the
+):
+    """Single-image multi-label head: every (box, class) pair over the
     threshold is a candidate. Top-k runs on the flat (N*nc,) scores;
     boxes/classes are derived from surviving indices (idx // nc,
     idx % nc) so the (N*nc, 4) box expansion is never materialized."""
@@ -108,12 +107,24 @@ def _multilabel_topk_nms(
     k = min(max_nms, gated.shape[0])
     top_scores, top_idx = jax.lax.top_k(gated, k)
     top_valid = top_scores > -jnp.inf
-    return _packed_nms(
+    return (
         boxes[top_idx // nc],
         jnp.where(top_valid, top_scores, 0.0),
         top_idx % nc,
         top_valid,
-        iou_thresh, max_det, class_agnostic, box_format, fused, interpret,
+    )
+
+
+def _best_class_or_multilabel(boxes, scores, conf_thresh, max_nms, multi_label):
+    """One image's candidates from (N, 4) boxes and (N, nc) scores."""
+    if multi_label and scores.shape[-1] > 1:
+        return _multilabel_topk(boxes, scores, conf_thresh, max_nms)
+    return _gate_topk(
+        boxes,
+        jnp.max(scores, axis=-1),
+        jnp.argmax(scores, axis=-1),
+        conf_thresh,
+        max_nms,
     )
 
 
@@ -121,7 +132,7 @@ def _multilabel_topk_nms(
     jax.jit,
     static_argnames=(
         "max_det", "max_nms", "class_agnostic", "multi_label", "fused",
-        "interpret",
+        "interpret", "return_steps",
     ),
 )
 def extract_boxes(
@@ -134,7 +145,8 @@ def extract_boxes(
     multi_label: bool = False,
     fused: bool = False,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    return_steps: bool = False,
+) -> tuple[jnp.ndarray, ...]:
     """Raw YOLO-style predictions -> packed per-image detections.
 
     Args:
@@ -151,47 +163,28 @@ def extract_boxes(
 
     Returns:
       (detections, valid): (B, max_det, 6) [x1, y1, x2, y2, conf, cls]
-      rows (zeros when invalid) and (B, max_det) bool mask.
+      rows (zeros when invalid) and (B, max_det) bool mask; with
+      ``return_steps`` a third value, the greedy steps each group of
+      eight frames ran in the fused kernel ((ceil(B / 8),) int32; None
+      when not ``fused``).
     """
-    nc = prediction.shape[-1] - 5
+    # fused path defers xywh->xyxy into the kernel (the "decode" half
+    # of decode+NMS — conversion commutes with the top-k gather, and
+    # *0.5 is exact, so rows stay bitwise-identical)
+    fmt = "xywh" if fused else "xyxy"
 
-    def one_image(pred: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-        # fused path defers xywh->xyxy into the kernel (the "decode"
-        # half of decode+NMS — conversion commutes with the top-k
-        # gather, and *0.5 is exact, so rows stay bitwise-identical)
+    def one_image(pred: jnp.ndarray):
         boxes = pred[:, :4] if fused else xywh2xyxy(pred[:, :4])
-        fmt = "xywh" if fused else "xyxy"
-        obj = pred[:, 4]
-        cls_conf = pred[:, 5:] * obj[:, None]  # conf = obj * cls
-
-        if multi_label and nc > 1:
-            return _multilabel_topk_nms(
-                boxes,
-                cls_conf,
-                conf_thresh,
-                iou_thresh,
-                max_det,
-                max_nms,
-                class_agnostic,
-                box_format=fmt,
-                fused=fused,
-                interpret=interpret,
-            )
-        return _gate_topk_nms(
-            boxes,
-            jnp.max(cls_conf, axis=-1),
-            jnp.argmax(cls_conf, axis=-1),
-            conf_thresh,
-            iou_thresh,
-            max_det,
-            max_nms,
-            class_agnostic,
-            box_format=fmt,
-            fused=fused,
-            interpret=interpret,
+        cls_conf = pred[:, 5:] * pred[:, 4, None]  # conf = obj * cls
+        return _best_class_or_multilabel(
+            boxes, cls_conf, conf_thresh, max_nms, multi_label
         )
 
-    return jax.vmap(one_image)(prediction)
+    packed = _packed_nms(
+        jax.vmap(one_image)(prediction),
+        iou_thresh, max_det, class_agnostic, fmt, fused, interpret,
+    )
+    return packed if return_steps else packed[:2]
 
 
 @functools.partial(
@@ -230,26 +223,21 @@ def extract_boxes_yolov4(
         boxes = boxes[:, :, 0, :]
 
     def one_image(b: jnp.ndarray, c: jnp.ndarray):
-        return _gate_topk_nms(
-            b,
-            jnp.max(c, axis=-1),
-            jnp.argmax(c, axis=-1),
-            conf_thresh,
-            iou_thresh,
-            max_det,
-            max_nms,
-            fused=fused,
-            interpret=interpret,
+        return _gate_topk(
+            b, jnp.max(c, axis=-1), jnp.argmax(c, axis=-1), conf_thresh, max_nms
         )
 
-    return jax.vmap(one_image)(boxes, confs)
+    return _packed_nms(
+        jax.vmap(one_image)(boxes, confs),
+        iou_thresh, max_det, False, "xyxy", fused, interpret,
+    )[:2]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "max_det", "max_nms", "class_agnostic", "multi_label", "fused",
-        "interpret",
+        "interpret", "return_steps",
     ),
 )
 def extract_boxes_scored(
@@ -263,7 +251,8 @@ def extract_boxes_scored(
     multi_label: bool = True,
     fused: bool = False,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    return_steps: bool = False,
+) -> tuple[jnp.ndarray, ...]:
     """Decoded-box detectors (RetinaNet/FCOS) -> packed detections.
 
     The reference's detectron family has NMS server-side and its client
@@ -279,34 +268,16 @@ def extract_boxes_scored(
 
     Returns:
       (detections, valid): (B, max_det, 6) [x1, y1, x2, y2, score,
-      class] + (B, max_det) mask.
+      class] + (B, max_det) mask; with ``return_steps`` the fused
+      kernel's steps a group as a third value (see extract_boxes).
     """
-    nc = scores.shape[-1]
-
     def one_image(b: jnp.ndarray, s: jnp.ndarray):
-        if multi_label and nc > 1:
-            return _multilabel_topk_nms(
-                b,
-                s,
-                conf_thresh,
-                iou_thresh,
-                max_det,
-                max_nms,
-                class_agnostic,
-                fused=fused,
-                interpret=interpret,
-            )
-        return _gate_topk_nms(
-            b,
-            jnp.max(s, axis=-1),
-            jnp.argmax(s, axis=-1),
-            conf_thresh,
-            iou_thresh,
-            max_det,
-            max_nms,
-            class_agnostic,
-            fused=fused,
-            interpret=interpret,
+        return _best_class_or_multilabel(
+            b, s, conf_thresh, max_nms, multi_label
         )
 
-    return jax.vmap(one_image)(boxes, scores)
+    packed = _packed_nms(
+        jax.vmap(one_image)(boxes, scores),
+        iou_thresh, max_det, class_agnostic, "xyxy", fused, interpret,
+    )
+    return packed if return_steps else packed[:2]

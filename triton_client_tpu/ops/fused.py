@@ -37,6 +37,13 @@ import os
 
 FUSED_STAGES = ("voxelize_scatter", "decode_nms")
 
+#: Reserved device-output key of a 2D detector whose ``decode_nms`` stage
+#: is fused: the greedy steps each group of eight frames ran in the
+#: kernel (ops/pallas_decode), a small int32 array that rides with the
+#: rows. The staged channel sums it into ``stats()`` at readback and
+#: drops it there. Never a wire tensor name.
+NMS_STEPS_KEY = "__nms_steps__"
+
 _OFF = ("0", "off", "false", "none", "")
 _ON = ("1", "on", "true", "all", "auto")
 

@@ -9,12 +9,21 @@ into single Pallas launches with every operand VMEM-resident, so
 detections are produced on-device in packed form and feed the session
 tracker (PR 15) with zero host hops:
 
-  * :func:`fused_decode_nms_2d` — ONE kernel: candidate box decode
-    (xywh->xyxy), adaptive class-offset, the greedy suppression loop
-    (ops/pallas_nms's proven formulation) and the packed
-    ``(max_det, 6)`` detection rows. Bitwise-identical to the
-    ``nms_padded`` reference path (same conversion math, same offset
-    stride, same tie-breaks — pinned by tests/test_fused_parity.py).
+  * :func:`fused_decode_nms_2d` — ONE kernel for a whole batch:
+    candidate box decode (xywh->xyxy), adaptive class-offset, the
+    greedy suppression loop (ops/pallas_nms's proven formulation) and
+    the packed ``(max_det, 6)`` detection rows. A grid step takes a
+    GROUP of eight frames, one a sublane of every (8, lanes) tile, so a
+    greedy step does eight frames' work in the vector registers one
+    frame used to fill an eighth of; and a group's loop ends when none
+    of its frames has a live candidate, not after ``max_det`` steps
+    (a frame keeps tens of boxes, not 300). A lone frame is a group
+    with seven dead sublanes. Bitwise-identical to the ``nms_padded``
+    reference path a frame (same conversion math, same offset stride
+    per frame, same tie-breaks — pinned by tests/test_fused_parity.py
+    and tests/test_fused_decode_groups.py). It reports the steps each
+    group ran, which the serving channel counts (``nms_steps`` over
+    ``nms_frames`` under ``/snapshot``).
   * :func:`fused_residual_decode` — the 3D anchor-residual decode +
     direction rectification for the K top-k candidates as one
     elementwise kernel (collapses decode_boxes + rectify_direction +
@@ -55,40 +64,58 @@ from triton_client_tpu.ops.pallas_nms import (
 )
 
 _LANES = 128
+# float32 sublanes of a vector register: the frames the 2D kernel runs
+# side by side. A constant of the chip's layout, like _LANES
+_SUBLANES = 8
 
 
 def _round_up(n: int, m: int) -> int:
     return ((max(1, n) + m - 1) // m) * m
 
 
-# -- 2D: decode + class-offset + NMS + pack in one launch ---------------------
+# -- 2D: decode + class-offset + NMS + pack, eight frames a grid step ---------
+
+# rows of the kernel's input block [c0..c3 (box_format coords), score
+# (0-filled), class, valid] and output block [x1, y1, x2, y2, score,
+# class, keep]: each an (8, lanes) tile, one frame a sublane
+_CAND_FIELDS = 7
+_OUT_FIELDS = 7
+
+
+def _pick_col(sel, tile):
+    """ops/pallas_nms.masked_pick, a frame a sublane: each frame's
+    selected lane as an (8, 1) column."""
+    return jnp.sum(jnp.where(sel, tile, 0.0), axis=1, keepdims=True)
 
 
 def _decode_nms_pack_2d_kernel(
     cand_ref,
     thresh_ref,
     out_ref,
+    steps_ref,
     live_ref,
     *,
     max_det,
     box_format,
     class_agnostic,
 ):
-    """cand_ref: (8, N) rows [c0..c3 (box_format coords), score
-    (0-filled), class, valid, 0]; out_ref: (8, max_det_pad) rows
-    [x1, y1, x2, y2, score, class, keep, 0]. The suppression loop is
-    ops/pallas_nms._nms_kernel's, extended with in-kernel decode and
-    the packing epilogue. Offset coords (IoU space) and original
-    coords (output space) both stay resident — the reference path's
-    separate batched_nms + gather/concat stages collapse here."""
-    n = cand_ref.shape[1]
+    """One GROUP of eight frames: cand_ref (7, 8, N), out_ref
+    (7, 8, max_det_pad), every field an (8, lanes) tile with the frame
+    along the sublanes, so one vector register holds eight frames' worth
+    of a greedy step. The step is ops/pallas_nms._nms_kernel's with
+    in-kernel decode and the packing epilogue; each per-frame reduction
+    is a lane reduction to an (8, 1) column. Offset coords (IoU space)
+    and original coords (output space) both stay resident. The loop ends
+    when no frame of the group has a live candidate: a frame that ran
+    out earlier writes zeros into a block zeroed before the loop, so the
+    rows are the same wherever it ends. steps_ref: the steps it ran."""
+    n = cand_ref.shape[2]
     iou_thresh = thresh_ref[0]
 
-    c0, c1 = cand_ref[0:1, :], cand_ref[1:2, :]
-    c2, c3 = cand_ref[2:3, :], cand_ref[3:4, :]
-    score = cand_ref[4:5, :]
-    clsf = cand_ref[5:6, :]
-    valid = cand_ref[6:7, :] > 0.0
+    c0, c1, c2, c3 = cand_ref[0], cand_ref[1], cand_ref[2], cand_ref[3]
+    score = cand_ref[4]
+    clsf = cand_ref[5]
+    valid = cand_ref[6] > 0.0
 
     if box_format == "xywh":  # ops/boxes.xywh2xyxy, bit for bit
         x1, y1 = c0 - c2 * 0.5, c1 - c3 * 0.5
@@ -101,30 +128,42 @@ def _decode_nms_pack_2d_kernel(
     if class_agnostic:
         ox1, oy1, ox2, oy2 = x1, y1, x2, y2
     else:
-        # ops/nms.batched_nms's adaptive stride: max |coord| over the
-        # candidate set (fp max is associative, so the reduction
-        # reorders bitwise-safely; zero pad lanes cannot raise it)
+        # ops/nms.batched_nms's adaptive stride, per FRAME: max |coord|
+        # over the frame's candidates (fp max is associative, so the
+        # reduction reorders bitwise-safely; zero pad lanes cannot
+        # raise it)
         m = jnp.maximum(jnp.maximum(jnp.abs(x1), jnp.abs(y1)),
                         jnp.maximum(jnp.abs(x2), jnp.abs(y2)))
-        stride = jnp.max(m) * 2.0 + 1.0
+        stride = jnp.max(m, axis=1, keepdims=True) * 2.0 + 1.0
         off = clsf * stride
         ox1, oy1, ox2, oy2 = x1 + off, y1 + off, x2 + off, y2 + off
 
     area = (ox2 - ox1) * (oy2 - oy1)
     live_ref[:] = jnp.where(valid, score, _NEG_INF)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-    out_lane = jax.lax.broadcasted_iota(jnp.int32, (1, out_ref.shape[1]), 1)
+    # lane indices as float32 (exact far beyond any max_nms): the
+    # first-index argmax below is then a float min-reduction
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, n), 1).astype(
+        jnp.float32
+    )
+    out_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (_SUBLANES, out_ref.shape[2]), 1
+    )
 
-    def body(i, _):
+    def body(carry):
+        i, _ = carry
         live = live_ref[:]
-        best_score = jnp.max(live)
-        best = jnp.argmax(live[0, :]).astype(jnp.int32)
+        best_score = jnp.max(live, axis=1, keepdims=True)
+        # jnp.argmax's tie-break: the first lane holding the maximum
+        best = jnp.min(
+            jnp.where(live == best_score, lane, float(n)),
+            axis=1, keepdims=True,
+        )
         is_valid = best_score > _NEG_INF
         sel = lane == best
 
-        bx1o, by1o = masked_pick(sel, ox1), masked_pick(sel, oy1)
-        bx2o, by2o = masked_pick(sel, ox2), masked_pick(sel, oy2)
-        barea = masked_pick(sel, area)
+        bx1o, by1o = _pick_col(sel, ox1), _pick_col(sel, oy1)
+        bx2o, by2o = _pick_col(sel, ox2), _pick_col(sel, oy2)
+        barea = _pick_col(sel, area)
         iw = jnp.clip(jnp.minimum(ox2, bx2o) - jnp.maximum(ox1, bx1o), 0.0, None)
         ih = jnp.clip(jnp.minimum(oy2, by2o) - jnp.maximum(oy1, by1o), 0.0, None)
         inter = iw * ih
@@ -133,19 +172,25 @@ def _decode_nms_pack_2d_kernel(
         live_ref[:] = jnp.where(suppress & is_valid, _NEG_INF, live)
 
         vals = (
-            masked_pick(sel, x1), masked_pick(sel, y1),
-            masked_pick(sel, x2), masked_pick(sel, y2),
-            masked_pick(sel, score), masked_pick(sel, clsf),
+            _pick_col(sel, x1), _pick_col(sel, y1),
+            _pick_col(sel, x2), _pick_col(sel, y2),
+            _pick_col(sel, score), _pick_col(sel, clsf),
             1.0,
         )
+        at_i = out_lane == i
         for r, v in enumerate(vals):
-            write_lane_col(
-                out_ref, r, out_lane, i, jnp.where(is_valid, v, 0.0)
+            out_ref[r] = jnp.where(
+                at_i, jnp.where(is_valid, v, 0.0), out_ref[r]
             )
-        return 0
+        return i + 1, jnp.max(is_valid.astype(jnp.int32))
 
     out_ref[:] = jnp.zeros(out_ref.shape, jnp.float32)
-    jax.lax.fori_loop(0, max_det, body, 0)
+    steps, _ = jax.lax.while_loop(
+        lambda carry: (carry[0] < max_det) & (carry[1] > 0),
+        body,
+        (jnp.int32(0), jnp.int32(1)),
+    )
+    steps_ref[:] = jnp.full(steps_ref.shape, steps, jnp.int32)
 
 
 @functools.partial(
@@ -162,42 +207,69 @@ def fused_decode_nms_2d(
     box_format: str = "xywh",
     class_agnostic: bool = False,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One-launch candidate tail: boxes (K, 4) in ``box_format``,
-    scores (K,) 0-filled on invalid slots, classes (K,) int, valid (K,)
-    bool -> packed ``(max_det, 6)`` [x1, y1, x2, y2, score, class] rows
-    + (max_det,) keep mask — the exact ``nms_padded`` contract."""
-    k = boxes.shape[0]
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One-launch candidate tail of a BATCH: boxes (B, K, 4) in
+    ``box_format``, scores (B, K) 0-filled on invalid slots, classes
+    (B, K) int, valid (B, K) bool -> packed ``(B, max_det, 6)``
+    [x1, y1, x2, y2, score, class] rows + (B, max_det) keep mask — the
+    exact ``nms_padded`` contract per frame — and the greedy steps each
+    group of eight frames ran, (ceil(B / 8),) int32: the largest kept
+    count of its frames plus the step that found nothing, at most
+    ``max_det``. The grid is the groups; a batch that is no multiple of
+    eight is padded with frames that have no valid candidate."""
+    b, k = scores.shape
+    b_pad = _round_up(b, _SUBLANES)
     k_pad = _round_up(k, _LANES)
     md_pad = _round_up(max_det, _LANES)
 
-    cand = jnp.zeros((8, k_pad), jnp.float32)
-    cand = cand.at[0:4, :k].set(boxes.astype(jnp.float32).T)
-    cand = cand.at[4, :k].set(scores.astype(jnp.float32))
-    cand = cand.at[5, :k].set(classes.astype(jnp.float32))
-    cand = cand.at[6, :k].set(valid.astype(jnp.float32))
+    fields = jnp.concatenate(
+        [
+            jnp.moveaxis(boxes.astype(jnp.float32), -1, 0),
+            jnp.stack(
+                [
+                    scores.astype(jnp.float32),
+                    classes.astype(jnp.float32),
+                    valid.astype(jnp.float32),
+                ]
+            ),
+        ]
+    )
+    cand = jnp.pad(fields, ((0, 0), (0, b_pad - b), (0, k_pad - k)))
     thresh = jnp.reshape(jnp.asarray(iou_thresh, jnp.float32), (1,))
 
     with jax.named_scope("fused:decode_nms"):
-        out = pl.pallas_call(
+        out, steps = pl.pallas_call(
             functools.partial(
                 _decode_nms_pack_2d_kernel,
                 max_det=max_det,
                 box_format=box_format,
                 class_agnostic=class_agnostic,
             ),
-            out_shape=jax.ShapeDtypeStruct((8, md_pad), jnp.float32),
+            grid=(b_pad // _SUBLANES,),
+            out_shape=(
+                jax.ShapeDtypeStruct((_OUT_FIELDS, b_pad, md_pad), jnp.float32),
+                jax.ShapeDtypeStruct(
+                    (b_pad // _SUBLANES, _SUBLANES, _LANES), jnp.int32
+                ),
+            ),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(
+                    (_CAND_FIELDS, _SUBLANES, k_pad), lambda g: (0, g, 0)
+                ),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.VMEM((1, k_pad), jnp.float32)],
+            out_specs=(
+                pl.BlockSpec(
+                    (_OUT_FIELDS, _SUBLANES, md_pad), lambda g: (0, g, 0)
+                ),
+                pl.BlockSpec((None, _SUBLANES, _LANES), lambda g: (g, 0, 0)),
+            ),
+            scratch_shapes=[pltpu.VMEM((_SUBLANES, k_pad), jnp.float32)],
             interpret=interpret,
         )(cand, thresh)
-    dets = out[0:6, :max_det].T
-    keep = out[6, :max_det] > 0.0
-    return dets, keep
+    dets = jnp.moveaxis(out[0:6, :b, :max_det], 0, -1)
+    keep = out[6, :b, :max_det] > 0.0
+    return dets, keep, steps[:, 0, 0]
 
 
 # -- 3D: residual decode + rectify as one elementwise launch ------------------

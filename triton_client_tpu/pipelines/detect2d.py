@@ -91,6 +91,13 @@ class Detect2DPipeline:
     def _pipeline(
         self, frames: jnp.ndarray, orig_hw: tuple[int, int]
     ) -> tuple[jnp.ndarray, jnp.ndarray]:
+        return self._pipeline_counted(frames, orig_hw)[:2]
+
+    def _pipeline_counted(self, frames: jnp.ndarray, orig_hw: tuple[int, int]):
+        """``(detections, valid, steps)``: ``steps`` is the fused
+        kernel's greedy steps a group of eight frames (None where the
+        tail is not fused), which ``device_fn`` hands the serving
+        channel for its counters."""
         cfg = self.config
         # policy compute dtype (f32 legacy; bf16 halves the resize/
         # normalize/forward HBM traffic). Wire inputs may arrive already
@@ -110,9 +117,8 @@ class Detect2DPipeline:
         fuse_tail = "decode_nms" in self.fused_stages
         interpret = fused_routing.fused_interpret()
         if cfg.head_style == "scored":
-            boxes_scores = pred
-            dets, valid = extract_boxes_scored(
-                *boxes_scores,
+            dets, valid, steps = extract_boxes_scored(
+                *pred,
                 conf_thresh=cfg.conf_thresh,
                 iou_thresh=cfg.iou_thresh,
                 max_det=cfg.max_det,
@@ -120,9 +126,10 @@ class Detect2DPipeline:
                 multi_label=cfg.multi_label,
                 fused=fuse_tail,
                 interpret=interpret,
+                return_steps=True,
             )
         else:
-            dets, valid = extract_boxes(
+            dets, valid, steps = extract_boxes(
                 pred,
                 conf_thresh=cfg.conf_thresh,
                 iou_thresh=cfg.iou_thresh,
@@ -131,11 +138,12 @@ class Detect2DPipeline:
                 multi_label=cfg.multi_label,
                 fused=fuse_tail,
                 interpret=interpret,
+                return_steps=True,
             )
         boxes = scale_boxes(dets[..., :4], cfg.input_hw, orig_hw)
         dets = jnp.concatenate([boxes, dets[..., 4:]], axis=-1)
         dets = jnp.where(valid[..., None], dets, 0.0)
-        return dets, valid
+        return dets, valid, steps
 
     def infer(self, frames) -> tuple[np.ndarray, np.ndarray]:
         """frames: (B, H, W, 3) or (H, W, 3) uint8/float RGB — numpy OR
@@ -188,29 +196,28 @@ class Detect2DPipeline:
         device-fused ensembles compose through (runtime/ensemble.py;
         intermediates stay in HBM instead of round-tripping host
         memory between steps). orig_hw comes off the traced shape, so
-        per-resolution retracing matches the wire path's behavior."""
-        if self.config.head_style == "scored":
+        per-resolution retracing matches the wire path's behavior. A
+        fused tail's step count rides along under ``NMS_STEPS_KEY``
+        (ops/fused), for the serving channel's counters."""
+        scored = self.config.head_style == "scored"
 
-            def fn(inputs):
-                frames = inputs["images"]
-                dets, valid = self._pipeline(
-                    frames, (frames.shape[1], frames.shape[2])
-                )
-                return {
+        def fn(inputs):
+            frames = inputs["images"]
+            dets, valid, steps = self._pipeline_counted(
+                frames, (frames.shape[1], frames.shape[2])
+            )
+            if scored:
+                out = {
                     "boxes": dets[..., :4],
                     "scores": dets[..., 4],
                     "classes": dets[..., 5].astype(jnp.int32),
                     "dims": valid.sum(axis=-1).astype(jnp.int32),
                 }
-
-        else:
-
-            def fn(inputs):
-                frames = inputs["images"]
-                dets, valid = self._pipeline(
-                    frames, (frames.shape[1], frames.shape[2])
-                )
-                return {"detections": dets, "valid": valid}
+            else:
+                out = {"detections": dets, "valid": valid}
+            if steps is not None:
+                out[fused_routing.NMS_STEPS_KEY] = steps
+            return out
 
         return fn
 
