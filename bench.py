@@ -720,13 +720,10 @@ def measure_serving(
     # into an error count instead of a rate
     deadline_s = max(180.0, direct_batch_ms / 1e3 * clients * 20)
 
-    # continuous scheduler (ISSUE 8): windowless EDF admission, dense
-    # fallback padded to live-occupancy buckets — the merge-hold knob
-    # the window batcher needed to fill merges is obsolete (arrivals
-    # pool while device work is in flight)
+    # windowless EDF admission, dense merges padded to live-occupancy
+    # buckets (arrivals pool while device work is in flight)
     batching = ContinuousBatchingChannel(
-        inner, max_batch=max_batch,
-        max_merge=max_merge, pad_to_buckets=True,
+        inner, max_batch=max_batch, max_merge=max_merge
     )
     server = InferenceServer(
         repo, batching, address="127.0.0.1:0", uds_address="auto",

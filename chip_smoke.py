@@ -631,10 +631,6 @@ def run_one_chip(rehearse: bool, work: pathlib.Path) -> None:
                 f"the launcher for stages {stages} — a kernel ran interpreted",
             )
         check(snap["batching"]["scheduler"] == "continuous", "not the continuous batcher")
-        check(
-            "triton_client_tpu.native" not in sys.modules,
-            "the smoke path loaded the native runtime (an uncommitted binary)",
-        )
 
         failures = []
         for name in MODELS:

@@ -42,7 +42,7 @@ import jax
 from triton_client_tpu.channel.base import InferRequest
 from triton_client_tpu.channel.tpu_channel import TPUChannel
 from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-from triton_client_tpu.runtime.batching import BatchingChannel
+from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 from triton_client_tpu.runtime.repository import ModelRepository
 from triton_client_tpu.runtime.server import InferenceServer
 
@@ -87,7 +87,7 @@ def main():
     args = p.parse_args()
 
     repo, inner, spec, frame = build_warm()
-    batching = BatchingChannel(inner, max_batch=MAX_BATCH, timeout_us=3000)
+    batching = ContinuousBatchingChannel(inner, max_batch=MAX_BATCH)
     server = InferenceServer(
         repo, batching, address="127.0.0.1:0", max_workers=8,
         metrics_port="auto",

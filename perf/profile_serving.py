@@ -36,7 +36,7 @@ import jax
 from triton_client_tpu.channel.base import InferRequest
 from triton_client_tpu.channel.tpu_channel import TPUChannel
 from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-from triton_client_tpu.runtime.batching import BatchingChannel
+from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 from triton_client_tpu.runtime.repository import ModelRepository
 from triton_client_tpu.runtime.server import InferenceServer
 
@@ -75,7 +75,7 @@ def run_combo(repo, inner, spec, frame, workers, clients, use_shm,
               duration_s=8.0):
     from triton_client_tpu.utils.loadgen import run_pool
 
-    batching = BatchingChannel(inner, max_batch=MAX_BATCH, timeout_us=3000)
+    batching = ContinuousBatchingChannel(inner, max_batch=MAX_BATCH)
     server = InferenceServer(
         repo, batching, address="127.0.0.1:0", max_workers=workers
     )

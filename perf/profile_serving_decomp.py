@@ -1,20 +1,18 @@
-"""Serving pipeline decomposition + depth/arena A/B (VERDICT r4 Weak
+"""Serving pipeline decomposition + depth A/B (VERDICT r4 Weak
 #5/#6): where does the served/ceiling gap go?
 
-Round 5 instrumented the batcher (runtime/batching.py stats()
+Round 5 instrumented the batcher (runtime/continuous.py stats()
 ``decomp_ms``): per device batch, mean milliseconds in
   * queue_wait — first request staged -> executor slot acquired
-    (includes the merge hold and pipeline-depth backpressure);
+    (includes pipeline-depth backpressure);
   * exec_wait — submit -> executor thread picks the group up;
-  * stage    — host merge build (np.asarray + slot/concat copy);
+  * stage    — host merge build (np.asarray + concat copy);
   * device   — the inner channel call (device_put + jit + readback).
 
 The sum x batches vs the wall window tells which leg owns the gap
-between served fps and device_ceiling_fps. The A/B axes:
+between served fps and device_ceiling_fps. The A/B axis:
   * pipeline_depth 1 / 2 / 4 — how many formed batches may be in
-    flight against the device at once;
-  * arena staging on/off — merged batches through recycled aligned
-    native slots vs a fresh np.concatenate per batch.
+    flight against the device at once.
 
 Usage: python perf/profile_serving_decomp.py [--duration 25] [--clients 16]
 """
@@ -50,7 +48,7 @@ def main(argv=None) -> None:
     from triton_client_tpu.channel.tpu_channel import TPUChannel
     from triton_client_tpu.obs import RuntimeCollector
     from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.repository import ModelRepository
     from triton_client_tpu.runtime.server import InferenceServer
     from triton_client_tpu.utils.loadgen import run_pool
@@ -86,12 +84,10 @@ def main(argv=None) -> None:
         ("depth1", dict(pipeline_depth=1)),
         ("depth2", dict(pipeline_depth=2)),
         ("depth4", dict(pipeline_depth=4)),
-        ("depth2_arena", dict(pipeline_depth=2, arena_slots=6)),
     ]
     for name, kw in cases:
-        batching = BatchingChannel(
-            inner, max_batch=8, timeout_us=3000, max_merge=16,
-            pad_to_buckets=True, merge_hold_us=25_000, **kw,
+        batching = ContinuousBatchingChannel(
+            inner, max_batch=8, max_merge=16, **kw
         )
         # the same snapshot/delta API the Prometheus custom collector
         # scrapes in production — perf rows and dashboards read
@@ -113,8 +109,7 @@ def main(argv=None) -> None:
             stats = RuntimeCollector.delta(s1, s0).get("batching", {})
             # level quantities (means / free-slot count), not counters:
             # read from the raw snapshot, not the delta
-            for key in ("decomp_ms", "arena_free_slots"):
-                stats[key] = s1["batching"].get(key)
+            stats["decomp_ms"] = s1["batching"].get("decomp_ms")
             lat = res.latencies_ms
             row = {
                 "case": name,
@@ -130,7 +125,6 @@ def main(argv=None) -> None:
                     stats.get("merged_frames", 0)
                     / max(stats.get("merges", 1), 1), 2,
                 ),
-                "arena_free_slots": stats.get("arena_free_slots"),
                 "errors": len(res.errors),
             }
             print(json.dumps(row), flush=True)

@@ -50,7 +50,7 @@ def main(argv=None) -> None:
     from triton_client_tpu.channel.base import InferRequest
     from triton_client_tpu.channel.tpu_channel import TPUChannel
     from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.repository import ModelRepository
     from triton_client_tpu.runtime.server import InferenceServer
     from triton_client_tpu.utils.loadgen import run_pool
@@ -73,10 +73,7 @@ def main(argv=None) -> None:
             )
         )
         k *= 2
-    batching = BatchingChannel(
-        inner, max_batch=8, timeout_us=3000, max_merge=16,
-        pad_to_buckets=True, merge_hold_us=25_000,
-    )
+    batching = ContinuousBatchingChannel(inner, max_batch=8, max_merge=16)
     server = InferenceServer(
         repo, batching, address="127.0.0.1:0", max_workers=args.clients + 8
     )

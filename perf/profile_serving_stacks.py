@@ -71,7 +71,7 @@ def main() -> None:
     from triton_client_tpu.channel.base import InferRequest
     from triton_client_tpu.channel.tpu_channel import TPUChannel
     from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.repository import ModelRepository
     from triton_client_tpu.runtime.server import InferenceServer
     from triton_client_tpu.utils.loadgen import run_pool
@@ -148,10 +148,8 @@ def main() -> None:
                                  inputs={"images": np.repeat(frame, k, 0)}))
         k *= 2
 
-    batching = BatchingChannel(
-        inner, max_batch=8, timeout_us=3000, max_merge=16,
-        pad_to_buckets=True, pipeline_depth=DEPTH,
-        merge_hold_us=int(os.environ.get("STACKS_HOLD_US", "0")),
+    batching = ContinuousBatchingChannel(
+        inner, max_batch=8, max_merge=16, pipeline_depth=DEPTH
     )
     server = InferenceServer(
         repo, batching, address="127.0.0.1:0", max_workers=CLIENTS + 8

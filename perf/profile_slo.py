@@ -39,7 +39,7 @@ import jax
 from triton_client_tpu.channel.base import InferRequest
 from triton_client_tpu.channel.tpu_channel import TPUChannel
 from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-from triton_client_tpu.runtime.batching import BatchingChannel
+from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 from triton_client_tpu.runtime.repository import ModelRepository
 from triton_client_tpu.runtime.server import InferenceServer
 from triton_client_tpu.utils.loadgen import run_open_loop, slo_capacity_search
@@ -85,9 +85,7 @@ def serve_and_search(args) -> dict:
                 inputs={"images": np.repeat(frame, k, axis=0)},
             )
         )
-    batching = BatchingChannel(
-        inner, max_batch=MAX_BATCH, timeout_us=2000, pad_to_buckets=True
-    )
+    batching = ContinuousBatchingChannel(inner, max_batch=MAX_BATCH)
     server = InferenceServer(
         repo, batching, address="127.0.0.1:0", max_workers=16,
         metrics_port="auto", slo_ms=args.slo_ms or 0.0,

@@ -33,3 +33,25 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+def pytest_collection_modifyitems(config, items):
+    """A module that states ``SHARD = (k, n)`` beside the names it
+    ``ADOPTED`` (the shards of the benchmark's rehearsals,
+    ``test_benchmark_seam.py``) keeps every n-th adopted case, counted
+    from k in the order of the cases' ids; its own tests stay."""
+    adopted = {}
+    for item in items:
+        module = getattr(item, "module", None)
+        if hasattr(module, "SHARD") and getattr(item, "originalname", None) in module.ADOPTED:
+            adopted.setdefault(module, []).append(item.name)
+    dropped = set()
+    for module, names in adopted.items():
+        k, n = module.SHARD
+        dropped.update((module, name) for rank, name in enumerate(sorted(names)) if rank % n != k)
+    if dropped:
+        kept, gone = [], []
+        for item in items:
+            (gone if (getattr(item, "module", None), item.name) in dropped else kept).append(item)
+        items[:] = kept
+        config.hook.pytest_deselected(items=gone)

@@ -545,12 +545,20 @@ class TestDeviceFusion:
                 fuse="maybe",
             )
 
-    def test_examples_fused_entry_serves(self):
+    def test_examples_fused_entry_serves(self, tmp_path):
         """The shipped preprocess->detector entry loads from disk with
         fuse: always (every member has a device form) and detects."""
+        import shutil
+
         from triton_client_tpu.runtime import disk_repository as dr
 
-        repo = dr.scan_disk("examples")
+        # the entry and the members its config.yaml names, as shipped:
+        # scan_disk builds every entry eagerly, and the other twelve
+        # of examples/ (axk1_ep16's weights among them) were most of this
+        # test's 376 s (24 s without them) and nothing it checks
+        for entry in ("ensemble_fused_pipeline", "camera_preprocess", "yolov5_crop"):
+            shutil.copytree(f"examples/{entry}", tmp_path / entry)
+        repo = dr.scan_disk(str(tmp_path))
         rm = repo.get("ensemble_fused_pipeline")
         assert rm.spec.extra["fused"] is True
         frame = np.zeros((1, 96, 128, 3), np.uint8)

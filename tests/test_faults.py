@@ -23,7 +23,6 @@ Covers the PR's acceptance contract:
     UNAVAILABLE, and completes in-flight requests inside the timeout.
 """
 
-import concurrent.futures
 import json
 import os
 import threading
@@ -94,10 +93,9 @@ def _repo(name="double", sleep_s=0.0, with_device_fn=False):
 
 
 def _stack(repo, batching=True, shed_expired=False, breaker_threshold=0,
-           breaker_reset_s=10.0, max_batch=4, merge_hold_us=2000,
-           **server_kw):
+           breaker_reset_s=10.0, max_batch=4, **server_kw):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
     chan = TPUChannel(
@@ -107,9 +105,8 @@ def _stack(repo, batching=True, shed_expired=False, breaker_threshold=0,
         breaker_reset_s=breaker_reset_s,
     )
     if batching:
-        chan = BatchingChannel(
-            chan, max_batch=max_batch, timeout_us=2000,
-            merge_hold_us=merge_hold_us, shed_expired=shed_expired,
+        chan = ContinuousBatchingChannel(
+            chan, max_batch=max_batch, shed_expired=shed_expired
         )
     server = InferenceServer(
         repo, chan, address="127.0.0.1:0", metrics_port="auto", **server_kw
@@ -482,11 +479,12 @@ class _SlowInner:
 class TestBatcherShedding:
     def test_queue_full_fail_fast(self):
         from triton_client_tpu.runtime.admission import QueueFullError
-        from triton_client_tpu.runtime.batching import BatchingChannel
+        from triton_client_tpu.runtime.continuous import (
+            ContinuousBatchingChannel,
+        )
 
-        chan = BatchingChannel(
-            _SlowInner(sleep_s=0.3), max_batch=1, timeout_us=100,
-            capacity=1, pipeline_depth=1,
+        chan = ContinuousBatchingChannel(
+            _SlowInner(sleep_s=0.3), max_batch=1, capacity=1, pipeline_depth=1
         )
         try:
             results = []
@@ -505,6 +503,9 @@ class TestBatcherShedding:
             for t in threads:
                 t.join()
             wall = time.perf_counter() - t0
+            # the one do_inference refuses at capacity: the ready set
+            # holds a single staged request, the next caller is turned
+            # away on its own thread
             assert "shed" in results  # the bounded queue rejected
             assert "ok" in results  # and still served
             # fail-fast contract: sheds returned in microseconds — the
@@ -518,11 +519,12 @@ class TestBatcherShedding:
     def test_merge_shed_expired_members(self):
         from triton_client_tpu.channel.base import InferRequest
         from triton_client_tpu.runtime.admission import DeadlineExpiredError
-        from triton_client_tpu.runtime.batching import BatchingChannel
+        from triton_client_tpu.runtime.continuous import (
+            ContinuousBatchingChannel,
+        )
 
-        chan = BatchingChannel(
-            _SlowInner(sleep_s=0.0), max_batch=4, timeout_us=5000,
-            merge_hold_us=5000, shed_expired=True,
+        chan = ContinuousBatchingChannel(
+            _SlowInner(sleep_s=0.0), max_batch=4, shed_expired=True
         )
         try:
             outcomes = {}
@@ -558,20 +560,48 @@ class TestBatcherShedding:
         finally:
             chan.close()
 
-    def test_priority_orders_staged_window(self):
+    def test_priority_orders_the_ready_set(self):
         from triton_client_tpu.channel.base import InferRequest
-        from triton_client_tpu.runtime.batching import BatchingChannel
-
-        chan = BatchingChannel(
-            _SlowInner(), max_batch=4, timeout_us=100, shed_expired=True
+        from triton_client_tpu.runtime.continuous import (
+            ContinuousBatchingChannel,
         )
-        chan.close()  # stop the dispatcher so _ready stays inspectable
-        for i, prio in enumerate([0, 5, -1, 1]):
-            req = InferRequest("double", {"x": X}, priority=prio)
-            with chan._lock:
-                chan._pending[i] = (req, concurrent.futures.Future())
-        chan._on_batch([0, 1, 2, 3])
-        order = [item[2].priority for item in chan._ready]
+
+        chan = ContinuousBatchingChannel(
+            _SlowInner(sleep_s=0.5), max_batch=4, pipeline_depth=1,
+            shed_expired=True,
+        )
+        threads = []
+
+        def submit(priority):
+            req = InferRequest("double", {"x": X}, priority=priority)
+            t = threading.Thread(target=chan.do_inference, args=(req,))
+            t.start()
+            threads.append(t)
+
+        def wait_for(cond):
+            deadline = time.perf_counter() + 10.0
+            while time.perf_counter() < deadline:
+                with chan._ready_cv:
+                    if cond():
+                        return
+                time.sleep(0.002)
+            pytest.fail("the ready set never reached the awaited state")
+
+        try:
+            # two launches hold the one executor thread and the one
+            # permit for a second: what arrives now stays staged
+            for _ in range(2):
+                submit(0)
+                wait_for(lambda: chan._merge_stats["merges"] == len(threads))
+            for prio in [0, 5, -1, 1]:
+                submit(prio)
+            wait_for(lambda: len(chan._ready) == 4)
+            with chan._ready_cv:
+                order = [item[2].priority for item in chan._ready]
+        finally:
+            chan.close()
+            for t in threads:
+                t.join(timeout=10.0)
         # high priority dispatches first; the background class queues
         # longest and therefore sheds first under a backlog
         assert order == [5, 1, 0, -1]
@@ -884,7 +914,6 @@ def test_overload_run_sheds_instead_of_late_launches():
         repo,
         shed_expired=True,
         max_batch=2,
-        merge_hold_us=0,
         admission_max_queue=4,
         slo_ms=slo_ms,
     )

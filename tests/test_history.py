@@ -186,15 +186,15 @@ def test_server_drain_persists_history_and_restart_restores(tmp_path):
     from triton_client_tpu.channel.base import InferRequest
     from triton_client_tpu.channel.grpc_channel import GRPCChannel
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
     path = tmp_path / "history.json"
     repo, spec = _double_repo()
 
     def build():
-        chan = BatchingChannel(
-            TPUChannel(repo), max_batch=4, timeout_us=2000
+        chan = ContinuousBatchingChannel(
+            TPUChannel(repo), max_batch=4
         )
         server = InferenceServer(
             repo, chan, address="127.0.0.1:0", metrics_port="auto",

@@ -122,17 +122,13 @@ def test_multitrace_fans_attrs_to_every_member():
         assert spans["h2d"].attrs["bytes"] == X.nbytes
 
 
-@pytest.mark.parametrize("scheduler", ["window", "continuous"])
-def test_merged_launch_has_one_launch_id_on_all_members(scheduler):
+@pytest.mark.parametrize("depth", [1, 2])
+def test_merged_launch_has_one_launch_id_on_all_members(depth):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
     from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 
     inner = TPUChannel(_repo(sleep_s=0.02))
-    if scheduler == "window":
-        chan = BatchingChannel(inner, max_batch=8, timeout_us=50000, merge_hold_us=50000)
-    else:
-        chan = ContinuousBatchingChannel(inner, max_batch=8)
+    chan = ContinuousBatchingChannel(inner, max_batch=8, pipeline_depth=depth)
     tracer = Tracer(capacity=64)
 
     def one():

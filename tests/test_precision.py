@@ -412,7 +412,7 @@ class TestSelection:
         args = argparse.Namespace(
             model_repository=str(tmp_path), address="127.0.0.1:0",
             max_workers=2, mesh="", batching=False, max_batch=4,
-            batch_timeout_us=2000, pipeline_depth=2, metrics_port=0,
+            pipeline_depth=2, metrics_port=0,
             warmup=False, verbose=False, precision="bf16",
         )
         server = serve.build_server(args)

@@ -131,11 +131,11 @@ def _det_repo(names=("qp_det", "qp_det_int8")):
 
 def _serving_stack(repo, **server_kw):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
-    chan = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=2000, merge_hold_us=0
+    chan = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4
     )
     server = InferenceServer(
         repo, chan, address="127.0.0.1:0", metrics_port="auto", **server_kw
@@ -852,7 +852,6 @@ def test_serve_cli_builds_quality_plane(tmp_path):
         mesh="",
         batching=False,
         max_batch=8,
-        batch_timeout_us=2000,
         pipeline_depth=2,
         metrics_port=0,
         warmup=False,

@@ -234,7 +234,7 @@ def test_serve_with_batching_channel(tmp_path):
     import concurrent.futures
 
     from triton_client_tpu.pipelines.detect2d import build_yolov5_pipeline
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.channel.base import InferRequest
 
     pipe, spec, _ = build_yolov5_pipeline(
@@ -242,8 +242,8 @@ def test_serve_with_batching_channel(tmp_path):
     )
     repo = ModelRepository()
     repo.register(spec, pipe.infer_fn())
-    channel = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=20_000
+    channel = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4
     )
     server = InferenceServer(repo, channel, address="127.0.0.1:0", max_workers=4)
     server.start()

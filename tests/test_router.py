@@ -92,11 +92,11 @@ def _repo(name="double", sleep_s=0.0):
 
 def _stack(repo, **server_kw):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
-    chan = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=2000, merge_hold_us=0
+    chan = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4
     )
     server = InferenceServer(
         repo, chan, address="127.0.0.1:0", metrics_port="auto", **server_kw
@@ -608,10 +608,12 @@ class _EchoInner:
 
 class TestDispatcherWatchdog:
     def test_stall_is_visible_and_clears_on_recovery(self):
-        from triton_client_tpu.runtime.batching import BatchingChannel
+        from triton_client_tpu.runtime.continuous import (
+            ContinuousBatchingChannel,
+        )
 
-        chan = BatchingChannel(
-            _EchoInner(), max_batch=1, timeout_us=100, pipeline_depth=1
+        chan = ContinuousBatchingChannel(
+            _EchoInner(), max_batch=1, pipeline_depth=1
         )
         chan.stall_threshold_s = 0.2
         try:

@@ -26,7 +26,6 @@ def _args(**over):
         mesh="",
         batching=False,
         max_batch=8,
-        batch_timeout_us=2000,
         pipeline_depth=2,
         metrics_port=0,
         warmup=False,
@@ -80,51 +79,26 @@ def test_serve_rejects_missing_repository(tmp_path):
         serve.build_server(_args(model_repository=str(tmp_path / "nope")))
 
 
-def test_batch_timeout_deprecation_warns_once_on_continuous(
-    tmp_path, caplog
-):
-    import logging
-    import shutil
-
-    # camera_preprocess: the cheapest servable entry — these tests
-    # exercise flag plumbing, not model math, and the tier-1 wall is
-    # close to its cap
-    shutil.copytree(
-        "examples/camera_preprocess", tmp_path / "camera_preprocess"
-    )
-    serve._timeout_warned = False  # reset the once-latch for the test
-    try:
-        with caplog.at_level(logging.WARNING, logger=serve.__name__):
-            server = serve.build_server(
-                _args(
-                    model_repository=str(tmp_path),
-                    batching=True,
-                    batch_timeout_us=3000,
-                )
-            )
-            server.stop()
-            warnings = [
-                r for r in caplog.records
-                if "window-timeout knob" in r.getMessage()
-            ]
-            assert len(warnings) == 1
-            assert "--batch-timeout-us" in warnings[0].getMessage()
-            # second build: the latch keeps the log noise-free
-            server = serve.build_server(
-                _args(
-                    model_repository=str(tmp_path),
-                    batching=True,
-                    batch_timeout_us=3000,
-                )
-            )
-            server.stop()
-            warnings = [
-                r for r in caplog.records
-                if "window-timeout knob" in r.getMessage()
-            ]
-            assert len(warnings) == 1
-    finally:
-        serve._timeout_warned = False
+@pytest.mark.parametrize(
+    "flag",
+    [
+        ["--batcher", "window"],
+        ["--batch-timeout-us", "3000"],
+        ["--merge-hold-us", "0"],
+        ["--pad-buckets"],
+    ],
+    ids=lambda flag: flag[0],
+)
+def test_serve_refuses_the_removed_batcher_flags(flag, capsys):
+    """The window batcher's four flags did nothing on the scheduler
+    every server ran; an old command line now fails in argparse."""
+    parser = serve.make_parser()
+    parser.parse_args(["-r", "examples", "--batching"])  # the rest still parses
+    with pytest.raises(SystemExit) as exit_info:
+        parser.parse_args(["-r", "examples", "--batching", *flag])
+    assert exit_info.value.code == 2
+    assert f"unrecognized arguments: {flag[0]}" in capsys.readouterr().err
+    assert flag[0] not in parser.format_help()
 
 
 def test_serve_builds_lifecycle_from_flags(tmp_path):

@@ -12,7 +12,7 @@ conftest.py provisions.
     rows sliced back off, so padding can never leak into answers;
   * pointpillars (max_batch_size=1: the dynamic leading dim is a point
     count, not a batch): runs fully replicated, same answers;
-  * BatchingChannel stacks in front unchanged and sizes its merge
+  * the batcher stacks in front unchanged and sizes its merge
     groups off ``batch_multiple`` so batcher padding and shard padding
     agree;
   * stats/gauges surface data_axis_size and mesh_devices for the
@@ -32,7 +32,7 @@ from triton_client_tpu.channel import (
 )
 from triton_client_tpu.parallel.mesh import MeshConfig
 from triton_client_tpu.runtime import ModelRepository
-from triton_client_tpu.runtime.batching import BatchingChannel
+from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 from triton_client_tpu.runtime.padding import bucket_for
 
 
@@ -234,7 +234,7 @@ def test_unshardable_inputs_not_row_split(pillars_sharded):
 
 def test_batcher_reads_batch_multiple(yolo_repo):
     inner = ShardedTPUChannel(yolo_repo, MeshConfig(data=-1, model=1))
-    chan = BatchingChannel(inner, max_batch=4, timeout_us=5_000)
+    chan = ContinuousBatchingChannel(inner, max_batch=4)
     try:
         n_dev = inner.batch_multiple
         stats = chan.stats()
@@ -248,7 +248,7 @@ def test_batcher_reads_batch_multiple(yolo_repo):
 
 def test_batched_sharded_stack_bitwise(yolo_repo, yolo_single):
     inner = ShardedTPUChannel(yolo_repo, MeshConfig(data=-1, model=1))
-    chan = BatchingChannel(inner, max_batch=4, timeout_us=20_000)
+    chan = ContinuousBatchingChannel(inner, max_batch=4)
     single = yolo_single
     try:
         results = {}

@@ -74,11 +74,11 @@ def _repo(name="double", sleep_s=0.0):
 
 def _serving_stack(repo, **server_kw):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
-    chan = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=2000, merge_hold_us=2000
+    chan = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4
     )
     server = InferenceServer(
         repo, chan, address="127.0.0.1:0", metrics_port="auto", **server_kw

@@ -57,11 +57,11 @@ def _serving_stack(repo, **server_kw):
     """batching + TPUChannel + InferenceServer on loopback with an
     ephemeral telemetry port — the full overlapped serving path."""
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
-    chan = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=2000, merge_hold_us=2000
+    chan = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4
     )
     server = InferenceServer(
         repo, chan, address="127.0.0.1:0", metrics_port="auto", **server_kw
@@ -216,10 +216,10 @@ def test_collector_families_match_metric_types_and_stats():
     prometheus_client = pytest.importorskip("prometheus_client")
     from triton_client_tpu.channel.base import InferRequest
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 
     repo, spec = _double_repo()
-    chan = BatchingChannel(TPUChannel(repo), max_batch=4, timeout_us=1000)
+    chan = ContinuousBatchingChannel(TPUChannel(repo), max_batch=4)
     registry = prometheus_client.CollectorRegistry()
     collector = RuntimeCollector(channel=chan, registry=registry)
     try:

@@ -768,7 +768,7 @@ class TestCallGraph:
 class TestRobustnessPathCoverage:
     # the overload-control code (runtime/admission.py helpers called
     # from _Servicer._issue, breaker checks inside StagedChannel.launch,
-    # shed scans inside BatchingChannel._on_batch) must stay inside the
+    # shed scans inside the batcher's _run_group) must stay inside the
     # lint's hot-path and lock-discipline umbrellas — these fixtures
     # pin the rule behavior the real modules rely on.
 

@@ -87,11 +87,11 @@ def _repo(name="double", sleep_s=0.0, flops=FLOPS_PER_CALL):
 
 def _stack(repo, **server_kw):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
     from triton_client_tpu.runtime.server import InferenceServer
 
-    chan = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=2000, merge_hold_us=0
+    chan = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4
     )
     server = InferenceServer(
         repo, chan, address="127.0.0.1:0", metrics_port="auto", **server_kw
@@ -534,31 +534,30 @@ class TestLiveJoinedTrace:
 
 def test_merged_batch_members_get_per_member_spans():
     from triton_client_tpu.channel.tpu_channel import TPUChannel
-    from triton_client_tpu.runtime.batching import BatchingChannel
+    from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
 
     repo, _ = _repo()
-    chan = BatchingChannel(
-        TPUChannel(repo), max_batch=4, timeout_us=2000,
-        merge_hold_us=100_000, pipeline_depth=1,
+    import concurrent.futures
+
+    chan = ContinuousBatchingChannel(
+        TPUChannel(repo), max_batch=4, pipeline_depth=1
     )
     ledger = DeviceTimeLedger()
     chan.inner.attach_device_time(ledger)
     traces = [RequestTrace(i + 1, model="double") for i in range(2)]
-    outs = [None, None]
-
-    def call(i):
-        outs[i] = chan.do_inference(
-            InferRequest("double", {"x": X}, trace=traces[i])
-        )
-
+    futures = [concurrent.futures.Future() for _ in traces]
     try:
-        threads = [
-            threading.Thread(target=call, args=(i,)) for i in range(2)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
+        # the group the dispatcher forms of two staged members, handed
+        # over directly: which arrivals share a slot is timing, what a
+        # formed group records is not
+        t_staged = time.perf_counter()
+        chan._run_group(
+            [
+                (t_staged, InferRequest("double", {"x": X}, trace=tr), fut)
+                for tr, fut in zip(traces, futures)
+            ]
+        )
+        outs = [fut.result(timeout=60.0) for fut in futures]
     finally:
         chan.close()
     for i in range(2):

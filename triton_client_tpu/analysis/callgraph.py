@@ -2,7 +2,7 @@
 
 The TPL3xx host-sync family needs "is this function on the serving hot
 path?" — i.e. reachable from ``TPUChannel.stage``/``launch``,
-``BatchingChannel``'s dispatch machinery, or ``_Servicer._issue``. A
+``ContinuousBatchingChannel``'s dispatch machinery, or ``_Servicer._issue``. A
 full points-to analysis is overkill for a ~30-module package with a
 conventional style, so resolution is name-based with three edges:
 

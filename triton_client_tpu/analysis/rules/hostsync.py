@@ -62,12 +62,6 @@ HOT_PATH_ROOTS = (
     "ShardedTPUChannel._place_inputs",
     "ShardedTPUChannel._make_launcher",
     "ShardedTPUChannel._host_outputs",
-    "BatchingChannel.do_inference",
-    "BatchingChannel._on_batch",
-    "BatchingChannel._dispatch_once",
-    "BatchingChannel._run_group",
-    "BatchingChannel._run_solo",
-    "BatchingChannel._merge_parts",
     "_Servicer._issue",
     # round-12 overload control: the admission gate and breaker check
     # run per request inside _issue/launch, but live on foreign objects
@@ -77,14 +71,19 @@ HOT_PATH_ROOTS = (
     "AdmissionController.finished",
     "CircuitBreaker.allow",
     "CircuitBreaker.record_success",
-    # ISSUE 8 continuous batching: the windowless scheduler's admission
-    # and packed-ragged dispatch run per request / per formed batch, and
-    # the segment-pack placement/launcher hooks are the ragged
-    # equivalents of _place_inputs/_make_launcher — all hot
+    # the batcher: admission runs per request, dispatch and every kind
+    # of group per formed batch, and the segment-pack placement/launcher
+    # hooks are the ragged equivalents of _place_inputs/_make_launcher
+    # — all hot
     "ContinuousBatchingChannel.do_inference",
+    "ContinuousBatchingChannel._dispatch_once",
     "ContinuousBatchingChannel._form_group_locked",
     "ContinuousBatchingChannel._run_group",
+    "ContinuousBatchingChannel._run_passthrough",
+    "ContinuousBatchingChannel._run_dense_merge",
+    "ContinuousBatchingChannel._run_session_steps",
     "ContinuousBatchingChannel._run_ragged_group",
+    "ContinuousBatchingChannel._run_solo",
     "ContinuousBatchingChannel._pad_target",
     "StagedChannel._place_ragged",
     "StagedChannel._ragged_launcher",

@@ -8,8 +8,8 @@ discipline *inside one class*; this model answers the questions that
 need the whole package:
 
   * which locks exist, unified across a class hierarchy (a
-    ``ContinuousBatchingChannel`` method holding ``self._ready_cv``
-    holds the SAME lock a ``BatchingChannel`` method acquires);
+    ``TPUChannel`` method holding ``self._slot_cv`` holds the SAME
+    lock a ``StagedChannel`` method acquires);
   * which locks are held on entry to every function, propagated
     interprocedurally along the call graph (so a ``*_locked`` helper
     called under ``with self._lock:`` is known to run locked);

@@ -27,7 +27,7 @@ applied to the perception stack: one model, all devices of a
     donate_argnums=...)`` so consecutive padded batches reuse the same
     per-device HBM input shards.
 
-``BatchingChannel`` stacks in front unchanged through the ``inner``
+``ContinuousBatchingChannel`` stacks in front unchanged through the ``inner``
 channel interface and reads :attr:`batch_multiple` (the data-axis
 width) to size merge groups up to ``max_batch x data_axis`` and align
 its pad buckets, so batcher padding and shard padding never disagree.

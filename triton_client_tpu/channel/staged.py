@@ -587,7 +587,7 @@ class StagedChannel(BaseChannel):
 
     def stats(self) -> dict:
         """Staging-slot counters (the channel-level analogue of
-        BatchingChannel.stats): ``slot_occupancy`` maps concurrent
+        the batcher's stats): ``slot_occupancy`` maps concurrent
         in-flight batches at launch -> launches observed at that depth."""
         with self._slot_cv:
             out = dict(self._stats)

@@ -15,10 +15,6 @@ import logging
 
 log = logging.getLogger(__name__)
 
-# one-time deprecation warning for --batch-timeout-us on the continuous
-# path (the flag is window-batcher-only; see build_server)
-_timeout_warned = False
-
 
 def make_parser() -> argparse.ArgumentParser:
     """The ``serve`` argv contract — split from :func:`main` so an
@@ -58,24 +54,11 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--batching", action="store_true",
         help="micro-batch concurrent requests before dispatch (Triton's "
-        "dynamic batcher role; see --batcher for the scheduler)",
-    )
-    p.add_argument(
-        "--batcher", default="continuous", choices=["continuous", "window"],
-        help="batch scheduler: 'continuous' (default) admits while device "
-        "work is in flight — EDF-ordered ready queue, packed ragged "
-        "execution for models registered with a ragged_fn, live "
-        "occupancy-driven pad buckets; 'window' is the legacy "
-        "admission-window merge (native C++ batcher with python fallback)",
+        "dynamic batcher role): admits while device work is in flight — "
+        "EDF-ordered ready queue, packed ragged execution for models "
+        "registered with a ragged_fn, live occupancy-driven pad buckets",
     )
     p.add_argument("--max-batch", type=int, default=8)
-    p.add_argument(
-        "--batch-timeout-us", type=int, default=None,
-        help="max time a request waits for batch-mates (window batcher "
-        "only, default 2000; DEPRECATED on the continuous scheduler, "
-        "which has no admission window — see docs/OPERATIONS.md "
-        "'Migration — the window-timeout knob')",
-    )
     p.add_argument(
         "--pipeline-depth", type=int, default=2,
         help="formed batches executing concurrently: batch N+1's "
@@ -85,20 +68,9 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--max-merge", type=int, default=None,
         help="frame cap for one device batch formed at dispatch time "
-        "(default: --max-batch). Higher values fuse several admission "
-        "windows into one device call, amortizing per-dispatch cost "
+        "(default: --max-batch). Higher values fuse more queued "
+        "requests into one device call, amortizing per-dispatch cost "
         "(Triton preferred_batch_size role)",
-    )
-    p.add_argument(
-        "--merge-hold-us", type=int, default=0,
-        help="hold a dispatch up to this long when the queue is "
-        "shallow, letting a client burst coalesce instead of shipping "
-        "a fragment (0 = strictly eager)",
-    )
-    p.add_argument(
-        "--pad-buckets", action="store_true",
-        help="pad each device batch to the next power of two so XLA "
-        "compiles log2(max-merge)+1 batch shapes instead of every size",
     )
     p.add_argument(
         "--metrics-port", default=8002,
@@ -491,66 +463,29 @@ def build_server(args):
             flush=True,
         )
     if args.batching:
-        from triton_client_tpu.runtime.batching import BatchingChannel
         from triton_client_tpu.runtime.continuous import (
             ContinuousBatchingChannel,
         )
 
         # getattr: embedders build the args Namespace by hand
         # (tests/test_serve_cli.py) and may predate these knobs
-        batcher = getattr(args, "batcher", "continuous")
-        cls = (
-            ContinuousBatchingChannel if batcher == "continuous"
-            else BatchingChannel
-        )
-        # --batch-timeout-us: None means "not given" (window default
-        # 2000us). An EXPLICIT value on the continuous path used to be
-        # silently ignored; warn once instead, pointing at the doc
-        timeout_us = getattr(args, "batch_timeout_us", None)
-        if timeout_us is not None and batcher == "continuous":
-            global _timeout_warned
-            if not _timeout_warned:
-                _timeout_warned = True
-                log.warning(
-                    "--batch-timeout-us is deprecated with the "
-                    "continuous scheduler and has no effect (there is "
-                    "no admission window); see docs/OPERATIONS.md "
-                    "section 'Migration — the window-timeout knob'"
-                )
-        channel = cls(
+        channel = ContinuousBatchingChannel(
             channel,
             max_batch=args.max_batch,
-            timeout_us=timeout_us if timeout_us is not None else 2000,
             pipeline_depth=args.pipeline_depth,
             max_merge=getattr(args, "max_merge", None),
-            # continuous always bucket-pads its dense fallback — the
-            # buckets come from the live occupancy table, so the pad
-            # tax is bounded without the static pow2 ladder
-            pad_to_buckets=(
-                batcher == "continuous"
-                or getattr(args, "pad_buckets", False)
-            ),
-            merge_hold_us=getattr(args, "merge_hold_us", 0),
             shed_expired=shed,
         )
-        if tenants is not None and batcher == "continuous":
+        if tenants is not None:
             # deficit-round-robin fair share folded into the EDF ready
             # ordering, weighted by each tenant's share
             channel.attach_tenants(tenants)
-        timeout_note = (
-            "windowless" if batcher == "continuous"
-            else f"timeout={timeout_us if timeout_us is not None else 2000}us"
-        )
         print(
-            f"micro-batching[{batcher}]: max_batch={args.max_batch} "
-            f"{timeout_note} "
-            f"pipeline_depth={args.pipeline_depth} "
+            f"micro-batching[continuous]: max_batch={args.max_batch} "
+            f"windowless pipeline_depth={args.pipeline_depth} "
             # default merge cap scales with the inner channel's data
             # axis: max_batch frames per device
-            f"max_merge={getattr(args, 'max_merge', None) or args.max_batch * getattr(channel.inner, 'batch_multiple', 1)} "
-            # the EFFECTIVE value: continuous always bucket-pads its
-            # dense fallback regardless of the flag
-            f"pad_buckets={batcher == 'continuous' or getattr(args, 'pad_buckets', False)}",
+            f"max_merge={getattr(args, 'max_merge', None) or args.max_batch * getattr(channel.inner, 'batch_multiple', 1)}",
             flush=True,
         )
     # continuous quality plane: shadow-scored online accuracy + canary

@@ -11,7 +11,7 @@ readback — so this package makes that decomposition first-class:
   ~zero-cost when disabled), a bounded ring buffer of recent request
   traces, and Chrome-trace/Perfetto JSON export.
 - ``collector`` — bridges the in-process ``stats()`` dicts of
-  TPUChannel and BatchingChannel, HBM ``memory_stats()`` and jit
+  TPUChannel and the batcher, HBM ``memory_stats()`` and jit
   compile events into Prometheus gauges/counters, with a ``snapshot()``
   API so perf scripts and production read identical numbers.
 - ``http``      — one HTTP endpoint on the metrics port serving

@@ -1,6 +1,6 @@
 """Runtime collector: in-process serving counters -> Prometheus.
 
-TPUChannel and BatchingChannel keep their hot-path counters in plain
+TPUChannel and ContinuousBatchingChannel keep their hot-path counters in plain
 dicts (``stats()``) so recording costs an increment under a lock the
 path already holds. Until this module, those numbers were visible only
 to offline perf scripts that diffed ``stats()`` dicts by hand
@@ -56,7 +56,7 @@ METRIC_TYPES: dict[str, str] = {
     # data axis; 1/1 on a single-executable channel, 0 when no channel)
     "tpu_serving_data_axis_size": "gauge",
     "tpu_serving_mesh_devices": "gauge",
-    # BatchingChannel formation
+    # batch formation
     "tpu_serving_queue_depth": "gauge",
     "tpu_serving_batch_active_slots": "gauge",
     "tpu_serving_batch_fill_ratio": "gauge",
@@ -257,7 +257,7 @@ class CompileEvents:
 
 
 def _split_channel(channel):
-    """(BatchingChannel | None, TPUChannel | None) from a channel stack.
+    """(batcher | None, TPUChannel | None) from a channel stack.
 
     Duck-typed: the batcher is anything with ``inner`` + ``stats``; the
     staging channel is anything with ``stats`` + ``pipeline_depth``."""
@@ -730,12 +730,11 @@ class RuntimeCollector:
             chan.get("mesh_devices", 0),
         )
 
-        # BatchingChannel formation
-        queue_depth = bat.get("ready_depth", 0) + bat.get("queue_depth", 0)
+        # batch formation
         yield gauge(
             f"{ns}_queue_depth",
-            "requests admitted or staged, awaiting dispatch",
-            queue_depth,
+            "requests staged, awaiting dispatch",
+            bat.get("ready_depth", 0),
         )
         yield gauge(
             f"{ns}_batch_active_slots",

@@ -1,53 +1,52 @@
-"""Continuous batching: windowless EDF admission + packed ragged batches.
+"""Continuous batching: coalesce concurrent requests into one TPU call.
 
-ISSUE 8's tentpole. The window batcher (``runtime/batching.py``) pays
-two taxes:
+Triton's dynamic batcher is a core piece of the serving runtime the
+reference leans on (config.pbtxt max_batch_size; SURVEY.md §2.9 row 1).
+Here the same role runs in-tree as ONE scheduler with no admission
+window and no admission thread:
 
-  * **the window barrier** — requests pool behind an admission window
-    even when an execution slot is free, so under open-loop traffic the
-    device idles while arrivals wait for a timer;
-  * **the padding tax** — every merge group rounds up to a static
-    power-of-two bucket (up to a third of device work in one served
-    run), and variable-size 3D inputs pad to the widest member
-    besides.
+  * **admission** — ``do_inference`` stages the request straight into
+    the ready set, kept ordered earliest-deadline-first (ties: higher
+    priority, then arrival). The DISPATCHER forms the device batch at
+    the moment an execution slot frees, merging every compatible
+    request queued by then — the continuous-admission discipline of
+    FlexNPU's dynamic co-location (PAPERS.md). Slot-time formation is
+    self-clocking: while ``pipeline_depth`` groups execute, arrivals
+    pool, and the next group takes them all, up to ``max_merge`` rows.
+  * **the kind of a group** is read off what the scheduler can observe
+    (:meth:`ContinuousBatchingChannel._group_kind`):
 
-This scheduler removes both, keeping the proven dispatch machinery
-(permits, executor, launch-time slot free, shed/trace planes) of
-``BatchingChannel`` and replacing its two policy surfaces:
+    - *pass-through*: ONE member whose rows already are a launch size
+      (pad 0, or a request wider than ``max_merge``). The inner channel
+      is handed the request's OWN arrays: for an shm request the
+      zero-copy view of the caller's region, read in place until the
+      answer leaves. ``stats()["passthrough_groups"]``.
+    - *dense merge*: same model, version and non-batch input shapes.
+      Rows concatenate into a new buffer (span ``batch_merge``,
+      ``stats()["merged_bytes"]``) padded with replicated rows to a
+      size from the LIVE occupancy histogram (:class:`LiveBuckets`), so
+      steady traffic converges to near-zero padding while the inner
+      channel still sees a bounded set of shapes. Bitwise identical per
+      request (pad rows are sliced back off — the
+      ``runtime/padding.py`` contract).
+    - *ragged*: models that register a segment-aware body
+      (``RegisteredModel.ragged_fn`` + ``spec.extra["ragged_inputs"]``)
+      execute as PACKED batches: member rows concatenate back to back
+      and a row->segment table rides along
+      (``parallel/ragged_kernels.py``), so every request runs at its
+      true size — zero pad rows beyond lane alignment.
+    - *session steps*: the one-token steps of DIFFERENT sessions of a
+      model that declares ``spec.extra["session_merge"]`` share one
+      launch, each row its own stream.
+    - *solo*: a session frame (its state advances per stream and per
+      frame), a lone ragged request, a lone step: the original request
+      goes down as it came.
 
-  * **admission** — no window, no admission thread. ``do_inference``
-    stages the request straight into the ready set, kept ordered
-    earliest-deadline-first (ties: higher priority, then arrival), so
-    the dispatcher — which keeps forming batches while device work is
-    in flight, exactly the continuous-admission discipline of FlexNPU's
-    dynamic co-location (PAPERS.md) — always launches the work closest
-    to its deadline and merges compatible later arrivals into it.
-  * **batch shape** — models that register a segment-aware body
-    (``RegisteredModel.ragged_fn`` + ``spec.extra["ragged_inputs"]``)
-    execute as PACKED ragged batches: member rows concatenate back to
-    back and a row->segment table rides along
-    (``parallel/ragged_kernels.py``), so every request runs at its true
-    size — zero pad rows beyond lane alignment. Fixed-shape 2D models
-    keep the dense padded path, but pad targets come from a LIVE
-    occupancy histogram (:class:`LiveBuckets`) instead of the static
-    power-of-two table, so steady traffic converges to near-zero
-    padding there too. The dense path stays bitwise identical per
-    request (pad rows replicate a real row and are sliced back off —
-    the `runtime/padding.py` contract — and data-parallel splits never
-    change a row's compute).
-
-Stacking is unchanged: ``ContinuousBatchingChannel(inner)`` drops in
-anywhere ``BatchingChannel(inner)`` did, including in front of the
-mesh-sharded channel — ragged batches are then packed SHARD-major
-(``ShardedRaggedLayout``) so each device gets whole segments and the
-sharded body needs no collectives.
-
-Migration note: the window-timeout knob (``timeout_us`` /
-``--batch-timeout-us``) has no meaning here — there is no window. The
-constructor accepts and ignores it so existing call sites and configs
-keep working; ``merge_hold_us`` is likewise forced to 0 (the scheduler
-self-clocks on slot frees, and EDF ordering makes a hold actively
-harmful: it would delay the tightest-deadline work).
+``ContinuousBatchingChannel`` is itself a BaseChannel, so it stacks
+under the gRPC façade or above TPUChannel unchanged, including in front
+of the mesh-sharded channel — ragged batches are then packed
+SHARD-major (``ShardedRaggedLayout``) so each device gets whole
+segments and the sharded body needs no collectives.
 """
 
 from __future__ import annotations
@@ -55,6 +54,8 @@ from __future__ import annotations
 import bisect
 import collections
 import concurrent.futures
+import itertools
+import logging
 import math
 import threading
 import time
@@ -74,9 +75,41 @@ from triton_client_tpu.parallel.ragged_kernels import (
     shard_pack_rows,
     shard_stack_segments,
 )
-from triton_client_tpu.runtime.admission import QueueFullError
-from triton_client_tpu.runtime.batching import BatchingChannel, _merge_key
-from triton_client_tpu.runtime.padding import bucket_for, pad_batch
+from triton_client_tpu.runtime import faults
+from triton_client_tpu.runtime.admission import (
+    AdmissionRejectedError,
+    DeadlineExpiredError,
+    QueueFullError,
+)
+from triton_client_tpu.runtime.padding import bucket_for, pad_batch, pad_rows
+
+log = logging.getLogger(__name__)
+
+
+def _merge_key(request: InferRequest):
+    if request.sequence_id:
+        # streaming-session frames NEVER merge: the device-resident
+        # tracking step (runtime/sessions.py) consumes the launch's
+        # outputs per stream and per frame — batching two streams (or
+        # two frames of one) into a single launch would interleave
+        # their state advances. A unique key makes every session frame
+        # a group of one, dispatched through the solo path.
+        return ("__session__", id(request))
+    return (
+        request.model_name,
+        request.model_version,
+        tuple(
+            (name, np.asarray(a).shape[1:], np.asarray(a).dtype.str)
+            for name, a in sorted(request.inputs.items())
+        ),
+    )
+
+
+def _rows(request: InferRequest) -> int:
+    """Batch rows of a request: the leading dimension of its first input."""
+    return next(
+        iter(int(np.asarray(a).shape[0]) for a in request.inputs.values())
+    )
 
 
 class LiveBuckets:
@@ -137,28 +170,127 @@ class LiveBuckets:
         return self._table
 
 
-class ContinuousBatchingChannel(BatchingChannel):
-    """Windowless EDF scheduler with packed-ragged execution (see
-    module docstring). Accepts the :class:`BatchingChannel` signature
-    so call sites migrate by swapping the class; ``timeout_us`` and
-    ``merge_hold_us`` are accepted for compatibility and ignored."""
+class ContinuousBatchingChannel(BaseChannel):
+    """Windowless EDF scheduler: dense, ragged and session-step merges
+    (see module docstring)."""
 
     def __init__(
         self,
         inner: BaseChannel,
         max_batch: int = 8,
-        timeout_us: int = 0,  # ignored: no admission window exists
         capacity: int = 256,
-        use_native: bool = False,  # ignored: no admission thread exists
         pipeline_depth: int = 2,
         max_merge: int | None = None,
-        pad_to_buckets: bool = True,
-        merge_hold_us: int = 0,  # ignored: EDF head must not be held
-        arena_slots: int = 0,
         shed_expired: bool = False,
         live_buckets: bool = True,
     ) -> None:
+        """``capacity``: staged requests the ready set holds before
+        ``do_inference`` refuses with ``QueueFullError``.
+
+        ``pipeline_depth``: formed groups executing concurrently
+        against the inner channel. At the default 2, group N+1's
+        host->device transfer overlaps group N's execution (the role
+        Triton's per-instance CUDA streams play); jax queues the
+        dispatches and the device serializes execution. Depth 1
+        restores strictly serial execution.
+
+        ``max_merge``: row cap for one device batch (default:
+        ``max_batch`` per device of a sharded inner channel). On a
+        dispatch-bound path the per-call fixed cost amortizes over
+        max_merge rows.
+
+        ``shed_expired`` (overload control): at dispatch time, members
+        whose deadline already passed are FAILED with
+        ``DeadlineExpiredError`` and never reach the device — the
+        merged batch would otherwise inherit the expired member's
+        deadline and be shed whole by the inner channel. Off by
+        default (count-only behavior).
+
+        ``live_buckets``: dense pad targets follow the live occupancy
+        histogram (:class:`LiveBuckets`); off, the static power-of-two
+        table.
+
+        Slot lifetime (overlapped dispatch): an execution slot frees
+        at *launch*, not at readback. Each group dispatches through
+        ``inner.do_inference_async`` and releases its permit as soon as
+        the call returns (inputs staged on device, compute enqueued);
+        the split/respond work then runs outside the permit, so batch
+        formation self-clocks off device occupancy instead of host copy
+        time. When the inner channel exposes a ``pipeline_depth``
+        staging knob (TPUChannel), it is aligned to this batcher's
+        depth so the channel's staging slots provide the device-side
+        backpressure."""
+        self._inner = inner
         self._capacity = max(1, int(capacity))
+        self._ids = itertools.count(1)
+        # a mesh-sharded inner channel declares its data-axis width as
+        # the preferred batch divisor: merged groups then grow to
+        # max_batch frames PER DEVICE (max_batch x data_axis total) and
+        # pad buckets stay divisible by the axis, so batcher padding and
+        # shard padding agree on the same table (runtime/padding.py)
+        self._batch_multiple = max(1, int(getattr(inner, "batch_multiple", 1)))
+        self._max_merge = int(
+            max_merge
+            if max_merge is not None
+            else max_batch * self._batch_multiple
+        )
+        self._live_buckets = (
+            LiveBuckets(multiple=self._batch_multiple) if live_buckets else None
+        )
+        self._pipeline_depth = max(1, int(pipeline_depth))
+        self._inflight = threading.Semaphore(max(1, pipeline_depth))
+        self._exec = concurrent.futures.ThreadPoolExecutor(
+            max_workers=max(1, pipeline_depth),
+            thread_name_prefix="batch-exec",
+        )
+        # the ready set: staged (key, rows, request, future, t_staged)
+        # items, an EDF-SORTED list (_edf_key), waiting for a slot
+        self._ready: list = []
+        self._ready_cv = threading.Condition()
+        self._dispatch_stop = False
+        # dispatcher heartbeat (stall watchdog): stamped every time the
+        # dispatch loop makes observable progress — top of each slot AND
+        # inside the idle cv-wait, so "idle" stays fresh and only a
+        # genuinely wedged dispatcher (batcher_stall exhausting the
+        # permit semaphore, a hung device call) goes stale. The
+        # watchdog thread logs loudly past stall_threshold_s and the
+        # age/stalled pair rides stats() into the collector.
+        self.stall_threshold_s = 5.0
+        self._hb_ts = time.perf_counter()
+        self._stall_logged = False
+        self._merge_stats = {
+            "merges": 0, "merged_frames": 0, "padded_frames": 0,
+            "launch_frees": 0,
+            # dense groups handed to the inner channel without a copy
+            # (one member, no pad rows), and the bytes of the buffers
+            # the batcher did build for all the others
+            "passthrough_groups": 0, "merged_bytes": 0,
+        }
+        self._ragged_stats = {
+            "ragged_batches": 0,
+            "ragged_segments": 0,
+            "ragged_rows": 0,
+            "ragged_pad_rows": 0,
+        }
+        # padding-tax attribution: pad frames per MODEL, so the
+        # Prometheus counter can carry a model label and an operator
+        # can see WHICH model's buckets waste device rows
+        self._padded_by_model: collections.Counter = collections.Counter()
+        self._shed_expired = bool(shed_expired)
+        # per "model|priority|stage" shed counts ("queue" = ready set
+        # full, "merge" = deadline expired at dispatch), merged into
+        # the collector's tpu_serving_shed_total family
+        self._shed: collections.Counter = collections.Counter()
+        self._merge_occupancy: collections.Counter = collections.Counter()
+        # per-slot occupancy: concurrently-active execution slots
+        # observed at each group launch (1..pipeline_depth)
+        self._active_slots = 0
+        self._slot_occupancy: collections.Counter = collections.Counter()
+        # per-batch wall decomposition sums (stats() exposes means):
+        # queue_wait (first item staged -> executor slot), exec_wait
+        # (submit -> run), stage (host merge build), device (inner
+        # channel call), respond (split + future resolution)
+        self._decomp = collections.defaultdict(float)
         # (model, version) -> frozenset of packed-input names, or None
         # when the model has no segment-aware body; filled lazily from
         # inner.get_metadata so registration order doesn't matter.
@@ -168,12 +300,6 @@ class ContinuousBatchingChannel(BatchingChannel):
         # setdefault)
         self._ragged_inputs_cache: dict = {}
         self._ragged_cache_lock = threading.Lock()
-        self._ragged_stats = {
-            "ragged_batches": 0,
-            "ragged_segments": 0,
-            "ragged_rows": 0,
-            "ragged_pad_rows": 0,
-        }
         # optional multi-tenant fair share (runtime/lifecycle.py
         # TenantTable): deficit-round-robin virtual time folded into the
         # EDF key — set via attach_tenants(); None keeps pure EDF
@@ -181,34 +307,42 @@ class ContinuousBatchingChannel(BatchingChannel):
         self._fair_quantum_s = 0.005
         self._vtime: dict[str, float] = {}
         self._tenant_frames: collections.Counter = collections.Counter()
-        super().__init__(
-            inner,
-            max_batch=max_batch,
-            timeout_us=0,
-            capacity=capacity,
-            use_native=False,
-            pipeline_depth=pipeline_depth,
-            max_merge=max_merge,
-            pad_to_buckets=pad_to_buckets,
-            merge_hold_us=0,
-            arena_slots=arena_slots,
-            shed_expired=shed_expired,
+        # plumb the depth through to the inner channel's staging slots
+        # (TPUChannel double-buffers H2D against execution at depth 2):
+        # the channel then backpressures on device occupancy while this
+        # batcher's permits backpressure on formed groups
+        if hasattr(inner, "pipeline_depth"):
+            try:
+                inner.pipeline_depth = max(1, int(pipeline_depth))
+            except (AttributeError, TypeError):
+                pass  # read-only attribute on a custom channel
+        self._dispatcher = threading.Thread(
+            target=self._dispatch_loop, daemon=True, name="batch-dispatch"
         )
-        self._live_buckets = (
-            LiveBuckets(multiple=self._batch_multiple) if live_buckets else None
+        self._dispatcher.start()
+        self._watchdog_stop = threading.Event()
+        self._watchdog = threading.Thread(
+            target=self._watchdog_loop, daemon=True, name="batch-watchdog"
         )
-        with self._ready_cv:
-            # the ready set is an EDF-SORTED list, not the base FIFO
-            # deque (same item tuples; _form_group_locked is overridden
-            # to match). Swapped under the cv so the already-running
-            # dispatcher never sees a half-state.
-            self._ready = []
+        self._watchdog.start()
+
+    # -- BaseChannel ----------------------------------------------------------
+
+    @property
+    def inner(self) -> BaseChannel:
+        """The wrapped channel (obs.RuntimeCollector walks the stack)."""
+        return self._inner
+
+    def register_channel(self) -> None:
+        self._inner.register_channel()
+
+    def fetch_channel(self):
+        return self._inner.fetch_channel()
+
+    def get_metadata(self, model_name: str, model_version: str = ""):
+        return self._inner.get_metadata(model_name, model_version)
 
     # -- admission: straight into the EDF ready set ---------------------------
-
-    def _start_admission(self, use_native, max_batch, timeout_us, capacity):
-        """No admission window: requests stage in ``do_inference``."""
-        # _impl/_py stay None; close() and stats() branch on that
 
     def attach_tenants(self, table, quantum_s: float = 0.005) -> None:
         """Fold deficit-round-robin fair share over a TenantTable
@@ -273,9 +407,11 @@ class ContinuousBatchingChannel(BatchingChannel):
             for tenant in self._vtime:
                 self._vtime[tenant] -= floor
 
-    def do_inference(self, request: InferRequest):
+    def do_inference(self, request: InferRequest) -> InferResponse:
         future: concurrent.futures.Future = concurrent.futures.Future()
         if request.trace is not None:
+            # closed at dispatch time (_open_group/_run_solo): ready-set
+            # wait + slot backpressure, end to end
             request.trace.begin("batch_queue")
         ragged_names = self._ragged_names(
             request.model_name, request.model_version
@@ -303,16 +439,15 @@ class ContinuousBatchingChannel(BatchingChannel):
         else:
             try:
                 key = _merge_key(request)
-                size = next(
-                    iter(
-                        int(np.asarray(a).shape[0])
-                        for a in request.inputs.values()
-                    )
-                )
+                size = _rows(request)
             except Exception:
                 key, size = ("__solo__", next(self._ids)), 1
         with self._ready_cv:
             if len(self._ready) >= self._capacity:
+                # fail-fast, never block the submitting RPC thread: the
+                # server surfaces this as RESOURCE_EXHAUSTED, which the
+                # client retry ladder treats as non-retryable for
+                # ModelInfer — shedding must not amplify offered load
                 self._shed[
                     f"{request.model_name}|{request.priority}|queue"
                 ] += 1
@@ -327,7 +462,159 @@ class ContinuousBatchingChannel(BatchingChannel):
             self._ready_cv.notify()
         return future.result()
 
-    # -- group formation: EDF head + compatible followers ---------------------
+    # -- dispatch (forms the device batch when a slot frees) ------------------
+
+    def _dispatch_loop(self) -> None:
+        while True:
+            try:
+                if self._dispatch_once():
+                    return
+            except Exception:
+                # The dispatcher is the only thread that forms batches:
+                # an escaped error here would stall every later
+                # do_inference forever on future.result(). Log and keep
+                # serving; the failed slot's futures were already
+                # failed by _dispatch_once.
+                log.exception("dispatcher slot failed; dispatcher continues")
+
+    def _beat(self) -> None:
+        """Stamp the dispatcher heartbeat. Single writer (the dispatch
+        thread); the watchdog and stats() only read, and a monotonic
+        float store is atomic in CPython — deliberately lock-free so
+        the heartbeat itself can never contend with dispatch."""
+        self._hb_ts = time.perf_counter()
+
+    def dispatcher_progress_age_s(self) -> float:
+        """Seconds since the dispatch loop last made progress (slot
+        start or idle wait). Small under load and at rest; grows only
+        when the dispatcher is wedged."""
+        return max(0.0, time.perf_counter() - self._hb_ts)
+
+    def _watchdog_loop(self) -> None:
+        """Stall watchdog: the batcher_stall fault (and any real hang —
+        a device call that never returns, a deadlocked executor) can
+        freeze the single dispatcher with NO signal: requests just
+        queue forever. Log loudly once per stall episode, and again on
+        recovery, so the operator sees the window edges."""
+        poll = max(0.25, self.stall_threshold_s / 4.0)
+        while not self._watchdog_stop.wait(poll):
+            age = self.dispatcher_progress_age_s()
+            if age >= self.stall_threshold_s:
+                if not self._stall_logged:
+                    self._stall_logged = True
+                    log.error(
+                        "dispatcher STALLED: no progress for %.1fs "
+                        "(threshold %.1fs) — ready_depth=%d, "
+                        "active_slots=%d; requests are queuing",
+                        age, self.stall_threshold_s,
+                        len(self._ready), self._active_slots,
+                    )
+            elif self._stall_logged:
+                self._stall_logged = False
+                log.warning("dispatcher recovered after stall")
+            poll = max(0.25, self.stall_threshold_s / 4.0)
+
+    def _dispatch_once(self) -> bool:
+        """One dispatcher slot: acquire a permit, form a group, submit.
+        Returns True when the loop should exit (close() requested and
+        the ready set is drained). Any unexpected error fails the
+        formed group's futures, releases the permit, and re-raises for
+        the loop to log — the thread itself survives."""
+        self._beat()
+        self._inflight.acquire()
+        self._beat()
+        group = None
+        try:
+            with self._ready_cv:
+                while not self._ready and not self._dispatch_stop:
+                    self._ready_cv.wait(timeout=0.1)
+                    # idle is progress: only a dispatcher that cannot
+                    # reach this loop (wedged on the permit semaphore or
+                    # a hung group) lets the heartbeat go stale
+                    self._beat()
+                if self._ready:
+                    group = self._form_group_locked()
+                    self._merge_stats["merges"] += 1
+                    frames = sum(it[1] for it in group)
+                    self._merge_stats["merged_frames"] += frames
+                    self._merge_occupancy[frames] += 1
+                elif self._dispatch_stop:
+                    self._inflight.release()
+                    return True
+            if group is None:
+                self._inflight.release()
+                return False
+
+            with self._ready_cv:
+                self._active_slots += 1
+
+            def run(g=group, t_submit=time.perf_counter()):
+                t_run = time.perf_counter()
+                with self._ready_cv:
+                    self._decomp["n"] += 1
+                    self._decomp["exec_wait_s"] += t_run - t_submit
+                    self._decomp["queue_wait_s"] += t_run - min(
+                        it[4] for it in g
+                    )
+                    # PER-MEMBER queue delay, not just the merged
+                    # batch's (which MultiTrace would fan out as one
+                    # shared number): each member's own staging
+                    # timestamp to this dispatch
+                    self._decomp["members"] += len(g)
+                    self._decomp["member_wait_s"] += sum(
+                        t_run - it[4] for it in g
+                    )
+                # the slot frees the moment the group LAUNCHES (inputs
+                # staged, compute enqueued on the inner channel) — the
+                # dispatcher can then form the next batch against
+                # device occupancy while this group's readback/split
+                # still runs. Exactly-once: the finally covers groups
+                # whose launch never happened (errors before dispatch).
+                released = [False]
+
+                def free_slot():
+                    if released[0]:
+                        return
+                    released[0] = True
+                    with self._ready_cv:
+                        self._slot_occupancy[self._active_slots] += 1
+                        self._active_slots -= 1
+                        self._merge_stats["launch_frees"] += 1
+                    self._inflight.release()
+
+                try:
+                    # (t_staged, request, future): the staging timestamp
+                    # rides along so each member gets its own merge_wait
+                    # span (staged -> this group's dispatch)
+                    self._run_group(
+                        [(it[4], it[2], it[3]) for it in g], free_slot
+                    )
+                except Exception as e:
+                    # No exception may escape: an unresolved future
+                    # hangs its caller forever.
+                    for it in g:
+                        if not it[3].done():
+                            it[3].set_exception(e)
+                finally:
+                    free_slot()
+
+            try:
+                self._exec.submit(run)
+            except RuntimeError as e:  # executor shut down mid-close
+                with self._ready_cv:
+                    self._active_slots -= 1
+                self._inflight.release()
+                for it in group:
+                    if not it[3].done():
+                        it[3].set_exception(e)
+            return False
+        except Exception as e:
+            self._inflight.release()
+            if group:
+                for it in group:
+                    if not it[3].done():
+                        it[3].set_exception(e)
+            raise
 
     def _form_group_locked(self):
         """Pop the EDF head, then walk the (still-sorted) ready set
@@ -359,16 +646,18 @@ class ContinuousBatchingChannel(BatchingChannel):
             self._charge_tenants_locked(group)
         return group
 
-    # -- dense pad targets from the live histogram ----------------------------
-
     def _pad_target(self, total: int) -> int:
+        """Padded device-batch size for a merged total: the live
+        occupancy table (buckets track the sizes traffic actually
+        produces), else the static power-of-two one; both kept divisible
+        by a sharded inner channel's data axis."""
         if self._live_buckets is None:
-            return super()._pad_target(total)
+            return bucket_for(total, self._batch_multiple)
         with self._ready_cv:
             self._live_buckets.observe(total)
             return self._live_buckets.target(total)
 
-    # -- mergeable sessions ---------------------------------------------------
+    # -- what the scheduler can observe of a model ----------------------------
 
     def _session_step(self, request: InferRequest) -> bool:
         """Whether this session request may share a launch with other
@@ -387,15 +676,13 @@ class ContinuousBatchingChannel(BatchingChannel):
         except Exception:
             return False
 
-    # -- ragged capability ----------------------------------------------------
-
     def _ragged_names(self, model_name: str, model_version: str):
         """Packed-input names for a model with a segment-aware body
         (``spec.extra["ragged_inputs"]``), else None. Cached, including
         negative answers — this sits on the per-request path.
 
         Called from RPC threads (``do_inference``) and from the
-        dispatcher/executor threads (``_run_group``), so the cache fill
+        dispatcher/executor threads (``_group_kind``), so the cache fill
         is double-checked: the lock-free fast path covers the steady
         state, the metadata RPC runs unlocked (it can block), and the
         insert goes through ``setdefault`` under ``_ragged_cache_lock``
@@ -418,49 +705,327 @@ class ContinuousBatchingChannel(BatchingChannel):
         with self._ragged_cache_lock:
             return self._ragged_inputs_cache.setdefault(key, names)
 
-    # -- ragged execution -----------------------------------------------------
+    # -- group execution (runs on the executor threads) -----------------------
+
+    def _shed_expired_members(self, group) -> list:
+        """Fail members whose deadline already passed (the batcher-merge
+        shed point) and return the still-live remainder. A merged batch
+        inherits its tightest member's deadline, so ONE expired member
+        left in place would get the whole group shed at launch."""
+        now = time.perf_counter()
+        live = []
+        for item in group:
+            t_staged, request, future = item
+            deadline = request.deadline_s
+            if deadline is None or now <= deadline:
+                live.append(item)
+                continue
+            if request.trace is not None:
+                request.trace.end("batch_queue")
+            with self._ready_cv:
+                self._shed[
+                    f"{request.model_name}|{request.priority}|merge"
+                ] += 1
+            future.set_exception(
+                DeadlineExpiredError(
+                    f"model '{request.model_name}': deadline expired "
+                    f"{(now - deadline) * 1e3:.1f}ms before dispatch"
+                )
+            )
+        return live
+
+    def _group_kind(self, group) -> tuple:
+        """``(kind, sizes, pad)`` of a formed group, from what the
+        scheduler can observe: a session frame, a lone step or a lone
+        ragged request is ``solo`` (the original request goes down, at
+        its true size and with its sequence fields); several sessions'
+        steps are ``session_steps``; members of a model with a
+        ``ragged_fn`` are ``ragged``; the rest is dense — ONE member
+        whose rows already are a launch size is ``passthrough``, any
+        other group a ``dense`` merge of ``sizes`` rows plus ``pad``."""
+        first = group[0][1]
+        lone = len(group) == 1
+        if first.sequence_id:
+            return ("solo" if lone else "session_steps"), None, 0
+        if self._ragged_names(first.model_name, first.model_version):
+            return ("solo" if lone else "ragged"), None, 0
+        try:
+            sizes = [_rows(r) for (_t, r, _f) in group]
+        except Exception:
+            return "solo", None, 0  # no batch axis to merge along
+        total = sum(sizes)
+        # pad only when the ROUNDED size still fits max_merge: a
+        # non-power-of-two max_merge (e.g. 6) must not round a total of
+        # 6 up to 8 — past the cap and past any size the inner channel
+        # precompiled. Oversized single requests (> max_merge) pass
+        # through unpadded for the same reason.
+        rounded = self._pad_target(total)
+        pad = rounded - total if rounded <= self._max_merge else 0
+        return ("passthrough" if lone and pad == 0 else "dense"), sizes, pad
 
     def _run_group(self, group, free_slot=None) -> None:
-        if self._ragged_names(
-            group[0][1].model_name, group[0][1].model_version
-        ):
-            if len(group) == 1:
-                # a lone ragged request runs solo at its TRUE size —
-                # never through the dense merged path, whose bucket
-                # padding is exactly the tax the ragged plane removes
-                if self._shed_expired:
-                    group = self._shed_expired_members(group)
-                    if not group:
-                        return
-                t_staged, request, future = group[0]
-                self._run_solo(request, future, free_slot, t_staged=t_staged)
-            else:
-                self._run_ragged_group(group, free_slot)
-            return
-        # dense groups keep the (bitwise-identical) base path
-        super()._run_group(group, free_slot)
-
-    def _run_ragged_group(self, group, free_slot=None) -> None:
-        """Execute one ragged group as a PACKED batch: member rows
-        concatenate, the segment table rides in ``request.ragged``, and
-        the inner channel's segment-aware launcher runs every member at
-        true size. Mirrors the base ``_run_group`` contract: futures
-        always resolve, failures fall back to per-request execution,
-        ``free_slot`` fires at launch."""
+        """Execute one formed group. ``free_slot`` (when given) is
+        called exactly once, as soon as the group's device work is
+        launched — inputs staged, compute enqueued — so the dispatcher
+        slot frees before the readback/split work."""
+        faults.probe("batcher_stall", group[0][1].model_name)
         if self._shed_expired:
             group = self._shed_expired_members(group)
             if not group:
-                return
+                return  # every member expired; caller's finally frees
+        kind, sizes, pad = self._group_kind(group)
+        if kind == "solo":
+            for t_staged, request, future in group:
+                self._run_solo(request, future, free_slot, t_staged=t_staged)
+        elif kind == "session_steps":
+            self._run_session_steps(group, free_slot)
+        elif kind == "ragged":
+            self._run_ragged_group(group, free_slot)
+        elif kind == "passthrough":
+            self._run_passthrough(group, sizes, free_slot)
+        else:
+            self._run_dense_merge(group, sizes, pad, free_slot)
+
+    def _open_group(self, group):
+        """A merged group leaves the ready set: each member's own
+        ``merge_wait`` (its staging timestamp -> this dispatch; the
+        merge_wait SLO stage) and the end of its ``batch_queue``."""
         requests = [g[1] for g in group]
         futures = [g[2] for g in group]
         traces = [r.trace for r in requests]
         t_dispatch = time.perf_counter()
+        if log.isEnabledFor(logging.DEBUG):
+            # correlated dispatch line: each member's trace/request tag,
+            # so a fleet trace_id greps straight to ITS device batch
+            from triton_client_tpu.obs.logs import log_tag
+
+            log.debug(
+                "dispatching merged batch of %d for model %s:%s",
+                len(requests), requests[0].model_name,
+                "".join(
+                    log_tag(r.trace, r.request_id) for r in requests
+                ) or " [untraced]",
+            )
         for (t_staged, r, _f) in group:
             if r.trace is not None and t_staged is not None:
                 r.trace.add("merge_wait", t_staged, t_dispatch)
         for tr in traces:
             if tr is not None:
                 tr.end("batch_queue")
+        return requests, futures, traces
+
+    def _still_live(self, group, free_slot) -> bool:
+        """Second deadline pass AFTER the pack: the host merge build
+        takes real time under load, so a member that was live at group
+        formation can be expired by now — launching would hand the
+        inner channel a batch whose inherited min-deadline is already
+        past (shed whole at launch, failing every live member). Shed
+        the stragglers and run the survivors as a group of their own
+        (rare path; t_staged=None so merge_wait is not double-recorded).
+        False when the group was taken over that way."""
+        if not self._shed_expired:
+            return True
+        live = self._shed_expired_members(group)
+        if len(live) == len(group):
+            return True
+        if live:
+            self._run_group(
+                [(None, r, f) for (_t, r, f) in live], free_slot
+            )
+        return False
+
+    def _launch(
+        self, requests, traces, merged, free_slot, t_stage0, t_disp, **fields
+    ):
+        """One launch for the whole group, async + deferred readback:
+        by the time ``do_inference_async`` returns, the inner channel
+        has device_put the batch and enqueued the compute — the slot
+        can free NOW; ``result()`` pays the device wait + host copy
+        outside the permit."""
+        deadlines = [r.deadline_s for r in requests if r.deadline_s is not None]
+        try:
+            fut = self._inner.do_inference_async(
+                InferRequest(
+                    model_name=requests[0].model_name,
+                    model_version=requests[0].model_version,
+                    inputs=merged,
+                    # channel-side spans (stage/launch/device/readback)
+                    # fan out to every member's trace
+                    trace=(
+                        MultiTrace(traces)
+                        if any(t is not None for t in traces)
+                        else None
+                    ),
+                    # the merged batch inherits its TIGHTEST member's
+                    # deadline and HIGHEST priority: the batch is late
+                    # the moment any member is
+                    deadline_s=min(deadlines) if deadlines else None,
+                    priority=max(r.priority for r in requests),
+                    **fields,
+                )
+            )
+            if free_slot is not None:
+                free_slot()
+            return fut.result()
+        finally:
+            t_dev_end = time.perf_counter()
+            with self._ready_cv:
+                self._decomp["stage_s"] += t_disp - t_stage0
+                self._decomp["device_s"] += t_dev_end - t_disp
+
+    def _count_merged(self, merged: dict, traces, t_stage0, t_disp) -> None:
+        """``merged_bytes`` and the ``batch_merge`` span: a device batch
+        this batcher built by copying its members' rows."""
+        with self._ready_cv:
+            self._merge_stats["merged_bytes"] += sum(
+                a.nbytes for a in merged.values()
+            )
+        for tr in traces:
+            if tr is not None:
+                tr.add("batch_merge", t_stage0, t_disp)
+
+    def _retry_solo(self, requests, futures) -> None:
+        """A merged failure must not take down unrelated requests: fall
+        back to per-request execution."""
+        for request, future in zip(requests, futures):
+            self._run_solo(request, future)
+
+    def _respond(self, requests, futures, resp, per_output) -> None:
+        """Member i takes ``per_output[name][i]`` of every output."""
+        t_resp0 = time.perf_counter()
+        for i, (request, future) in enumerate(zip(requests, futures)):
+            if request.trace is not None:
+                # before set_result: the waiting thread may finish the
+                # trace the moment the future resolves
+                request.trace.add("batch_respond", t_resp0, time.perf_counter())
+            future.set_result(
+                InferResponse(
+                    model_name=resp.model_name,
+                    model_version=resp.model_version,
+                    outputs={k: v[i] for k, v in per_output.items()},
+                    request_id=request.request_id,
+                    latency_s=resp.latency_s,
+                )
+            )
+
+    def _respond_rows(self, requests, futures, resp, sizes, pad=0) -> None:
+        """Split a row-batched answer back over the members, pad rows
+        dropped; an output with no batch axis goes to every member."""
+        total = sum(sizes)
+        splits = np.cumsum(sizes)[:-1]
+        per_output = {}
+        for name, arr in resp.outputs.items():
+            arr = np.asarray(arr)
+            if arr.ndim >= 1 and arr.shape[0] in (total + pad, total):
+                per_output[name] = np.split(arr[:total], splits)
+            else:
+                per_output[name] = [arr] * len(requests)
+        self._respond(requests, futures, resp, per_output)
+
+    def _run_passthrough(self, group, sizes, free_slot=None) -> None:
+        """ONE member whose rows already are the launch: there is
+        nothing to merge, so its own arrays go down as they came (for
+        an shm request the zero-copy view of the caller's region; it is
+        answered only after the device has read them). Concatenating
+        one part into a fresh buffer cost 0.95 s for 604 MB and kept
+        the device waiting two thirds of the time (PERF.md, PR 27). No
+        ``batch_merge`` span where nothing was copied: one of zero
+        length would still read as a state in obs/launch_timeline.py."""
+        requests, futures, traces = self._open_group(group)
+        try:
+            t_stage0 = time.perf_counter()
+            merged = {
+                name: np.asarray(a) for name, a in requests[0].inputs.items()
+            }
+            t_disp = time.perf_counter()
+            with self._ready_cv:
+                self._merge_stats["passthrough_groups"] += 1
+            if not self._still_live(group, free_slot):
+                return
+            resp = self._launch(
+                requests, traces, merged, free_slot, t_stage0, t_disp
+            )
+        except Exception:
+            self._retry_solo(requests, futures)
+            return
+        self._respond_rows(requests, futures, resp, sizes)
+
+    def _run_dense_merge(self, group, sizes, pad, free_slot=None) -> None:
+        """Same-shaped requests as ONE buffer per input, ``pad`` rows
+        appended to reach the bucket."""
+        requests, futures, traces = self._open_group(group)
+        try:
+            t_stage0 = time.perf_counter()
+            merged = {}
+            for name in requests[0].inputs:
+                parts = [np.asarray(r.inputs[name]) for r in requests]
+                if pad:
+                    # replicate a real row: zeros can steer a model
+                    # down numerically different paths, a copy cannot
+                    parts = pad_rows(parts, pad)
+                merged[name] = np.concatenate(parts)
+            t_disp = time.perf_counter()
+            self._count_merged(merged, traces, t_stage0, t_disp)
+            if not self._still_live(group, free_slot):
+                return
+            resp = self._launch(
+                requests, traces, merged, free_slot, t_stage0, t_disp
+            )
+            if pad:
+                # counted only for a padded call that actually ran,
+                # under the same lock stats() reads through (executor
+                # threads race here at pipeline_depth >= 2)
+                with self._ready_cv:
+                    self._merge_stats["padded_frames"] += pad
+                    self._padded_by_model[requests[0].model_name] += pad
+        except Exception:
+            self._retry_solo(requests, futures)
+            return
+        self._respond_rows(requests, futures, resp, sizes, pad)
+
+    def _run_session_steps(self, group, free_slot=None) -> None:
+        """The steps of several sessions of a model that declares
+        mergeable sessions: one launch, each row its own stream, no pad
+        rows here (the model's session state forms the launch shape)
+        and no retry of a failed launch (the cache may have moved on)."""
+        requests, futures, traces = self._open_group(group)
+        try:
+            sizes = [_rows(r) for r in requests]
+            t_stage0 = time.perf_counter()
+            merged = {
+                name: np.concatenate(
+                    [np.asarray(r.inputs[name]) for r in requests]
+                )
+                for name in requests[0].inputs
+            }
+            t_disp = time.perf_counter()
+            self._count_merged(merged, traces, t_stage0, t_disp)
+            if not self._still_live(group, free_slot):
+                return
+            resp = self._launch(
+                requests, traces, merged, free_slot, t_stage0, t_disp,
+                sequence_rows=tuple(
+                    (r.sequence_id, r.sequence_start, r.sequence_end)
+                    for r in requests
+                ),
+            )
+        except AdmissionRejectedError:
+            # a row refused at admission took the whole launch back
+            # before it reached the device: those retry one by one
+            self._retry_solo(requests, futures)
+            return
+        except Exception as e:
+            for future in futures:
+                future.set_exception(e)
+            return
+        self._respond_rows(requests, futures, resp, sizes)
+
+    def _run_ragged_group(self, group, free_slot=None) -> None:
+        """Execute one ragged group as a PACKED batch: member rows
+        concatenate, the segment table rides in ``request.ragged``, and
+        the inner channel's segment-aware launcher runs every member at
+        true size."""
+        requests, futures, traces = self._open_group(group)
         try:
             ragged_names = self._ragged_names(
                 requests[0].model_name, requests[0].model_version
@@ -496,47 +1061,13 @@ class ContinuousBatchingChannel(BatchingChannel):
                         np.stack(parts), layout.seg_bucket
                     )
             t_disp = time.perf_counter()
-            self._count_merged(merged)
-            for tr in traces:
-                if tr is not None:
-                    tr.add("batch_merge", t_stage0, t_disp)
-            if self._shed_expired:
-                # same post-pack recheck as the dense path: a slow pack
-                # must not launch already-expired members
-                live = self._shed_expired_members(group)
-                if len(live) != len(group):
-                    if live:
-                        self._run_ragged_group(
-                            [(None, r, f) for (_t, r, f) in live], free_slot
-                        )
-                    return
-            deadlines = [
-                r.deadline_s for r in requests if r.deadline_s is not None
-            ]
-            try:
-                fut = self._inner.do_inference_async(
-                    InferRequest(
-                        model_name=requests[0].model_name,
-                        model_version=requests[0].model_version,
-                        inputs=merged,
-                        trace=(
-                            MultiTrace(traces)
-                            if any(t is not None for t in traces)
-                            else None
-                        ),
-                        deadline_s=min(deadlines) if deadlines else None,
-                        priority=max(r.priority for r in requests),
-                        ragged=lay,
-                    )
-                )
-                if free_slot is not None:
-                    free_slot()
-                resp = fut.result()
-            finally:
-                t_dev_end = time.perf_counter()
-                with self._ready_cv:
-                    self._decomp["stage_s"] += t_disp - t_stage0
-                    self._decomp["device_s"] += t_dev_end - t_disp
+            self._count_merged(merged, traces, t_stage0, t_disp)
+            if not self._still_live(group, free_slot):
+                return
+            resp = self._launch(
+                requests, traces, merged, free_slot, t_stage0, t_disp,
+                ragged=lay,
+            )
             with self._ready_cv:
                 self._ragged_stats["ragged_batches"] += 1
                 self._ragged_stats["ragged_segments"] += len(requests)
@@ -547,12 +1078,8 @@ class ContinuousBatchingChannel(BatchingChannel):
                     else layout.pad_rows
                 )
         except Exception:
-            # a packed failure must not take down unrelated requests:
-            # per-request fallback, same as the dense merged path
-            for request, future in zip(requests, futures):
-                self._run_solo(request, future)
+            self._retry_solo(requests, futures)
             return
-        t_resp0 = time.perf_counter()
         n = len(requests)
         per_output = {}
         for name, arr in resp.outputs.items():
@@ -565,45 +1092,108 @@ class ContinuousBatchingChannel(BatchingChannel):
                 per_output[name] = [arr[i] for i in range(n)]
             else:  # non-segmented output — replicate
                 per_output[name] = [arr] * n
-        for i, (request, future) in enumerate(zip(requests, futures)):
-            if request.trace is not None:
-                request.trace.add(
-                    "batch_respond", t_resp0, time.perf_counter()
-                )
-            future.set_result(
-                InferResponse(
-                    model_name=resp.model_name,
-                    model_version=resp.model_version,
-                    outputs={k: v[i] for k, v in per_output.items()},
-                    request_id=request.request_id,
-                    latency_s=resp.latency_s,
-                )
-            )
+        self._respond(requests, futures, resp, per_output)
 
-    # -- stats ----------------------------------------------------------------
+    def _run_solo(
+        self, request: InferRequest, future, free_slot=None, t_staged=None
+    ) -> None:
+        if request.trace is not None:
+            if t_staged is not None:
+                # solo dispatches report merge_wait too (a group of
+                # one), so queue-delay attribution covers every path;
+                # None on the merged-failure retry path, whose wait was
+                # already recorded by the group dispatch
+                request.trace.add("merge_wait", t_staged, time.perf_counter())
+            request.trace.end("batch_queue")  # no-op on the retry path
+        try:
+            fut = self._inner.do_inference_async(request)
+            if free_slot is not None:
+                free_slot()  # launched: slot frees before the readback
+            future.set_result(fut.result())
+        except Exception as e:
+            future.set_exception(e)
+
+    # -- stats / lifecycle ----------------------------------------------------
 
     def stats(self) -> dict:
-        out = super().stats()
-        out["scheduler"] = "continuous"
+        out: dict = {}
         with self._ready_cv:
+            out.update(self._merge_stats)
+            out["merge_occupancy"] = dict(
+                sorted(self._merge_occupancy.items())
+            )
+            out["padded_by_model"] = dict(sorted(self._padded_by_model.items()))
+            # concurrently-active execution slots observed at each
+            # group launch: {slots_active: launches} — 2s and above mean
+            # batch N+1 formed/staged while batch N still executed
+            out["slot_occupancy"] = dict(sorted(self._slot_occupancy.items()))
+            out["active_slots"] = self._active_slots
+            out["ready_depth"] = len(self._ready)
+            out["shed"] = dict(self._shed)
+            out["max_merge"] = self._max_merge
+            out["batch_multiple"] = self._batch_multiple
+            out["pipeline_depth"] = self._pipeline_depth
+            age = self.dispatcher_progress_age_s()
+            out["dispatcher_last_progress_age_s"] = age
+            out["dispatcher_stalled"] = (
+                1 if age >= self.stall_threshold_s else 0
+            )
+            n = self._decomp.get("n", 0.0)
+            if n:
+                out["decomp_ms"] = {
+                    k[:-2]: round(self._decomp[k] / n * 1e3, 2)
+                    for k in (
+                        "queue_wait_s", "exec_wait_s", "stage_s", "device_s"
+                    )
+                }
+                out["decomp_batches"] = int(n)
+            members = self._decomp.get("members", 0.0)
+            if members:
+                # mean PER-MEMBER ready-queue wait (merge_wait), vs
+                # decomp_ms.queue_wait which is per merged batch from
+                # its earliest member
+                out["member_queue_delay_ms"] = round(
+                    self._decomp["member_wait_s"] / members * 1e3, 2
+                )
+                out["merge_members"] = int(members)
+            out["scheduler"] = "continuous"
             out.update(self._ragged_stats)
             if self._live_buckets is not None:
                 out["live_bucket_table"] = list(self._live_buckets.table)
             if self._tenant_table is not None:
                 out["tenant_served_frames"] = dict(self._tenant_frames)
                 out["tenant_vtime"] = dict(self._vtime)
-        shipped = (
-            out["merged_frames"]
-            + out["padded_frames"]
-            + out["ragged_rows"]
-            + out["ragged_pad_rows"]
-        )
-        if shipped:
-            # fold ragged rows into the headline pad fraction: ragged
-            # pad rows are lane-alignment slack, dense pad rows are
-            # bucket slack — both are rows the device computed for
-            # nobody
-            out["pad_fraction"] = (
-                out["padded_frames"] + out["ragged_pad_rows"]
-            ) / shipped
+        # share of device rows that were padding — the headline
+        # padding-tax number: dense pad rows are bucket slack, ragged
+        # pad rows lane-alignment slack, both rows the device computed
+        # for nobody
+        padded = out["padded_frames"] + out["ragged_pad_rows"]
+        shipped = padded + out["merged_frames"] + out["ragged_rows"]
+        out["pad_fraction"] = padded / shipped if shipped else 0.0
         return out
+
+    def close(self) -> None:
+        # the watchdog first: a slow drain below is not a stall
+        self._watchdog_stop.set()
+        # the dispatcher keeps forming batches until the ready set is
+        # empty, THEN exits — no admitted future is stranded
+        with self._ready_cv:
+            self._dispatch_stop = True
+            self._ready_cv.notify_all()
+        # The executor must not shut down while the dispatcher can
+        # still submit (futures would get 'cannot schedule new
+        # futures' instead of executing), and a first compile can run
+        # minutes — so loop-join with a progress warning instead of
+        # abandoning the thread after a fixed timeout.
+        waited = 0.0
+        while self._dispatcher.is_alive():
+            self._dispatcher.join(timeout=30.0)
+            if self._dispatcher.is_alive():
+                waited += 30.0
+                log.warning(
+                    "batcher close(): dispatcher still draining after "
+                    "%.0fs (device call in flight?)", waited,
+                )
+        # after the dispatcher stops, drain in-flight groups so every
+        # admitted future resolves before close() returns
+        self._exec.shutdown(wait=True)

@@ -15,7 +15,7 @@ probe named injection points:
   readback        InferFuture resolve, before host copy      raise
   slow_launch     StagedChannel.launch, before the jit call  sleep
   codec_decode    codec.parse_infer_request                  raise
-  batcher_stall   BatchingChannel dispatcher, slot time      sleep
+  batcher_stall   batcher _run_group, slot time              sleep
   replica_down    _Servicer ServerReady/ModelReady/_issue    flag
   shm_detach      _Servicer before shm request parse         flag
   quality_corrupt eval ShadowMirror worker, before scoring   flag

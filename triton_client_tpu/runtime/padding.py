@@ -3,8 +3,8 @@
 Both batch producers pad to a bounded set of device batch sizes so the
 inner executable cache stays small (the role Triton's
 preferred_batch_size plays): the micro-batcher
-(``BatchingChannel._merge_parts``) pads merged request groups, and the
-mesh-sharded serving channel (``channel/sharded_channel.py``) pads each
+(``ContinuousBatchingChannel._run_dense_merge``) pads merged request
+groups, and the mesh-sharded serving channel (``channel/sharded_channel.py``) pads each
 request batch so it splits evenly over the mesh's ``data`` axis. Before
 this module each carried its own ``_bucket`` — two tables that could
 drift apart and double XLA's compiled-shape set. Now:
