@@ -749,11 +749,14 @@ def run_four_chips(rehearse: bool, work: pathlib.Path) -> None:
             {k: v for k, v in inputs.items() if k in donate},
             {k: v for k, v in inputs.items() if k not in donate},
         )
+        # the device array behind the staged frames (its one leaf where
+        # they crossed in their transfer form: channel/staged.py)
+        (placed,) = jax.tree_util.tree_leaves(inputs["images"])
         shards = {
-            "input": sorted(s.device.id for s in inputs["images"].addressable_shards),
+            "input": sorted(s.device.id for s in placed.addressable_shards),
             "output": sorted(s.device.id for s in out["detections"].addressable_shards),
         }
-        input_rows = [s.data.shape[0] for s in inputs["images"].addressable_shards]
+        input_rows = [s.data.shape[0] for s in placed.addressable_shards]
         for what, ids in shards.items():
             check(len(set(ids)) == 4, f"{what} shards on devices {ids}, not four")
         check(input_rows == [2, 2, 2, 2], f"b8 split as {input_rows}")

@@ -329,6 +329,8 @@ def test_a_stream_through_the_channel_keeps_the_cache_on_the_device(channel, tok
     ask(tokens[29:30], sequence_end=True)
     stats = ch.session_stats()["models"][name]
     assert stats["session_cache_slots_in_use"] == 0 and stats["lm_tokens_step"] == 10
+    # token ids and rows cross as they came: the transfer view is for frame batches
+    assert ch.stats()["staged_bytes"] > 0 and ch.stats()["staged_dense_bytes"] == 0
     assert np.asarray(stats["expert_rows"]).shape == (2, 4) and np.asarray(stats["expert_rows"]).sum() > 0
 
 

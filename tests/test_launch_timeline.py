@@ -9,6 +9,7 @@ import pathlib
 import sys
 import threading
 import time
+import types
 import urllib.request
 
 import numpy as np
@@ -432,6 +433,7 @@ def _get(url):
 @pytest.mark.parametrize("broken", [False, True])
 def test_profile_reports_the_launch_timeline_or_its_error(monkeypatch, broken):
     from triton_client_tpu.channel.tpu_channel import TPUChannel
+    from triton_client_tpu.obs import http
     from triton_client_tpu.obs.http import TelemetryServer
 
     if broken:
@@ -443,13 +445,29 @@ def test_profile_reports_the_launch_timeline_or_its_error(monkeypatch, broken):
     tracer = Tracer(capacity=64)
     srv = TelemetryServer(port=0, tracer=tracer)
     stop = threading.Event()
+    answered = threading.Semaphore(0)
 
     def serve():
         while not stop.is_set():
             tr = tracer.start("double")
             chan.do_inference(_request(tr))
             tracer.finish(tr)
+            answered.release()
             time.sleep(0.002)
+
+    def until_launches(seconds):
+        """The capture's length in LAUNCHES, not in seconds: a machine that
+        runs six test workers may answer fewer than three requests in 0.2 s.
+        The first answer counted may have begun before the capture."""
+        while answered.acquire(blocking=False):
+            pass
+        for _ in range(6):
+            assert answered.acquire(timeout=60.0), "the serving thread stalled"
+
+    monkeypatch.setattr(
+        http, "time",
+        types.SimpleNamespace(sleep=until_launches, perf_counter=time.perf_counter),
+    )
 
     t = threading.Thread(target=serve, daemon=True)
     t.start()

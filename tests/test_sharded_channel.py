@@ -30,6 +30,7 @@ from triton_client_tpu.channel import (
     ShardedTPUChannel,
     TPUChannel,
 )
+from triton_client_tpu.channel.staged import DenseStaged
 from triton_client_tpu.parallel.mesh import MeshConfig
 from triton_client_tpu.runtime import ModelRepository
 from triton_client_tpu.runtime.continuous import ContinuousBatchingChannel
@@ -99,16 +100,38 @@ def test_sharded_yolo_bitwise_matches_single_device(
     assert a.outputs["valid"].shape[0] == batch
 
 
-def test_sharded_inputs_actually_shard(yolo_sharded):
+@pytest.mark.parametrize("dtype", [np.float32, np.uint8])
+def test_sharded_inputs_actually_shard(yolo_sharded, dtype):
     n_dev = yolo_sharded.batch_multiple
     staged = yolo_sharded.stage(
-        InferRequest("yolov5n", {"images": _frames(0, n_dev)})
+        InferRequest("yolov5n", {"images": _frames(0, n_dev).astype(dtype)})
     )
     placed = staged.device_inputs["images"]
+    if dtype is np.uint8:
+        # byte frames cross in their transfer form (staged.transfer_view):
+        # the device array is the one leaf, its rows split as the wire's would
+        assert isinstance(placed, DenseStaged) and placed.shape[0] == n_dev
+        placed = placed.data
     # one row-shard per device, all devices addressed
     assert len(placed.sharding.device_set) == n_dev
     assert placed.addressable_shards[0].data.shape[0] == 1
     yolo_sharded.launch(staged).result()
+
+
+@pytest.mark.parametrize("batch", [8, 3])
+def test_sharded_byte_frames_cross_dense_and_match_single_device(
+    yolo_sharded, yolo_single, batch
+):
+    """The sharded launcher undoes the transfer view as the single-device
+    one does: same answers, pad rows and all."""
+    x = _frames(batch, batch).astype(np.uint8)
+    dense = yolo_sharded.stats()["staged_dense_bytes"]
+    a = yolo_sharded.do_inference(InferRequest("yolov5n", {"images": x}))
+    b = yolo_single.do_inference(InferRequest("yolov5n", {"images": x}))
+    for k in ("detections", "valid"):
+        np.testing.assert_array_equal(a.outputs[k], b.outputs[k])
+    padded = bucket_for(batch, yolo_sharded.batch_multiple)
+    assert yolo_sharded.stats()["staged_dense_bytes"] - dense == padded * x[0].nbytes
 
 
 def test_uneven_batch_pads_to_device_multiple(yolo_sharded):

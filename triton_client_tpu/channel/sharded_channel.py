@@ -48,6 +48,7 @@ from triton_client_tpu.channel.staged import (
     SEGMENT_IDS_KEY,
     StagedChannel,
     cast_wire_input,
+    put_staged,
 )
 from triton_client_tpu.obs.roofline import name_launcher
 from triton_client_tpu.parallel.mesh import (
@@ -102,6 +103,10 @@ class ShardedTPUChannel(StagedChannel):
                 n = int(np.asarray(request.inputs[t.name]).shape[0])
                 break
         target = bucket_for(n, multiple) if n is not None else None
+        # the transfer form of staged.transfer_view (its batch axis is
+        # never merged, so the rows still split over the data axis); a
+        # host-boundary model is handed the staged arrays as they are
+        put = put_staged if model.device_fn is not None else jax.device_put
         device_inputs = {}
         for name, arr in request.inputs.items():
             arr = cast_wire_input(model, name, np.asarray(arr))
@@ -114,11 +119,9 @@ class ShardedTPUChannel(StagedChannel):
                 # pad rows replicate a real row (bitwise-safe; see
                 # runtime/padding.py), then split rows over the data
                 # axis — the only H2D path that scatters
-                device_inputs[name] = jax.device_put(
-                    pad_batch(arr, target), batch_s
-                )
+                device_inputs[name] = put(pad_batch(arr, target), batch_s)
             else:
-                device_inputs[name] = jax.device_put(arr, repl_s)
+                device_inputs[name] = put(arr, repl_s)
         # meta: (real rows, padded rows) so resolve can slice the pad
         # back off before the host copy pays for it
         meta = (n, target) if n is not None and target != n else None

@@ -105,6 +105,83 @@ def cast_wire_input(model, name: str, arr: np.ndarray) -> np.ndarray:
     return arr
 
 
+_LANES = 128  # elements of a tile's minor dimension, whatever the type
+
+
+def transfer_view(arr: np.ndarray) -> np.ndarray:
+    """The form in which a host array crosses to the device: a FREE view
+    of the same bytes whose two minor dimensions are whole tiles, or the
+    array itself.
+
+    A TPU array is tiled over its two minor dimensions, 128 lanes by 8
+    sublanes of 32 bits; one- and two-byte elements are PACKED four or
+    two rows to a sublane word (32 rows of ``uint8``, 16 of
+    ``bfloat16``). A frame batch ``[B, H, W, 3]`` cannot be kept dense
+    that way, so the device holds it PLANAR (``major_to_minor=(0, 3, 1,
+    2)``), and for packed elements one thread of the transfer
+    de-interleaves and packs every launch's bytes on the host: ``uint8``
+    4.0-4.5 GB/s where the same bytes as ``[B, H*W*3/128, 128]`` move at
+    5.5-5.7, ``bfloat16`` 4.5 against 5.3 (``perf/profile_h2d.py``;
+    PERF.md, PR 34). Four-byte elements have no packing and cross at
+    9.0-9.8 GB/s in either form, so they go as they are. The rule reads
+    only the array: one- or two-byte elements, at least two trailing
+    dimensions to merge (the batch axis is never merged, so a
+    batch-sharded put still splits on rows), the trailing two not whole
+    tiles already, a row's elements a whole number of tiles,
+    C-contiguous and aligned (never a copy). Anything else goes as it
+    is: ``float32`` frames, points ``[N, 4]``, a prompt ``[1, 4096]``,
+    a step row ``[7]``."""
+    item = arr.dtype.itemsize
+    if arr.ndim < 3 or arr.size == 0 or item not in (1, 2):
+        return arr
+    if not (arr.flags.c_contiguous and arr.flags.aligned):
+        return arr
+    sublanes = 8 * (4 // item)
+    if arr.shape[-1] % _LANES == 0 and arr.shape[-2] % sublanes == 0:
+        return arr
+    per_row = arr.size // arr.shape[0]
+    if per_row % (sublanes * _LANES):
+        return arr
+    return arr.reshape(arr.shape[0], per_row // _LANES, _LANES)
+
+
+@jax.tree_util.register_pytree_node_class
+class DenseStaged:
+    """A staged array in its transfer form (:func:`transfer_view`) with
+    what undoes it: the wire array's trailing shape, static in every
+    program that takes it. ``shape`` and ``ndim`` are the WIRE array's;
+    the one leaf is the device array, so donation donates the dense
+    buffer and a batch sharding splits its rows."""
+
+    __slots__ = ("data", "trailing")
+
+    def __init__(self, data, trailing) -> None:
+        self.data, self.trailing = data, tuple(trailing)
+
+    shape = property(lambda self: (self.data.shape[0], *self.trailing))
+    ndim = property(lambda self: 1 + len(self.trailing))
+    dtype = property(lambda self: self.data.dtype)
+    nbytes = property(lambda self: self.data.nbytes)
+
+    def tree_flatten(self):
+        return (self.data,), self.trailing
+
+    @classmethod
+    def tree_unflatten(cls, trailing, children):
+        return cls(children[0], trailing)
+
+    def wire(self):
+        """The array the caller sent, inside a traced program."""
+        return self.data.reshape(self.data.shape[0], *self.trailing)
+
+
+def put_staged(arr: np.ndarray, sharding=None):
+    """``jax.device_put`` of a launch's host array in its transfer form."""
+    view = transfer_view(arr)
+    placed = jax.device_put(view, sharding)
+    return placed if view is arr else DenseStaged(placed, arr.shape[1:])
+
+
 class StagedRequest:
     """A request whose inputs live on the mesh, awaiting launch.
 
@@ -375,6 +452,10 @@ class StagedChannel(BaseChannel):
             # max_det / 8 = sublane packing alone, below = both
             "nms_steps": 0,
             "nms_frames": 0,
+            # bytes placed on the device by stage(), and those of them
+            # that crossed in a changed view (transfer_view)
+            "staged_bytes": 0,
+            "staged_dense_bytes": 0,
         }
         self._shed_expired = bool(shed_expired)
         self._breaker = (
@@ -533,14 +614,25 @@ class StagedChannel(BaseChannel):
         (runtime/precision.py), so the cached launcher stages in the
         wire dtype and runs the body at the policy dtype."""
         device_fn = model.device_fn
+        if device_fn is None:
+            return None
         policy = getattr(model, "precision", None)
-        if (
-            device_fn is None
-            or policy is None
-            or not getattr(policy, "wire_ingest_needed", False)
-        ):
-            return device_fn
-        return lambda inputs, *rest: device_fn(policy.ingest(inputs), *rest)
+        if policy is not None and getattr(policy, "wire_ingest_needed", False):
+            wire_fn = device_fn
+            device_fn = lambda inputs, *rest: wire_fn(policy.ingest(inputs), *rest)
+
+        def body(inputs, *rest):
+            # first, undo the transfer form of what was staged dense; where
+            # nothing was, this traces to the program it always was
+            return device_fn(
+                {
+                    k: v.wire() if isinstance(v, DenseStaged) else v
+                    for k, v in inputs.items()
+                },
+                *rest,
+            )
+
+        return body
 
     def _host_outputs(self, outputs, out_dtype, meta) -> dict:
         """Device outputs -> host numpy dict at the wire dtypes. The
@@ -684,8 +776,14 @@ class StagedChannel(BaseChannel):
             if session is not None:
                 session[0].abort(session[1])
             raise
+        placed_bytes = sum(v.nbytes for v in device_inputs.values())
+        dense_bytes = sum(
+            v.nbytes for v in device_inputs.values() if isinstance(v, DenseStaged)
+        )
         with self._slot_cv:
             self._stats["staged"] += 1
+            self._stats["staged_bytes"] += placed_bytes
+            self._stats["staged_dense_bytes"] += dense_bytes
         t_staged = time.perf_counter()
         staged = StagedRequest(model, device_inputs, request, t_staged, meta)
         staged.lifecycle_key = lifecycle_key

@@ -21,6 +21,9 @@ round 6 now lives in :mod:`triton_client_tpu.channel.staged`
     on device), wider stray dtypes cast down to the wire contract;
   * per-array sharding heuristic: shard batch-leading arrays over the
     ``data`` axis when the batch divides, otherwise replicate;
+  * transfer form (PR 34): an array crosses as ``staged.transfer_view``
+    gives it (whole tiles, a free view) and the launcher's body undoes
+    the view first thing;
   * launcher: cached ``jax.jit(fn, donate_argnums=(0,))`` whose first
     arg carries the spec-marked ``donatable`` inputs, so consecutive
     batches reuse the same HBM input buffers.
@@ -39,6 +42,7 @@ from triton_client_tpu.channel.staged import (  # noqa: F401 — re-exported
     StagedRequest,
     _Inflight,
     cast_wire_input,
+    put_staged,
 )
 from triton_client_tpu.config import config_dtypes
 from triton_client_tpu.obs.roofline import name_launcher
@@ -50,6 +54,9 @@ class TPUChannel(StagedChannel):
 
     def _place_inputs(self, model, request):
         sharding = batch_sharding(self._mesh)
+        # a host-boundary model (no device_fn) is handed the staged arrays
+        # themselves: nothing there could undo a transfer view
+        put = put_staged if model.device_fn is not None else jax.device_put
         device_inputs = {}
         for name, arr in request.inputs.items():
             # Shard batch-leading arrays over the data axis when the
@@ -63,7 +70,7 @@ class TPUChannel(StagedChannel):
                 and arr.shape[0] % self._mesh.shape["data"] == 0
                 else NamedSharding(self._mesh, PartitionSpec())
             )
-            device_inputs[name] = jax.device_put(arr, use)
+            device_inputs[name] = put(arr, use)
         return device_inputs, None
 
     def _make_launcher(self, model):
