@@ -154,6 +154,32 @@ def test_segment_sum_lowers(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_sparse_selection_kernels_lower_at_the_served_slot(one_chip):
+    """The three kernels of an extend launch of ``family: deepseek_v32``
+    at ``examples/dsv32_ep32``'s sizes: 4,096 queries against a slot of
+    34,048 positions (index scores, the threshold of each row, attention
+    under the selection a segment of 4,864 positions at a time)."""
+    from triton_client_tpu.ops import latent_attention, sparse_index
+
+    t, s_len, h = 4096, 34048, 128
+    assert sparse_index.kernel_fits(t, s_len, 64, 128) and sparse_index.kth_kernel_fits(t, s_len)
+    assert latent_attention.selected_kernel_fits(t, s_len, 128, 128)
+
+    def selected(q, w, keys, positions, q_nope, q_rope, rows, kv_b):
+        scores = sparse_index.extend_scores(q, w, keys, positions, kernel=True)
+        tau = sparse_index.kth_largest(scores, jnp.minimum(positions + 1, 2048), last=positions[-1], kernel=True)
+        return latent_attention.expanded_attention(
+            q_nope, q_rope, rows, positions, kv_b, 0.1, 128, (scores, tau), kernel=True)
+
+    text = _compile(
+        selected, one_chip,
+        ((t, 64, 128), jnp.bfloat16), ((t, 64), jnp.float32), ((s_len, 128), jnp.bfloat16), ((t,), jnp.int32),
+        ((t, h, 128), jnp.bfloat16), ((t, h, 64), jnp.bfloat16), ((s_len, 640), jnp.bfloat16),
+        ((512, h, 256), jnp.bfloat16),
+    )
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_nms_pallas_lowers(one_chip):
     from triton_client_tpu.ops.pallas_nms import nms_pallas
 

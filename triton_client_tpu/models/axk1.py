@@ -1,24 +1,45 @@
-"""A.X-K1 (``model_type`` ``axk1``): latent attention and routed experts,
-served as one chip's share of a wider deployment.
+"""The DeepSeek-V3 block, latent attention and routed experts, served as
+one chip's share of a wider deployment: ONE module for the two families
+that carry it, ``family: axk1`` (A.X-K1, ``model_type`` ``axk1``, which
+brought the module and gave it its name) and ``family: deepseek_v32``
+(DeepSeek-V3.2-Exp, ``model_type`` ``deepseek_v32``). What differs is
+read from the entry's ``model`` block, never from the family's name.
 
-The published block (https://huggingface.co/skt/A.X-K1): RMSNorm,
+The published block (https://huggingface.co/skt/A.X-K1,
+https://huggingface.co/deepseek-ai/DeepSeek-V3.2-Exp): RMSNorm,
 multi-head latent attention (ops/latent_attention.py) with YaRN rotary
-embeddings (ops/rope.py), one leading dense SwiGLU layer, then layers
-of sigmoid-routed experts (ops/experts.py) beside a shared one. This
-chip holds ``experts_here`` of the ``router_experts`` routed experts of
-each layer, ``vocab_size`` rows of embedding and head and
-``num_hidden_layers`` layers; every width is the published one.
+embeddings (ops/rope.py), ``first_k_dense_replace`` leading dense SwiGLU
+layers, then layers of sigmoid-routed experts (ops/experts.py) beside a
+shared one. This chip holds ``experts_here`` of the ``router_experts``
+routed experts of each layer, ``vocab_size`` rows of embedding and head
+and ``num_hidden_layers`` layers; every width is the published one.
+
+What an entry may switch on:
+
+  * ``index_topk`` > 0: the learned sparse attention (ops/sparse_index.py).
+    An indexer of ``index_n_heads`` heads of ``index_head_dim`` values
+    scores every cached position for every query, and attention reads
+    only the ``index_topk`` best (all of them while the context is no
+    longer). Every position's index key is cached beside its latent;
+  * ``topk_method: noaux_tc``: the router chooses by ``scores +
+    router_bias`` among the ``topk_group`` best of ``n_group`` groups of
+    experts and gates with the unbiased scores; ``none`` is plain top-k.
+  * ``expert_chunk_rows``: a tuning, the token-slots one grouped product
+    of the held experts takes (ops/experts.py ``routed_experts``).
 
 Functional: parameters are a pytree (bfloat16 matrices ``[in, out]``,
 float32 norm scales), the cache is an array ``[layers, slots, slot_len,
-kv_rank + rope]`` that a launch takes in and gives back. One operation,
+kv_rank + rope]`` that a launch takes in and gives back; with an
+indexer it is a dict of that array (``latent``) and the index keys
+``[layers, slots, slot_len, index_head_dim]`` (``index``). One operation,
 *extend*: a launch appends ``lengths[b]`` tokens to the session in slot
 ``slots[b]`` from position ``positions[b]`` on and answers the logits
 of each row's last appended position. A launch of one row with many
-tokens (a prompt, a further turn) expands the slot's latents per head;
-a launch of many rows with one token each (decoded steps of different
-sessions) runs the absorbed form. The router, norms, softmax and logits
-are float32; everything a matrix product reads is bfloat16.
+tokens (a prompt, a further turn on whatever the slot holds) expands
+the slot's latents per head; a launch of many rows with one token each
+(decoded steps of different sessions) runs the absorbed form. The
+router, norms, softmax, index scores and logits are float32; everything
+a matrix product reads is bfloat16.
 
 The layers after the dense ones run under ONE ``lax.scan`` (their
 weights stacked on a leading axis by :func:`stack_layers`), so each
@@ -33,7 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from triton_client_tpu.ops import experts as experts_op
-from triton_client_tpu.ops import latent_attention, rope
+from triton_client_tpu.ops import latent_attention, rope, sparse_index
 
 #: the keys an entry's ``model`` block may hold beside ``rope_scaling`` and ``precision``
 _PUBLISHED = {
@@ -43,6 +64,8 @@ _PUBLISHED = {
     "expert_offset", "n_shared_experts", "num_experts_per_tok",
     "norm_topk_prob", "routed_scaling_factor", "num_hidden_layers",
     "first_k_dense_replace", "vocab_size", "rms_norm_eps", "rope_theta",
+    "index_n_heads", "index_head_dim", "index_topk",
+    "n_group", "topk_group", "topk_method", "expert_chunk_rows",
 }
 
 
@@ -71,6 +94,15 @@ class AXK1Config:
     vocab_size: int = 20480
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 0  # positions a query attends to; 0: no indexer, every cached position
+    n_group: int = 1  # groups the router's experts fall into ...
+    topk_group: int = 1  # ... of which a token may choose among this many
+    topk_method: str = "none"  # "noaux_tc": the group limit and a correction bias
+    #: token-slots a grouped product of the held experts takes (ops/experts.py); an entry sets it to
+    #: about twice the rows it expects here a launch, so that every layer takes ONE pass on every seed
+    expert_chunk_rows: int = experts_op.CHUNK_ROWS
     yarn: rope.YarnConfig = rope.YarnConfig()
 
     @classmethod
@@ -82,6 +114,8 @@ class AXK1Config:
         if unknown:
             raise KeyError(f"axk1 model config: unknown keys {sorted(unknown)}")
         cfg = cls(**doc)
+        if cfg.topk_method not in ("none", "noaux_tc"):
+            raise ValueError(f"axk1 model config: topk_method {cfg.topk_method!r} (known: none, noaux_tc)")
         yarn = rope.YarnConfig(
             dim=cfg.qk_rope_head_dim,
             theta=float(cfg.rope_theta),
@@ -110,6 +144,10 @@ class AXK1Config:
         natural layout, row-major, which every launch program and a fresh
         array agree on without being told (channel/staged.py)."""
         return -(-self.cache_width // 128) * 128
+
+    @property
+    def group_limited(self) -> bool:
+        return self.topk_method == "noaux_tc"
 
     @property
     def softmax_scale(self) -> float:
@@ -144,7 +182,7 @@ def init_params(key, cfg: AXK1Config) -> dict:
     keys = jax.random.split(key, cfg.num_hidden_layers + 2)
     layers = {}
     for i in range(cfg.num_hidden_layers):
-        k = jax.random.split(keys[i], 10)
+        k = jax.random.split(keys[i], 14)
         layer = {
             "norm1": jnp.ones((d,), jnp.float32),
             "norm2": jnp.ones((d,), jnp.float32),
@@ -162,10 +200,21 @@ def init_params(key, cfg: AXK1Config) -> dict:
                 "o": _normal(k[4], (h * cfg.v_head_dim, d), 0.5 * (h * cfg.v_head_dim) ** -0.5),
             },
         }
+        if cfg.index_topk:
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            layer["attn"]["index"] = {
+                "q_b": _normal(k[9], (cfg.q_lora_rank, hi * di), cfg.q_lora_rank**-0.5),
+                "k": _normal(k[10], (d, di), d**-0.5),
+                "k_scale": jnp.ones((di,), jnp.float32),
+                "k_bias": jnp.zeros((di,), jnp.float32),
+                "w": _normal(k[11], (d, hi), d**-0.5),
+            }
         if i < cfg.first_k_dense_replace:
             layer["mlp"] = _mlp(k[5], d, cfg.intermediate_size)
         else:
             layer["router"] = _normal(k[6], (d, cfg.router_experts), 1.5 * d**-0.5)
+            if cfg.group_limited:
+                layer["router_bias"] = jnp.zeros((cfg.router_experts,), jnp.float32)
             layer["shared"] = _mlp(
                 k[7], d, cfg.moe_intermediate_size * cfg.n_shared_experts
             )
@@ -216,9 +265,17 @@ def stack_layers(tree: dict, cfg: AXK1Config) -> dict:
 
 
 def empty_cache(cfg: AXK1Config, slots: int, slot_len: int):
-    return jnp.zeros(
+    """The device state of ``slots`` sessions: the latent cache, and with
+    an indexer the index keys beside it (a dict of both)."""
+    latent = jnp.zeros(
         (cfg.num_hidden_layers, slots, slot_len, cfg.cache_row), jnp.bfloat16
     )
+    if not cfg.index_topk:
+        return latent
+    index = jnp.zeros(
+        (cfg.num_hidden_layers, slots, slot_len, cfg.index_head_dim), jnp.bfloat16
+    )
+    return {"latent": latent, "index": index}
 
 
 # -- the forward pass -----------------------------------------------------------
@@ -233,15 +290,58 @@ def _swiglu(x, p):
     return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]
 
 
-def _attention(cfg, p, x, kv, layer, slots, positions, valid, cos, sin):
+def _rope_head(x, cos, sin, rp):
+    """The first ``rp`` values of each head of ``x [B, n, ..., dim]``
+    rotated (float32), the rest as they are."""
+    while cos.ndim < x.ndim:
+        cos, sin = cos[:, :, None], sin[:, :, None]
+    x = x.astype(jnp.float32)
+    return jnp.concatenate([rope.apply_rope(x[..., :rp], cos, sin), x[..., rp:]], axis=-1)
+
+
+def _selection(cfg, p, x, qr, ik, layer, slots, positions, where, cos, sin):
+    """The indexer: writes the new tokens' index keys into ``ik[layer]``
+    and scores every cached position for every new token. Returns the
+    ``select`` the attention forms take (index scores and thresholds,
+    one row a query) and ``ik``."""
+    b, n, _ = x.shape
+    hi, di, rp = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    bf = jnp.bfloat16
+    q = _rope_head((qr @ p["q_b"]).reshape(b, n, hi, di), cos, sin, rp).astype(bf)
+    k = (x @ p["k"]).astype(jnp.float32)
+    mean = jnp.mean(k, axis=-1, keepdims=True)
+    k = (k - mean) * jax.lax.rsqrt(jnp.mean((k - mean) ** 2, axis=-1, keepdims=True) + 1e-6)
+    k = _rope_head(k * p["k_scale"] + p["k_bias"], cos, sin, rp).astype(bf)
+    ik = ik.at[layer, slots[:, None], where].set(k, mode="drop")
+    w = (x @ p["w"]).astype(jnp.float32) * (hi**-0.5 * di**-0.5)
+    wanted = jnp.minimum(positions + 1, cfg.index_topk)
+    if n == 1:
+        with jax.named_scope("lm_index_scores"):
+            scores = sparse_index.step_scores(q[:, 0], w[:, 0], ik, layer, slots, positions[:, 0])
+        with jax.named_scope("lm_index_select"):
+            tau = sparse_index.kth_largest(scores, wanted[:, 0])
+    else:
+        keys = jax.lax.dynamic_index_in_dim(
+            jax.lax.dynamic_index_in_dim(ik, layer, 0, keepdims=False), slots[0], 0, keepdims=False,
+        )
+        with jax.named_scope("lm_index_scores"):
+            scores = sparse_index.extend_scores(q[0], w[0], keys, positions[0])
+        with jax.named_scope("lm_index_select"):
+            tau = sparse_index.kth_largest(scores, wanted[0], last=positions[0, -1])
+    return (scores, tau), ik
+
+
+def _attention(cfg, p, x, kv, ik, layer, slots, positions, valid, cos, sin):
     """``x [B, n, D]`` bfloat16 normalised. Writes the new tokens'
-    ``(c, kr)`` into ``kv[layer]`` (pad tokens are dropped), then
-    attends. Returns the attention output ``[B, n, D]`` and ``kv``."""
+    ``(c, kr)`` into ``kv[layer]`` and, with an indexer, their index
+    keys into ``ik[layer]`` (pad tokens are dropped), then attends.
+    Returns the attention output ``[B, n, D]``, ``kv`` and ``ik``."""
     b, n, _ = x.shape
     h, nope, rp = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rank, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
     bf = jnp.bfloat16
-    q = (_rms(x @ p["q_a"], p["q_norm"], eps).astype(bf) @ p["q_b"]).reshape(b, n, h, nope + rp)
+    qr = _rms(x @ p["q_a"], p["q_norm"], eps).astype(bf)
+    q = (qr @ p["q_b"]).reshape(b, n, h, nope + rp)
     q_nope = q[..., :nope]
     q_rope = rope.apply_rope(
         q[..., nope:].astype(jnp.float32), cos[:, :, None], sin[:, :, None]
@@ -257,11 +357,15 @@ def _attention(cfg, p, x, kv, layer, slots, positions, valid, cos, sin):
     where = jnp.where(valid, positions, slot_len)
     kv = kv.at[layer, slots[:, None], where].set(new, mode="drop")
     kv_b = p["kv_b"].reshape(rank, h, -1)
+    select = None
+    if cfg.index_topk:
+        select, ik = _selection(cfg, p["index"], x, qr, ik, layer, slots, positions, where, cos, sin)
     if n == 1:
-        out = latent_attention.absorbed_attention(
-            q_nope[:, 0], q_rope[:, 0], kv, layer, slots, positions[:, 0],
-            kv_b, cfg.softmax_scale, nope,
-        )[:, None]
+        with jax.named_scope("lm_sparse_attention" if select else "lm_attention"):
+            out = latent_attention.absorbed_attention(
+                q_nope[:, 0], q_rope[:, 0], kv, layer, slots, positions[:, 0],
+                kv_b, cfg.softmax_scale, nope, select,
+            )[:, None]
     else:
         # many tokens: ONE session a launch (pipelines/lm.py forms it so)
         assert b == 1, "a launch of many tokens a row holds one session"
@@ -269,22 +373,25 @@ def _attention(cfg, p, x, kv, layer, slots, positions, valid, cos, sin):
             jax.lax.dynamic_index_in_dim(kv, layer, 0, keepdims=False),
             slots[0], 0, keepdims=False,
         )
-        out = latent_attention.expanded_attention(
-            q_nope[0], q_rope[0], rows, positions[0], kv_b,
-            cfg.softmax_scale, nope,
-        )[None]
-    return out.reshape(b, n, -1) @ p["o"], kv
+        with jax.named_scope("lm_sparse_attention" if select else "lm_attention"):
+            out = latent_attention.expanded_attention(
+                q_nope[0], q_rope[0], rows, positions[0], kv_b,
+                cfg.softmax_scale, nope, select,
+            )[None]
+    return out.reshape(b, n, -1) @ p["o"], kv, ik
 
 
-def _layer(cfg, p, hidden, kv, layer, slots, positions, valid, cos, sin):
-    """One layer; returns the stream, the cache and the rows each held
-    expert saw (zeros for a dense layer)."""
+def _layer(cfg, p, hidden, cache, layer, slots, positions, valid, cos, sin):
+    """One layer; returns the stream, the cache (:func:`empty_cache`'s
+    form) and the rows each held expert saw (None for a dense layer)."""
     bf = jnp.bfloat16
     eps = cfg.rms_norm_eps
-    a, kv = _attention(
-        cfg, p["attn"], _rms(hidden, p["norm1"], eps).astype(bf), kv, layer,
+    kv, ik = (cache["latent"], cache["index"]) if cfg.index_topk else (cache, None)
+    a, kv, ik = _attention(
+        cfg, p["attn"], _rms(hidden, p["norm1"], eps).astype(bf), kv, ik, layer,
         slots, positions, valid, cos, sin,
     )
+    kv = {"latent": kv, "index": ik} if cfg.index_topk else kv
     hidden = hidden + a.astype(jnp.float32)
     x32 = _rms(hidden, p["norm2"], eps)
     x = x32.astype(bf)
@@ -294,10 +401,11 @@ def _layer(cfg, p, hidden, kv, layer, slots, positions, valid, cos, sin):
     idx, gates = experts_op.route(
         x32.reshape(b * n, d), p["router"], cfg.num_experts_per_tok,
         cfg.routed_scaling_factor, cfg.norm_topk_prob,
+        bias=p["router_bias"] if cfg.group_limited else None, n_group=cfg.n_group, topk_group=cfg.topk_group,
     )
     y, rows = experts_op.routed_experts(
         x.reshape(b * n, d), valid.reshape(-1), idx, gates, p["experts"],
-        cfg.expert_offset,
+        cfg.expert_offset, cfg.expert_chunk_rows,
     )
     shared = _swiglu(x, p["shared"]).astype(jnp.float32)
     return hidden + y.reshape(b, n, d) + shared, kv, rows
@@ -305,9 +413,10 @@ def _layer(cfg, p, hidden, kv, layer, slots, positions, valid, cos, sin):
 
 def extend(cfg: AXK1Config, weights: dict, kv, tokens, slots, positions, lengths):
     """Append ``lengths[b]`` of ``tokens [B, n]`` to the session in slot
-    ``slots[b]`` from ``positions[b]`` on. Returns ``logits [B, V]``
-    float32 of each row's last appended position, ``expert_rows
-    [expert layers, experts_here]`` int32 and the cache."""
+    ``slots[b]`` from ``positions[b]`` on; ``kv`` is the cache in
+    :func:`empty_cache`'s form. Returns ``logits [B, V]`` float32 of
+    each row's last appended position, ``expert_rows [expert layers,
+    experts_here]`` int32 and the cache."""
     b, n = tokens.shape
     offsets = jnp.arange(n, dtype=jnp.int32)[None, :]
     valid = offsets < lengths[:, None]

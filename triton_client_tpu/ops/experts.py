@@ -26,16 +26,30 @@ CHUNK_ROWS = 1024
 DENSE_TOKENS = 64
 
 
-def route(x, router, top_k: int, scale: float, normalise: bool = True):
+def route(x, router, top_k: int, scale: float, normalise: bool = True,
+          bias=None, n_group: int = 1, topk_group: int = 1):
     """Sigmoid scores in float32 over all experts: the ``top_k``
-    largest and their gates ``scale * s_i / sum s_j``."""
+    largest and their gates ``scale * s_i / sum s_j``. With ``bias``
+    (``topk_method: noaux_tc``) the choice is made by ``s + bias`` and
+    only inside the ``topk_group`` best of ``n_group`` groups of experts
+    (a group's score: the sum of its two largest ``s + bias``); the
+    gates are still the chosen experts' ``s``."""
     scores = jax.nn.sigmoid(
         jnp.dot(
             x.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,  # a TPU's float32 product is bfloat16 passes unless told
         )
     )
-    top, idx = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, top_k)
+    else:
+        t, e = scores.shape
+        biased = scores + bias
+        group = jnp.sum(jax.lax.top_k(biased.reshape(t, n_group, e // n_group), 2)[0], axis=-1)
+        kept = group >= jax.lax.top_k(group, topk_group)[0][:, -1:]
+        biased = jnp.where(jnp.repeat(kept, e // n_group, axis=-1), biased, -jnp.inf)
+        idx = jax.lax.top_k(biased, top_k)[1]
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     if normalise:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     return idx, top * scale
@@ -49,12 +63,16 @@ def _swiglu_grouped(rows, experts, sizes):
     )
 
 
-def routed_experts(x, valid, idx, gates, experts, expert_offset: int):
+def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows: int = CHUNK_ROWS):
     """``x [T, D]`` bfloat16, ``valid [T]`` (pad tokens route nowhere),
     ``idx``/``gates [T, k]`` from :func:`route`, ``experts`` the held
     experts' ``gate``/``up [E, D, F]`` and ``down [E, F, D]``. Returns
     the held experts' sum ``[T, D]`` float32 and the rows each expert
-    saw ``[E]`` int32."""
+    saw ``[E]`` int32. ``chunk_rows``: the token-slots one grouped
+    product takes; the launch runs as many as the rows routed HERE
+    fill, so a launch whose expected rows equal ``chunk_rows`` takes one
+    pass or two as the seed's router falls (models/axk1.py
+    ``expert_chunk_rows``)."""
     t, k = idx.shape
     held = experts["gate"].shape[0]
     local = idx - expert_offset
@@ -83,7 +101,7 @@ def routed_experts(x, valid, idx, gates, experts, expert_offset: int):
     )
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
-    chunk = min(t * k, CHUNK_ROWS)
+    chunk = min(t * k, chunk_rows)
     assert (t * k) % chunk == 0, "token-slots must fill whole chunks"
 
     def add_chunk(i, acc):

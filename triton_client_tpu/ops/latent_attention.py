@@ -16,18 +16,38 @@ the same attention and the launch's shape picks one (models/axk1.py):
     and values once a launch, and blocks of queries go against the
     blocks of keys at or before them with a running softmax.
 
+Either form takes a ``select`` ``(index_scores, tau)`` (ops/sparse_index.py:
+one row of float32 index scores a query over the slot's positions and
+the row's threshold): a query then reads only the positions whose index
+score is at least its threshold, the learned sparse attention of
+``family: deepseek_v32``. The selection is a mask over the same dense
+products; without it the code is what it was. A slot of more than
+:data:`SEGMENT_ROWS` positions is expanded a segment at a time. On a TPU
+the selected extend launch runs each segment through one Pallas kernel
+(:func:`_selected_segment`: eight heads a grid step share the step's
+block of the mask, scores and softmax stay in VMEM); the same blocks in
+plain XLA, whose scores ``[H, queries, keys]`` pass through HBM, took
+six times as long at 128 heads (my chip run, PR 35).
+
 Scores, softmax and the mask are float32; products read bfloat16.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 QUERY_BLOCK = 512
 KEY_BLOCK = 256
+#: the most positions whose per-head keys and values exist at once in an
+#: extend launch: a slot of 34,048 positions expanded whole is 2.2 GB at
+#: 128 heads, beside 10 GB of weights and cache
+SEGMENT_ROWS = 8192
 
 
 def _masked_softmax(scores, key_pos, query_pos):
@@ -36,11 +56,13 @@ def _masked_softmax(scores, key_pos, query_pos):
     return jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
 
 
-def absorbed_attention(q_nope, q_rope, kv, layer, slots, positions, kv_b, scale, nope):
+def absorbed_attention(q_nope, q_rope, kv, layer, slots, positions, kv_b, scale, nope, select=None):
     """``q_nope [B, H, nope]``, ``q_rope [B, H, rope]`` (one token a
     session), ``kv [layers, slots, S, rank + rope]`` the whole cache,
     ``slots``/``positions [B]`` of the sessions and their new tokens,
-    ``kv_b [rank, H, nope + v]``. Returns ``[B, H, v]`` bfloat16.
+    ``kv_b [rank, H, nope + v]``, ``select`` the sessions' index scores
+    ``[B, S]`` and thresholds ``[B]`` or None. Returns ``[B, H, v]``
+    bfloat16.
 
     The sessions go one after another (``lax.map``): each reads its own
     slot in place with one dynamic slice, 5 MB at the served size. A
@@ -56,25 +78,166 @@ def absorbed_attention(q_nope, q_rope, kv, layer, slots, positions, kv_b, scale,
     key_pos = jnp.arange(kv.shape[2])
 
     def one(args):
-        q_row, slot, pos = args  # [H, rank + rope]
+        q_row, slot, pos, *picked = args  # [H, rank + rope]
         rows = jax.lax.dynamic_slice(
             kv, (layer, slot, 0, 0), (1, 1, kv.shape[2], kv.shape[3])
         )[0, 0]
         scores = jnp.einsum(
             "hc,sc->hs", q_row, rows, preferred_element_type=jnp.float32
         ) * scale
+        if picked:
+            index_scores, tau = picked
+            scores = jnp.where(index_scores >= tau, scores, -jnp.inf)
         w = _masked_softmax(scores, key_pos, pos[None])
         return jnp.einsum("hs,sc->hc", w.astype(rows.dtype), rows[:, :rank])
 
-    out_lat = jax.lax.map(one, (q, slots, positions))
+    out_lat = jax.lax.map(one, (q, slots, positions, *(select or ())))
     return jnp.einsum("bhc,chd->bhd", out_lat, kv_b[..., nope:])
 
 
-def expanded_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope):
+def _segment_rows(s_len: int, kb: int) -> int:
+    """The slot whole where it has at most :data:`SEGMENT_ROWS`
+    positions, else its largest part of whole key blocks that divides it
+    and has at most that many."""
+    blocks = s_len // kb
+    fits = [n for n in range(1, blocks + 1) if blocks % n == 0 and n * kb <= SEGMENT_ROWS]
+    return s_len if s_len <= SEGMENT_ROWS else max(fits) * kb
+
+
+HEAD_TILE = 8  # heads a grid step of the selected kernel holds: they share its block of the mask
+SELECTED_QUERY_TILE = 256  # ... and queries ...
+# ... and at most this many keys, in whole lane tiles that divide the segment: 2,432 of the served slot's
+# 4,864. A layer of a 4,096-token launch on 16k took 158 ms at 512 x 256, 189 at 256 x 256, 102 at
+# 128 x 2,432, 95 at 512 x 2,432, 81.5 at 256 x 2,432 and 80.4 at 256 x 4,864 (my chip run, PR 35)
+SELECTED_KEY_TILE = 2560
+
+
+def _key_tile(seg: int) -> int:
+    fits = [n for n in range(128, min(seg, SELECTED_KEY_TILE) + 1, 128) if seg % n == 0]
+    return max(fits) if fits else seg
+
+
+def _selected_kernel(meta_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, sel_ref, tau_ref,
+                     top_in, total_in, acc_in, top_ref, total_ref, acc_ref, *, scale, tq, tk):
+    """One grid step: ``HEAD_TILE`` heads, ``tq`` queries, ``tk`` keys of
+    the segment; the running softmax sits in the output blocks, which
+    stay in VMEM while the key blocks (the last grid axis) go by."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    start, base = meta_ref[0], meta_ref[1]  # the first query's position; the segment's first key's
+
+    @pl.when(j == 0)
+    def _():
+        top_ref[...] = top_in[...]
+        total_ref[...] = total_in[...]
+        acc_ref[...] = acc_in[...]
+
+    @pl.when(base + j * tk <= start + (i + 1) * tq - 1)  # else: every key lies after every query
+    def _():
+        keep = sel_ref[...] >= tau_ref[...]  # [tq, tk]: minus infinity after the query, so causal too
+        kr = kr_ref[...]
+        dims = (((1,), (1,)), ((), ()))
+        for g in range(qn_ref.shape[0]):
+            scores = (
+                jax.lax.dot_general(qn_ref[g], kn_ref[g], dims, preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(qr_ref[g], kr, dims, preferred_element_type=jnp.float32)
+            ) * scale
+            scores = jnp.where(keep, scores, -jnp.inf)
+            top = top_ref[g]
+            new_top = jnp.maximum(top, jnp.max(scores, axis=1, keepdims=True))
+            w = jnp.exp(scores - new_top)
+            shrink = jnp.exp(top - new_top)
+            total_ref[g] = total_ref[g] * shrink + jnp.sum(w, axis=1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * shrink + jnp.dot(
+                w.astype(v_ref.dtype), v_ref[g], preferred_element_type=jnp.float32
+            )
+            top_ref[g] = new_top
+
+
+def _selected_segment(state, q_nope, q_rope, k_nope, kr, v, index_scores, tau, start, base, scale,
+                      interpret=False):
+    """One expanded segment into every query's running softmax.
+    ``state`` ``(top [H, T, 1], total [H, T, 1], acc [H, T, v])``
+    float32, ``q_nope [H, T, nope]``, ``q_rope [H, T, rope]``, ``k_nope
+    [H, seg, nope]``, ``kr [seg, rope]``, ``v [H, seg, v]``,
+    ``index_scores [T, S]``, ``tau [T, 1]``; ``start`` the first
+    query's position (they ascend by one), ``base`` the segment's first
+    key's. Returns the state."""
+    h, t, _ = q_nope.shape
+    seg = k_nope.shape[1]
+    g, tq, tk = math.gcd(h, HEAD_TILE), math.gcd(t, SELECTED_QUERY_TILE), _key_tile(seg)
+    assert h % g == 0 and t % tq == 0 and seg % tk == 0, "the tiles divide heads, queries and the segment"
+    # a key block after the query block's last position is not computed: name the last one that is
+    last = lambda i, meta: jnp.clip((meta[0] + (i + 1) * tq - 1 - meta[1]) // tk, 0, seg // tk - 1)
+    heads = lambda width, rows: pl.BlockSpec((g, rows, width), lambda a, i, j, meta: (a, i, 0))
+    keys = lambda width: pl.BlockSpec((g, tk, width), lambda a, i, j, meta: (a, jnp.minimum(j, last(i, meta)), 0))
+    state_specs = [heads(1, tq), heads(1, tq), heads(v.shape[-1], tq)]
+    return pl.pallas_call(
+        functools.partial(_selected_kernel, scale=scale, tq=tq, tk=tk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h // g, t // tq, seg // tk),
+            in_specs=[
+                heads(q_nope.shape[-1], tq), heads(q_rope.shape[-1], tq),
+                keys(k_nope.shape[-1]),
+                pl.BlockSpec((tk, kr.shape[-1]), lambda a, i, j, meta: (jnp.minimum(j, last(i, meta)), 0)),
+                keys(v.shape[-1]),
+                pl.BlockSpec((tq, tk), lambda a, i, j, meta: (i, meta[1] // tk + jnp.minimum(j, last(i, meta)))),
+                pl.BlockSpec((tq, 1), lambda a, i, j, meta: (i, 0)),
+                *state_specs,
+            ],
+            out_specs=state_specs,
+        ),
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=96 << 20
+        ),
+        interpret=interpret,
+        name="lm_sparse_attention",
+    )(jnp.stack([start, base]).astype(jnp.int32), q_nope, q_rope, k_nope, kr, v, index_scores, tau, *state)
+
+
+def selected_kernel_fits(t: int, s_len: int, nope: int, vd: int) -> bool:
+    """Whether the Pallas kernel takes these shapes: whole lane tiles a
+    head's keys and values and a block of the mask."""
+    kb = math.gcd(s_len, KEY_BLOCK)
+    return nope % 128 == 0 and vd % 128 == 0 and kb % 128 == 0 and math.gcd(t, QUERY_BLOCK) % 8 == 0
+
+
+def _selected_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope, select, interpret=False):
+    """:func:`expanded_attention` under a selection, a segment at a time
+    through :func:`_selected_segment`."""
+    rank = kv_b.shape[0]
+    t, h = q_nope.shape[:2]
+    s_len, rope = kv_rows.shape[0], q_rope.shape[-1]
+    vd = kv_b.shape[-1] - nope
+    seg = _segment_rows(s_len, math.gcd(s_len, KEY_BLOCK))
+    index_scores, tau = select
+    qn, qr = jnp.moveaxis(q_nope, 0, 1), jnp.moveaxis(q_rope, 0, 1)
+
+    def segment(g, state):
+        rows = jax.lax.dynamic_slice_in_dim(kv_rows, g * seg, seg)
+        kv = jnp.einsum("sc,chd->hsd", rows[:, :rank], kv_b)
+        return tuple(_selected_segment(
+            state, qn, qr, kv[..., :nope], rows[:, rank : rank + rope], kv[..., nope:],
+            index_scores, tau[:, None], positions[0], g * seg, scale, interpret,
+        ))
+
+    state = (
+        jnp.full((h, t, 1), -1e30, jnp.float32),
+        jnp.zeros((h, t, 1), jnp.float32),
+        jnp.zeros((h, t, vd), jnp.float32),
+    )
+    segments = jnp.minimum(positions[-1] // seg + 1, s_len // seg)
+    _, total, acc = jax.lax.fori_loop(0, segments, segment, state)
+    return jnp.moveaxis(acc / total, 0, 1).astype(kv_rows.dtype)
+
+
+def expanded_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope, select=None, kernel=None):
     """``q_nope [T, H, nope]``, ``q_rope [T, H, rope]`` (T new tokens of
     one session), ``kv_rows [S, rank + rope]`` (its slot, the new
-    tokens already written), ``positions [T]`` ascending. Returns
-    ``[T, H, v]``.
+    tokens already written), ``positions [T]`` ascending, ``select`` the
+    queries' index scores ``[T, S]`` and thresholds ``[T]`` or None.
+    Returns ``[T, H, v]``.
 
     Blocks of queries against blocks of keys with a running softmax, and
     for each block of queries only the key blocks up to its last
@@ -82,18 +245,44 @@ def expanded_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope):
     1,024 tokens reads 1,024 keys, not the slot's 4,352, and the causal
     half is skipped. One pass over scores ``[H, queries, S]`` in float32
     took 20 ms a layer at 2,048 tokens, ten times the products' time (my
-    chip run, PR 29)."""
+    chip run, PR 29). A long slot goes a segment at a time: the
+    segment's latents are expanded, every block of queries takes its
+    key blocks in, and the running softmax of all queries waits in
+    memory for the next segment. ``kernel``: under a selection, the
+    Pallas kernel (default: on a TPU, where the shapes are whole tiles)
+    or these blocks in plain XLA."""
     rank = kv_b.shape[0]
     t, h = q_nope.shape[:2]
     s_len = kv_rows.shape[0]
-    kv = jnp.einsum("sc,chd->shd", kv_rows[:, :rank], kv_b)
     rope = q_rope.shape[-1]
-    k_nope, v, kr = kv[..., :nope], kv[..., nope:], kv_rows[:, rank : rank + rope]
+    vd = kv_b.shape[-1] - nope
+    if kernel is None:
+        kernel = (select is not None and jax.default_backend() == "tpu"
+                  and selected_kernel_fits(t, s_len, nope, vd))
+    if kernel:
+        return _selected_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope, select)
     qb = min(t, QUERY_BLOCK)
     kb = math.gcd(s_len, KEY_BLOCK)
+    seg = _segment_rows(s_len, kb)
+    split = lambda a: a.reshape(t // qb, qb, *a.shape[1:])
+    queries = (split(q_nope), split(q_rope), split(positions), *map(split, select or ()))
 
-    def block(args):
-        qn, qr, pos = args  # [qb, H, .], [qb]
+    def expand(rows):
+        kv = jnp.einsum("sc,chd->shd", rows[:, :rank], kv_b)
+        return kv[..., :nope], kv[..., nope:], rows[:, rank : rank + rope]
+
+    def fresh():
+        return (
+            jnp.full((h, qb), -1e30, jnp.float32),
+            jnp.zeros((h, qb), jnp.float32),
+            jnp.zeros((h, qb, vd), jnp.float32),
+        )
+
+    def attend(expanded, base, state, qn, qr, pos, picked):
+        """One block of queries ``[qb, H, .]`` takes in the key blocks of
+        the expanded rows (the slot's from ``base`` on) at or before its
+        last position."""
+        k_nope, v, kr = expanded
 
         def keys(j, carry):
             top, total, acc = carry
@@ -106,7 +295,11 @@ def expanded_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope):
                     preferred_element_type=jnp.float32,
                 )
             ) * scale
-            keep = (lo + jnp.arange(kb))[None, None, :] <= pos[None, :, None]
+            keep = (base + lo + jnp.arange(kb))[None, None, :] <= pos[None, :, None]
+            if picked:
+                index_scores, tau = picked  # [qb, S], [qb]
+                chosen = jax.lax.dynamic_slice_in_dim(index_scores, base + lo, kb, axis=1)
+                keep = keep & (chosen >= tau[:, None])[None]
             scores = jnp.where(keep, scores, -jnp.inf)
             new_top = jnp.maximum(top, scores.max(axis=-1))
             w = jnp.exp(scores - new_top[..., None])
@@ -119,17 +312,32 @@ def expanded_attention(q_nope, q_rope, kv_rows, positions, kv_b, scale, nope):
             return new_top, total * shrink + w.sum(axis=-1), acc
 
         # key 0 is at or before every query, so the first block leaves no row empty
-        blocks = jnp.minimum(pos[-1] // kb + 1, s_len // kb)
-        top, total, acc = jax.lax.fori_loop(
-            0, blocks, keys,
-            (
-                jnp.full((h, qb), -1e30, jnp.float32),
-                jnp.zeros((h, qb), jnp.float32),
-                jnp.zeros((h, qb, v.shape[-1]), jnp.float32),
-            ),
-        )
-        return jnp.moveaxis(acc / total[..., None], 0, 1).astype(v.dtype)
+        blocks = jnp.clip((pos[-1] - base) // kb + 1, 0, k_nope.shape[0] // kb)
+        return jax.lax.fori_loop(0, blocks, keys, state)
 
-    split = lambda a: a.reshape(t // qb, qb, *a.shape[1:])
-    out = jax.lax.map(block, (split(q_nope), split(q_rope), split(positions)))
+    if seg == s_len:
+        expanded = expand(kv_rows)
+
+        def block(args):
+            qn, qr, pos, *picked = args
+            _, total, acc = attend(expanded, 0, fresh(), qn, qr, pos, picked)
+            return jnp.moveaxis(acc / total[..., None], 0, 1).astype(kv_rows.dtype)
+
+        return jax.lax.map(block, queries).reshape(t, h, -1)
+
+    def segment(g, state):
+        expanded = expand(jax.lax.dynamic_slice_in_dim(kv_rows, g * seg, seg))
+
+        def block(args):
+            carried, qn, qr, pos, *picked = args
+            return attend(expanded, g * seg, carried, qn, qr, pos, picked)
+
+        return jax.lax.map(block, (state, *queries))
+
+    state = jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a, (t // qb, *a.shape)), fresh()
+    )
+    segments = jnp.minimum(positions[-1] // seg + 1, s_len // seg)
+    _, total, acc = jax.lax.fori_loop(0, segments, segment, state)
+    out = jnp.moveaxis(acc / total[..., None], 1, 2).astype(kv_rows.dtype)
     return out.reshape(t, h, -1)

@@ -1,11 +1,14 @@
 """Token sessions over a language model whose cache lives on the device.
 
-Builds the served form of a ``family: axk1`` repository entry
-(models/axk1.py): the device program ``device_fn(inputs, params)``, the
+Builds the served form of a ``family: axk1`` or ``family: deepseek_v32``
+repository entry (one model module for both, models/axk1.py: what an
+entry's ``model`` block switches on decides, not the family's name):
+the device program ``device_fn(inputs, params)``, the
 ``params`` it takes as launcher ARGUMENTS (``weights`` and, under
-``cache``, the latent cache that the channel donates into each launch
-and takes back from its outputs: gigabytes of weights cannot be
-constants of an HLO module, and the cache never crosses to the host),
+``cache``, the latent cache, with an indexer a dict of it and the index
+keys, that the channel donates into each launch and takes back from its
+outputs: gigabytes of weights cannot be constants of an HLO module, and
+the cache never crosses to the host),
 and the session state the model declares
 (``runtime.sessions.TokenSessions``).
 
@@ -13,7 +16,8 @@ The contract of the served model. One KServe request under a
 ``sequence_id`` carries ``tokens`` int32 ``[1, n]``; the server appends
 them to that session's cache (empty on ``sequence_start``) and answers
 ``logits`` float32 ``[1, vocab]`` of the last appended position: one
-operation, extend. Two launch shapes reach the device. An extend of ONE
+operation, extend. A session may be fed in several many-token requests
+(turns), each on whatever the slot already holds. Two launch shapes reach the device. An extend of ONE
 session pads ``n`` to :func:`token_bucket`; the one-token requests of
 up to ``max_batch_size`` DIFFERENT sessions merge into a step launch,
 padded to :func:`step_bucket`. Sampling is the client's.
@@ -25,6 +29,8 @@ published sizes and this chip's share, ``precision``), ``pipeline``
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import numpy as np
@@ -120,9 +126,13 @@ def _int8_rounded(w):
     return precision_policy.fake_quant_channelwise(w, contract_axis=-2)
 
 
+@functools.lru_cache(maxsize=None)
 def make_device_fn(cfg: axk1.AXK1Config):
     """``device_fn(inputs, params)``: one extend launch. The cache rides
-    in ``params`` and comes back among the outputs under the same key."""
+    in ``params`` and comes back among the outputs under the same key.
+    ONE function a configuration: an entry built again in the same
+    process (a reload, a dozen seeds of one rehearsal) finds what jax
+    traced and compiled for the first."""
 
     def device_fn(inputs, params):
         logits, expert_rows, kv = axk1.extend(
@@ -161,25 +171,35 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
     }
     del tree
 
+    index_cache_bytes = (
+        params[STATE_KEY]["index"].nbytes if cfg.index_topk else 0
+    )
     sessions = TokenSessions(
         slots=slots, slot_len=slot_len, max_tokens=max_tokens,
         token_bucket=token_bucket,
         step_bucket=lambda n: step_bucket(n, slots),
         ttl_s=float(pipe.get("session_ttl_s", 60.0)),
+        index_topk=cfg.index_topk, layers=cfg.num_hidden_layers,
+        index_cache_bytes=index_cache_bytes,
     )
     device_fn = make_device_fn(cfg)
     program = jax.jit(device_fn)
+    stepped = [True]  # the in-process session's last request was a step (or there was none)
 
     def infer_fn(inputs):
         """The in-process call (no channel, so no request parameters):
-        ONE implicit session. ``tokens [1, n]`` with n > 1 starts it anew
-        (a prompt), n = 1 extends it (a step). Not for use beside a
-        serving channel on the same model: both own the cache."""
+        ONE implicit session, a stream of turns and then steps.
+        ``tokens [1, n]`` with n > 1 after a step (or first of all)
+        starts it anew; after another many-token request it is a further
+        turn of the same session; n = 1 extends it (a step). Not for use
+        beside a serving channel on the same model: both own the cache."""
         tokens = np.asarray(inputs["tokens"])
+        many = tokens.shape[1] > 1
         request, ticket = sessions.open(InferRequest(
             name, {"tokens": tokens}, sequence_id="__in_process__",
-            sequence_start=tokens.shape[1] > 1,
+            sequence_start=many and stepped[0],
         ))
+        stepped[0] = not many
         try:
             out = dict(program(dict(request.inputs), params))
         except Exception:
@@ -198,7 +218,7 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
         inputs=(TensorSpec("tokens", (-1, -1), "INT32"),),
         outputs=(TensorSpec("logits", (-1, cfg.vocab_size), "FP32"),),
         extra={
-            "family": "axk1",
+            "family": doc.get("family", "axk1"),
             "device_state": STATE_KEY,
             # one-token requests of different sessions merge into one launch
             "session_merge": True,

@@ -484,11 +484,14 @@ class TokenLaunch:
     rows: list  # [(stream_id, tokens, restarted, ends)]
     tokens: int = 0
     sessions: int = 0
+    context: int = 0  # positions cached before the launch, summed over its sessions
+    keys_visible: int = 0  # over its appended tokens: the positions each may attend to ...
+    keys_selected: int = 0  # ... and those it reads (the model's ``index_topk`` at most)
 
     @property
     def span(self):
         """The launch's span over its device window, and its attributes."""
-        return self.kind, {"tokens": self.tokens, "sessions": self.sessions}
+        return self.kind, {"tokens": self.tokens, "sessions": self.sessions, "context": self.context}
 
 
 class TokenSessions:
@@ -526,9 +529,19 @@ class TokenSessions:
         ttl_s: float = 60.0,
         time_fn=time.monotonic,
         turn_timeout_s: float = 30.0,
+        index_topk: int = 0,
+        layers: int = 1,
+        index_cache_bytes: int = 0,
     ) -> None:
+        """``index_topk``: the positions a token of the model attends to
+        at most (0: all), ``layers`` its layers, ``index_cache_bytes``
+        what its index keys take beside the latent cache (a gauge): what
+        the counters ``lm_keys_visible`` / ``lm_keys_selected`` need."""
         self.slot_len = int(slot_len)
         self.max_tokens = int(max_tokens)
+        self._index_topk = int(index_topk)
+        self._layers = int(layers)
+        self._index_cache_bytes = int(index_cache_bytes)
         self._token_bucket = token_bucket
         self._step_bucket = step_bucket
         self._free = list(range(int(slots) - 1, -1, -1))
@@ -539,7 +552,9 @@ class TokenSessions:
         self._counters = {
             "lm_tokens_prefill": 0, "lm_tokens_step": 0,
             "lm_prefill_launches": 0, "lm_step_launches": 0,
-            "lm_step_sessions": 0, "created_total": 0, "ended_total": 0,
+            "lm_step_sessions": 0, "lm_context_prefill": 0,
+            "lm_keys_visible": 0, "lm_keys_selected": 0,
+            "created_total": 0, "ended_total": 0,
             "outgrown_total": 0, "unknown_total": 0,
         }
         self._expert_rows = None  # [expert layers, experts held], summed over launches
@@ -652,6 +667,13 @@ class TokenSessions:
             slot.refs += 1
             slot.last_used = now
             ticket.rows.append((stream_id, n, restarted, end))
+            # a token at position p may attend to p + 1 positions, in every layer
+            visible = np.arange(position + 1, position + n + 1, dtype=np.int64)
+            ticket.context += position
+            ticket.keys_visible += self._layers * int(visible.sum())
+            ticket.keys_selected += self._layers * int(
+                np.minimum(visible, self._index_topk).sum() if self._index_topk else visible.sum()
+            )
             return slot.state, position
 
     def advance(self, ticket: TokenLaunch, outputs):
@@ -687,6 +709,8 @@ class TokenSessions:
                     self._pool.free_locked(slot)
             ticket.rows = []
             if not failed and ticket.tokens:
+                self._counters["lm_keys_visible"] += ticket.keys_visible
+                self._counters["lm_keys_selected"] += ticket.keys_selected
                 if ticket.kind == "lm_step":
                     self._counters["lm_tokens_step"] += ticket.tokens
                     self._counters["lm_step_launches"] += 1
@@ -694,6 +718,7 @@ class TokenSessions:
                 else:
                     self._counters["lm_tokens_prefill"] += ticket.tokens
                     self._counters["lm_prefill_launches"] += 1
+                    self._counters["lm_context_prefill"] += ticket.context
             self._turn.notify_all()
         if host_outputs is not None:
             rows = host_outputs.pop(self.EXPERT_ROWS, None)
@@ -725,6 +750,7 @@ class TokenSessions:
                 "session_cache_slot_len": self.slot_len,
                 "session_cache_slots_in_use": len(slots),
                 "session_cache_tokens": sum(s.length for s in slots),
+                "session_index_cache_bytes": self._index_cache_bytes,
                 "expired_total": self._pool.expired,
                 "reclaimed_total": self._pool.reclaimed,
                 "rejected_total": self._pool.rejected,
