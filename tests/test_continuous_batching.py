@@ -960,3 +960,361 @@ def test_batching_decomposition_counters():
     assert d["device"] >= 20.0  # the inner call's 20 ms is in the device share
     assert stats["merge_members"] == 12
     assert stats["member_queue_delay_ms"] >= 0
+
+
+# -- when a group of session steps closes ------------------------------------
+
+
+class _Clock:
+    """The batcher's clock, moved by the test alone."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+
+class _StepInner:
+    """A ``session_merge`` model whose launches resolve when the test
+    says so: ``launches`` holds what went down and ``began`` when, on
+    the test's clock; ``land(i)`` hands launch ``i`` its answer (a row a
+    member)."""
+
+    batch_multiple = 1
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.launches, self.began, self._gates = [], [], []
+
+    def get_metadata(self, name, version=""):
+        return types.SimpleNamespace(extra={"session_merge": True})
+
+    def do_inference_async(self, request):
+        gate = threading.Event()
+        self.began.append(self.clock.now)
+        self._gates.append(gate)
+        self.launches.append(request)
+        rows = np.asarray(request.inputs["tokens"]).shape[0]
+
+        def result():
+            assert gate.wait(30.0)
+            return InferResponse(
+                model_name=request.model_name,
+                outputs={"y": np.zeros((rows, 1), np.float32)},
+            )
+
+        return types.SimpleNamespace(result=result)
+
+    def land(self, i):
+        self._gates[i].set()
+
+    def sessions(self, i):
+        """The sessions of launch ``i``, in row order."""
+        request = self.launches[i]
+        if request.sequence_rows is not None:
+            return [row[0] for row in request.sequence_rows]
+        return [request.sequence_id]
+
+
+def _until(what, timeout=20.0):
+    deadline = time.monotonic() + timeout
+    while not what():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.002)
+
+
+class _StepRig:
+    """Closed-loop callers around a batcher on a clock the test moves:
+    ``send`` stages a session's request from a thread of its own,
+    ``land`` ends a launch ``T`` of that clock after it began."""
+
+    T = 0.020
+
+    def __init__(self, monkeypatch, depth=2):
+        from triton_client_tpu.runtime import continuous
+
+        self.clock = _Clock()
+        monkeypatch.setattr(continuous, "time", self.clock)
+        self.inner = _StepInner(self.clock)
+        self.chan = ContinuousBatchingChannel(
+            self.inner, max_batch=8, pipeline_depth=depth
+        )
+        self.pool = concurrent.futures.ThreadPoolExecutor(16)
+        self.answers = {}
+
+    def close(self):
+        for i in range(len(self.inner.launches)):
+            self.inner.land(i)
+        self.chan.close()
+        self.pool.shutdown(wait=True)
+
+    def send(self, sid, tokens=1, end=False):
+        """Returns once the request is in the ready set (or beyond)."""
+        request = InferRequest(
+            "m", {"tokens": np.zeros((1, tokens), np.int32)},
+            sequence_id=sid, sequence_end=end,
+        )
+        before = self._admitted()
+        self.answers[sid] = self.pool.submit(self.chan.do_inference, request)
+        _until(lambda: self._admitted() > before)
+
+    def _admitted(self):
+        """Requests that reached the ready set so far: those still in
+        it and those formed into groups (a session request is one row)."""
+        stats = self.chan.stats()
+        return stats["ready_depth"] + stats["merged_frames"]
+
+    def launched(self, n):
+        """The sessions of the ``n``-th launch, once it is there."""
+        _until(lambda: len(self.inner.launches) >= n)
+        return self.inner.sessions(n - 1)
+
+    def never_launched(self, n, for_s=0.25):
+        """No ``n``-th launch, although the dispatcher had ``for_s`` of
+        the host's clock (it looks again every 0.1 s at the latest)."""
+        time.sleep(for_s)
+        assert len(self.inner.launches) < n
+
+    def land(self, n, sids):
+        """The ``n``-th launch ends ``T`` after it began (no earlier
+        than now); its callers hold their answers when this returns."""
+        self.clock.now = max(self.clock.now, self.inner.began[n - 1] + self.T)
+        self.inner.land(n - 1)
+        for sid in sids:
+            self.answers[sid].result(timeout=20.0)
+        _until(
+            lambda: self.chan._launches_ahead == 0
+            or len(self.inner.launches) > n
+        )
+
+    def after(self, seconds):
+        self.clock.now += seconds
+        with self.chan._ready_cv:
+            self.chan._ready_cv.notify_all()
+
+    def warm(self, return_s, ends=()):
+        """Two launches that teach the batcher ``T`` and how fast a
+        session comes back: s0 alone, then s1-s3, pooled BEHIND it (a
+        step group stays open while a launch is ahead), with s0 back
+        ``return_s`` after its answer. Ends with both launches landed
+        and s0's next step staged."""
+        self.send("s0")
+        assert self.launched(1) == ["s0"]
+        for sid in ("s1", "s2", "s3"):
+            self.send(sid, end=sid in ends)
+        # depth 2 has a permit free: it is the GROUP that stays open
+        self.never_launched(2, for_s=0.15)
+        self.land(1, ["s0"])
+        assert self.launched(2) == ["s1", "s2", "s3"]
+        if return_s < self.T:
+            self.after(return_s)
+            self.send("s0")
+            self.never_launched(3, for_s=0.15)  # launch 2 is ahead
+        self.land(2, ["s1", "s2", "s3"])
+        if return_s >= self.T:
+            self.after(return_s - self.T)
+            self.send("s0")
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "all_back", "slow_return", "never_back", "other_key", "sequence_end",
+        "spread_return", "close_drains", "heartbeat",
+    ],
+)
+def test_a_step_group_closes_when_the_device_can_take_it(case, monkeypatch):
+    """``_step_wait_locked`` on a clock the test moves; a launch lasts
+    20 ms. A step group never closes while a launch is ahead of it; at
+    a free device it waits for the sessions that are about to come back
+    only where they come back faster than a launch lasts, for one
+    launch's time at most, and never past other work."""
+    rig = _StepRig(monkeypatch, depth=1 if case == "other_key" else 2)
+    T = rig.T
+    holds = lambda: tuple(
+        rig.chan.stats()[k]
+        for k in ("step_holds", "step_hold_joined", "step_hold_expired")
+    )
+    try:
+        if case in ("close_drains", "heartbeat"):
+            rig.send("s0")
+            assert rig.launched(1) == ["s0"]
+            rig.send("s1")
+            rig.never_launched(2, for_s=0.15)  # open behind launch 1
+        if case == "close_drains":
+            # close() waits for nobody: the open group goes down behind
+            # the launch ahead, and every admitted caller is answered
+            closing = rig.pool.submit(rig.chan.close)
+            assert rig.launched(2) == ["s1"]
+            rig.inner.land(0)
+            rig.inner.land(1)
+            closing.result(timeout=20.0)
+            assert rig.answers["s0"].result(timeout=20.0).outputs["y"].shape == (1, 1)
+            assert rig.answers["s1"].result(timeout=20.0).outputs["y"].shape == (1, 1)
+            return
+        if case == "heartbeat":
+            # an open step group is not a stall while the launch ahead
+            # of it is younger than the threshold; one that has not
+            # moved for a whole threshold is
+            for _ in range(4):
+                rig.after(1.0)
+                _until(lambda: rig.chan.dispatcher_progress_age_s() == 0.0)
+            rig.after(2.0)  # 6 s behind launch 1: threshold 5 s
+            rig.after(1.0)
+            time.sleep(0.25)
+            assert rig.chan.dispatcher_progress_age_s() >= 1.0
+            rig.land(1, ["s0"])
+            assert rig.launched(2) == ["s1"]
+            _until(lambda: rig.chan.dispatcher_progress_age_s() == 0.0)
+            return
+        if case == "spread_return":
+            # half of the sessions are back 2 ms after their answers and
+            # half after 30: the MEAN return is under a launch, the last
+            # of a launch's sessions is not back in time, and a wait
+            # that runs out buys nothing: nobody is waited for
+            rig.send("s0")
+            assert rig.launched(1) == ["s0"]
+            for sid in ("s1", "s2", "s3"):
+                rig.send(sid)
+            rig.land(1, ["s0"])
+            assert rig.launched(2) == ["s1", "s2", "s3"]
+            rig.after(0.002)
+            rig.send("s0")
+            with rig.chan._ready_cv:
+                for after_s in (0.030, 0.002) * 8:
+                    rig.chan._step_pace["m", ""].returned(after_s)
+            rig.land(2, ["s1", "s2", "s3"])
+            assert rig.launched(3) == ["s0"]  # at once, alone
+            s = rig.chan.stats()
+            assert s["step_return_ms"] < s["step_launch_ms"]
+            assert s["step_return_ms"] + 2 * s["step_return_dev_ms"] > s["step_launch_ms"]
+            assert holds() == (0, 0, 0)
+            return
+        if case == "slow_return":
+            # (b) the sessions take longer to come back than a launch
+            # lasts: nobody is waited for, and a group still closes
+            # only when the device frees
+            rig.warm(return_s=0.030)
+            assert rig.launched(3) == ["s0"]  # at once, alone
+            rig.send("s1")
+            rig.send("s2")
+            rig.never_launched(4, for_s=0.15)
+            rig.land(3, ["s0"])
+            assert rig.launched(4) == ["s1", "s2"]
+            s = rig.chan.stats()
+            assert holds() == (0, 0, 0) and s["step_hold_s"] == 0.0
+            assert s["step_launch_ms"] == pytest.approx(T * 1e3)
+            assert s["step_return_ms"] > s["step_launch_ms"]
+            return
+        rig.warm(return_s=0.005, ends=("s3",) if case == "sequence_end" else ())
+        # the device is free and s0 is ready; s1-s3 were answered this
+        # moment, and a session is back in 5 ms where a launch takes 20
+        assert rig.chan.stats()["step_return_ms"] == pytest.approx(5.0)
+        assert rig.chan.stats()["step_launch_ms"] == pytest.approx(T * 1e3)
+        rig.never_launched(3)
+        if case == "all_back":
+            # (a) four to a launch, and the counters say so
+            rig.after(0.004)
+            for sid in ("s1", "s2", "s3"):
+                rig.send(sid)
+            assert rig.launched(3) == ["s0", "s1", "s2", "s3"]
+            assert holds() == (1, 3, 0)
+            assert rig.chan.stats()["step_hold_s"] == pytest.approx(0.004)
+            # and again: the closed loop stays at four a launch
+            rig.land(3, ["s0", "s1", "s2", "s3"])
+            rig.after(0.005)
+            for sid in ("s0", "s1", "s2", "s3"):
+                rig.send(sid)
+            assert rig.launched(4) == ["s0", "s1", "s2", "s3"]
+            assert holds() == (2, 6, 0)
+        elif case == "never_back":
+            # (c) s3 never comes back: the wait ends one launch's time
+            # after the device went free, and s3 is expected no more
+            rig.after(0.004)
+            rig.send("s1")
+            rig.send("s2")
+            rig.never_launched(3)
+            rig.after(T - 0.004 + 0.001)
+            assert rig.launched(3) == ["s0", "s1", "s2"]
+            assert holds() == (1, 2, 1)
+            assert rig.chan.stats()["step_hold_s"] == pytest.approx(T + 0.001)
+            rig.land(3, ["s0", "s1", "s2"])
+            rig.after(0.005)
+            rig.send("s0")
+            # two launches after its answer s3 counts as a return of
+            # that length and is forgotten; a session that stayed away
+            # says that not everybody is back in time, so for the next
+            # few returns nobody is waited for
+            assert rig.launched(4) == ["s0"]
+            assert "s3" not in rig.chan._step_pace["m", ""].answered
+            s = rig.chan.stats()
+            assert s["step_return_ms"] + 2 * s["step_return_dev_ms"] > s["step_launch_ms"]
+            assert holds() == (1, 2, 1)
+        elif case == "other_key":
+            # (d) a many-token request of another session becomes
+            # ready: the wait ends at once, and the step that EDF put
+            # first is not overtaken
+            rig.after(0.002)
+            rig.send("s9", tokens=5)
+            assert rig.launched(3) == ["s0"]
+            rig.land(3, ["s0"])
+            assert rig.launched(4) == ["s9"]
+            assert rig.inner.launches[3].inputs["tokens"].shape == (1, 5)
+            assert holds() == (1, 0, 0)
+            assert rig.chan.stats()["step_hold_s"] == pytest.approx(0.002)
+        else:
+            # (e) s3's last step said sequence_end: only s1 and s2 are
+            # expected, and the second of them closes the group
+            rig.after(0.004)
+            rig.send("s1")
+            rig.never_launched(3)
+            rig.send("s2")
+            assert rig.launched(3) == ["s0", "s1", "s2"]
+            assert holds() == (1, 2, 0)
+    finally:
+        rig.close()
+
+
+def _reader_ctx(batching_before, batching_after, launches=(10, 74)):
+    sessions = lambda n: {"models": {"lm": {"lm_step_launches": n}}}
+    return {
+        "model": "lm",
+        "snapshot_before": {"batching": batching_before, "sessions": sessions(launches[0])},
+        "snapshot_after": {"batching": batching_after, "sessions": sessions(launches[1])},
+    }
+
+
+@pytest.mark.parametrize("case", ["engaged", "aside", "no_step_launch", "no_counters"])
+def test_the_reader_of_the_hold_counters(case, capsys):
+    """``benchmarks/layer_metrics/step_hold_ms.py``: ``step_hold_s``
+    over the window's step launches, in ms; the counters' growth and the
+    times the rule compares in the log; nothing from a program without them."""
+    import importlib
+    import json
+
+    reader = importlib.import_module("benchmarks.layer_metrics.step_hold_ms")
+    zero = {"step_holds": 0, "step_hold_s": 0.0, "step_hold_joined": 0, "step_hold_expired": 0}
+    before = {**zero, "step_holds": 8, "step_hold_s": 0.25, "step_hold_joined": 20}
+    if case == "engaged":
+        after = {
+            "step_holds": 72, "step_hold_s": 0.89, "step_hold_joined": 212,
+            "step_hold_expired": 1, "step_launch_ms": 21.0, "step_return_ms": 9.5,
+            "step_return_dev_ms": 2.25,
+        }
+        assert reader.read(_reader_ctx(before, after)) == pytest.approx(10.0)  # 640 ms over 64
+        (line,) = capsys.readouterr().out.splitlines()
+        assert json.loads(line) == {"step_hold": {
+            "step_holds": 64, "step_hold_s": pytest.approx(0.64), "step_hold_joined": 192,
+            "step_hold_expired": 1, "step_launch_ms": 21.0, "step_return_ms": 9.5,
+            "step_return_dev_ms": 2.25}}
+    elif case == "aside":
+        after = {**before, "step_launch_ms": 13.0, "step_return_ms": 26.0}
+        assert reader.read(_reader_ctx(before, after)) == 0.0
+    elif case == "no_step_launch":
+        assert reader.read(_reader_ctx(zero, zero, launches=(10, 10))) is None
+    else:
+        parent = {"merges": 5, "passthrough_groups": 5}
+        assert reader.read(_reader_ctx(parent, {**parent, "merges": 9})) is None
+        assert reader.read({}) is None
+        assert capsys.readouterr().out == ""

@@ -37,7 +37,12 @@ window and no admission thread:
       true size — zero pad rows beyond lane alignment.
     - *session steps*: the one-token steps of DIFFERENT sessions of a
       model that declares ``spec.extra["session_merge"]`` share one
-      launch, each row its own stream.
+      launch, each row its own stream. The one kind whose group does
+      NOT close the moment a slot frees: it stays open, absorbing
+      arrivals, while a launch is ahead of it on the device, and at a
+      free device it waits for the sessions that are about to come
+      back where that pays
+      (:meth:`ContinuousBatchingChannel._step_wait_locked`).
     - *solo*: a session frame (its state advances per stream and per
       frame), a lone ragged request, a lone step: the original request
       goes down as it came.
@@ -170,6 +175,57 @@ class LiveBuckets:
         return self._table
 
 
+def _running_mean(mean: float, sample: float) -> float:
+    """A mean that follows the last eight or so samples."""
+    return mean + (sample - mean) / 8.0
+
+
+class _StepPace:
+    """What the batcher has observed of ONE model's session steps: the
+    times that decide whether a step group waits at a free device
+    (:meth:`ContinuousBatchingChannel._step_wait_locked`). Guarded by
+    the batcher's ``_ready_cv``."""
+
+    __slots__ = ("launch_s", "return_s", "return_dev_s", "answered")
+
+    def __init__(self, launch_s: float) -> None:
+        # T: a step group's dispatch -> its answers handed back
+        self.launch_s = launch_s
+        # h: a step's answer -> the same session's next step staged (a
+        # session not back after two launches counts as two launches),
+        # and how far a return lies from that mean
+        self.return_s: float | None = None
+        self.return_dev_s = 0.0
+        # open sessions whose last request was a step: when it was
+        # answered
+        self.answered: dict[str, float] = {}
+
+    def returned(self, after_s: float) -> None:
+        """One more return observed: the mean and the mean deviation,
+        kept as a round-trip timer keeps them."""
+        if self.return_s is None:
+            self.return_s, self.return_dev_s = after_s, after_s / 2.0
+            return
+        self.return_dev_s += (abs(after_s - self.return_s) - self.return_dev_s) / 4.0
+        self.return_s = _running_mean(self.return_s, after_s)
+
+    def back_within_s(self) -> float:
+        """The time within which nearly every session is back: the mean
+        return and two mean deviations. A wait for the LAST of the
+        missing sessions pays only where this is under one launch (the
+        mean alone says when half of them are back)."""
+        return self.return_s + 2.0 * self.return_dev_s
+
+    def forget_stale(self, now: float) -> None:
+        """Sessions that are not back after two launches: counted as a
+        return of that length, then forgotten (one that went away with
+        no ``sequence_end`` must not be remembered for ever)."""
+        limit = 2.0 * self.launch_s
+        for sid in [s for s, t in self.answered.items() if now - t >= limit]:
+            del self.answered[sid]
+            self.returned(limit)
+
+
 class ContinuousBatchingChannel(BaseChannel):
     """Windowless EDF scheduler: dense, ragged and session-step merges
     (see module docstring)."""
@@ -219,7 +275,16 @@ class ContinuousBatchingChannel(BaseChannel):
         time. When the inner channel exposes a ``pipeline_depth``
         staging knob (TPUChannel), it is aligned to this batcher's
         depth so the channel's staging slots provide the device-side
-        backpressure."""
+        backpressure.
+
+        When a group closes: a pass-through, dense, ragged or solo
+        group the moment a permit frees, which is up to
+        ``pipeline_depth`` launches before it runs; that look-ahead is
+        what hides a large group's host->device transfer. A group of
+        SESSION STEPS ships four bytes a session, so it stays open
+        instead (:meth:`_step_wait_locked`): while any group formed
+        before it has not resolved, and then, at a free device, for the
+        sessions that are about to come back."""
         self._inner = inner
         self._capacity = max(1, int(capacity))
         self._ids = itertools.count(1)
@@ -265,7 +330,24 @@ class ContinuousBatchingChannel(BaseChannel):
             # (one member, no pad rows), and the bytes of the buffers
             # the batcher did build for all the others
             "passthrough_groups": 0, "merged_bytes": 0,
+            # step groups that waited at a FREE device for sessions
+            # about to come back, the seconds so waited, the sessions
+            # that came in meanwhile, and the waits that ran out
+            "step_holds": 0, "step_hold_s": 0.0,
+            "step_hold_joined": 0, "step_hold_expired": 0,
         }
+        # groups formed and not yet resolved (answers handed back or
+        # failed): 0 means no launch is ahead of the next group on the
+        # device; when it last fell to 0, and when it last changed
+        self._launches_ahead = 0
+        self._device_free_t = time.perf_counter()
+        self._ahead_changed_t = self._device_free_t
+        # (model, version) -> _StepPace; the wait in progress at a free
+        # device: (began, the steps' key, its members in the ready set
+        # then)
+        self._step_pace: dict = {}
+        self._last_pace: _StepPace | None = None
+        self._hold: tuple | None = None
         self._ragged_stats = {
             "ragged_batches": 0,
             "ragged_segments": 0,
@@ -454,13 +536,31 @@ class ContinuousBatchingChannel(BaseChannel):
                 raise QueueFullError(
                     f"model '{request.model_name}': inference queue full"
                 )
+            now = time.perf_counter()
+            if request.sequence_id:
+                self._observe_session_request_locked(request, session_step, now)
             bisect.insort(
                 self._ready,
-                (key, size, request, future, time.perf_counter()),
+                (key, size, request, future, now),
                 key=self._edf_key,
             )
             self._ready_cv.notify()
         return future.result()
+
+    def _observe_session_request_locked(
+        self, request: InferRequest, is_step: bool, now: float
+    ) -> None:
+        """A session's request reaches the ready set: if its last one
+        was a step, how long it took to come back (``_StepPace.return_s``;
+        capped at two launches, where :meth:`_StepPace.forget_stale`
+        would have counted it). Either way the session is no longer one
+        to wait for: it is here, or its next answer is no step's."""
+        pace = self._step_pace.get((request.model_name, request.model_version))
+        if pace is None:
+            return
+        answered = pace.answered.pop(request.sequence_id, None)
+        if is_step and answered is not None:
+            pace.returned(min(now - answered, 2.0 * pace.launch_s))
 
     # -- dispatch (forms the device batch when a slot frees) ------------------
 
@@ -515,38 +615,57 @@ class ContinuousBatchingChannel(BaseChannel):
             poll = max(0.25, self.stall_threshold_s / 4.0)
 
     def _dispatch_once(self) -> bool:
-        """One dispatcher slot: acquire a permit, form a group, submit.
+        """One dispatcher slot: acquire a permit, form the EDF head's
+        group, submit. A group of any kind but session steps closes
+        here the moment the permit is held and something is ready; a
+        group of session steps closes when :meth:`_step_wait_locked`
+        says so (no launch ahead of it, and nobody worth waiting for),
+        and keeps absorbing arrivals in the ready set until then.
         Returns True when the loop should exit (close() requested and
-        the ready set is drained). Any unexpected error fails the
-        formed group's futures, releases the permit, and re-raises for
-        the loop to log — the thread itself survives."""
+        the ready set is drained: close() waits for nobody). Any
+        unexpected error fails the formed group's futures, releases the
+        permit, and re-raises for the loop to log — the thread itself
+        survives."""
         self._beat()
         self._inflight.acquire()
         self._beat()
         group = None
         try:
             with self._ready_cv:
-                while not self._ready and not self._dispatch_stop:
-                    self._ready_cv.wait(timeout=0.1)
-                    # idle is progress: only a dispatcher that cannot
-                    # reach this loop (wedged on the permit semaphore or
-                    # a hung group) lets the heartbeat go stale
-                    self._beat()
+                while not self._dispatch_stop:
+                    wait_s = self._head_wait_locked()
+                    if wait_s is None:
+                        break
+                    self._ready_cv.wait(timeout=min(wait_s, 0.1))
+                    # idle is progress, and so is an open step group
+                    # while the launches ahead of it keep resolving:
+                    # only a dispatcher that cannot reach this loop
+                    # (wedged on the permit semaphore or a hung group),
+                    # or one whose steps wait behind a launch that has
+                    # not moved for a whole threshold, lets the
+                    # heartbeat go stale
+                    if not (
+                        self._ready
+                        and self._launches_ahead
+                        and time.perf_counter() - self._ahead_changed_t
+                        >= self.stall_threshold_s
+                    ):
+                        self._beat()
                 if self._ready:
                     group = self._form_group_locked()
                     self._merge_stats["merges"] += 1
                     frames = sum(it[1] for it in group)
                     self._merge_stats["merged_frames"] += frames
                     self._merge_occupancy[frames] += 1
+                    self._active_slots += 1
+                    self._launches_ahead += 1
+                    self._ahead_changed_t = time.perf_counter()
                 elif self._dispatch_stop:
                     self._inflight.release()
                     return True
             if group is None:
                 self._inflight.release()
                 return False
-
-            with self._ready_cv:
-                self._active_slots += 1
 
             def run(g=group, t_submit=time.perf_counter()):
                 t_run = time.perf_counter()
@@ -597,12 +716,14 @@ class ContinuousBatchingChannel(BaseChannel):
                             it[3].set_exception(e)
                 finally:
                     free_slot()
+                    self._group_resolved(g, t_run)
 
             try:
                 self._exec.submit(run)
             except RuntimeError as e:  # executor shut down mid-close
                 with self._ready_cv:
                     self._active_slots -= 1
+                    self._launches_ahead -= 1
                 self._inflight.release()
                 for it in group:
                     if not it[3].done():
@@ -645,6 +766,123 @@ class ContinuousBatchingChannel(BaseChannel):
         if self._tenant_table is not None:
             self._charge_tenants_locked(group)
         return group
+
+    # -- when a group of session steps closes ----------------------------------
+
+    def _head_wait_locked(self) -> float | None:
+        """Seconds until the dispatcher should look again, or None: form
+        the EDF head's group now. Only a head that is a session step
+        ever waits with something ready; the wait at a FREE device is
+        what ``step_holds`` and its three companions count."""
+        if not self._ready:
+            return 0.1
+        key = self._ready[0][0]
+        now = time.perf_counter()
+        wait_s, missing = None, 0
+        if key[0] == "__session_step__":
+            if self._launches_ahead:
+                # a launch is ahead on the device: closing now would fix
+                # the group's members one launch early and buy nothing
+                # (a step ships four bytes a session); its resolution
+                # notifies
+                return 0.1
+            wait_s, missing = self._step_wait_locked(key, now)
+        hold = self._hold
+        if hold is not None and (wait_s is None or hold[1] != key):
+            # the wait is over: the last one came in, the time ran out
+            # (somebody is still missing), or other work became ready
+            began, step_key, members_then = hold
+            self._hold = None
+            self._merge_stats["step_holds"] += 1
+            self._merge_stats["step_hold_s"] += now - began
+            self._merge_stats["step_hold_joined"] += (
+                self._members_locked(step_key) - members_then
+            )
+            if missing and step_key == key:
+                self._merge_stats["step_hold_expired"] += 1
+        if wait_s is not None and self._hold is None:
+            self._hold = (now, key, self._members_locked(key))
+        return wait_s
+
+    def _members_locked(self, key) -> int:
+        return sum(1 for it in self._ready if it[0] == key)
+
+    def _step_wait_locked(self, key, now: float) -> tuple[float | None, int]:
+        """The device is free and the EDF head is a session step of the
+        model of ``key``: ``(seconds to wait, sessions still missing)``,
+        seconds None where the group closes now.
+
+        The group waits only where a wait buys whole launches. A session
+        is EXPECTED while it is open, its last request was a step, and
+        less than ``T`` (the model's mean step launch) has passed since
+        that answer. Where nothing else is ready for the device and the
+        sessions come back within one launch's time (their mean return
+        ``h`` and two mean deviations are under ``T``:
+        :meth:`_StepPace.back_within_s`), the group waits for the
+        expected sessions that are missing from it: until the last is in
+        or stops being expected, or ``T`` after the device went free,
+        whichever is first. So the wait comes out of the device's idle
+        time alone, costs at most the one launch a joining session
+        saves, and lets nobody overtake: the head stays the head. All
+        three times are the batcher's own observations
+        (:class:`_StepPace`). A model whose sessions take about as long
+        to come back as a launch lasts (many callers on one interpreter:
+        returns of 14-38 ms around a launch of 24) is never waited for:
+        there the last of a launch's sessions is not back in time, every
+        wait would run out, and a wait that runs out costs the sessions
+        that were ready a launch's time for nothing."""
+        pace = self._step_pace.get(key[1:])
+        if pace is None:
+            return None, 0
+        pace.forget_stale(now)
+        if pace.return_s is None or pace.back_within_s() >= pace.launch_s:
+            return None, 0
+        here = set()
+        for it in self._ready:
+            if it[0] != key:
+                return None, 0  # something else is ready for the device
+            here.add(it[2].sequence_id)
+        # expected when this wait began (now, if none has)
+        hold = self._hold
+        began = hold[0] if hold is not None and hold[1] == key else now
+        missing = [
+            t
+            for sid, t in pace.answered.items()
+            if sid not in here and began - t < pace.launch_s
+        ]
+        if not missing or len(here) >= self._max_merge:
+            return None, 0
+        wait_s = min(self._device_free_t, max(missing)) + pace.launch_s - now
+        return (wait_s if wait_s > 0 else None), len(missing)
+
+    def _group_resolved(self, group, t_run: float) -> None:
+        """A formed group is off the device: its answers are handed back
+        (or it failed). One launch fewer is ahead of the next group, and
+        a group of session steps leaves behind how long it took and
+        which sessions may come back for more."""
+        key = group[0][0]
+        with self._ready_cv:
+            now = time.perf_counter()
+            self._launches_ahead -= 1
+            self._ahead_changed_t = now
+            if self._launches_ahead == 0:
+                self._device_free_t = now
+            answered = key[0] == "__session_step__" and [
+                it[2]
+                for it in group
+                if it[3].done() and it[3].exception() is None
+            ]
+            if answered:
+                pace = self._step_pace.get(key[1:])
+                if pace is None:
+                    pace = self._step_pace[key[1:]] = _StepPace(now - t_run)
+                else:
+                    pace.launch_s = _running_mean(pace.launch_s, now - t_run)
+                for request in answered:
+                    if not request.sequence_end:
+                        pace.answered[request.sequence_id] = now
+                self._last_pace = pace
+            self._ready_cv.notify_all()
 
     def _pad_target(self, total: int) -> int:
         """Padded device-batch size for a merged total: the live
@@ -1156,6 +1394,14 @@ class ContinuousBatchingChannel(BaseChannel):
                     self._decomp["member_wait_s"] / members * 1e3, 2
                 )
                 out["merge_members"] = int(members)
+            pace = self._last_pace
+            if pace is not None:
+                # the times behind step_holds, of the model whose step
+                # group resolved last
+                out["step_launch_ms"] = round(pace.launch_s * 1e3, 3)
+                if pace.return_s is not None:
+                    out["step_return_ms"] = round(pace.return_s * 1e3, 3)
+                    out["step_return_dev_ms"] = round(pace.return_dev_s * 1e3, 3)
             out["scheduler"] = "continuous"
             out.update(self._ragged_stats)
             if self._live_buckets is not None:
