@@ -206,7 +206,7 @@ def main(argv=None) -> int:
     del cache, index, rows, q_i, keys_i, q_nope, q_rope
     weights = jax.jit(lambda k: axk1.stack_layers(axk1.init_params(k, cfg), cfg))(key)
     state = axk1.empty_cache(cfg, 8, slot_len)
-    fn = lm.make_device_fn(cfg)
+    fn = lm.make_device_fn(axk1, cfg)
     program = jax.jit(lambda i, w, s: fn(i, {"weights": w, "cache": s}), donate_argnums=(2,))
     rng = np.random.default_rng(0)
 

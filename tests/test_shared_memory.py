@@ -489,3 +489,38 @@ def test_bf16_tensor_through_shm_region():
             parsed["x"].view(np.uint16), arr.view(np.uint16)
         )
         reg.unregister_all()
+
+
+def test_output_windows_stay_inside_the_arena_when_a_size_is_learned_meanwhile():
+    """Several callers share one channel and the model's answers differ
+    in rows (a block model: ``[1, V]`` for an extend, ``[4, V]`` for a
+    block): another caller's response may teach the channel a larger
+    size between the arena's sizing and the windows. Every window asked
+    for lies inside the arena that was sized for it."""
+    chan = GRPCChannel("127.0.0.1:1", use_shared_memory=True)
+    chan._learned_out["m"] = {"logits": 1024, "aux": 100}
+    arenas = []
+
+    class _Arena:
+        key = "/arena_g0"
+
+        def __init__(self, size):
+            self.size = size
+
+    class _Slot:
+        def region_for(self, name, nbytes):
+            # what another caller's ``_parse_shm_response`` does meanwhile
+            chan._learned_out["m"]["logits"] = 4096
+            arenas.append(_Arena(nbytes))
+            return arenas[-1]
+
+    wire = pb.ModelInferRequest(model_name="m")
+    chan._request_shm_outputs(wire, _Slot(), "m")
+    assert len(wire.outputs) == 2
+    for t in wire.outputs:
+        region, offset, nbytes = codec.shm_params(t)
+        assert region == "arena_g0" and offset + nbytes <= arenas[0].size
+    # the next request sizes its arena for what was learned
+    wire = pb.ModelInferRequest(model_name="m")
+    chan._request_shm_outputs(wire, _Slot(), "m")
+    assert arenas[1].size >= 4096 + 100

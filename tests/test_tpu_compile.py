@@ -180,6 +180,74 @@ def test_sparse_selection_kernels_lower_at_the_served_slot(one_chip):
     assert text.count("tpu_custom_call") >= 3
 
 
+def _sdar_launch(one_chip, model: dict, slots: int, slot_len: int, launch: dict):
+    """One launch shape of ``family: sdar_moe`` compiled as
+    ``ParamLauncher`` launches it: weights and cache as arguments, the
+    cache donated and row-major on both sides. Returns the executable's
+    text and the configuration."""
+    from jax.experimental.layout import Format, Layout
+    from triton_client_tpu.models import sdar
+    from triton_client_tpu.pipelines import lm
+
+    cfg = sdar.SDARConfig.from_dict(model)
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    weights = placed(jax.eval_shape(lambda: sdar.stack_layers(sdar.init_params(jax.random.PRNGKey(0), cfg), cfg)))
+    cache = placed(jax.eval_shape(lambda: sdar.empty_cache(cfg, slots, slot_len)))
+    row_major = jax.tree_util.tree_map(
+        lambda x: Format(Layout(major_to_minor=tuple(range(x.ndim))), one_chip), cache)
+    ((kind, size),) = launch.items()
+    inputs = placed({k: jnp.asarray(v) for k, v in lm.launch_inputs(kind, size, cfg.block_length).items()})
+    device_fn = lm.make_device_fn(sdar, cfg)
+
+    def run(inputs, weights, cache):
+        out = dict(device_fn(inputs, {"weights": weights, lm.STATE_KEY: cache}))
+        return out, out.pop(lm.STATE_KEY)
+
+    return jax.jit(
+        run, donate_argnums=(2,), in_shardings=(None, None, row_major), out_shardings=(None, row_major),
+    ).lower(inputs, weights, cache).compile().as_text(), cfg
+
+
+def _sdar_config() -> dict:
+    import json
+    import pathlib
+
+    return json.loads(
+        (pathlib.Path(__file__).resolve().parents[1] / "benchmarks/configs/sdar30b-ep8-l48.json").read_text())
+
+
+@pytest.mark.parametrize("launch", ({"extend": 16}, {"extend": 32}, {"block": 8}))
+def test_sdar_launch_kinds_lower_at_the_tiny_preset(one_chip, launch):
+    """The launch shapes of the benchmark configuration's rehearsal: an
+    extend of whole blocks at two buckets and a block launch of
+    denoising and committing rows."""
+    doc = _sdar_config()
+    model = {**doc["model"], **doc["rehearsal"]["model"]}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _sdar_launch(one_chip, model, 8, slot_len, launch)
+    ((kind, size),) = launch.items()
+    rows = size * cfg.block_length if kind == "block" else 1
+    assert f"f32[{rows},{cfg.vocab_size}]" in text
+
+
+@pytest.mark.parametrize("launch", ({"extend": 2048}, {"block": 16}))
+def test_sdar_launches_update_the_served_cache_in_place(one_chip, launch):
+    """At the served widths (two layers of the 48) the cache keeps its
+    row-major layout through the layer scan: no launch begins or ends
+    with a copy of it. With the key/value heads on an axis of their own
+    the compiler laid the cache out with the heads minor and copied all
+    of it, 4 GB at the served depth, at both ends of every launch."""
+    model = {**_sdar_config()["model"], "num_hidden_layers": 2}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _sdar_launch(one_chip, model, 20, slot_len, launch)
+    whole = f"bf16[2,20,{slot_len},{cfg.num_key_value_heads * cfg.head_dim}]"
+    assert whole in text
+    assert not [line for line in text.splitlines() if f"= {whole}" in line and " copy(" in line]
+
+
 def test_nms_pallas_lowers(one_chip):
     from triton_client_tpu.ops.pallas_nms import nms_pallas
 

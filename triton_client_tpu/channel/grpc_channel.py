@@ -439,7 +439,10 @@ class GRPCChannel(BaseChannel):
         """Attach requested-output windows (learned sizes, cache-line
         aligned) in the slot's output arena. No-op until a first
         response has taught the channel this model's output sizes."""
-        sizes = self._learned_out.get(model_name)
+        # a copy: another caller's response may teach this channel a
+        # larger size between the arena's sizing and the windows below
+        # (a model whose answers differ in rows: a window past the arena)
+        sizes = dict(self._learned_out.get(model_name) or ())
         if not sizes:
             return
         offsets = {}

@@ -156,6 +156,9 @@ class AXK1Config:
         )
 
 
+Config = AXK1Config  # what pipelines/lm.py asks of a model module
+
+
 def _normal(key, shape, std):
     if len(shape) >= 3:
         return jax.lax.map(
@@ -235,26 +238,39 @@ def abstract_params(cfg: AXK1Config):
     return jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
 
 
-def stack_layers(tree: dict, cfg: AXK1Config) -> dict:
+def stack_layers(tree: dict, cfg, one_program: bool = False) -> dict:
     """The served form of a loaded tree: the dense layers as they are,
     the expert layers stacked leaf by leaf on a leading axis for the
     scan. TAKES the per-layer leaves out of ``tree`` (its ``layers`` is
     left empty) and drops each group of them as its stack is built, so
     at most one stacked leaf exists twice: beside 8.33 GB of weights a
-    second copy of the expert layers (6.75 GB) does not fit a chip."""
+    second copy of the expert layers (6.75 GB) does not fit a chip.
+    ``one_program``: each stack is ONE jitted program and is waited for.
+    An eager ``jnp.stack`` gives every part a leading axis first, a copy
+    a part, and concatenates those: three times the leaf beside the
+    tree, 7.25 GB for a 2.4 GB leaf of 48 layers (9.24 -> 16.49 GB of a
+    chip's 16.9: my chip run, PR 39). The families that came first keep
+    the eager form: their served peak is what ``benchmarks/run.py``
+    holds the yardstick's under, and with this form the missionlog cell
+    exits 4 (served 10.2 GB under the reference's 11.03: my chip run,
+    PR 39; PERF.md section 7): one form once ``references/dsv32.py``
+    is slimmer."""
     layers = tree["layers"]
     n_dense = cfg.first_k_dense_replace
     dense = [layers.pop(str(i)) for i in range(n_dense)]
     rest = [layers.pop(str(i)) for i in range(n_dense, cfg.num_hidden_layers)]
     stacked = None
     if rest:
+        stack = jax.jit(lambda *parts: jnp.stack(parts)) if one_program else (lambda *parts: jnp.stack(parts))
         flat = [jax.tree_util.tree_flatten(layer) for layer in rest]
         treedef = flat[0][1]
         columns = [list(leaves) for leaves, _ in flat]
         del rest, flat  # the columns alone hold the per-layer leaves now
         out = []
         for j in range(len(columns[0])):
-            out.append(jnp.stack([col[j] for col in columns]))
+            out.append(stack(*[col[j] for col in columns]))
+            if one_program:
+                jax.block_until_ready(out[-1])  # a tracer (shapes alone) has nothing to wait for
             for col in columns:
                 col[j] = None
         stacked = jax.tree_util.tree_unflatten(treedef, out)

@@ -1,0 +1,173 @@
+"""Grouped-query attention over a per-head key/value slot cache under a
+BLOCK mask: causal between blocks of ``block`` positions, bidirectional
+inside one (the attention of a model that generates by diffusion over
+blocks, models/sdar.py). A query at position ``p`` reads every key at a
+position ``s`` with ``s // block <= p // block``, that is, up to the end
+of its own block: the causal mask with the query's position rounded up.
+
+The cache is a dict of two arrays, keys ``k`` and values ``v``, each
+``[layers, slots, slot_len, kv_heads * head_dim]`` bfloat16: a position
+is ONE row of whole 128-lane tiles (512 values at 4 heads of 128), the
+layout of models/axk1.py's latent rows, which every launch program and a
+fresh array agree on without being told. (With the heads on an axis of
+their own, ``[..., kv_heads, slot_len, head_dim]``, the chip's compiler
+laid the array out with the heads minor for the scatter that writes a
+block's rows, and copied all 4 GB at both ends of every launch: my AOT
+compile, PR 39.) Query head ``j`` reads key/value head ``j // (heads /
+kv_heads)``. Two forms, one a launch kind:
+
+  * :func:`prefill_attention`: many new tokens of ONE session, already
+    written to its slot: blocks of queries against the blocks of keys up
+    to their last visible position, with a running softmax, the slot's
+    rows split by head once a layer (2 MB);
+  * :func:`block_attention`: ONE block of each of several sessions
+    against what their slots hold BEFORE the block plus the block itself,
+    which need not be in the cache (a denoising pass writes nothing).
+    Every slot of the layer is attended to IN PLACE, in one batched
+    product over the rows as they lie: the queries of key/value head
+    ``g`` are zero outside that head's 128 lanes of a row (a
+    block-diagonal query, four times the multiply-adds of a product a
+    head, in a launch that is bound by the bytes it reads), so nothing
+    of the cache is sliced, transposed or copied. The launch's rows are
+    scattered to their slots' places (a few KB); a slot without a row
+    attends with a zero query and is thrown away. Reading the launch's
+    slots one after another was a dynamic slice, a softmax and two
+    products a session a layer, 768 small programs a launch at 16
+    sessions and 48 layers; gathering them into one array copies each
+    slot first.
+
+Scores, softmax and the mask are float32; products read bfloat16.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+QUERY_BLOCK = 512
+KEY_BLOCK = 512
+
+
+def write_rows(kv, layer, slots, where, k, v):
+    """``k``, ``v`` ``[R, n, G, d]`` into the cache's layer ``layer`` at
+    positions ``where [R, n]`` of ``slots [R]``; a position past the
+    slot is dropped (a pad token, a row that writes nothing)."""
+    rows = lambda a: a.reshape(*a.shape[:2], -1).astype(kv["k"].dtype)
+    put = lambda cache, new: cache.at[layer, slots[:, None], where].set(rows(new), mode="drop")
+    return {"k": put(kv["k"], k), "v": put(kv["v"], v)}
+
+
+def write_span(kv, layer, slot, start, k, v):
+    """``k``, ``v`` ``[n, G, d]`` of ONE session into its slot from
+    position ``start`` on, as one contiguous update; returns the cache
+    and the slot's rows ``(keys [S, G * d], values [S, G * d])`` as they
+    now are. Pad tokens at the span's end are written too: positions
+    past a session's length are read by nobody before the request that
+    appends them has written them. The update goes through a copy of the
+    slot widened by the span, so that a padded span that runs past the
+    slot's end is cut there (a plain ``dynamic_update_slice`` would move
+    its start back instead)."""
+
+    def put(cache, new):
+        new = new.reshape(new.shape[0], -1).astype(cache.dtype)
+        s_len, width = cache.shape[2:]
+        rows = jax.lax.dynamic_slice(cache, (layer, slot, 0, 0), (1, 1, s_len, width))[0, 0]
+        wide = jnp.concatenate([rows, jnp.zeros_like(new)])
+        rows = jax.lax.dynamic_update_slice(wide, new, (start, 0))[:s_len]
+        return jax.lax.dynamic_update_slice(cache, rows[None, None], (layer, slot, 0, 0)), rows
+
+    keys, key_rows = put(kv["k"], k)
+    values, value_rows = put(kv["v"], v)
+    return {"k": keys, "v": values}, (key_rows, value_rows)
+
+
+def prefill_attention(q, rows, positions, block: int, scale: float):
+    """``q [n, H, d]`` (n new tokens of one session, ``positions [n]``
+    ascending by one), ``rows`` its slot's ``(keys, values)`` ``[S, G *
+    d]`` with the new tokens written. Returns ``[n, H * d]``.
+
+    For each block of queries only the key blocks up to its last visible
+    position (a loop whose length the positions decide): a prompt of 512
+    tokens reads 512 keys, not the slot's 2,048."""
+    n, h, d = q.shape
+    s_len, g = rows[0].shape[0], rows[0].shape[1] // d
+    r = h // g
+    qb, kb = min(n, QUERY_BLOCK), math.gcd(s_len, KEY_BLOCK)
+    assert n % qb == 0, "the launch's tokens fill whole query blocks"
+    last_visible = (positions // block + 1) * block - 1
+    keys, values = (jnp.moveaxis(a.reshape(s_len, g, d), 0, 1) for a in rows)  # [G, S, d]
+
+    def one(args):
+        qq, limit = args  # [qb, H, d], [qb]
+        # a key/value head's queries side by side: rows (head in group, query)
+        qq = jnp.moveaxis(qq.reshape(qb, g, r, d), 0, 2).reshape(g, r * qb, d)
+        limit_rows = jnp.tile(limit, r)
+
+        def take(j, carry):
+            top, total, acc = carry
+            lo = j * kb
+            scores = jnp.einsum(
+                "gqd,gkd->gqk", qq, jax.lax.dynamic_slice_in_dim(keys, lo, kb, axis=1),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            keep = (lo + jnp.arange(kb))[None, None, :] <= limit_rows[None, :, None]
+            scores = jnp.where(keep, scores, -jnp.inf)
+            new_top = jnp.maximum(top, scores.max(axis=-1))
+            w = jnp.exp(scores - new_top[..., None])
+            shrink = jnp.exp(top - new_top)
+            acc = acc * shrink[..., None] + jnp.einsum(
+                "gqk,gkd->gqd", w.astype(values.dtype),
+                jax.lax.dynamic_slice_in_dim(values, lo, kb, axis=1),
+                preferred_element_type=jnp.float32,
+            )
+            return new_top, total * shrink + w.sum(axis=-1), acc
+
+        # key 0 is visible to every query, so the first block leaves no row empty
+        blocks = jnp.clip(limit[-1] // kb + 1, 1, s_len // kb)
+        state = (
+            jnp.full((g, r * qb), -1e30, jnp.float32),
+            jnp.zeros((g, r * qb), jnp.float32),
+            jnp.zeros((g, r * qb, d), jnp.float32),
+        )
+        _, total, acc = jax.lax.fori_loop(0, blocks, take, state)
+        out = (acc / total[..., None]).reshape(g, r, qb, d)
+        return jnp.moveaxis(out, 2, 0).reshape(qb, h * d).astype(values.dtype)
+
+    split = lambda a: a.reshape(n // qb, qb, *a.shape[1:])
+    return jax.lax.map(one, (split(q), split(last_visible))).reshape(n, h * d)
+
+
+def block_attention(q, k, v, kv, layer, slots, positions, scale: float):
+    """``q [R, B, H, d]``, ``k``, ``v`` ``[R, B, G, d]``: one block of B
+    positions of each of R sessions, in slots ``slots [R]`` (a pad row's
+    is the slot COUNT: it is dropped), the block's first position
+    ``positions [R]`` = the positions cached before it. Each row reads
+    the ``positions[r]`` cached positions of its slot and its own block
+    (whether or not the launch has written it). Returns ``[R, B, H * d]``."""
+    rows, b, h, d = q.shape
+    g = k.shape[2]
+    r = h // g
+    n_slots, s_len = kv["k"].shape[1:3]
+    dtype = kv["k"].dtype
+    layer_of = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)  # [slots, S, G * d]
+    # the queries of key/value head g, rows (g, head in group, position in block), zero outside g's lanes
+    qs = jnp.moveaxis(q.reshape(rows, b, g, r, d), 1, 3)  # [R, G, r, B, d]
+    qs = (qs[:, :, :, :, None, :] * jnp.eye(g, dtype=q.dtype)[:, None, None, :, None]).reshape(rows, g * r * b, g * d)
+    place = lambda a: jnp.zeros((n_slots, *a.shape[1:]), a.dtype).at[slots].set(a, mode="drop")
+    own = lambda a: place(a.reshape(rows, b, g * d).astype(dtype))
+    qs, own_k, own_v, cached = place(qs), own(k), own(v), place(positions)
+    scores = jnp.einsum("sqc,skc->sqk", qs, layer_of(kv["k"]), preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(jnp.arange(s_len) < cached[:, None, None], scores, -jnp.inf)
+    mine = jnp.einsum("sqc,skc->sqk", qs, own_k, preferred_element_type=jnp.float32) * scale
+    top = jnp.maximum(scores.max(axis=-1), mine.max(axis=-1))[..., None]
+    w, w_mine = jnp.exp(scores - top), jnp.exp(mine - top)
+    total = w.sum(axis=-1) + w_mine.sum(axis=-1)
+    out = jnp.einsum(
+        "sqk,skc->sqc", w.astype(dtype), layer_of(kv["v"]), preferred_element_type=jnp.float32
+    ) + jnp.einsum("sqk,skc->sqc", w_mine.astype(dtype), own_v, preferred_element_type=jnp.float32)
+    out = (out / total[..., None])[jnp.minimum(slots, n_slots - 1)]  # [R, G * r * B, G * d]
+    # a row of key/value head g keeps that head's lanes of its output
+    out = jnp.einsum("rgqhd,gh->rgqd", out.reshape(rows, g, r * b, g, d), jnp.eye(g, dtype=out.dtype))
+    return jnp.moveaxis(out.reshape(rows, g, r, b, d), 3, 1).reshape(rows, b, h * d).astype(dtype)

@@ -27,19 +27,19 @@ DENSE_TOKENS = 64
 
 
 def route(x, router, top_k: int, scale: float, normalise: bool = True,
-          bias=None, n_group: int = 1, topk_group: int = 1):
-    """Sigmoid scores in float32 over all experts: the ``top_k``
-    largest and their gates ``scale * s_i / sum s_j``. With ``bias``
+          bias=None, n_group: int = 1, topk_group: int = 1, softmax: bool = False):
+    """Sigmoid scores in float32 over all experts (``softmax``: a
+    softmax over them, the Qwen3-MoE router of ``family: sdar_moe``): the
+    ``top_k`` largest and their gates ``scale * s_i / sum s_j``. With ``bias``
     (``topk_method: noaux_tc``) the choice is made by ``s + bias`` and
     only inside the ``topk_group`` best of ``n_group`` groups of experts
     (a group's score: the sum of its two largest ``s + bias``); the
     gates are still the chosen experts' ``s``."""
-    scores = jax.nn.sigmoid(
-        jnp.dot(
-            x.astype(jnp.float32), router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,  # a TPU's float32 product is bfloat16 passes unless told
-        )
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,  # a TPU's float32 product is bfloat16 passes unless told
     )
+    scores = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(logits)
     if bias is None:
         top, idx = jax.lax.top_k(scores, top_k)
     else:

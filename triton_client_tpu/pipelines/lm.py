@@ -2,11 +2,14 @@
 
 Builds the served form of a ``family: axk1`` or ``family: deepseek_v32``
 repository entry (one model module for both, models/axk1.py: what an
-entry's ``model`` block switches on decides, not the family's name):
+entry's ``model`` block switches on decides, not the family's name) or
+of a ``family: sdar_moe`` entry (models/sdar.py: grouped-query attention
+under a block mask, generation by diffusion over blocks):
 the device program ``device_fn(inputs, params)``, the
 ``params`` it takes as launcher ARGUMENTS (``weights`` and, under
 ``cache``, the latent cache, with an indexer a dict of it and the index
-keys, that the channel donates into each launch and takes back from its
+keys, for ``sdar_moe`` the keys and values a head, that the channel
+donates into each launch and takes back from its
 outputs: gigabytes of weights cannot be constants of an HLO module, and
 the cache never crosses to the host),
 and the session state the model declares
@@ -21,6 +24,30 @@ operation, extend. A session may be fed in several many-token requests
 session pads ``n`` to :func:`token_bucket`; the one-token requests of
 up to ``max_batch_size`` DIFFERENT sessions merge into a step launch,
 padded to :func:`step_bucket`. Sampling is the client's.
+
+The contract of a BLOCK session (``family: sdar_moe``; B is the entry's
+``block_length``). Three request forms under a ``sequence_id``:
+
+  * extend: ``tokens`` int32 ``[1, n]``, ``n`` a multiple of B, appended
+    to the session's cache under the block mask (empty on
+    ``sequence_start``); answers ``logits`` float32 ``[1, vocab]`` of
+    the last appended position. ONE session a launch, as above;
+  * denoise: ``tokens`` int32 ``[1, B]`` (the block as the client holds
+    it: revealed ids, the ``[MASK]`` id elsewhere) beside ``commit``
+    int32 ``[1, 1]`` = 0. The block is run at the session's length
+    against the cache and itself; answers ``logits`` float32 ``[B,
+    vocab]``, a row a position of the block: the model's belief about
+    the token AT that position. Cache and length stay as they were;
+  * commit: the same with ``commit`` = 1: the same answer, and the
+    block's keys and values are written: the length moves by B.
+
+This family has no one-token step. The block requests of up to
+``max_batch_size`` DIFFERENT sessions, denoise and commit rows mixed,
+merge into one launch (``tokens [S, B]``, padded to
+:func:`step_bucket`), as one-token steps do. Refused, with the reason:
+an extend of ``n mod B != 0``; a block request of another width than B,
+or of a session whose length is no multiple of B. Which positions a pass
+reveals, and in how many passes, is the client's, as sampling is.
 
 Everything is read from the entry's ``config.yaml``: ``model`` (the
 published sizes and this chip's share, ``precision``), ``pipeline``
@@ -37,7 +64,7 @@ import numpy as np
 
 from triton_client_tpu.channel.base import InferRequest
 from triton_client_tpu.config import ModelSpec, TensorSpec
-from triton_client_tpu.models import axk1
+from triton_client_tpu.models import axk1, sdar
 from triton_client_tpu.runtime import precision as precision_policy
 from triton_client_tpu.runtime.repository import RegisteredModel
 from triton_client_tpu.runtime.sessions import TokenSessions
@@ -65,18 +92,22 @@ def step_bucket(sessions: int, slots: int) -> int:
     return min(rows, max(slots, sessions))
 
 
-def launch_inputs(kind: str, size: int) -> dict:
+def launch_inputs(kind: str, size: int, block: int = 0) -> dict:
     """One launch's plain arrays at a launch shape, all rows pad (they
     write nothing): ``kind`` ``"extend"`` with ``size`` tokens of one
-    session, or ``"step"`` with ``size`` sessions. What compiles a shape
+    session, ``"step"`` with ``size`` sessions, or ``"block"`` with
+    ``size`` sessions' blocks of ``block`` tokens. What compiles a shape
     ahead of traffic sends this straight to the device channel."""
-    rows, width = (1, size) if kind == "extend" else (size, 1)
-    return {
+    rows, width = {"extend": (1, size), "step": (size, 1), "block": (size, block)}[kind]
+    launch = {
         "tokens": np.zeros((rows, width), np.int32),
         "slots": np.zeros(rows, np.int32),
         "positions": np.zeros(rows, np.int32),
         "lengths": np.zeros(rows, np.int32),
     }
+    if kind == "block":
+        launch[TokenSessions.COMMIT] = np.zeros(rows, np.int32)
+    return launch
 
 
 def read_weights(path, template, put=None) -> dict:
@@ -126,19 +157,32 @@ def _int8_rounded(w):
     return precision_policy.fake_quant_channelwise(w, contract_axis=-2)
 
 
+#: family -> the model module that serves it: its ``Config``,
+#: ``init_params``, ``abstract_params``, ``stack_layers``, ``empty_cache``
+#: and ``extend`` (models/sdar.py also has ``block``). The ONE place a
+#: family is tied to a module; runtime/disk_repository.py reads its keys
+MODULES = {"axk1": axk1, "deepseek_v32": axk1, "sdar_moe": sdar}
+
+
 @functools.lru_cache(maxsize=None)
-def make_device_fn(cfg: axk1.AXK1Config):
-    """``device_fn(inputs, params)``: one extend launch. The cache rides
-    in ``params`` and comes back among the outputs under the same key.
-    ONE function a configuration: an entry built again in the same
-    process (a reload, a dozen seeds of one rehearsal) finds what jax
-    traced and compiled for the first."""
+def make_device_fn(model, cfg):
+    """``device_fn(inputs, params)`` of a configuration of the module
+    ``model``: one launch, an extend or, where the inputs carry
+    ``commit`` (a block model's), a block launch. The cache rides in
+    ``params`` and comes back among the outputs under the same key. ONE
+    function a configuration: an entry built again in the same process
+    (a reload, a dozen seeds of one rehearsal) finds what jax traced and
+    compiled for the first."""
 
     def device_fn(inputs, params):
-        logits, expert_rows, kv = axk1.extend(
+        args = (
             cfg, params["weights"], params[STATE_KEY], inputs["tokens"],
             inputs["slots"], inputs["positions"], inputs["lengths"],
         )
+        if TokenSessions.COMMIT in inputs:
+            logits, expert_rows, kv = model.block(*args, inputs[TokenSessions.COMMIT])
+        else:
+            logits, expert_rows, kv = model.extend(*args)
         return {"logits": logits, TokenSessions.EXPERT_ROWS: expert_rows, STATE_KEY: kv}
 
     return device_fn
@@ -148,7 +192,11 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
     """The entry as the repository registers it."""
     model_doc = dict(doc.get("model", {}))
     policy = precision_policy.PrecisionPolicy.parse(model_doc.pop("precision", "bf16"))
-    cfg = axk1.AXK1Config.from_dict(model_doc)
+    family = doc.get("family", "axk1")
+    model = MODULES[family]
+    cfg = model.Config.from_dict(model_doc)
+    block = getattr(cfg, "block_length", 0)  # the tokens a block request carries; 0: one-token steps
+    index_topk = getattr(cfg, "index_topk", 0)
     pipe = dict(doc.get("pipeline", {}))
     slots = int(doc.get("max_batch_size", 8))
     slot_len = int(pipe.get("slot_len", 4352))
@@ -160,29 +208,29 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
             _int8_rounded(jax.device_put(leaf)) if leaf.ndim >= 2 else jax.device_put(leaf)
         )
     if weights is not None:
-        tree = read_weights(weights, axk1.abstract_params(cfg), put)
+        tree = read_weights(weights, model.abstract_params(cfg), put)
     else:
         tree = jax.tree_util.tree_map(
-            put, jax.jit(lambda k: axk1.init_params(k, cfg))(jax.random.PRNGKey(0))
+            put, jax.jit(lambda k: model.init_params(k, cfg))(jax.random.PRNGKey(0))
         )
     params = {
-        "weights": axk1.stack_layers(tree, cfg),
-        STATE_KEY: axk1.empty_cache(cfg, slots, slot_len),
+        "weights": model.stack_layers(tree, cfg),
+        STATE_KEY: model.empty_cache(cfg, slots, slot_len),
     }
     del tree
 
     index_cache_bytes = (
-        params[STATE_KEY]["index"].nbytes if cfg.index_topk else 0
+        params[STATE_KEY]["index"].nbytes if index_topk else 0
     )
     sessions = TokenSessions(
         slots=slots, slot_len=slot_len, max_tokens=max_tokens,
         token_bucket=token_bucket,
         step_bucket=lambda n: step_bucket(n, slots),
         ttl_s=float(pipe.get("session_ttl_s", 60.0)),
-        index_topk=cfg.index_topk, layers=cfg.num_hidden_layers,
-        index_cache_bytes=index_cache_bytes,
+        index_topk=index_topk, layers=cfg.num_hidden_layers,
+        index_cache_bytes=index_cache_bytes, block=block,
     )
-    device_fn = make_device_fn(cfg)
+    device_fn = make_device_fn(model, cfg)
     program = jax.jit(device_fn)
     stepped = [True]  # the in-process session's last request was a step (or there was none)
 
@@ -191,12 +239,14 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
         ONE implicit session, a stream of turns and then steps.
         ``tokens [1, n]`` with n > 1 after a step (or first of all)
         starts it anew; after another many-token request it is a further
-        turn of the same session; n = 1 extends it (a step). Not for use
+        turn of the same session; n = 1 extends it (a step). For a block
+        model the steps are its block requests (those that carry
+        ``commit``). Not for use
         beside a serving channel on the same model: both own the cache."""
-        tokens = np.asarray(inputs["tokens"])
-        many = tokens.shape[1] > 1
+        inputs = {k: np.asarray(v) for k, v in inputs.items()}
+        many = inputs["tokens"].shape[1] > 1 and TokenSessions.COMMIT not in inputs
         request, ticket = sessions.open(InferRequest(
-            name, {"tokens": tokens}, sequence_id="__in_process__",
+            name, inputs, sequence_id="__in_process__",
             sequence_start=many and stepped[0],
         ))
         stepped[0] = not many
@@ -218,10 +268,12 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
         inputs=(TensorSpec("tokens", (-1, -1), "INT32"),),
         outputs=(TensorSpec("logits", (-1, cfg.vocab_size), "FP32"),),
         extra={
-            "family": doc.get("family", "axk1"),
+            "family": family,
             "device_state": STATE_KEY,
-            # one-token requests of different sessions merge into one launch
+            # the steps of different sessions merge into one launch; a step
+            # carries this many tokens (a block model's block, else one)
             "session_merge": True,
+            "step_width": block or 1,
             "precision": policy.name,
             "slot_len": slot_len,
             "max_tokens": max_tokens,

@@ -35,7 +35,8 @@ window and no admission thread:
       and a row->segment table rides along
       (``parallel/ragged_kernels.py``), so every request runs at its
       true size — zero pad rows beyond lane alignment.
-    - *session steps*: the one-token steps of DIFFERENT sessions of a
+    - *session steps*: the steps of DIFFERENT sessions (one new token
+      each, or one block each of ``spec.extra["step_width"]`` tokens) of a
       model that declares ``spec.extra["session_merge"]`` share one
       launch, each row its own stream. The one kind whose group does
       NOT close the moment a slot frees: it stays open, absorbing
@@ -902,15 +903,23 @@ class ContinuousBatchingChannel(BaseChannel):
         sessions' requests: the model declares it
         (``spec.extra["session_merge"]``: its session state is a slot of
         its own device cache, runtime/sessions.py TokenSessions) and the
-        request carries ONE new token; a request of many runs alone."""
+        request is ONE step of the model: as many tokens as the model
+        says a step carries (``spec.extra["step_width"]``: one new
+        token, or a block model's block, which has its one-element
+        ``commit`` flag beside it); a request of any other shape (a
+        prompt, a further turn) runs alone."""
         try:
             spec = self._inner.get_metadata(
                 request.model_name, request.model_version
             )
-            if not (getattr(spec, "extra", None) or {}).get("session_merge"):
+            extra = getattr(spec, "extra", None) or {}
+            if not extra.get("session_merge"):
                 return False
-            (tokens,) = request.inputs.values()
-            return tuple(getattr(tokens, "shape", ())) == (1, 1)
+            width = extra.get("step_width", 1)
+            shapes = sorted(
+                tuple(getattr(a, "shape", ())) for a in request.inputs.values()
+            )
+            return shapes == ([(1, 1)] if width == 1 else [(1, 1), (1, width)])
         except Exception:
             return False
 
@@ -1225,10 +1234,14 @@ class ContinuousBatchingChannel(BaseChannel):
         """The steps of several sessions of a model that declares
         mergeable sessions: one launch, each row its own stream, no pad
         rows here (the model's session state forms the launch shape)
-        and no retry of a failed launch (the cache may have moved on)."""
+        and no retry of a failed launch (the cache may have moved on).
+        The answer holds a row a token of each step (one, or a block
+        model's block), in the members' order."""
         requests, futures, traces = self._open_group(group)
         try:
-            sizes = [_rows(r) for r in requests]
+            sizes = [
+                max(np.shape(a)[1] for a in r.inputs.values()) for r in requests
+            ]
             t_stage0 = time.perf_counter()
             merged = {
                 name: np.concatenate(

@@ -49,7 +49,9 @@ def _families_lm() -> tuple[str, ...]:
     """Token models served in sessions over a device-resident cache
     (pipelines/lm.py): weights are launcher arguments, read from
     ``weights.msgpack`` onto the device leaf by leaf."""
-    return ("axk1", "deepseek_v32")
+    from triton_client_tpu.pipelines.lm import MODULES
+
+    return tuple(MODULES)
 
 
 def _families_3d() -> tuple[str, ...]:

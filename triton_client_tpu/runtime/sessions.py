@@ -480,18 +480,25 @@ class TokenLaunch:
     and counters say of it. ``rows`` is empty for a launch that came as
     plain arrays (a compile of a launch shape)."""
 
-    kind: str  # "lm_prefill": one session, many tokens; "lm_step": one token a session
-    rows: list  # [(stream_id, tokens, restarted, ends)]
+    #: "lm_prefill": one session, many tokens; "lm_step": one token a session;
+    #: "lm_block": one block a session, written by the rows that commit
+    kind: str
+    rows: list  # [(stream_id, tokens its length moved by, restarted, ends)]
     tokens: int = 0
     sessions: int = 0
     context: int = 0  # positions cached before the launch, summed over its sessions
-    keys_visible: int = 0  # over its appended tokens: the positions each may attend to ...
+    keys_visible: int = 0  # over its tokens: the positions each may attend to ...
     keys_selected: int = 0  # ... and those it reads (the model's ``index_topk`` at most)
+    answers: int = 0  # rows of the answer that are some session's: one a session, a block launch's one a token
+    commit_rows: int = 0  # of a block launch's sessions, those that wrote their block
 
     @property
     def span(self):
         """The launch's span over its device window, and its attributes."""
-        return self.kind, {"tokens": self.tokens, "sessions": self.sessions, "context": self.context}
+        attrs = {"tokens": self.tokens, "sessions": self.sessions, "context": self.context}
+        if self.kind == "lm_block":
+            attrs["commit_rows"] = self.commit_rows
+        return self.kind, attrs
 
 
 class TokenSessions:
@@ -502,7 +509,14 @@ class TokenSessions:
     ``RegisteredModel.sessions``. A request under a ``sequence_id``
     carries ``tokens [1, n]``; the batcher may merge the one-token
     requests of DIFFERENT sessions into one launch (``tokens [B, 1]``
-    with ``InferRequest.sequence_rows`` naming each row's stream).
+    with ``InferRequest.sequence_rows`` naming each row's stream). A
+    model that generates by blocks (``block`` > 0) has a second
+    operation on a slot, and no one-token step: a request that carries
+    ``commit [1, 1]`` beside ``tokens [1, block]`` runs that block at
+    the slot's length and answers every position of it; with ``commit``
+    0 (a denoising pass) the slot's length, like the cache, stays as it
+    was, with ``commit`` 1 it moves by ``block``. The batcher merges the
+    block requests of different sessions as it merges steps.
     :meth:`open` gives every row its slot and position, refuses a stream
     that would outgrow its slot or finds no room
     (:class:`SessionLimitError`), and forms the launch's plain arrays
@@ -516,6 +530,7 @@ class TokenSessions:
     """
 
     INPUT = "tokens"
+    COMMIT = "commit"  # beside ``tokens``: a block request, and whether it writes its block
     LAUNCH_INPUTS = ("tokens", "slots", "positions", "lengths")
     EXPERT_ROWS = "expert_rows"
 
@@ -532,13 +547,17 @@ class TokenSessions:
         index_topk: int = 0,
         layers: int = 1,
         index_cache_bytes: int = 0,
+        block: int = 0,
     ) -> None:
         """``index_topk``: the positions a token of the model attends to
         at most (0: all), ``layers`` its layers, ``index_cache_bytes``
         what its index keys take beside the latent cache (a gauge): what
-        the counters ``lm_keys_visible`` / ``lm_keys_selected`` need."""
+        the counters ``lm_keys_visible`` / ``lm_keys_selected`` need.
+        ``block``: the tokens a block request of the model carries (0:
+        the model has one-token steps and no block operation)."""
         self.slot_len = int(slot_len)
         self.max_tokens = int(max_tokens)
+        self._block = int(block)
         self._index_topk = int(index_topk)
         self._layers = int(layers)
         self._index_cache_bytes = int(index_cache_bytes)
@@ -554,6 +573,8 @@ class TokenSessions:
             "lm_prefill_launches": 0, "lm_step_launches": 0,
             "lm_step_sessions": 0, "lm_context_prefill": 0,
             "lm_keys_visible": 0, "lm_keys_selected": 0,
+            "lm_block_launches": 0, "lm_block_rows": 0,
+            "lm_block_commit_rows": 0, "lm_tokens_committed": 0,
             "created_total": 0, "ended_total": 0,
             "outgrown_total": 0, "unknown_total": 0,
         }
@@ -566,10 +587,13 @@ class TokenSessions:
     # -- the launch bracket ---------------------------------------------------
 
     def launch_kind(self, inputs: dict) -> str:
-        """``lm_step`` for a launch of one token a row, ``lm_prefill``
-        for one of many tokens: the launch's span, and the suffix of its
+        """``lm_step`` for a launch of one token a row, ``lm_block`` for
+        one of a block a row (it carries ``commit``), ``lm_prefill`` for
+        one of many tokens: the launch's span, and the suffix of its
         module's name in a device trace."""
-        return "lm_step" if inputs[self.INPUT].shape[1] == 1 else "lm_prefill"
+        if inputs[self.INPUT].shape[1] == 1:
+            return "lm_step"
+        return "lm_block" if self.COMMIT in inputs else "lm_prefill"
 
     def open(self, request):
         """Admit one launch. Returns the request as the device program
@@ -587,12 +611,14 @@ class TokenSessions:
         named = request.sequence_rows or (
             (request.sequence_id, request.sequence_start, request.sequence_end),
         )
+        if self._block:
+            return self._open_blocks(request, tokens, named)
         if len(named) != b or (b > 1 and n != 1) or not 1 <= n <= self.max_tokens:
             raise ValueError(
                 f"a request carries tokens [1, n], 1 <= n <= {self.max_tokens}; "
                 f"got {tokens.shape} for {len(named)} session(s)"
             )
-        ticket = TokenLaunch(self.launch_kind({self.INPUT: tokens}), [], b * n, b)
+        ticket = TokenLaunch(self.launch_kind({self.INPUT: tokens}), [], b * n, b, answers=b)
         slots = np.zeros(b, np.int32)
         positions = np.zeros(b, np.int32)
         try:
@@ -611,17 +637,77 @@ class TokenSessions:
                 "lengths": np.concatenate([np.ones(b, np.int32), np.zeros(pad, np.int32)]),
             }
         else:
-            width = self._token_bucket(n)
+            launch = self._extend_arrays(tokens, slots, positions)
+        return dataclasses.replace(request, inputs=launch), ticket
+
+    def _extend_arrays(self, tokens, slots, positions) -> dict:
+        """An extend launch's plain arrays: one session's ``tokens [1,
+        n]`` padded to the launch shape."""
+        n = tokens.shape[1]
+        width = self._token_bucket(n)
+        return {
+            "tokens": np.concatenate([tokens, np.zeros((1, width - n), np.int32)], axis=1),
+            "slots": slots, "positions": positions,
+            "lengths": np.full(1, n, np.int32),
+        }
+
+    def _open_blocks(self, request, tokens, named):
+        """:meth:`open` for a model that generates by blocks: an extend
+        of whole blocks (one session), or the block requests of
+        ``len(named)`` sessions (``tokens [S, block]`` beside ``commit``:
+        a row that commits moves its session's length by a block, a
+        denoising row moves nothing, so a launch that fails has nothing
+        of it to take back)."""
+        (b, n), block = tokens.shape, self._block
+        writes = request.inputs.get(self.COMMIT)
+        if writes is None:
+            if len(named) != 1 or b != 1 or n % block or not block <= n <= self.max_tokens:
+                raise ValueError(
+                    f"an extend of this model appends whole blocks of {block} tokens (attention is "
+                    f"bidirectional inside a block): tokens [1, n], n a multiple of {block} up to "
+                    f"{self.max_tokens}; got {tokens.shape} for {len(named)} session(s). A block "
+                    f"request carries tokens [1, {block}] beside commit [1, 1]"
+                )
+            ticket = TokenLaunch("lm_prefill", [], n, 1, answers=1)
+        else:
+            writes = np.asarray(writes).reshape(-1) != 0
+            if len(named) != b or len(writes) != b or n != block:
+                raise ValueError(
+                    f"a block request carries tokens [1, {block}] and commit [1, 1]; "
+                    f"got {tokens.shape} and {len(writes)} flag(s) for {len(named)} session(s)"
+                )
+            ticket = TokenLaunch(
+                "lm_block", [], b * n, b, answers=b * n, commit_rows=int(writes.sum())
+            )
+        slots = np.zeros(b, np.int32)
+        positions = np.zeros(b, np.int32)
+        try:
+            for i, (stream_id, start, end) in enumerate(named):
+                slots[i], positions[i] = self._admit(
+                    ticket, stream_id, start, end, n, write=writes is None or bool(writes[i]),
+                )
+        except Exception:
+            self.close(ticket, None, failed=True)
+            raise
+        if writes is None:
+            launch = self._extend_arrays(tokens, slots, positions)
+        else:
+            pad = self._step_bucket(b) - b
+            padded = lambda a: np.concatenate([a, np.zeros((pad, *a.shape[1:]), np.int32)])
             launch = {
-                "tokens": np.concatenate([tokens, np.zeros((1, width - n), np.int32)], axis=1),
-                "slots": slots, "positions": positions,
-                "lengths": np.full(1, n, np.int32),
+                "tokens": padded(tokens), "slots": padded(slots), "positions": padded(positions),
+                "lengths": padded(np.full(b, n, np.int32)),
+                self.COMMIT: padded(writes.astype(np.int32)),
             }
         return dataclasses.replace(request, inputs=launch), ticket
 
-    def _admit(self, ticket: TokenLaunch, stream_id: str, start: bool, end: bool, n: int):
+    def _admit(self, ticket: TokenLaunch, stream_id: str, start: bool, end: bool, n: int,
+               write: bool = True):
         """One row's slot index and start position; the stream's length
-        moves on at once (rolled back if the launch fails)."""
+        moves on at once (rolled back if the launch fails). A row of a
+        model that generates by blocks stands at a block's boundary and
+        its ``n`` tokens all see each other; ``write`` False: a denoising
+        pass of such a model, the length stays."""
         now = self._pool.time()
         with self._turn:
             if not stream_id:
@@ -663,13 +749,26 @@ class TokenSessions:
                     f"its cache slot of {self.slot_len} positions"
                 )
             position = slot.length
-            slot.length += n
+            if self._block and position % self._block:
+                raise SessionLimitError(
+                    f"stream '{stream_id}': its {position} cached positions are no whole number "
+                    f"of blocks of {self._block}: a block is appended or denoised at a block's boundary"
+                )
+            moved = n if write else 0
+            slot.length += moved
             slot.refs += 1
             slot.last_used = now
-            ticket.rows.append((stream_id, n, restarted, end))
+            ticket.rows.append((stream_id, moved, restarted, end))
+            ticket.context += position
+            if self._block:
+                # a position may attend to every position up to the end of its own block, in every layer
+                ends = (np.arange(position, position + n, dtype=np.int64) // self._block + 1) * self._block
+                visible = self._layers * int(ends.sum())
+                ticket.keys_visible += visible
+                ticket.keys_selected += visible
+                return slot.state, position
             # a token at position p may attend to p + 1 positions, in every layer
             visible = np.arange(position + 1, position + n + 1, dtype=np.int64)
-            ticket.context += position
             ticket.keys_visible += self._layers * int(visible.sum())
             ticket.keys_selected += self._layers * int(
                 np.minimum(visible, self._index_topk).sum() if self._index_topk else visible.sum()
@@ -715,6 +814,11 @@ class TokenSessions:
                     self._counters["lm_tokens_step"] += ticket.tokens
                     self._counters["lm_step_launches"] += 1
                     self._counters["lm_step_sessions"] += ticket.tokens
+                elif ticket.kind == "lm_block":
+                    self._counters["lm_block_launches"] += 1
+                    self._counters["lm_block_rows"] += ticket.sessions
+                    self._counters["lm_block_commit_rows"] += ticket.commit_rows
+                    self._counters["lm_tokens_committed"] += ticket.commit_rows * self._block
                 else:
                     self._counters["lm_tokens_prefill"] += ticket.tokens
                     self._counters["lm_prefill_launches"] += 1
@@ -722,9 +826,9 @@ class TokenSessions:
             self._turn.notify_all()
         if host_outputs is not None:
             rows = host_outputs.pop(self.EXPERT_ROWS, None)
-            if ticket.sessions:  # the real rows; the launch's pad rows end here
+            if ticket.answers:  # the real rows; the launch's pad rows end here
                 for k, v in host_outputs.items():
-                    host_outputs[k] = v[: ticket.sessions]
+                    host_outputs[k] = v[: ticket.answers]
             if rows is not None and ticket.tokens:
                 with self._turn:
                     total = np.asarray(rows, np.int64)
