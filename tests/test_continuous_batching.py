@@ -515,10 +515,11 @@ def test_dense_occupancy_accounting_under_drive():
 
 
 def test_ragged_names_cache_fill_is_locked_and_converges():
-    """Regression (TPL602): ``_ragged_inputs_cache`` used to be filled
+    """Regression (TPL602): ``_model_facts_cache`` (then
+    ``_ragged_inputs_cache``) used to be filled
     check-then-act with no lock, from the caller's RPC thread AND the
     dispatcher/executor threads. All fillers must now insert under
-    ``_ragged_cache_lock`` and converge on one value, with the metadata
+    ``_model_facts_lock`` and converge on one value, with the metadata
     RPC kept outside the lock."""
 
     calls = []
@@ -545,7 +546,7 @@ def test_ragged_names_cache_fill_is_locked_and_converges():
         _Inner(), max_batch=1, pipeline_depth=1, live_buckets=False
     )
     try:
-        lock = chan._ragged_cache_lock
+        lock = chan._model_facts_lock
 
         class _LockChecked(dict):
             def __setitem__(self, key, value):
@@ -556,7 +557,7 @@ def test_ragged_names_cache_fill_is_locked_and_converges():
                 assert lock.locked(), "cache mutated without the lock"
                 return dict.setdefault(self, key, default)
 
-        chan._ragged_inputs_cache = _LockChecked()
+        chan._model_facts_cache = _LockChecked()
 
         results = []
         workers = [
@@ -1318,3 +1319,79 @@ def test_the_reader_of_the_hold_counters(case, capsys):
         assert reader.read(_reader_ctx(parent, {**parent, "merges": 9})) is None
         assert reader.read({}) is None
         assert capsys.readouterr().out == ""
+
+
+# -- what the scheduler reads off a model's spec, resolved once a model -------
+
+
+def _facts_spec(version="1", **extra):
+    return ModelSpec(
+        name="m", version=version,
+        inputs=(TensorSpec("tokens", (-1, -1), "INT32"),),
+        outputs=(TensorSpec("logits", (-1, 8), "FP32"),),
+        extra=extra,
+    )
+
+
+@pytest.mark.parametrize("change", ["reload", "unregister", "newer_version"])
+def test_model_facts_follow_the_repository(change):
+    """A model's facts are asked once and looked up afterwards, and are
+    dropped when the repository changes: a reload over the same name, an
+    unregister, a newer version that ``(name, "")`` now resolves to."""
+    repo = ModelRepository()
+    infer = lambda inputs: {"logits": np.zeros((1, 8), np.float32)}
+    repo.register(_facts_spec(session_merge=True, step_width=4), infer)
+    inner = TPUChannel(repo)
+    asked = []
+    get_metadata = inner.get_metadata
+    inner.get_metadata = lambda name, version="": (
+        asked.append((name, version)) or get_metadata(name, version)
+    )
+    chan = ContinuousBatchingChannel(inner, max_batch=4)
+    try:
+        block = {"tokens": np.zeros((1, 4), np.int32), "commit": np.zeros((1, 1), np.int32)}
+        step = InferRequest("m", block, sequence_id="s")
+        for _ in range(3):
+            assert chan._model_facts("m", "") == (
+                None, [(1, 1), (1, 4)], ("__session_step__", "m", ""),
+            )
+            assert chan._session_step(step)
+        assert asked == [("m", "")]  # once a model
+        if change == "reload":
+            repo.register(_facts_spec(ragged_inputs=["tokens"]), infer)
+            assert chan._model_facts("m", "") == (frozenset({"tokens"}), None, None)
+            assert not chan._session_step(step)
+            assert chan._ragged_names("m", "") == frozenset({"tokens"})
+        elif change == "unregister":
+            repo.unregister("m")
+            assert chan._model_facts("m", "") == (None, None, None)
+            assert not chan._session_step(step)
+            # nothing kept of a model that is not there: asked again
+            n = len(asked)
+            chan._model_facts("m", "")
+            assert len(asked) == n + 1
+            repo.register(_facts_spec(session_merge=True), infer)
+            assert chan._model_facts("m", "")[1] == [(1, 1)]
+        else:
+            repo.register(_facts_spec(version="2", session_merge=True), infer)
+            assert chan._model_facts("m", "")[1] == [(1, 1)]  # one token a step now
+            assert chan._model_facts("m", "1")[1] == [(1, 1), (1, 4)]
+            assert not chan._session_step(step)
+        assert len(asked) >= 2
+    finally:
+        chan.close()
+
+
+def test_answer_hands_over_once_and_reads_twice():
+    from triton_client_tpu.runtime.continuous import _Answer
+
+    a = _Answer()
+    assert not a.done()
+    threading.Timer(0.05, a.set_result, args=("r",)).start()
+    assert a.result() == "r" and a.result() == "r" and a.done()
+    assert a.exception() is None
+    b = _Answer()
+    b.set_exception(ValueError("x"))
+    assert b.done() and isinstance(b.exception(), ValueError)
+    with pytest.raises(ValueError):
+        b.result()

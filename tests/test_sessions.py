@@ -427,6 +427,76 @@ class TestServerSessions:
 # -- session-affinity routing -------------------------------------------------
 
 
+class TestFrontMemoUnderSessions:
+    @pytest.mark.parametrize("shm", [False, True], ids=["wire", "shm"])
+    def test_interleaved_sessions_answer_as_a_cold_server_does(self, shm):
+        """Two sessions interleaved through one server, whose front memo
+        is warm from the second frame on, answer array for array and id
+        for id what a server does that sees each session alone; the
+        counters say so (``/snapshot`` -> ``front_end``)."""
+        import json
+        import urllib.request
+
+        from triton_client_tpu.channel.grpc_channel import GRPCChannel
+
+        def frames(sid):
+            shift = 3.0 if sid == "cam-b" else 0.0
+            return [
+                InferRequest(
+                    "echo", _detections([(0.2 * k + shift, shift)]),
+                    request_id=f"{sid}-{k}", sequence_id=sid,
+                    sequence_start=(k == 0), sequence_end=(k == 5),
+                )
+                for k in range(6)
+            ]
+
+        def serve(order):
+            server, _ = _server()
+            clients = {}
+            try:
+                got = {}
+                for req in order:
+                    client = clients.get(req.sequence_id)
+                    if client is None:
+                        client = clients[req.sequence_id] = GRPCChannel(
+                            f"127.0.0.1:{server.port}", use_shared_memory=shm
+                        )
+                    resp = client.do_inference(req)
+                    assert resp.request_id == req.request_id
+                    got[req.request_id] = {
+                        k: np.array(v) for k, v in resp.outputs.items()
+                    }
+                with urllib.request.urlopen(
+                    f"http://127.0.0.1:{server.metrics_port}/snapshot"
+                ) as r:
+                    return got, json.load(r)["front_end"]
+            finally:
+                for client in clients.values():
+                    client.close()
+                server.stop()
+
+        a, b = frames("cam-a"), frames("cam-b")
+        together, front = serve([r for pair in zip(a, b) for r in pair])
+        alone = {**serve(a)[0], **serve(b)[0]}
+        assert together.keys() == alone.keys()
+        for request_id, outputs in alone.items():
+            assert outputs.keys() == together[request_id].keys()
+            for name, arr in outputs.items():
+                other = together[request_id][name]
+                if name.endswith("track_ids"):
+                    # a track id carries its session's slot above bit 16,
+                    # and the second of two live sessions sits in another
+                    arr = np.where(arr > 0, arr & 0xFFFF, arr)
+                    other = np.where(other > 0, other & 0xFFFF, other)
+                np.testing.assert_array_equal(other, arr)
+        assert front["handler_requests"] == 12
+        assert front["handler_cpu_s"] > 0.0
+        assert front["front_memo_hits"] + front["front_memo_misses"] == 12
+        # a session's descriptors are new at its first frame, and once
+        # more where the client has learned the answer's windows
+        assert front["front_memo_hits"] >= 8
+
+
 class TestAffinityRouting:
     def test_rendezvous_is_deterministic_and_spread(self):
         from triton_client_tpu.runtime.router import _rendezvous_score

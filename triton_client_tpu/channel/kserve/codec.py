@@ -124,6 +124,29 @@ def get_bool_param(msg, key: str, default: bool = False) -> bool:
 SEQUENCE_ID_PARAM = "sequence_id"
 SEQUENCE_START_PARAM = "sequence_start"
 SEQUENCE_END_PARAM = "sequence_end"
+PRIORITY_PARAM = "priority"
+
+
+def sequence_params(msg) -> tuple[str, bool, bool, int]:
+    """``(sequence_id, sequence_start, sequence_end, priority)`` of a
+    request in ONE access of its parameter map (each ``get_*_param``
+    builds the map's wrapper anew; a session's request pays for four).
+    Start and end are read only under a ``sequence_id``."""
+    p = msg.parameters
+    if not p:
+        return "", False, False, 0
+    priority = int(p[PRIORITY_PARAM].int64_param) if PRIORITY_PARAM in p else 0
+    if SEQUENCE_ID_PARAM not in p:
+        return "", False, False, priority
+    sequence_id = p[SEQUENCE_ID_PARAM].string_param
+    if not sequence_id:
+        return "", False, False, priority
+    return (
+        sequence_id,
+        SEQUENCE_START_PARAM in p and bool(p[SEQUENCE_START_PARAM].bool_param),
+        SEQUENCE_END_PARAM in p and bool(p[SEQUENCE_END_PARAM].bool_param),
+        priority,
+    )
 
 
 # multi-frame streaming protocol (round 13): one ModelStreamInfer
@@ -248,37 +271,106 @@ def set_shm_params(tensor, region: str, offset: int, byte_size: int) -> None:
         tensor.parameters["shared_memory_offset"].int64_param = offset
 
 
+class RequestPlan:
+    """What a request's tensor DESCRIPTORS fix, whatever its id, its
+    parameters and its payload: how each input is read (name, numpy
+    dtype, shape, shm window or the wire), the requested-output
+    windows, whether any tensor rides shared memory and how many input
+    bytes do. A session's requests repeat their descriptors, so the
+    server walks them once (:func:`plan_infer_request`) and looks the
+    plan up afterwards under :func:`request_fingerprint`. A pure
+    function of the descriptors: a plan is never stale, only unused.
+    ``responses`` holds the answer messages built under this plan
+    (:func:`build_infer_response`)."""
+
+    __slots__ = (
+        "inputs", "wire_inputs", "shm_outputs", "uses_shm", "shm_bytes",
+        "responses",
+    )
+
+
+_SERIALIZE_INPUT = pb.ModelInferRequest.InferInputTensor.SerializeToString
+_SERIALIZE_OUTPUT = (
+    pb.ModelInferRequest.InferRequestedOutputTensor.SerializeToString
+)
+
+
+def request_fingerprint(req: pb.ModelInferRequest) -> tuple:
+    """The tensor descriptors' bytes (inputs, requested outputs; no
+    payload: that is ``raw_input_contents`` or a shm window's). Equal
+    fingerprints are equal descriptors; equal descriptors that
+    serialise their parameter maps in another order only miss."""
+    return (
+        tuple(map(_SERIALIZE_INPUT, req.inputs)),
+        tuple(map(_SERIALIZE_OUTPUT, req.outputs)),
+    )
+
+
+def plan_infer_request(req: pb.ModelInferRequest) -> RequestPlan:
+    """ONE walk over a request's tensors. Raises ``ValueError`` as
+    :func:`parse_infer_request` would for malformed shm parameters or
+    an unknown datatype."""
+    plan = RequestPlan()
+    inputs = []
+    plan.shm_bytes = plan.wire_inputs = 0
+    for t in req.inputs:
+        if t.datatype not in _TO_NP:
+            raise ValueError(f"unsupported wire datatype '{t.datatype}'")
+        target = shm_params(t)
+        if target is None:
+            plan.wire_inputs += 1
+        else:
+            plan.shm_bytes += target[2]
+        inputs.append(
+            (t.name, _TO_NP[t.datatype], tuple(int(d) for d in t.shape), target)
+        )
+    plan.inputs = tuple(inputs)
+    plan.shm_outputs = {
+        t.name: target
+        for t in req.outputs
+        if (target := shm_params(t)) is not None
+    }
+    plan.uses_shm = bool(plan.shm_bytes or plan.shm_outputs)
+    plan.responses = {}
+    return plan
+
+
 def parse_infer_request(
-    req: pb.ModelInferRequest, shm=None
+    req: pb.ModelInferRequest, shm=None, plan: RequestPlan | None = None
 ) -> dict[str, np.ndarray]:
     """Wire -> arrays. Inputs carrying shared-memory parameters are
     read from ``shm`` (a SystemSharedMemoryRegistry) and consume NO
     raw_input_contents slot — the wire pairs raw buffers positionally
-    with the non-shm inputs only (Triton semantics)."""
+    with the non-shm inputs only (Triton semantics). ``plan``: this
+    request's :class:`RequestPlan` where the caller holds it (the walk
+    over the descriptors is then not repeated)."""
     faults.probe("codec_decode", req.model_name)
-    wire_inputs = [t for t in req.inputs if shm_params(t) is None]
-    if len(req.raw_input_contents) != len(wire_inputs):
+    if plan is None:
+        plan = plan_infer_request(req)
+    raws = req.raw_input_contents
+    if len(raws) != plan.wire_inputs:
         raise ValueError(
-            f"{len(wire_inputs)} wire input tensors but "
-            f"{len(req.raw_input_contents)} raw buffers"
+            f"{plan.wire_inputs} wire input tensors but "
+            f"{len(raws)} raw buffers"
         )
-    raws = iter(req.raw_input_contents)
     out = {}
-    for t in req.inputs:
-        region = shm_params(t)
-        if region is None:
-            out[t.name] = deserialize_tensor(next(raws), t.datatype, t.shape)
-            continue
-        if shm is None:
+    wire = 0
+    for name, dtype, shape, target in plan.inputs:
+        if target is None:
+            raw = raws[wire]
+            wire += 1
+        elif shm is None:
             raise ValueError(
-                f"input {t.name!r} requests shared-memory transport but "
+                f"input {name!r} requests shared-memory transport but "
                 "this server has no shared-memory registry"
             )
-        name, offset, byte_size = region
-        out[t.name] = deserialize_tensor(
-            shm.read(name, offset, byte_size), t.datatype, t.shape
-        )
+        else:
+            raw = shm.read(*target)
+        out[name] = np.frombuffer(raw, dtype=dtype).reshape(shape)
     return out
+
+
+_TEMPLATES_A_PLAN = 8
 
 
 def build_infer_response(
@@ -290,6 +382,7 @@ def build_infer_response(
     shm=None,
     parameters: dict | None = None,
     fallback_to_wire: bool = False,
+    templates: dict | None = None,
 ) -> pb.ModelInferResponse:
     """``shm_outputs`` maps output name -> (region, offset, byte_size):
     those tensors are written into the registry's region and travel as
@@ -300,33 +393,64 @@ def build_infer_response(
     ships as raw content instead of raising — the serving path passes
     True so a client whose learned output sizes lag a growing batch
     still gets its response (and learns the larger size from it);
-    the strict default stays for direct codec users."""
+    the strict default stays for direct codec users.
+
+    ``templates``: a dict the caller keeps for ONE set of
+    ``shm_outputs`` (a :class:`RequestPlan`'s ``responses``). An answer
+    whose model, output names, dtypes and shapes were answered under it
+    before is the same message but for its id and payload: the kept
+    message is copied and only those are written. At most
+    ``_TEMPLATES_A_PLAN`` are kept; further shapes build as before."""
+    arrays = [(name, np.asarray(outputs[name])) for name in sorted(outputs)]
+    key = None
+    if templates is not None and not parameters:
+        key = (
+            model_name, model_version,
+            tuple((name, arr.dtype, arr.shape) for name, arr in arrays),
+        )
+        kept = templates.get(key)
+        if kept is not None:
+            message, targets = kept
+            resp = pb.ModelInferResponse()
+            resp.CopyFrom(message)
+            resp.id = request_id
+            for (_, arr), target in zip(arrays, targets):
+                if target is None:
+                    resp.raw_output_contents.append(serialize_tensor(arr))
+                else:
+                    shm.write(target[0], target[1], arr)
+            return resp
     resp = pb.ModelInferResponse(
         model_name=model_name, model_version=model_version, id=request_id
     )
     set_request_params(resp, parameters)
-    for name in sorted(outputs):
-        arr = np.asarray(outputs[name])
+    targets = []  # where each output went: None the wire, else its window
+    for name, arr in arrays:
         t = resp.outputs.add(
             name=name, datatype=datatype_of(arr), shape=arr.shape
         )
         target = (shm_outputs or {}).get(name)
-        if target is None:
-            resp.raw_output_contents.append(serialize_tensor(arr))
-            continue
-        region, offset, byte_size = target
-        if arr.nbytes > byte_size:
+        if target is not None and arr.nbytes > target[2]:
             if not fallback_to_wire:
                 raise ValueError(
                     f"output {name!r} is {arr.nbytes} bytes but the "
-                    f"requested shared-memory window is {byte_size}"
+                    f"requested shared-memory window is {target[2]}"
                 )
+            target = None
+        targets.append(target)
+        if target is None:
             resp.raw_output_contents.append(serialize_tensor(arr))
             continue
         # single designed copy: readback view -> client's mapped page
         # (write() handles contiguity; no intermediate materialization)
-        shm.write(region, offset, arr)
-        set_shm_params(t, region, offset, arr.nbytes)
+        shm.write(target[0], target[1], arr)
+        set_shm_params(t, target[0], target[1], arr.nbytes)
+    if key is not None and len(templates) < _TEMPLATES_A_PLAN:
+        message = pb.ModelInferResponse()
+        message.CopyFrom(resp)
+        message.ClearField("id")
+        message.ClearField("raw_output_contents")
+        templates[key] = (message, tuple(targets))
     return resp
 
 

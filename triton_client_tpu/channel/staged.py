@@ -510,6 +510,11 @@ class StagedChannel(BaseChannel):
     def get_metadata(self, model_name: str, model_version: str = "") -> ModelSpec:
         return self._repository.metadata(model_name, model_version)
 
+    def models_generation(self) -> int:
+        """Moves when the repository's models change: a wrapping
+        channel that keeps facts of a model's spec asks them again."""
+        return self._repository.generation
+
     def do_inference(self, request: InferRequest) -> InferResponse:
         return self.launch(self.stage(request)).result()
 

@@ -333,6 +333,7 @@ class RuntimeCollector:
         self._quality = None
         self._temporal = None
         self._draining = False
+        self._front_end = self._front_active = None
         self._registry = None
         if registry is not None:
             registry.register(self)
@@ -400,6 +401,16 @@ class RuntimeCollector:
         """Wire the MetricHistory whose ring depth this collector
         exports."""
         self._history = history
+
+    def attach_front_end(self, stats, active) -> None:
+        """Wire the gRPC servicer (runtime/server.py): its
+        ``front_stats`` (the handler threads' CPU time a request, the
+        front memo's hit counts) land under ``/snapshot["front_end"]``,
+        its transport mix is added to this collector's own, and its
+        in-flight count (``active``) is the gauge: a request then takes
+        one lock for that, and none here."""
+        self._front_end = stats
+        self._front_active = active
 
     def attach_temporal(self, temporal) -> None:
         """Wire the temporal reuse plane (runtime/temporal.py) whose
@@ -484,7 +495,8 @@ class RuntimeCollector:
             "batching": (
                 self._batching.stats() if self._batching is not None else None
             ),
-            "inflight_requests": inflight,
+            "inflight_requests": inflight
+            + (self._front_active() if self._front_active else 0),
             "errors": errors,
             "compile": self._compile.snapshot(),
             "memory": self._memory(),
@@ -499,6 +511,14 @@ class RuntimeCollector:
                 shed[key] = shed.get(key, 0) + n
         snap["shed"] = shed
         snap["draining"] = int(draining)
+        if self._front_end is not None:
+            front = snap["front_end"] = self._front_end()
+            for label, (n, wire, shm) in front.pop("transport").items():
+                transport["requests"][label] = (
+                    transport["requests"].get(label, 0) + n
+                )
+                transport["wire_bytes"] += wire
+                transport["shm_bytes"] += shm
         snap["transport"] = transport
         if self._admission is not None:
             snap["admission"] = self._admission.stats()

@@ -228,6 +228,7 @@ class PrometheusStageExporter:
         self._lock = threading.Lock()
         self._label_sources: dict[str, str] = {}
         self._warned: set[tuple[str, str]] = set()
+        self._children: dict = {}  # stage -> its labelled series
         name = f"{namespace}_stage_latency_seconds"
         cls = type(self)
         with cls._family_cache_lock:
@@ -264,6 +265,12 @@ class PrometheusStageExporter:
     def observe(self, stage: str, seconds: float) -> None:
         if self._family is None:
             return
+        child = self._children.get(stage)
+        if child is not None:
+            # a stage's series, resolved once (the serving path records
+            # ``infer_<model>`` on every request)
+            child.observe(seconds)
+            return
         safe = "".join(c if c.isalnum() else "_" for c in stage)
         collision = None
         with self._lock:
@@ -283,6 +290,7 @@ class PrometheusStageExporter:
                 "stage label %r now receives both %r and %r — series "
                 "merged", safe, collision, stage,
             )
+        self._children[stage] = child
         child.observe(seconds)
 
     def attach(self, profiler: StageProfiler) -> "PrometheusStageExporter":

@@ -111,6 +111,54 @@ def _merge_key(request: InferRequest):
     )
 
 
+class _Answer:
+    """One request's answer, handed from the thread that resolves its
+    launch to the caller that waits in :meth:`do_inference`: the part
+    of ``concurrent.futures.Future`` this batcher uses (set once, one
+    waiter) on one bare lock. A Future builds a condition for every
+    request and its waiter's lock besides, and wakes through both."""
+
+    __slots__ = ("_ready", "_value", "_error", "_done")
+
+    def __init__(self) -> None:
+        self._ready = threading.Lock()
+        self._ready.acquire()
+        self._value = self._error = None
+        self._done = False
+
+    def set_result(self, value) -> None:
+        self._value, self._done = value, True
+        self._ready.release()
+
+    def set_exception(self, error: BaseException) -> None:
+        self._error, self._done = error, True
+        self._ready.release()
+
+    def done(self) -> bool:
+        return self._done
+
+    def exception(self) -> BaseException | None:
+        return self._error
+
+    def result(self):
+        with self._ready:  # released again: a second reader passes too
+            pass
+        if self._error is not None:
+            raise self._error
+        return self._value
+
+
+def _is_step(request: InferRequest, step_shapes: list | None) -> bool:
+    """Whether a request's inputs are ONE step of a model whose steps
+    merge (``step_shapes``: ``_model_facts``; None for any other model)."""
+    if step_shapes is None:
+        return False
+    try:
+        return sorted(a.shape for a in request.inputs.values()) == step_shapes
+    except Exception:
+        return False
+
+
 def _rows(request: InferRequest) -> int:
     """Batch rows of a request: the leading dimension of its first input."""
     return next(
@@ -374,15 +422,21 @@ class ContinuousBatchingChannel(BaseChannel):
         # (submit -> run), stage (host merge build), device (inner
         # channel call), respond (split + future resolution)
         self._decomp = collections.defaultdict(float)
-        # (model, version) -> frozenset of packed-input names, or None
-        # when the model has no segment-aware body; filled lazily from
-        # inner.get_metadata so registration order doesn't matter.
-        # Filled from RPC threads AND the dispatcher/executor threads,
-        # so writes go through _ragged_cache_lock (the metadata RPC
-        # itself runs outside the lock; racing fillers converge via
-        # setdefault)
-        self._ragged_inputs_cache: dict = {}
-        self._ragged_cache_lock = threading.Lock()
+        # (model, version) -> what the scheduler reads off the model's
+        # spec (_model_facts); filled lazily from inner.get_metadata so
+        # registration order doesn't matter. Filled from RPC threads AND
+        # the dispatcher/executor threads, so writes go through
+        # _model_facts_lock (the metadata RPC itself runs outside the
+        # lock; racing fillers converge via setdefault). The facts of a
+        # repository that has changed since (a model registered,
+        # reloaded or unregistered) are dropped whole, where the inner
+        # channel can say so (StagedChannel.models_generation)
+        self._model_facts_cache: dict = {}
+        self._model_facts_lock = threading.Lock()
+        self._models_generation = getattr(
+            inner, "models_generation", lambda: None
+        )
+        self._facts_generation = self._models_generation()
         # optional multi-tenant fair share (runtime/lifecycle.py
         # TenantTable): deficit-round-robin virtual time folded into the
         # EDF key — set via attach_tenants(); None keeps pure EDF
@@ -491,12 +545,12 @@ class ContinuousBatchingChannel(BaseChannel):
                 self._vtime[tenant] -= floor
 
     def do_inference(self, request: InferRequest) -> InferResponse:
-        future: concurrent.futures.Future = concurrent.futures.Future()
+        future = _Answer()
         if request.trace is not None:
             # closed at dispatch time (_open_group/_run_solo): ready-set
             # wait + slot backpressure, end to end
             request.trace.begin("batch_queue")
-        ragged_names = self._ragged_names(
+        ragged_names, step_shapes, step_key = self._model_facts(
             request.model_name, request.model_version
         )
         session_step = False
@@ -506,12 +560,12 @@ class ContinuousBatchingChannel(BaseChannel):
             # sees exactly one stream's frame per launch in order —
             # unless the model declares mergeable sessions
             ragged_names = None
-            session_step = self._session_step(request)
+            session_step = _is_step(request, step_shapes)
         if session_step:
             # one new token of a session whose state is a slot of the
             # model's own device cache: the steps of DIFFERENT sessions
             # merge into one launch under the model's key
-            key = ("__session_step__", request.model_name, request.model_version)
+            key = step_key
             size = 1
         elif ragged_names:
             # one segment per request: same-model ragged requests merge
@@ -540,11 +594,13 @@ class ContinuousBatchingChannel(BaseChannel):
             now = time.perf_counter()
             if request.sequence_id:
                 self._observe_session_request_locked(request, session_step, now)
-            bisect.insort(
-                self._ready,
-                (key, size, request, future, now),
-                key=self._edf_key,
-            )
+            item = (key, size, request, future, now)
+            if not self._ready or self._edf_key(self._ready[-1]) <= self._edf_key(item):
+                # the common arrival (no deadline, or the latest one)
+                # goes where insort would put it, without the bisection
+                self._ready.append(item)
+            else:
+                bisect.insort(self._ready, item, key=self._edf_key)
             self._ready_cv.notify()
         return future.result()
 
@@ -908,49 +964,61 @@ class ContinuousBatchingChannel(BaseChannel):
         token, or a block model's block, which has its one-element
         ``commit`` flag beside it); a request of any other shape (a
         prompt, a further turn) runs alone."""
-        try:
-            spec = self._inner.get_metadata(
-                request.model_name, request.model_version
-            )
-            extra = getattr(spec, "extra", None) or {}
-            if not extra.get("session_merge"):
-                return False
-            width = extra.get("step_width", 1)
-            shapes = sorted(
-                tuple(getattr(a, "shape", ())) for a in request.inputs.values()
-            )
-            return shapes == ([(1, 1)] if width == 1 else [(1, 1), (1, width)])
-        except Exception:
-            return False
+        return _is_step(
+            request,
+            self._model_facts(request.model_name, request.model_version)[1],
+        )
 
     def _ragged_names(self, model_name: str, model_version: str):
         """Packed-input names for a model with a segment-aware body
-        (``spec.extra["ragged_inputs"]``), else None. Cached, including
-        negative answers — this sits on the per-request path.
+        (``spec.extra["ragged_inputs"]``), else None."""
+        return self._model_facts(model_name, model_version)[0]
+
+    def _model_facts(self, model_name: str, model_version: str) -> tuple:
+        """``(ragged names, step shapes, step key)``: what this
+        scheduler reads off a registered model's spec, resolved once a
+        model and looked up afterwards (it sits on every request's
+        path): the packed-input names of a segment-aware body or None;
+        the sorted input shapes of ONE mergeable session step or None
+        (:meth:`_session_step`); the key such steps merge under.
+        Negative answers are kept too; a model the inner channel does
+        not know is asked for again.
 
         Called from RPC threads (``do_inference``) and from the
         dispatcher/executor threads (``_group_kind``), so the cache fill
         is double-checked: the lock-free fast path covers the steady
         state, the metadata RPC runs unlocked (it can block), and the
-        insert goes through ``setdefault`` under ``_ragged_cache_lock``
-        so racing fillers agree on one winner."""
+        insert goes through ``setdefault`` under ``_model_facts_lock``
+        so racing fillers agree on one winner. A filler that raced a
+        change of the repository inserts into the cache it started
+        from, which that change has dropped."""
+        generation = self._models_generation()
+        if generation != self._facts_generation:
+            with self._model_facts_lock:
+                if generation != self._facts_generation:
+                    self._model_facts_cache = {}
+                    self._facts_generation = generation
+        cache = self._model_facts_cache
         key = (model_name, model_version)
         try:
-            return self._ragged_inputs_cache[key]
+            return cache[key]
         except KeyError:
             pass
-        names = None
+        facts = (None, None, None)
         try:
             spec = self._inner.get_metadata(model_name, model_version)
-            declared = (getattr(spec, "extra", None) or {}).get(
-                "ragged_inputs"
-            )
-            if declared:
-                names = frozenset(declared)
+            extra = getattr(spec, "extra", None) or {}
+            declared = extra.get("ragged_inputs")
+            shapes = step_key = None
+            if extra.get("session_merge"):
+                width = extra.get("step_width", 1)
+                shapes = [(1, 1)] if width == 1 else [(1, 1), (1, width)]
+                step_key = ("__session_step__", model_name, model_version)
+            facts = (frozenset(declared) if declared else None, shapes, step_key)
         except Exception:
-            names = None
-        with self._ragged_cache_lock:
-            return self._ragged_inputs_cache.setdefault(key, names)
+            return facts  # not registered now: asked again next time
+        with self._model_facts_lock:
+            return cache.setdefault(key, facts)
 
     # -- group execution (runs on the executor threads) -----------------------
 

@@ -96,6 +96,10 @@ class ModelRepository:
         # (and the replicated params that closure pins in HBM) — the
         # same invalidation path the circuit breaker uses.
         self._unregister_listeners: list[Callable[[str, str], None]] = []
+        # moves whenever a model is registered (anew or over one that
+        # stood) or unregistered: what a channel derived from a model's
+        # spec and kept holds until this has moved
+        self.generation = 0
         # access accounting for lifecycle LRU: per-name hit count and
         # last-touch monotonic sequence, maintained by get().
         self._access_count: dict[str, int] = {}
@@ -122,6 +126,7 @@ class ModelRepository:
                 spec, infer_fn, warmup, device_fn, params, precision, ragged_fn,
                 sessions,
             )
+            self.generation += 1
 
     def unregister(self, name: str, version: str = "") -> None:
         removed: list[tuple[str, str]] = []
@@ -134,6 +139,7 @@ class ModelRepository:
             else:
                 for v in self._models.pop(name, {}):
                     removed.append((name, v))
+            self.generation += len(removed)
             listeners = list(self._unregister_listeners)
         # notify outside the lock: listeners take channel locks of
         # their own and must be free to call back into the repository

@@ -107,6 +107,37 @@ _GRPC_STATUS = {
 }
 
 
+class _Memo:
+    """What a peer string or a request's tensor descriptors fix,
+    resolved once and looked up afterwards. Reads take no lock (a dict
+    lookup is atomic), writers hold their owner's (a miss is rare); a
+    full memo gives up its oldest entry. Every value is a pure function
+    of its key, so an entry is never stale: one that is gone only costs
+    the code that derives it again."""
+
+    def __init__(self, capacity: int) -> None:
+        self._entries: dict = {}
+        self._capacity = capacity
+        self.get = self._entries.get
+
+    def put(self, key, value):
+        if len(self._entries) >= self._capacity:
+            del self._entries[next(iter(self._entries))]
+        self._entries[key] = value
+
+
+class _RemoteShm(Exception):
+    """A shared-memory request from a peer that is not on this host."""
+
+
+# transport label by (peer on a unix socket, input bytes in shm)
+_TRANSPORT = {
+    (False, False): "grpc", (False, True): "shm",
+    (True, False): "uds", (True, True): "uds+shm",
+}
+_LOCAL_PEERS = ("ipv4:127.", "ipv6:[::1]", "ipv6:[::ffff:127.", "unix:")
+
+
 class _Servicer(service.GRPCInferenceServiceServicer):
     def __init__(
         self,
@@ -157,10 +188,55 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         # collector — drain() polls it to know when the building is empty
         self._active = 0
         self._active_lock = threading.Lock()
+        # the handler threads' own counters, a cell a thread so that a
+        # request takes no lock for them: [thread CPU seconds inside
+        # ModelInfer, requests, front-memo hits, misses, {transport
+        # label: [requests, wire bytes, shm bytes]}]
+        self._front_tls = threading.local()
+        self._front_cells: list[list] = []
+        # peer string -> (same host, unix socket); a request's tensor
+        # descriptors -> its codec.RequestPlan (a client's shm slots
+        # times its request shapes: a session's 193 requests are two)
+        self._peers = _Memo(256)
+        self._plans = _Memo(2048)
+        self._memo_lock = threading.Lock()
 
     def active_requests(self) -> int:
         with self._active_lock:
             return self._active
+
+    def _front_cell(self) -> list:
+        try:
+            return self._front_tls.cell
+        except AttributeError:
+            cell = self._front_tls.cell = [0.0, 0, 0, 0, {}]
+            with self._active_lock:
+                self._front_cells.append(cell)
+            return cell
+
+    def front_stats(self) -> dict:
+        """What a request costs this process's interpreter, for
+        ``/snapshot`` -> ``front_end``: ``handler_cpu_s`` is the handler
+        threads' CPU time (``time.thread_time``: a thread that waits for
+        its answer accrues none) inside ``ModelInfer`` over
+        ``handler_requests`` of them. ``transport`` is the mix the
+        collector reports (``/snapshot`` -> ``transport``): requests by
+        negotiated label, input payload bytes by the wire and by shm."""
+        with self._active_lock:
+            cells = list(self._front_cells)
+        transport: dict = {}
+        for cell in cells:
+            for label, counts in list(cell[4].items()):
+                total = transport.setdefault(label, [0, 0, 0])
+                for i, count in enumerate(counts):
+                    total[i] += count
+        return {
+            "handler_cpu_s": sum(c[0] for c in cells),
+            "handler_requests": sum(c[1] for c in cells),
+            "front_memo_hits": sum(c[2] for c in cells),
+            "front_memo_misses": sum(c[3] for c in cells),
+            "transport": transport,
+        }
 
     def _draining_now(self) -> bool:
         return self._draining is not None and self._draining.is_set()
@@ -269,12 +345,9 @@ class _Servicer(service.GRPCInferenceServiceServicer):
 
     @staticmethod
     def _is_local_peer(context) -> bool:
-        peer = context.peer()
         # ipv6:[::ffff:127.*] is the v4-mapped loopback a dual-stack
         # bind reports for a 127.0.0.1 dial
-        return peer.startswith(
-            ("ipv4:127.", "ipv6:[::1]", "ipv6:[::ffff:127.", "unix:")
-        )
+        return context.peer().startswith(_LOCAL_PEERS)
 
     @classmethod
     def _require_local(cls, context) -> None:
@@ -285,11 +358,15 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         exfiltrate or corrupt it through model IO). Loopback and unix
         sockets only."""
         if not cls._is_local_peer(context):
-            context.abort(
-                grpc.StatusCode.PERMISSION_DENIED,
-                f"shared-memory extension is restricted to same-host "
-                f"clients (peer {context.peer()})",
-            )
+            cls._refuse_remote(context, context.peer())
+
+    @staticmethod
+    def _refuse_remote(context, peer: str) -> None:
+        context.abort(
+            grpc.StatusCode.PERMISSION_DENIED,
+            f"shared-memory extension is restricted to same-host "
+            f"clients (peer {peer})",
+        )
 
     def SystemSharedMemoryRegister(self, request, context):
         self._require_local(context)
@@ -325,7 +402,9 @@ class _Servicer(service.GRPCInferenceServiceServicer):
 
     # -- inference ------------------------------------------------------------
 
-    def _issue(self, request, inputs_override=None, id_override=None):
+    def _issue(
+        self, request, inputs_override=None, id_override=None, plan=None
+    ):
         """Parse + dispatch one request; returns a finisher callable.
 
         ``inputs_override``/``id_override``: set by _issue_group when
@@ -335,7 +414,10 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         parse, content decoding, and response shm placement are then
         skipped (the group was parsed once, encoded groups are not
         packed client-side, and a shared output region cannot serve G
-        members).
+        members). ``plan``: the request's ``codec.RequestPlan`` where
+        :meth:`_front` holds one: parse and response then follow it
+        instead of walking the tensors again, and the request carries
+        no content encoding (a plan of one that does is not kept).
 
         The dispatch goes through ``do_inference_async`` so the device
         (or inner batcher) starts while THIS thread still prepares the
@@ -382,28 +464,19 @@ class _Servicer(service.GRPCInferenceServiceServicer):
             if not tid:
                 tid = f"anon-{next(self._quality_seq)}"
             served_name = self._quality.route(request.model_name, tid)
-        deadline_s, priority = None, 0
+        # streaming-session identity (runtime/sessions.py), decoded
+        # independent of the SLO plane and absent on stateless requests,
+        # and the priority: one access of the request's parameters
+        sequence_id, sequence_start, sequence_end, priority = (
+            codec.sequence_params(request)
+        )
+        deadline_s = None
         if self._slo is not None:
             deadline_s = self._slo.deadline_for(request.model_name, t0)
-            try:
-                params = request.parameters
-                if params and "priority" in params:
-                    priority = int(params["priority"].int64_param)
-            except (AttributeError, TypeError, ValueError):
-                priority = 0  # malformed parameter: never fail the request
-        # streaming-session identity (runtime/sessions.py) — decoded
-        # independent of the SLO plane; absent on stateless requests
-        sequence_id = codec.get_string_param(request, codec.SEQUENCE_ID_PARAM)
-        sequence_start = sequence_end = False
-        if sequence_id:
-            sequence_start = codec.get_bool_param(
-                request, codec.SEQUENCE_START_PARAM
-            )
-            sequence_end = codec.get_bool_param(
-                request, codec.SEQUENCE_END_PARAM
-            )
-        if self._collector is not None:
-            self._collector.request_started()
+        else:
+            priority = 0
+        # the in-flight count is this one (the collector's gauge reads
+        # it at scrape time: attach_front_end)
         with self._active_lock:
             self._active += 1
         admitted = False
@@ -469,11 +542,15 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                 if trace is not None:
                     with trace.span("parse"):
                         inputs = codec.parse_infer_request(
-                            request, shm=self._shm
+                            request, shm=self._shm, plan=plan
                         )
                 else:
-                    inputs = codec.parse_infer_request(request, shm=self._shm)
-                encodings = wire_encoding.encodings_of(request)
+                    inputs = codec.parse_infer_request(
+                        request, shm=self._shm, plan=plan
+                    )
+                encodings = (
+                    wire_encoding.encodings_of(request) if plan is None else None
+                )
                 if encodings:
                     # compressed wire payloads (JPEG frames, quantized
                     # pointclouds) decode on the host pool / device here;
@@ -517,15 +594,17 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                 future = self._channel.do_inference_async(ireq)
             # overlapped with device execution: shm placement parsing
             # needs only the request, not the result
-            shm_outputs = (
-                {}
-                if inputs_override is not None
-                else {
+            templates = None
+            if inputs_override is not None:
+                shm_outputs = {}
+            elif plan is not None:
+                shm_outputs, templates = plan.shm_outputs, plan.responses
+            else:
+                shm_outputs = {
                     t.name: params
                     for t in request.outputs
                     if (params := codec.shm_params(t)) is not None
                 }
-            )
         except BaseException as e:
             # parse/dispatch failed before a finisher existed: close out
             # the request's accounting here (finish() will never run)
@@ -576,6 +655,7 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                         shm_outputs=shm_outputs,
                         shm=self._shm,
                         fallback_to_wire=True,
+                        templates=templates,
                     )
                     trace.add("encode", t_e0, time.perf_counter())
                     # compact span summary in the response parameters
@@ -594,6 +674,7 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                     shm_outputs=shm_outputs,
                     shm=self._shm,
                     fallback_to_wire=True,
+                    templates=templates,
                 )
             except BaseException as e:
                 error = e
@@ -653,10 +734,8 @@ class _Servicer(service.GRPCInferenceServiceServicer):
             self._profiler.record(
                 f"infer_{model_name}", time.perf_counter() - t0
             )
-        if self._collector is not None:
-            if error is not None:
-                self._collector.record_error(model_name, _grpc_code(error))
-            self._collector.request_finished()
+        if self._collector is not None and error is not None:
+            self._collector.record_error(model_name, _grpc_code(error))
         if self._admission is not None and admitted:
             # successful requests feed the EWMA the estimated-wait
             # check divides by; failures only release their slot
@@ -669,40 +748,77 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         with self._active_lock:
             self._active -= 1
 
-    def _infer(self, request):
-        return self._issue(request)()
-
     def _uses_shm(self, request) -> bool:
         return any(
             "shared_memory_region" in t.parameters
             for t in list(request.inputs) + list(request.outputs)
         )
 
+    def _front(self, request, context, cell=None):
+        """What the connection and the request's tensor descriptors fix,
+        before anything is issued: ONE ``context.peer()`` (it lets go of
+        the interpreter lock and takes it again), its locality and
+        transport label looked up by the peer string; the request's
+        ``codec.RequestPlan`` looked up by its descriptors' bytes, or
+        made in one walk and kept. A shared-memory request from a peer
+        that is not on this host raises :class:`_RemoteShm`, on EVERY
+        request and from the peer THAT request came from (the memo
+        holds what a peer string means, never who may pass); the
+        transport mix is counted in the thread's cell. Returns the plan, or None for a
+        request that has none to keep (malformed descriptors, a content
+        encoding, whose parameters differ request by request): the
+        caller then walks the tensors as it always did."""
+        peer = context.peer()
+        facts = self._peers.get(peer)
+        if facts is None:
+            facts = peer.startswith(_LOCAL_PEERS), peer.startswith("unix:")
+            with self._memo_lock:
+                self._peers.put(peer, facts)
+        key = codec.request_fingerprint(request)
+        plan = self._plans.get(key)
+        if cell is None:
+            cell = self._front_cell()
+        if plan is not None:
+            cell[2] += 1
+        else:
+            cell[3] += 1
+            try:
+                if not wire_encoding.encodings_of(request):
+                    plan = codec.plan_infer_request(request)
+                    with self._memo_lock:
+                        self._plans.put(key, plan)
+            except ValueError:
+                pass  # said where it always was: by the parse, accounted
+        if plan is not None:
+            uses_shm, shm_bytes = plan.uses_shm, plan.shm_bytes
+        else:
+            uses_shm, shm_bytes = self._uses_shm(request), 0
+            for t in request.inputs:
+                p = t.parameters
+                if "shared_memory_region" in p and "shared_memory_byte_size" in p:
+                    shm_bytes += int(p["shared_memory_byte_size"].int64_param)
+        if uses_shm and not facts[0]:
+            raise _RemoteShm(peer)
+        # which transport carried this request's tensors and how many
+        # payload bytes each moved (input side only: it dominates for
+        # perception serving, and response bytes are not knowable until
+        # resolution)
+        label = _TRANSPORT[facts[1], shm_bytes > 0]
+        mix = cell[4].get(label)
+        if mix is None:
+            mix = cell[4][label] = [0, 0, 0]
+        raws = request.raw_input_contents
+        mix[0] += 1
+        if raws:
+            mix[1] += sum(map(len, raws))
+        mix[2] += shm_bytes
+        return plan
+
     @staticmethod
     def _stream_group_size(request) -> int:
         return max(1, codec.get_int_param(request, codec.STREAM_GROUP_PARAM, 1))
 
-    def _record_transport(self, request, context) -> None:
-        """Feed the transport-mix counters: which transport carried
-        this request's tensors and how many payload bytes each moved.
-        (Input side only — it dominates for perception serving, and
-        response bytes are not knowable until resolution.)"""
-        if self._collector is None:
-            return
-        wire_bytes = sum(len(b) for b in request.raw_input_contents)
-        shm_bytes = 0
-        for t in request.inputs:
-            p = t.parameters
-            if "shared_memory_region" in p and "shared_memory_byte_size" in p:
-                shm_bytes += int(p["shared_memory_byte_size"].int64_param)
-        uds = context.peer().startswith("unix:")
-        if shm_bytes:
-            transport = "uds+shm" if uds else "shm"
-        else:
-            transport = "uds" if uds else "grpc"
-        self._collector.record_transport(transport, wire_bytes, shm_bytes)
-
-    def _issue_group(self, request):
+    def _issue_group(self, request, plan=None):
         """Fan one multi-frame stream message into per-member batcher
         requests; returns one finisher per member, in member order.
 
@@ -718,12 +834,12 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         per-member error_message."""
         g = self._stream_group_size(request)
         if g == 1:
-            return [self._issue(request)]
+            return [self._issue(request, plan=plan)]
         if self._shm is not None and faults.probe_flag(
             "shm_detach", request.model_name
         ):
             self._shm.unregister_all()
-        inputs = codec.parse_infer_request(request, shm=self._shm)
+        inputs = codec.parse_infer_request(request, shm=self._shm, plan=plan)
         members: list[dict] = [{} for _ in range(g)]
         for name, arr in inputs.items():
             if arr.ndim < 1 or arr.shape[0] % g:
@@ -774,11 +890,14 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         return str(e)
 
     def ModelInfer(self, request, context):
-        if self._uses_shm(request):
-            self._require_local(context)
-        self._record_transport(request, context)
+        c0 = time.thread_time()
+        cell = self._front_cell()
         try:
-            return self._infer(request)
+            return self._issue(
+                request, plan=self._front(request, context, cell)
+            )()
+        except _RemoteShm as e:
+            self._refuse_remote(context, str(e))
         except OverloadError as e:
             context.abort(_GRPC_STATUS[_grpc_code(e)], str(e))
         except KeyError as e:
@@ -791,6 +910,11 @@ class _Servicer(service.GRPCInferenceServiceServicer):
             # instead of grpc's opaque UNKNOWN, so clients can key
             # retry-elsewhere policy on a stable code
             context.abort(grpc.StatusCode.INTERNAL, str(e))
+        finally:
+            # what the request cost this process's interpreter: a thread
+            # that waits for its answer accrues no CPU time
+            cell[0] += time.thread_time() - c0
+            cell[1] += 1
 
     def ModelStreamInfer(self, request_iterator, context):
         """Pipelined stream serving: up to ``stream_pipeline_depth``
@@ -804,16 +928,18 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         Depth 1 skips the reader thread entirely."""
         if self._stream_depth <= 1:
             for request in request_iterator:
-                if self._uses_shm(request):
-                    self._require_local(context)
-                self._record_transport(request, context)
+                try:
+                    plan = self._front(request, context)
+                except _RemoteShm as e:
+                    self._refuse_remote(context, str(e))
+                    return
                 if (
                     self._collector is not None
                     and (g := self._stream_group_size(request)) > 1
                 ):
                     self._collector.record_stream_group(g)
                 try:
-                    finishers = self._issue_group(request)
+                    finishers = self._issue_group(request, plan)
                 except (KeyError, ValueError, OverloadError) as e:
                     yield pb.ModelStreamInferResponse(
                         error_message=self._group_error(request, e)
@@ -841,20 +967,19 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         def issue_loop() -> None:
             try:
                 for request in request_iterator:
-                    if self._uses_shm(request) and not self._is_local_peer(
-                        context
-                    ):
+                    try:
+                        plan = self._front(request, context)
+                    except _RemoteShm as e:
                         # the abort must run on the handler thread
-                        q.put(("non_local", None))
+                        q.put(("non_local", str(e)))
                         return
-                    self._record_transport(request, context)
                     if (
                         self._collector is not None
                         and (g := self._stream_group_size(request)) > 1
                     ):
                         self._collector.record_stream_group(g)
                     try:
-                        finishers = self._issue_group(request)
+                        finishers = self._issue_group(request, plan)
                     except (KeyError, ValueError, OverloadError) as e:
                         q.put(("error", self._group_error(request, e)))
                         continue
@@ -890,7 +1015,7 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                 elif kind == "error":
                     yield pb.ModelStreamInferResponse(error_message=payload)
                 elif kind == "non_local":
-                    self._require_local(context)
+                    self._refuse_remote(context, payload)
                 else:  # crash
                     raise payload
         finally:
@@ -1212,6 +1337,10 @@ class InferenceServer:
             quality=quality,
             temporal=temporal,
         )
+        if self.collector is not None:
+            self.collector.attach_front_end(
+                self._servicer.front_stats, self._servicer.active_requests
+            )
         service.add_servicer_to_server(self._servicer, self._server)
         self._port = self._server.add_insecure_port(address)
         if self._port == 0:

@@ -47,6 +47,11 @@ _SHM_DIR = "/dev/shm"
 _WRITE_LOG: collections.deque = collections.deque(maxlen=4096)
 
 
+# up to this size a write copies with the interpreter lock held
+# (SharedMemoryRegion.write): 1 MiB is some 100 us of memcpy
+_COPY_HOLDING_LOCK_BYTES = 1 << 20
+
+
 def write_log() -> list[tuple[float, float, int]]:
     """This process's most recent ``SharedMemoryRegion.write`` calls,
     oldest first: ``(t0, t1, nbytes)`` on ``time.perf_counter``."""
@@ -120,11 +125,17 @@ class SharedMemoryRegion:
                 f"write of {n} bytes at offset {offset} exceeds region "
                 f"{self.key!r} ({self.size} bytes)"
             )
-        # numpy-to-numpy copy releases the GIL (a plain mmap slice
-        # assignment holds it) — concurrent serving clients on a small
-        # host overlap their memcpys
-        dst = np.frombuffer(self._mm, np.uint8, count=n, offset=offset)
-        np.copyto(dst, arr.view(np.uint8).reshape(-1))
+        src = arr.view(np.uint8).reshape(-1)
+        if n <= _COPY_HOLDING_LOCK_BYTES:
+            # a plain mmap slice assignment holds the interpreter lock:
+            # for an answer of some hundred KB that is 10-30 us, where
+            # letting go of the lock and waiting for it again, with a
+            # launch's other members awake, is a thread switch each way
+            self._mm[offset : offset + n] = src
+        else:
+            # numpy-to-numpy copy releases the GIL — concurrent serving
+            # clients on a small host overlap their memcpys
+            np.copyto(np.frombuffer(self._mm, np.uint8, count=n, offset=offset), src)
         _WRITE_LOG.append((t0, time.perf_counter(), n))
         return n
 
@@ -215,12 +226,11 @@ class SystemSharedMemoryRegistry:
     def read(self, name: str, offset: int, byte_size: int) -> memoryview:
         """Bytes of a registered region; ``offset`` is relative to the
         region's registered base offset (Triton semantics)."""
-        with self._lock:
-            if name not in self._regions:
-                raise ValueError(
-                    f"shared-memory region {name!r} is not registered"
-                )
-            reg = self._regions[name]
+        reg = self._regions.get(name)  # one atomic lookup: no lock a request
+        if reg is None:
+            raise ValueError(
+                f"shared-memory region {name!r} is not registered"
+            )
         if offset < 0 or byte_size > reg.byte_size - offset:
             raise ValueError(
                 f"request for {byte_size} bytes at offset {offset} exceeds "
@@ -229,12 +239,11 @@ class SystemSharedMemoryRegistry:
         return reg.region.read(reg.offset + offset, byte_size)
 
     def write(self, name: str, offset: int, arr: np.ndarray) -> int:
-        with self._lock:
-            if name not in self._regions:
-                raise ValueError(
-                    f"shared-memory region {name!r} is not registered"
-                )
-            reg = self._regions[name]
+        reg = self._regions.get(name)
+        if reg is None:
+            raise ValueError(
+                f"shared-memory region {name!r} is not registered"
+            )
         if offset < 0 or arr.nbytes > reg.byte_size - offset:
             raise ValueError(
                 f"output of {arr.nbytes} bytes at offset {offset} exceeds "
