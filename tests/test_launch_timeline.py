@@ -17,7 +17,7 @@ import pytest
 
 from triton_client_tpu.obs import launch_timeline
 from triton_client_tpu.obs.trace import (
-    MultiTrace,
+    LaunchRecord,
     RequestTrace,
     Tracer,
     chrome_trace,
@@ -112,11 +112,11 @@ def test_stage_never_waits_for_the_device(monkeypatch):
     assert len(waits) == 2 and "h2d" in _by_name(trace)  # the marker, then the outputs
 
 
-def test_multitrace_fans_attrs_to_every_member():
+def test_launch_record_shows_its_attrs_on_every_member():
     from triton_client_tpu.channel.tpu_channel import TPUChannel
 
     members = [RequestTrace(i) for i in (1, 2, 3)]
-    TPUChannel(_repo()).do_inference(_request(MultiTrace(members)))
+    TPUChannel(_repo()).do_inference(_request(LaunchRecord(members)))
     for m in members:
         spans = _by_name(m)
         assert {spans[n].attrs["launch_id"] for n in LAUNCH_SPANS} == {1}
@@ -281,7 +281,7 @@ def test_launches_group_by_id_and_keep_the_earliest_member():
     early.add("parse", 10.0, 10.1)
     late.add("parse", 10.2, 10.25)
     early.add("batch_queue", 10.1, 10.3)
-    merged = MultiTrace([late, early])
+    merged = LaunchRecord([late, early])
     merged.add("batch_merge", 10.3, 10.4)  # the batcher's copy: on every member, without an id
     ids = {"launch_id": 5}
     merged.add("slot_wait", 10.4, 10.5, ids)
@@ -483,7 +483,7 @@ def test_profile_reports_the_launch_timeline_or_its_error(monkeypatch, broken):
         return
     timeline = doc["launch_timeline"]
     assert set(timeline) == {"offset_s", "residual_ms", "launches", "busy_s",
-                             "idle_by_state_s", "h2d_overlap"}
+                             "idle_by_state_s", "cycle_by_phase_s", "h2d_overlap"}
     # the CPU backend's trace has no device line: the host-clock estimate
     assert timeline["offset_s"] is None and timeline["launches"] >= 3
     assert timeline["busy_s"] > 0 and set(timeline["idle_by_state_s"]) == {

@@ -323,6 +323,38 @@ class TestSLOTracker:
         t.observe_request("m", wall_s=0.0001, trace="fast")
         assert t.violations() == ["late"]
 
+    def test_p99_is_estimated_anew_once_the_histogram_has_grown(self, monkeypatch):
+        """Not on every request: the estimate stands until the e2e
+        histogram has 3% more samples (at least 100 more)."""
+        fam = HistogramFamily()
+        t = SLOTracker(slo_ms=0.0, histograms=fam)
+        reads = []
+        real = LatencyHistogram.quantile
+        monkeypatch.setattr(
+            LatencyHistogram, "quantile",
+            lambda self, q: (reads.append(self.count), real(self, q))[1],
+        )
+        for _ in range(100):
+            fam.observe("m", "e2e", 0.001)
+        t.observe_request("m", wall_s=0.0005, trace="a")  # the first estimate, at 100
+        assert reads == [100]
+        # the histogram moves up by 99 slow samples: the threshold is still the old one
+        for _ in range(99):
+            fam.observe("m", "e2e", 1.0)
+        t.observe_request("m", wall_s=0.01, trace="over_the_old_p99")
+        assert reads == [100] and t.violations() == ["over_the_old_p99"]
+        # the 200th sample: estimated anew, and 0.01 s is no tail any more
+        fam.observe("m", "e2e", 1.0)
+        t.observe_request("m", wall_s=0.01, trace="under_the_new_p99")
+        assert reads == [100, 200] and t.violations() == ["over_the_old_p99"]
+        # from 3,200 samples on the step is 3% (n >> 5), not 100
+        for _ in range(6200):
+            fam.observe("m", "e2e", 1.0)
+        t.observe_request("m", wall_s=0.01, trace="b")
+        assert reads[-1] == 6400 and t._p99["m"][0] == 6400 + 200
+        # another model has an estimate of its own
+        assert t._p99_s("other") == float("inf") and "other" not in t._p99
+
 
 # -- live server --------------------------------------------------------------
 

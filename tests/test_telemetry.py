@@ -26,10 +26,13 @@ import pytest
 
 from triton_client_tpu.obs.collector import METRIC_TYPES, RuntimeCollector
 from triton_client_tpu.obs.trace import (
-    MultiTrace,
+    LaunchRecord,
     RequestTrace,
     Tracer,
     chrome_trace,
+    decode_span_summary,
+    encode_span_summary,
+    graft_span_summary,
 )
 
 jax = pytest.importorskip("jax")
@@ -141,16 +144,55 @@ def test_span_coverage_is_union_of_intervals():
     assert tr.span_coverage() == pytest.approx(0.7)
 
 
-def test_multitrace_fans_out_to_members():
+def test_span_coverage_counts_a_span_for_its_part_inside_the_wall():
+    tr = RequestTrace(1)
+    tr.t_start, tr.t_end = 10.0, 20.0
+    tr.add("front", 4.0, 10.0)  # ends where the wall begins: covers none of it
+    assert tr.span_coverage() == 0.0
+    tr.add("a", 9.0, 12.0)  # 2 s of it inside
+    tr.add("b", 18.0, 25.0)  # and 2 s of this one
+    assert tr.span_coverage() == pytest.approx(0.4)
+
+
+def test_spans_is_a_new_list_every_read_with_or_without_a_launch():
+    alone, merged = RequestTrace(1), RequestTrace(2)
+    LaunchRecord([merged]).add("stage", 1.0, 2.0)
+    for tr in (alone, merged):
+        tr.add("parse", 0.0, 1.0)
+        read = tr.spans
+        assert read is not tr.spans and read is not tr.own
+        tr.add("encode", 2.0, 3.0)  # a later write does not grow what was read
+        assert len(tr.spans) == len(read) + 1
+
+
+def test_a_front_span_before_the_first_trace_sorts_behind_the_metadata_and_grafts():
+    tr = RequestTrace(7, model="m")
+    tr.add("front", tr.t_start - 0.002, tr.t_start)
+    tr.add("parse", tr.t_start, tr.t_start + 0.001)
+    tr.t_end = tr.t_start + 0.004
+    events = chrome_trace([tr])["traceEvents"]
+    assert [e["ph"] for e in events[:2]] == ["M", "M"]
+    assert events[2]["name"] == "front" and events[2]["ts"] == pytest.approx(-2000.0)
+    # the far side lays a summary's negative offset BEFORE the server's start
+    local = RequestTrace(8)
+    graft_span_summary(local, decode_span_summary(encode_span_summary(tr)), 100.0, 100.010)
+    spans = {s.name: s for s in local.spans}
+    assert spans["wire_send"].t1 == pytest.approx(100.003)  # (10 - 4) / 2 ms of wire each way
+    assert spans["srv.front"].t0 == pytest.approx(100.001)
+    assert spans["srv.front"].t1 == pytest.approx(spans["srv.parse"].t0) == pytest.approx(100.003)
+
+
+def test_launch_record_is_written_once_and_read_on_every_member():
     a, b = RequestTrace(1), RequestTrace(2)
-    mt = MultiTrace([a, None, b])
-    mt.add("stage", 1.0, 2.0)
-    with mt.span("launch"):
-        pass
-    mt.begin("x")
-    mt.end("x")
-    for tr in (a, b):
-        assert [s.name for s in tr.spans] == ["stage", "launch", "x"]
+    a.add("own", 0.5, 0.6)
+    rec = LaunchRecord([a, None, b])
+    rec.add("stage", 1.0, 2.0)
+    rec.add("launch", 2.0, 2.5, {"launch_id": 3})
+    assert [s.name for s in rec.spans] == ["stage", "launch"]  # one write a span
+    assert [s.name for s in a.spans] == ["own", "stage", "launch"]
+    assert [s.name for s in b.spans] == ["stage", "launch"]
+    assert a.spans[1] is b.spans[0]  # the launch's span, not a copy a member
+    assert a.own == [a.spans[0]] and b.own == []
 
 
 def test_tracer_disabled_returns_none():

@@ -27,7 +27,8 @@ class InferRequest:
     inputs: Mapping[str, np.ndarray]
     model_version: str = ""
     request_id: str = ""
-    # request-scoped telemetry (obs.trace.RequestTrace / MultiTrace).
+    # request-scoped telemetry (obs.trace.RequestTrace; a merged
+    # launch's obs.trace.LaunchRecord).
     # None on the un-traced hot path: channels guard on the attribute,
     # so disabled tracing costs one attribute read per phase.
     trace: object | None = dataclasses.field(

@@ -20,7 +20,7 @@ readback — so this package makes that decomposition first-class:
 """
 
 from triton_client_tpu.obs.trace import (
-    MultiTrace,
+    LaunchRecord,
     RequestTrace,
     Span,
     Tracer,
@@ -48,7 +48,7 @@ __all__ = [
     "CompileEvents",
     "HistogramFamily",
     "LatencyHistogram",
-    "MultiTrace",
+    "LaunchRecord",
     "RequestTrace",
     "RuntimeCollector",
     "SLOTracker",

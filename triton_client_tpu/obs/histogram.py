@@ -102,6 +102,12 @@ class LatencyHistogram:
         return quantile_from_snapshot(self.snapshot(), q)
 
     @property
+    def count(self) -> int:
+        """Observations so far: one read, no lock (a reader that needs
+        the buckets with it takes ``snapshot``)."""
+        return self._count
+
+    @property
     def bounds(self) -> tuple[float, ...]:
         return self._bounds
 

@@ -55,23 +55,32 @@ class StageProfiler:
         self._listeners: list[Callable[[str, float], None]] = []
 
     def record(self, stage: str, seconds: float) -> None:
+        self.record_many(((stage, seconds),))
+
+    def record_many(self, samples) -> None:
+        """``(stage, seconds)`` samples under ONE hold of the lock (the
+        tracer hands over a finished request's spans at once, on the
+        handler thread the caller's next request waits behind)."""
         with self._lock:
-            buf = self._stages.get(stage)
-            if buf is None:
-                buf = self._stages[stage] = self._deque(maxlen=self._window)
-            buf.append(float(seconds))
-            self._counts[stage] = self._counts.get(stage, 0) + 1
+            stages, counts = self._stages, self._counts
+            for stage, seconds in samples:
+                buf = stages.get(stage)
+                if buf is None:
+                    buf = stages[stage] = self._deque(maxlen=self._window)
+                buf.append(float(seconds))
+                counts[stage] = counts.get(stage, 0) + 1
             listeners = list(self._listeners)
         for listener in listeners:
-            try:
-                listener(stage, seconds)
-            except Exception:  # noqa: BLE001 — observability must never
-                # fail the observed path (e.g. a gRPC request)
-                import logging
+            for stage, seconds in samples:
+                try:
+                    listener(stage, seconds)
+                except Exception:  # noqa: BLE001 — observability must never
+                    # fail the observed path (e.g. a gRPC request)
+                    import logging
 
-                logging.getLogger(__name__).warning(
-                    "profiler listener failed for stage %r", stage, exc_info=True
-                )
+                    logging.getLogger(__name__).warning(
+                        "profiler listener failed for stage %r", stage, exc_info=True
+                    )
 
     def add_listener(self, fn: Callable[[str, float], None]) -> None:
         """Observe every sample as it lands (Prometheus export hook)."""

@@ -16,7 +16,9 @@ Three jobs:
     server must not report 100% attainment as if it had one).
   * **tail sampler** — a bounded ring of full ``RequestTrace``
     exemplars, retained ONLY for requests that missed their SLO or
-    landed at/above the live p99 of their model's e2e histogram. The
+    landed at/above the p99 of their model's e2e histogram as last
+    estimated (anew each time the histogram has grown by 3%, at least
+    100 samples: not on every request). The
     main tracer ring keeps the last N requests regardless; this ring
     answers "show me the slow ones" after millions of fast requests
     have cycled the main ring. Exported at ``/traces?slo_violations=1``.
@@ -50,7 +52,7 @@ class SLOTracker:
         requests are scored only when they carry an explicit deadline).
         ``per_model``: model name -> budget ms overrides.
         ``histograms``: the serving ``HistogramFamily``; when present,
-        its live (model, e2e) p99 also qualifies traces for the tail
+        its (model, e2e) p99 (``_p99_s``) also qualifies traces for the tail
         ring, so the sampler keeps exemplars even on a server whose SLO
         is generous enough to never miss."""
         self._slo_s = max(0.0, float(slo_ms)) / 1e3
@@ -67,6 +69,10 @@ class SLOTracker:
         )
         self._tail_retained = 0
         self._deadline_missed = 0
+        # model -> (the e2e count at which its p99 is estimated anew,
+        # the estimate): a snapshot and a walk of the buckets a traced
+        # request cost 17 us on the handler thread (PERF.md, PR 42)
+        self._p99: dict[str, tuple[int, float]] = {}
 
     # -- configuration --------------------------------------------------------
 
@@ -145,17 +151,33 @@ class SLOTracker:
         keep = missed
         if not keep and self._hist is not None:
             try:
-                if (
-                    self._hist.count(model, "e2e") >= _MIN_P99_SAMPLES
-                    and wall_s >= self._hist.quantile(model, "e2e", 0.99)
-                ):
-                    keep = True
+                keep = wall_s >= self._p99_s(str(model))
             except Exception:
                 keep = False  # observability must never fail the path
         if keep:
             with self._lock:
                 self._tail.append(trace)
                 self._tail_retained += 1
+
+    def _p99_s(self, model: str) -> float:
+        """The p99 of ``model``'s e2e histogram (infinite under
+        ``_MIN_P99_SAMPLES``), estimated anew once the histogram has
+        grown by 3% (at least ``_MIN_P99_SAMPLES``) since the last
+        estimate, not on every request: between two estimates the
+        threshold is up to that many samples old (the first, taken at
+        100 samples, stands until 200). Handler threads write
+        ``_p99`` without the lock: one dict store of a tuple, and two
+        that race store estimates a few samples apart."""
+        hist = self._hist.child(model, "e2e")
+        n = hist.count
+        if n < _MIN_P99_SAMPLES:
+            return float("inf")
+        known = self._p99.get(model)
+        if known is None or n >= known[0]:
+            known = self._p99[model] = (
+                n + max(_MIN_P99_SAMPLES, n >> 5), hist.quantile(0.99)
+            )
+        return known[1]
 
     # -- reading --------------------------------------------------------------
 

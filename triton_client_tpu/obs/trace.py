@@ -32,6 +32,8 @@ import time
 import uuid
 from typing import Iterator
 
+from triton_client_tpu.obs.histogram import SLO_STAGES
+
 
 class TraceContext:
     """W3C-traceparent-style distributed context.
@@ -124,19 +126,26 @@ class RequestTrace:
     thread and closes it on the executor); ``end`` without a matching
     ``begin`` is a no-op, and a span left open when the trace finishes
     is dropped — observability must never fail the observed path.
+
+    No lock: a write is one ``list.append`` or one ``dict`` store or
+    ``pop``, each atomic under the interpreter lock, and a reader takes
+    a copy. What a merged launch did for ALL its members is not written
+    here: the trace points at the launch's :class:`LaunchRecord`
+    (``launches``), and ``spans`` reads both.
     """
 
     __slots__ = (
         "trace_id",
         "model",
         "request_id",
+        "session",
         "t_start",
         "t_end",
         "status",
-        "spans",
+        "own",
+        "launches",
         "context",
         "_open",
-        "_lock",
     )
 
     def __init__(
@@ -152,22 +161,32 @@ class RequestTrace:
         self.t_start = time.perf_counter()
         self.t_end: float | None = None
         self.status = "ok"
-        self.spans: list[Span] = []
+        # the sequence id the batcher merges on ("" for a stateless
+        # request): what tells a session's consecutive requests to be
+        # one caller's (obs/launch_timeline.py, the cycle's phases)
+        self.session = ""
+        self.own: list[Span] = []
+        self.launches: list[LaunchRecord] = []
         # distributed context (TraceContext): None on purely local
         # traces; set when the server adopts an inbound traceparent or
         # the router originates one. The local int trace_id still keys
         # the ring buffer — the context's hex trace_id keys the FLEET.
         self.context = context
         self._open: dict[str, float] = {}
-        self._lock = threading.Lock()
+
+    @property
+    def spans(self) -> list[Span]:
+        """The request's own spans and, after them, those of every
+        launch it rode on (each launch's in the order written): a new
+        list every read, so a reader never holds one a writer grows."""
+        return self.own + [s for rec in self.launches for s in rec.spans]
 
     # -- recording ------------------------------------------------------------
 
     def add(
         self, name: str, t0: float, t1: float, attrs: dict | None = None
     ) -> None:
-        with self._lock:
-            self.spans.append(Span(name, t0, t1, attrs))
+        self.own.append(Span(name, t0, t1, attrs))
 
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:
@@ -178,15 +197,13 @@ class RequestTrace:
             self.add(name, t0, time.perf_counter())
 
     def begin(self, name: str) -> None:
-        with self._lock:
-            self._open[name] = time.perf_counter()
+        self._open[name] = time.perf_counter()
 
     def end(self, name: str) -> None:
         t1 = time.perf_counter()
-        with self._lock:
-            t0 = self._open.pop(name, None)
-            if t0 is not None:
-                self.spans.append(Span(name, t0, t1))
+        t0 = self._open.pop(name, None)
+        if t0 is not None:
+            self.own.append(Span(name, t0, t1))
 
     # -- reading --------------------------------------------------------------
 
@@ -196,12 +213,16 @@ class RequestTrace:
 
     def span_coverage(self) -> float:
         """Fraction of [t_start, t_end] covered by the union of spans —
-        the acceptance gauge for 'no invisible time in the pipeline'."""
+        the acceptance gauge for 'no invisible time in the pipeline'.
+        A span is counted for its part inside the wall (``front`` ends
+        where the wall begins and covers none of it)."""
         wall = self.wall_s()
         if wall <= 0:
             return 1.0
-        with self._lock:
-            ivals = sorted((s.t0, s.t1) for s in self.spans)
+        lo, hi = self.t_start, self.t_start + wall
+        ivals = sorted(
+            (max(s.t0, lo), min(s.t1, hi)) for s in self.spans if s.t1 > lo and s.t0 < hi
+        )
         covered, cur0, cur1 = 0.0, None, None
         for t0, t1 in ivals:
             if cur1 is None or t0 > cur1:
@@ -215,16 +236,15 @@ class RequestTrace:
         return min(1.0, covered / wall)
 
     def summary(self) -> dict:
-        with self._lock:
-            spans = [
-                {
-                    "name": s.name,
-                    "t0_s": s.t0 - self.t_start,
-                    "dur_ms": s.duration_s * 1e3,
-                    **({"attrs": s.attrs} if s.attrs else {}),
-                }
-                for s in sorted(self.spans, key=lambda s: s.t0)
-            ]
+        spans = [
+            {
+                "name": s.name,
+                "t0_s": s.t0 - self.t_start,
+                "dur_ms": s.duration_s * 1e3,
+                **({"attrs": s.attrs} if s.attrs else {}),
+            }
+            for s in sorted(self.spans, key=lambda s: s.t0)
+        ]
         out = {
             "trace_id": self.trace_id,
             "model": self.model,
@@ -238,45 +258,32 @@ class RequestTrace:
         return out
 
 
-class MultiTrace:
-    """Fan-out proxy for merged device batches.
+class LaunchRecord:
+    """What ONE launch of a merged group did, written once.
 
     The batcher concatenates N requests into one inner-channel call;
-    the merged InferRequest carries a MultiTrace over the members'
-    traces, so channel-side spans (stage/launch/device_execute/
-    readback) land on EVERY member — each request's trace shows the
-    shared device work it rode on."""
+    the merged InferRequest carries this record as its ``trace``, so
+    the channel-side spans (``slot_wait``/``stage``/``h2d``/``launch``/
+    ``device_execute``/the named device window/``readback``) and the
+    batcher's ``batch_merge`` are ONE append each on the executor
+    thread, however many members the launch has. Every member's trace
+    points at the record (``RequestTrace.launches``), and a reader of
+    ``RequestTrace.spans`` (the ``/traces`` export, the span
+    histograms, the launch timeline) still sees the launch's spans on
+    each member: the expansion happens where it is read."""
 
-    __slots__ = ("members",)
+    __slots__ = ("spans",)
 
-    def __init__(self, members) -> None:
-        self.members = [m for m in members if m is not None]
+    def __init__(self, members=()) -> None:
+        self.spans: list[Span] = []
+        for m in members:
+            if m is not None:
+                m.launches.append(self)
 
     def add(
         self, name: str, t0: float, t1: float, attrs: dict | None = None
     ) -> None:
-        # one attrs dict shared by every member: the channel fills in
-        # ``launch_id`` once for the whole merged launch
-        for m in self.members:
-            m.add(name, t0, t1, attrs)
-
-    @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            for m in self.members:
-                m.add(name, t0, t1)
-
-    def begin(self, name: str) -> None:
-        for m in self.members:
-            m.begin(name)
-
-    def end(self, name: str) -> None:
-        for m in self.members:
-            m.end(name)
+        self.spans.append(Span(name, t0, t1, attrs))
 
 
 class Tracer:
@@ -313,6 +320,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._ids = itertools.count(1)
         self._finished = 0
+        # span name -> its stage label, resolved once a name
+        self._stage_names: dict[str, str] = {}
         # one (perf_counter, time_ns) pair, taken together: what lets a
         # reader lay this process's spans beside another clock's events
         self._clock_anchor = (time.perf_counter(), time.time_ns())
@@ -341,20 +350,27 @@ class Tracer:
         with self._lock:
             self._ring.append(trace)
             self._finished += 1
+        if self._profiler is None and self._histograms is None:
+            return
+        spans = trace.spans
         if self._profiler is not None:
-            for s in list(trace.spans):
-                self._profiler.record(f"span_{s.name}", s.duration_s)
+            # one hold of the profiler's lock a request, the stage
+            # names resolved once a name: not a lock and a label a span
+            names = self._stage_names
+            samples = []
+            for s in spans:
+                stage = names.get(s.name)
+                if stage is None:
+                    stage = names.setdefault(s.name, f"span_{s.name}")
+                samples.append((stage, s.t1 - s.t0))
+            self._profiler.record_many(samples)
         if self._histograms is not None:
-            from triton_client_tpu.obs.histogram import SLO_STAGES
-
             model = trace.model or ""
-            for s in list(trace.spans):
+            for s in spans:
                 stage = SLO_STAGES.get(s.name)
                 if stage is not None:
-                    self._histograms.observe(model, stage, s.duration_s)
-            self._histograms.observe(
-                model, "e2e", trace.t_end - trace.t_start
-            )
+                    self._histograms.observe(model, stage, s.t1 - s.t0)
+            self._histograms.observe(model, "e2e", trace.t_end - trace.t_start)
 
     def recent(self, n: int = 0) -> list[RequestTrace]:
         """Most recent ``n`` finished traces (0 = everything buffered),
@@ -431,6 +447,8 @@ def chrome_trace(traces, clock_anchor=None) -> dict:
         ctx = getattr(tr, "context", None)
         if ctx is not None:
             req_args["traceparent"] = ctx.encode()
+        if tr.session:
+            req_args["session"] = tr.session
         events.append(
             {
                 "ph": "X",
@@ -456,7 +474,9 @@ def chrome_trace(traces, clock_anchor=None) -> dict:
             if s.attrs:
                 ev["args"] = dict(s.attrs)
             events.append(ev)
-    events.sort(key=lambda e: (e.get("ts", -1.0), e["tid"]))
+    # the metadata events (no ``ts``) first: a ``front`` span begins
+    # before its trace and so, on the first trace, before ``base``
+    events.sort(key=lambda e: (e.get("ts", float("-inf")), e["tid"]))
     return {"traceEvents": events, "displayTimeUnit": "ms", **clock}
 
 
@@ -477,14 +497,16 @@ def encode_span_summary(trace: RequestTrace) -> str:
     Times are microseconds RELATIVE to the trace's own t_start (each
     process has its own perf_counter epoch — absolute values would be
     meaningless on the far side): ``{"w": wall_us, "st": status,
-    "s": [[name, t0_rel_us, dur_us], ...]}``. Kept deliberately terse:
-    this string rides every traced response."""
+    "s": [[name, t0_rel_us, dur_us], ...]}`` (``front`` lies before
+    the start, so its offset is negative and the far side lays it into
+    what it counts as the wire). Kept deliberately terse:
+    this string rides every response whose REQUEST carried a
+    ``traceparent`` (somebody upstream wants to graft it)."""
     t_start = trace.t_start
-    with trace._lock:
-        spans = [
-            [s.name, round((s.t0 - t_start) * 1e6), round(s.duration_s * 1e6)]
-            for s in sorted(trace.spans, key=lambda s: s.t0)
-        ]
+    spans = [
+        [s.name, round((s.t0 - t_start) * 1e6), round(s.duration_s * 1e6)]
+        for s in sorted(trace.spans, key=lambda s: s.t0)
+    ]
     doc = {
         "w": round(trace.wall_s() * 1e6),
         "st": trace.status,

@@ -73,7 +73,7 @@ from triton_client_tpu.channel.base import (
     InferRequest,
     InferResponse,
 )
-from triton_client_tpu.obs.trace import MultiTrace
+from triton_client_tpu.obs.trace import LaunchRecord
 from triton_client_tpu.parallel.ragged_kernels import (
     RaggedLayout,
     pack_rows,
@@ -733,8 +733,7 @@ class ContinuousBatchingChannel(BaseChannel):
                         it[4] for it in g
                     )
                     # PER-MEMBER queue delay, not just the merged
-                    # batch's (which MultiTrace would fan out as one
-                    # shared number): each member's own staging
+                    # batch's (one shared number): each member's own staging
                     # timestamp to this dispatch
                     self._decomp["members"] += len(g)
                     self._decomp["member_wait_s"] += sum(
@@ -1104,10 +1103,13 @@ class ContinuousBatchingChannel(BaseChannel):
     def _open_group(self, group):
         """A merged group leaves the ready set: each member's own
         ``merge_wait`` (its staging timestamp -> this dispatch; the
-        merge_wait SLO stage) and the end of its ``batch_queue``."""
+        merge_wait SLO stage) and the end of its ``batch_queue``.
+        Returns the members' requests and futures and, where any member
+        is traced, the launch's ONE record (else None): what the batcher
+        and the channel below it do for the whole launch is written
+        there once."""
         requests = [g[1] for g in group]
         futures = [g[2] for g in group]
-        traces = [r.trace for r in requests]
         t_dispatch = time.perf_counter()
         if log.isEnabledFor(logging.DEBUG):
             # correlated dispatch line: each member's trace/request tag,
@@ -1124,10 +1126,10 @@ class ContinuousBatchingChannel(BaseChannel):
         for (t_staged, r, _f) in group:
             if r.trace is not None and t_staged is not None:
                 r.trace.add("merge_wait", t_staged, t_dispatch)
-        for tr in traces:
-            if tr is not None:
-                tr.end("batch_queue")
-        return requests, futures, traces
+        traced = [r.trace for r in requests if r.trace is not None]
+        for tr in traced:
+            tr.end("batch_queue")
+        return requests, futures, LaunchRecord(traced) if traced else None
 
     def _still_live(self, group, free_slot) -> bool:
         """Second deadline pass AFTER the pack: the host merge build
@@ -1150,7 +1152,7 @@ class ContinuousBatchingChannel(BaseChannel):
         return False
 
     def _launch(
-        self, requests, traces, merged, free_slot, t_stage0, t_disp, **fields
+        self, requests, record, merged, free_slot, t_stage0, t_disp, **fields
     ):
         """One launch for the whole group, async + deferred readback:
         by the time ``do_inference_async`` returns, the inner channel
@@ -1165,12 +1167,9 @@ class ContinuousBatchingChannel(BaseChannel):
                     model_version=requests[0].model_version,
                     inputs=merged,
                     # channel-side spans (stage/launch/device/readback)
-                    # fan out to every member's trace
-                    trace=(
-                        MultiTrace(traces)
-                        if any(t is not None for t in traces)
-                        else None
-                    ),
+                    # are written once, on the launch's record, which
+                    # every traced member's trace points at
+                    trace=record,
                     # the merged batch inherits its TIGHTEST member's
                     # deadline and HIGHEST priority: the batch is late
                     # the moment any member is
@@ -1188,16 +1187,15 @@ class ContinuousBatchingChannel(BaseChannel):
                 self._decomp["stage_s"] += t_disp - t_stage0
                 self._decomp["device_s"] += t_dev_end - t_disp
 
-    def _count_merged(self, merged: dict, traces, t_stage0, t_disp) -> None:
+    def _count_merged(self, merged: dict, record, t_stage0, t_disp) -> None:
         """``merged_bytes`` and the ``batch_merge`` span: a device batch
         this batcher built by copying its members' rows."""
         with self._ready_cv:
             self._merge_stats["merged_bytes"] += sum(
                 a.nbytes for a in merged.values()
             )
-        for tr in traces:
-            if tr is not None:
-                tr.add("batch_merge", t_stage0, t_disp)
+        if record is not None:
+            record.add("batch_merge", t_stage0, t_disp)
 
     def _retry_solo(self, requests, futures) -> None:
         """A merged failure must not take down unrelated requests: fall
@@ -1246,7 +1244,7 @@ class ContinuousBatchingChannel(BaseChannel):
         the device waiting two thirds of the time (PERF.md, PR 27). No
         ``batch_merge`` span where nothing was copied: one of zero
         length would still read as a state in obs/launch_timeline.py."""
-        requests, futures, traces = self._open_group(group)
+        requests, futures, record = self._open_group(group)
         try:
             t_stage0 = time.perf_counter()
             merged = {
@@ -1258,7 +1256,7 @@ class ContinuousBatchingChannel(BaseChannel):
             if not self._still_live(group, free_slot):
                 return
             resp = self._launch(
-                requests, traces, merged, free_slot, t_stage0, t_disp
+                requests, record, merged, free_slot, t_stage0, t_disp
             )
         except Exception:
             self._retry_solo(requests, futures)
@@ -1268,7 +1266,7 @@ class ContinuousBatchingChannel(BaseChannel):
     def _run_dense_merge(self, group, sizes, pad, free_slot=None) -> None:
         """Same-shaped requests as ONE buffer per input, ``pad`` rows
         appended to reach the bucket."""
-        requests, futures, traces = self._open_group(group)
+        requests, futures, record = self._open_group(group)
         try:
             t_stage0 = time.perf_counter()
             merged = {}
@@ -1280,11 +1278,11 @@ class ContinuousBatchingChannel(BaseChannel):
                     parts = pad_rows(parts, pad)
                 merged[name] = np.concatenate(parts)
             t_disp = time.perf_counter()
-            self._count_merged(merged, traces, t_stage0, t_disp)
+            self._count_merged(merged, record, t_stage0, t_disp)
             if not self._still_live(group, free_slot):
                 return
             resp = self._launch(
-                requests, traces, merged, free_slot, t_stage0, t_disp
+                requests, record, merged, free_slot, t_stage0, t_disp
             )
             if pad:
                 # counted only for a padded call that actually ran,
@@ -1305,7 +1303,7 @@ class ContinuousBatchingChannel(BaseChannel):
         and no retry of a failed launch (the cache may have moved on).
         The answer holds a row a token of each step (one, or a block
         model's block), in the members' order."""
-        requests, futures, traces = self._open_group(group)
+        requests, futures, record = self._open_group(group)
         try:
             sizes = [
                 max(np.shape(a)[1] for a in r.inputs.values()) for r in requests
@@ -1318,11 +1316,11 @@ class ContinuousBatchingChannel(BaseChannel):
                 for name in requests[0].inputs
             }
             t_disp = time.perf_counter()
-            self._count_merged(merged, traces, t_stage0, t_disp)
+            self._count_merged(merged, record, t_stage0, t_disp)
             if not self._still_live(group, free_slot):
                 return
             resp = self._launch(
-                requests, traces, merged, free_slot, t_stage0, t_disp,
+                requests, record, merged, free_slot, t_stage0, t_disp,
                 sequence_rows=tuple(
                     (r.sequence_id, r.sequence_start, r.sequence_end)
                     for r in requests
@@ -1344,7 +1342,7 @@ class ContinuousBatchingChannel(BaseChannel):
         concatenate, the segment table rides in ``request.ragged``, and
         the inner channel's segment-aware launcher runs every member at
         true size."""
-        requests, futures, traces = self._open_group(group)
+        requests, futures, record = self._open_group(group)
         try:
             ragged_names = self._ragged_names(
                 requests[0].model_name, requests[0].model_version
@@ -1380,11 +1378,11 @@ class ContinuousBatchingChannel(BaseChannel):
                         np.stack(parts), layout.seg_bucket
                     )
             t_disp = time.perf_counter()
-            self._count_merged(merged, traces, t_stage0, t_disp)
+            self._count_merged(merged, record, t_stage0, t_disp)
             if not self._still_live(group, free_slot):
                 return
             resp = self._launch(
-                requests, traces, merged, free_slot, t_stage0, t_disp,
+                requests, record, merged, free_slot, t_stage0, t_disp,
                 ragged=lay,
             )
             with self._ready_cv:

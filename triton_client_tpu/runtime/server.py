@@ -403,7 +403,8 @@ class _Servicer(service.GRPCInferenceServiceServicer):
     # -- inference ------------------------------------------------------------
 
     def _issue(
-        self, request, inputs_override=None, id_override=None, plan=None
+        self, request, inputs_override=None, id_override=None, plan=None,
+        t_front=None,
     ):
         """Parse + dispatch one request; returns a finisher callable.
 
@@ -418,6 +419,9 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         :meth:`_front` holds one: parse and response then follow it
         instead of walking the tensors again, and the request carries
         no content encoding (a plan of one that does is not kept).
+        ``t_front``: when ``ModelInfer`` was entered, taken there only
+        with a tracer attached: the trace then has a ``front`` span
+        from that moment to its own start (what :meth:`_front` did).
 
         The dispatch goes through ``do_inference_async`` so the device
         (or inner batcher) starts while THIS thread still prepares the
@@ -452,6 +456,8 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                 model=request.model_name, request_id=request_id,
                 context=context,
             )
+            if trace is not None and t_front is not None:
+                trace.add("front", t_front, trace.t_start)
         # quality plane: the sampling/canary key is the trace id when
         # tracing is on (stable fleet-wide: the router's traceparent is
         # adopted above, so router and replica decide identically) and
@@ -470,6 +476,8 @@ class _Servicer(service.GRPCInferenceServiceServicer):
         sequence_id, sequence_start, sequence_end, priority = (
             codec.sequence_params(request)
         )
+        if trace is not None and sequence_id:
+            trace.session = sequence_id
         deadline_s = None
         if self._slo is not None:
             deadline_s = self._slo.deadline_for(request.model_name, t0)
@@ -658,13 +666,19 @@ class _Servicer(service.GRPCInferenceServiceServicer):
                         templates=templates,
                     )
                     trace.add("encode", t_e0, time.perf_counter())
-                    # compact span summary in the response parameters
-                    # (AFTER the encode span lands, so the far side's
-                    # grafted timeline includes it): the router/client
-                    # merges it onto the end-to-end trace
-                    codec.set_request_params(
-                        resp, {SUMMARY_PARAM_KEY: encode_span_summary(trace)}
-                    )
+                    if trace.context is not None:
+                        # the request carried a traceparent: somebody
+                        # upstream (the router originates one) grafts
+                        # this replica's spans onto the end-to-end
+                        # trace, so the compact span summary rides the
+                        # response (AFTER the encode span lands, so the
+                        # grafted timeline includes it). A bare client's
+                        # traced request gets none: a sort and a
+                        # json.dumps a request that nobody reads
+                        codec.set_request_params(
+                            resp,
+                            {SUMMARY_PARAM_KEY: encode_span_summary(trace)},
+                        )
                     return resp
                 return codec.build_infer_response(
                     model_name=result.model_name,
@@ -891,10 +905,13 @@ class _Servicer(service.GRPCInferenceServiceServicer):
 
     def ModelInfer(self, request, context):
         c0 = time.thread_time()
+        # a traced request's ``front`` span begins here
+        t_front = time.perf_counter() if self._tracer is not None else None
         cell = self._front_cell()
         try:
             return self._issue(
-                request, plan=self._front(request, context, cell)
+                request, plan=self._front(request, context, cell),
+                t_front=t_front,
             )()
         except _RemoteShm as e:
             self._refuse_remote(context, str(e))
