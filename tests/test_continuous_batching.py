@@ -979,36 +979,50 @@ class _Clock:
 class _StepInner:
     """A ``session_merge`` model whose launches resolve when the test
     says so: ``launches`` holds what went down and ``began`` when, on
-    the test's clock; ``land(i)`` hands launch ``i`` its answer (a row a
-    member)."""
+    the test's clock (``stage_s`` of it after the call came in: what
+    stage and launch take); ``off_device(i)`` says launch ``i``'s
+    outputs are ready on the device, ``land(i)`` that and hands it its
+    answer (a row a member); ``events`` is the order of it all."""
 
     batch_multiple = 1
+    stage_s = 0.0
 
     def __init__(self, clock):
         self.clock = clock
-        self.launches, self.began, self._gates = [], [], []
+        self.launches, self.began, self._gates, self.events = [], [], [], []
 
     def get_metadata(self, name, version=""):
         return types.SimpleNamespace(extra={"session_merge": True})
 
     def do_inference_async(self, request):
-        gate = threading.Event()
+        device, answer = threading.Event(), threading.Event()
+        self.clock.now += self.stage_s
+        i = len(self.launches)
         self.began.append(self.clock.now)
-        self._gates.append(gate)
+        self._gates.append((device, answer))
         self.launches.append(request)
+        self.events.append(("launch", i))
         rows = np.asarray(request.inputs["tokens"]).shape[0]
 
+        def wait_device():
+            assert device.wait(30.0)
+
         def result():
-            assert gate.wait(30.0)
+            assert answer.wait(30.0)
+            self.events.append(("result", i))
             return InferResponse(
                 model_name=request.model_name,
                 outputs={"y": np.zeros((rows, 1), np.float32)},
             )
 
-        return types.SimpleNamespace(result=result)
+        return types.SimpleNamespace(result=result, wait_device=wait_device)
+
+    def off_device(self, i):
+        self._gates[i][0].set()
 
     def land(self, i):
-        self._gates[i].set()
+        self.off_device(i)
+        self._gates[i][1].set()
 
     def sessions(self, i):
         """The sessions of launch ``i``, in row order."""
@@ -1050,10 +1064,10 @@ class _StepRig:
         self.chan.close()
         self.pool.shutdown(wait=True)
 
-    def send(self, sid, tokens=1, end=False):
+    def send(self, sid, tokens=1, end=False, model="m", token=0):
         """Returns once the request is in the ready set (or beyond)."""
         request = InferRequest(
-            "m", {"tokens": np.zeros((1, tokens), np.int32)},
+            model, {"tokens": np.full((1, tokens), token, np.int32)},
             sequence_id=sid, sequence_end=end,
         )
         before = self._admitted()
@@ -1277,6 +1291,209 @@ def test_a_step_group_closes_when_the_device_can_take_it(case, monkeypatch):
         rig.close()
 
 
+@pytest.mark.parametrize(
+    "case",
+    [
+        "due", "returns_within_a_launch", "too_many_together", "prompt_ahead",
+        "other_model_ahead",
+        "prediction_late", "missed", "launch_s", "no_second_behind",
+        "close_drains", "one_of_a_session",
+    ],
+)
+def test_a_step_group_closes_behind_a_step_launch_about_to_leave(case, monkeypatch):
+    """``_early_wait_locked`` on a clock the test moves; a launch holds
+    the device 20 ms and a close takes 3 ms to reach it. Where the
+    sessions take longer to come back than a launch lasts, a step group
+    behind a step launch of its own model closes 3 ms before that
+    launch is due off the device, at the latest when its outputs are
+    ready there, and before any of its answers; everywhere else it
+    stays open until the launch ahead has been answered."""
+    rig = _StepRig(monkeypatch)
+    rig.inner.stage_s = lead = 0.003
+    T = rig.T
+    early = lambda: tuple(
+        rig.chan.stats()[k]
+        for k in ("step_early_closes", "step_early_by_event", "step_early_missed")
+    )
+    try:
+        if case in ("returns_within_a_launch", "too_many_together"):
+            # (b) sessions are back 5 ms after their answers: those IN
+            # the launch ahead are worth waiting for. s4, new, is ready
+            # behind launch 3; the group stays open past the due time
+            # and past the device's event, until launch 3 is answered,
+            # and then waits at the free device for s0-s3 (PR 37)
+            rig.warm(return_s=0.005)
+            for sid in ("s1", "s2", "s3"):
+                rig.send(sid)
+            assert rig.launched(3) == ["s0", "s1", "s2", "s3"]
+            rig.send("s4")
+            if case == "too_many_together":
+                # six are ready behind a launch of four: a launch of
+                # all ten would answer two and a half times the
+                # sessions that the observed returns followed, and
+                # those would not be back within a launch's time. Two
+                # cohorts stay two: the six go down behind launch 3
+                for sid in ("s5", "s6", "s7", "s8", "s9"):
+                    rig.send(sid)
+                rig.after(T - lead - 0.001)
+                rig.never_launched(4, for_s=0.15)
+                rig.after(0.0015)
+                assert rig.launched(4) == ["s4", "s5", "s6", "s7", "s8", "s9"]
+                assert ("result", 2) not in rig.inner.events
+                assert early() == (1, 0, 0)
+                return
+            rig.after(T - lead + 0.001)
+            rig.never_launched(4, for_s=0.15)
+            rig.inner.off_device(2)
+            rig.never_launched(4, for_s=0.15)
+            rig.land(3, ["s0", "s1", "s2", "s3"])
+            rig.never_launched(4, for_s=0.15)
+            rig.after(0.005)
+            for sid in ("s0", "s1", "s2", "s3"):
+                rig.send(sid)
+            assert rig.launched(4) == ["s4", "s0", "s1", "s2", "s3"]
+            assert early() == (0, 0, 0)
+            assert rig.chan.stats()["step_holds"] == 2
+            return
+        # sessions are back 30 ms after their answers, a launch lasts 20
+        rig.warm(return_s=0.030)
+        assert rig.launched(3) == ["s0"]  # at once, alone
+        s = rig.chan.stats()
+        assert s["step_device_ms"] == pytest.approx(T * 1e3)
+        assert s["step_lead_ms"] == pytest.approx(lead * 1e3)
+        assert s["step_launch_ms"] == pytest.approx((lead + T) * 1e3)
+        assert s["step_return_ms"] + 2 * s["step_return_dev_ms"] > s["step_launch_ms"]
+        if case in ("prompt_ahead", "other_model_ahead"):
+            # (c), (d) what is ahead is no step launch of this model:
+            # how long it holds the device is not the pace's to say,
+            # and the group stays open until it has been answered
+            rig.land(3, ["s0"])
+            if case == "prompt_ahead":
+                rig.send("s9", tokens=5)
+            else:
+                rig.send("s9", model="m2")
+            assert rig.launched(4) == ["s9"]
+            rig.send("s1")
+            rig.send("s2")
+            rig.after(T)
+            rig.never_launched(5, for_s=0.15)
+            rig.inner.off_device(3)
+            rig.never_launched(5, for_s=0.15)
+            rig.land(4, ["s9"])
+            assert rig.launched(5) == ["s1", "s2"]
+            assert early() == (0, 0, 0)
+            return
+        began = rig.inner.began[2]
+        assert rig.clock.now == began  # launch 3 is on the device since now
+        rig.send("s1", token=1)
+        if case == "one_of_a_session":
+            rig.send("s1", token=2)  # (j) its next step, already here
+        else:
+            rig.send("s2")
+        if case == "prediction_late":
+            # (e) the device is done before the prediction said so: the
+            # group closes on that event, before launch 3 is read back
+            rig.never_launched(4, for_s=0.15)
+            rig.inner.off_device(2)
+            assert rig.launched(4) == ["s1", "s2"]
+            assert rig.inner.events[-1] == ("launch", 3)
+            assert not rig.answers["s0"].done()
+            assert early() == (1, 1, 0)
+            return
+        # (a) it closes `lead` before launch 3 is due, not before
+        rig.after(T - lead - 0.001)
+        rig.never_launched(4, for_s=0.15)
+        rig.after(0.0015)
+        closed = rig.clock.now
+        if case == "one_of_a_session":
+            # at most one of a session in a launch, and in order
+            assert rig.launched(4) == ["s1"]
+            assert rig.inner.launches[3].inputs["tokens"].tolist() == [[1]]
+            rig.land(3, ["s0"])
+            rig.after(T)
+            assert rig.launched(5) == ["s1"]
+            assert rig.inner.launches[4].inputs["tokens"].tolist() == [[2]]
+            return
+        assert rig.launched(4) == ["s1", "s2"]
+        assert rig.inner.began[3] == pytest.approx(closed + lead)
+        # the inner channel has launch 4 before launch 3's answer is taken
+        assert ("result", 2) not in rig.inner.events
+        assert not rig.answers["s0"].done()
+        assert early() == (1, 0, 0)
+        if case == "due":
+            rig.land(3, ["s0"])
+            rig.land(4, ["s1", "s2"])
+            assert rig.inner.events.index(("launch", 3)) < rig.inner.events.index(("result", 2))
+        elif case == "launch_s":
+            # (g) launch 3 leaves the device 10 ms late and launch 4
+            # queues behind it meanwhile: T counts launch 4 from launch
+            # 3's end on the device, not from its own dispatch
+            rig.after(0.010)
+            rig.land(3, ["s0"])
+            rig.after(T)
+            rig.inner.land(3)
+            for sid in ("s1", "s2"):
+                rig.answers[sid].result(timeout=20.0)
+            _until(lambda: rig.chan._launches_ahead == 0)
+            s = rig.chan.stats()
+            late = rig.clock.now - T - began - T
+            mean = lambda m, sample: m + (sample - m) / 8
+            assert late == pytest.approx(0.0105)
+            assert s["step_launch_ms"] == pytest.approx(
+                mean(mean(lead + T, lead + T + late), T) * 1e3, abs=1e-3
+            )
+            assert s["step_device_ms"] == pytest.approx(
+                mean(mean(T, T + late), T) * 1e3, abs=1e-3
+            )
+        else:
+            # (f) a step staged between the early close and launch 3's
+            # end missed launch 4, which is what the rule costs, and
+            # goes into the NEXT group; (h) that group does not close
+            # while launch 4 itself still waits for the device, however
+            # late it gets
+            rig.send("s3")
+            assert early() == (1, 0, 1)
+            if case != "no_second_behind":
+                rig.after(0.050)
+            rig.never_launched(5, for_s=0.15)
+            if case == "close_drains":
+                # (i) an open group behind two launches is no stall
+                # while they are younger than the threshold, and
+                # close() waits for nobody: the group closes behind both
+                # (and runs when an executor thread is free)
+                for _ in range(4):
+                    rig.after(1.0)
+                    _until(lambda: rig.chan.dispatcher_progress_age_s() == 0.0)
+                closing = rig.pool.submit(rig.chan.close)
+                _until(lambda: rig.chan.stats()["ready_depth"] == 0)
+                assert early() == (2, 0, 1)
+                rig.inner.land(2)
+                assert rig.launched(5) == ["s3"]
+                rig.inner.land(3)
+                rig.inner.land(4)
+                closing.result(timeout=20.0)
+                for sid in ("s0", "s1", "s2", "s3"):
+                    assert rig.answers[sid].result(timeout=20.0).outputs["y"].shape == (1, 1)
+                return
+            if case == "no_second_behind":
+                # launch 4 is on the device from launch 3's end, and the
+                # next group closes `lead` before launch 4 is due
+                rig.land(3, ["s0"])
+                rig.after(T - lead - 0.001)
+                rig.never_launched(5, for_s=0.15)
+                rig.after(0.0015)
+            else:
+                rig.inner.off_device(2)  # 50 ms late
+                rig.never_launched(5, for_s=0.15)
+                rig.land(3, ["s0"])  # the mean device time grew by it
+                rig.never_launched(5, for_s=0.15)
+                rig.after(2 * T)
+            assert rig.launched(5) == ["s3"]
+            assert early() == (2, 0, 1)
+    finally:
+        rig.close()
+
+
 def _reader_ctx(batching_before, batching_after, launches=(10, 74)):
     sessions = lambda n: {"models": {"lm": {"lm_step_launches": n}}}
     return {
@@ -1316,6 +1533,43 @@ def test_the_reader_of_the_hold_counters(case, capsys):
         assert reader.read(_reader_ctx(zero, zero, launches=(10, 10))) is None
     else:
         parent = {"merges": 5, "passthrough_groups": 5}
+        assert reader.read(_reader_ctx(parent, {**parent, "merges": 9})) is None
+        assert reader.read({}) is None
+        assert capsys.readouterr().out == ""
+
+
+@pytest.mark.parametrize("case", ["steps", "blocks", "aside", "no_launch", "no_counters"])
+def test_the_reader_of_the_early_close_counters(case, capsys):
+    """``benchmarks/layer_metrics/step_early_share.py``: the growth of
+    ``step_early_closes`` over the window's step and block launches, in
+    %; the three counters' growth and the two times of the prediction in
+    the log; nothing from a program without them."""
+    import importlib
+    import json
+
+    reader = importlib.import_module("benchmarks.layer_metrics.step_early_share")
+    zero = {"step_early_closes": 0, "step_early_by_event": 0, "step_early_missed": 0}
+    before = {"step_early_closes": 40, "step_early_by_event": 4, "step_early_missed": 30}
+    after = {
+        "step_early_closes": 88, "step_early_by_event": 10, "step_early_missed": 75,
+        "step_device_ms": 13.5, "step_lead_ms": 3.25,
+    }
+    if case in ("steps", "blocks"):
+        ctx = _reader_ctx(before, after)  # 64 step launches
+        if case == "blocks":
+            for snap, n in ((ctx["snapshot_before"], 10), (ctx["snapshot_after"], 74)):
+                snap["sessions"]["models"]["lm"] = {"lm_step_launches": 0, "lm_block_launches": n}
+        assert reader.read(ctx) == pytest.approx(75.0)  # 48 of 64
+        (line,) = capsys.readouterr().out.splitlines()
+        assert json.loads(line) == {"step_early": {
+            "step_early_closes": 48, "step_early_by_event": 6, "step_early_missed": 45,
+            "step_device_ms": 13.5, "step_lead_ms": 3.25}}
+    elif case == "aside":
+        assert reader.read(_reader_ctx(zero, {**zero, "step_device_ms": 19.6, "step_lead_ms": 2.0})) == 0.0
+    elif case == "no_launch":
+        assert reader.read(_reader_ctx(zero, zero, launches=(10, 10))) is None
+    else:
+        parent = {"merges": 5, "step_holds": 3, "step_hold_s": 0.1}
         assert reader.read(_reader_ctx(parent, {**parent, "merges": 9})) is None
         assert reader.read({}) is None
         assert capsys.readouterr().out == ""

@@ -374,6 +374,20 @@ class _Inflight:
         jax.block_until_ready(self.outputs)
 
 
+class _LaunchFuture(InferFuture):
+    """A launch's lazy future that can also say when the outputs are
+    ready ON THE DEVICE: ``wait_device()`` returns then, their readback
+    still to come in ``result()``. The batcher closes a group of session
+    steps behind a running launch on that event
+    (runtime/continuous.py)."""
+
+    __slots__ = ("wait_device",)
+
+    def __init__(self, resolve, wait_device) -> None:
+        super().__init__(resolve)
+        self.wait_device = wait_device
+
+
 class StagedChannel(BaseChannel):
     """Shared stage/launch/resolve machinery over a device mesh.
 
@@ -1004,14 +1018,27 @@ class StagedChannel(BaseChannel):
         # a token launch names its span: lm_prefill or lm_step
         launch_span = getattr(session[1], "span", None) if session else None
 
+        ready = []
+
+        def wait_device() -> float:
+            """Until the outputs are ready ON THE DEVICE, their readback
+            still to come; when that was, as the first call saw it. The
+            batcher calls it ahead of ``result()`` for a launch of
+            session steps (runtime/continuous.py), on the same thread."""
+            nonlocal h2d
+            if not ready:
+                if h2d is not None:
+                    (t_h0, marker, h2d_attrs), h2d = h2d, None
+                    jax.block_until_ready(marker)
+                    tr.add("h2d", t_h0, time.perf_counter(), h2d_attrs)
+                jax.block_until_ready(outputs)
+                ready.append(time.perf_counter())
+            return ready[0]
+
         def resolve() -> InferResponse:
             host = None
             try:
                 if tr is not None or ledger is not None:
-                    if h2d is not None:
-                        t_h0, marker, h2d_attrs = h2d
-                        jax.block_until_ready(marker)
-                        tr.add("h2d", t_h0, time.perf_counter(), h2d_attrs)
                     # device window: enqueue -> execution complete.
                     # block_until_ready is what np.asarray would wait on
                     # anyway; forcing it here splits execute from the
@@ -1023,8 +1050,7 @@ class StagedChannel(BaseChannel):
                     # queueing behind the previous launch and the
                     # compute; the end of h2d, inside it, says where the
                     # compute can have begun.
-                    jax.block_until_ready(outputs)
-                    t_ready = time.perf_counter()
+                    t_ready = wait_device()
                     if tr is not None:
                         tr.add("device_execute", t_launched, t_ready, ids)
                         if launch_span is not None:
@@ -1072,7 +1098,7 @@ class StagedChannel(BaseChannel):
                 latency_s=time.perf_counter() - t0,
             )
 
-        return InferFuture(resolve)
+        return _LaunchFuture(resolve, wait_device)
 
     def _launcher(self, model):
         """(jitted device_fn launcher | None, donate names, out dtypes),

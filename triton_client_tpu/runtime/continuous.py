@@ -40,10 +40,13 @@ window and no admission thread:
       model that declares ``spec.extra["session_merge"]`` share one
       launch, each row its own stream. The one kind whose group does
       NOT close the moment a slot frees: it stays open, absorbing
-      arrivals, while a launch is ahead of it on the device, and at a
-      free device it waits for the sessions that are about to come
-      back where that pays
-      (:meth:`ContinuousBatchingChannel._step_wait_locked`).
+      arrivals, while a launch is ahead of it on the device. Behind a
+      step launch of its own model whose sessions are not worth
+      waiting for it closes when the device is about to take it
+      (:meth:`ContinuousBatchingChannel._early_wait_locked`); else
+      when the launch ahead has been answered, and at a free device it
+      then waits for the sessions that are about to come back where
+      that pays (:meth:`ContinuousBatchingChannel._step_wait_locked`).
     - *solo*: a session frame (its state advances per stream and per
       frame), a lone ragged request, a lone step: the original request
       goes down as it came.
@@ -224,22 +227,38 @@ class LiveBuckets:
         return self._table
 
 
-def _running_mean(mean: float, sample: float) -> float:
-    """A mean that follows the last eight or so samples."""
-    return mean + (sample - mean) / 8.0
+def _running_mean(mean: float | None, sample: float) -> float:
+    """A mean that follows the last eight or so samples (the first
+    sample, while there is none)."""
+    return sample if mean is None else mean + (sample - mean) / 8.0
 
 
 class _StepPace:
     """What the batcher has observed of ONE model's session steps: the
     times that decide whether a step group waits at a free device
-    (:meth:`ContinuousBatchingChannel._step_wait_locked`). Guarded by
+    (:meth:`ContinuousBatchingChannel._step_wait_locked`) and when it
+    closes behind a step launch that is still on the device
+    (:meth:`ContinuousBatchingChannel._early_wait_locked`). Guarded by
     the batcher's ``_ready_cv``."""
 
-    __slots__ = ("launch_s", "return_s", "return_dev_s", "answered")
+    __slots__ = (
+        "launch_s", "return_s", "return_dev_s", "answered", "device_s", "lead_s",
+        "cohort",
+    )
 
     def __init__(self, launch_s: float) -> None:
-        # T: a step group's dispatch -> its answers handed back
+        # T: a step group's dispatch (or, where it was enqueued behind
+        # another launch, that launch's end on the device) -> its
+        # answers handed back
         self.launch_s = launch_s
+        # a step launch's start on the device -> its outputs ready
+        # there, and a step group's close -> its launch enqueued
+        # (dispatcher, executor, stage, launch); None until observed
+        self.device_s: float | None = None
+        self.lead_s: float | None = None
+        # sessions a step launch answers: the returns above followed
+        # launches of about that many
+        self.cohort: float | None = None
         # h: a step's answer -> the same session's next step staged (a
         # session not back after two launches counts as two launches),
         # and how far a return lies from that mean
@@ -258,6 +277,17 @@ class _StepPace:
         self.return_dev_s += (abs(after_s - self.return_s) - self.return_dev_s) / 4.0
         self.return_s = _running_mean(self.return_s, after_s)
 
+    def observe_launch(
+        self, sessions: int, lead_s: float, device_s: float | None
+    ) -> None:
+        """One more step launch: the sessions it answered, its group's
+        close to its launch enqueued, and its time on the device where
+        that was observed."""
+        self.cohort = _running_mean(self.cohort, sessions)
+        self.lead_s = _running_mean(self.lead_s, lead_s)
+        if device_s is not None:
+            self.device_s = _running_mean(self.device_s, device_s)
+
     def back_within_s(self) -> float:
         """The time within which nearly every session is back: the mean
         return and two mean deviations. A wait for the LAST of the
@@ -273,6 +303,58 @@ class _StepPace:
         for sid in [s for s, t in self.answered.items() if now - t >= limit]:
             del self.answered[sid]
             self.returned(limit)
+
+
+class _Formed:
+    """One formed group from its close to its resolution, as
+    ``free_slot`` on its way through ``_run_group``: called, it frees
+    the group's execution slot (once: at its launch, or when the group
+    resolves without one). Beside that it keeps what a step group
+    BEHIND this one reads of it
+    (:meth:`ContinuousBatchingChannel._early_wait_locked`). Guarded by
+    the batcher's ``_ready_cv``."""
+
+    __slots__ = (
+        "key", "members", "closed_t", "launched_t", "ready_t", "device_s",
+        "behind", "clear_t", "early", "_chan",
+    )
+
+    def __init__(
+        self, chan, key, members: int, closed_t: float, behind, early: bool
+    ) -> None:
+        self._chan = chan
+        self.key = key
+        self.members = members
+        self.closed_t = closed_t
+        # enqueued on the inner channel (``do_inference_async`` back),
+        # and its outputs ready ON THE DEVICE, before their readback
+        self.launched_t: float | None = None
+        self.ready_t: float | None = None
+        # ready - start, where the inner channel said when (else None)
+        self.device_s: float | None = None
+        # the group it was closed behind while that one is still on the
+        # device, then None and ``clear_t`` says when that one left it
+        self.behind: _Formed | None = behind
+        self.clear_t: float | None = None
+        # a step group closed while a launch was ahead of it
+        self.early = early
+
+    @property
+    def steps(self) -> bool:
+        return self.key[0] == "__session_step__"
+
+    def start_t(self) -> float | None:
+        """When this launch began on the device, once that is known: the
+        later of its enqueueing and the end of the launch ahead of it."""
+        if self.launched_t is None or self.behind is not None:
+            return None
+        return max(self.launched_t, self.clear_t or 0.0)
+
+    def __call__(self) -> None:
+        self._chan._launched(self)
+
+    def off_device(self) -> None:
+        self._chan._off_device(self)
 
 
 class ContinuousBatchingChannel(BaseChannel):
@@ -331,9 +413,22 @@ class ContinuousBatchingChannel(BaseChannel):
         ``pipeline_depth`` launches before it runs; that look-ahead is
         what hides a large group's host->device transfer. A group of
         SESSION STEPS ships four bytes a session, so it stays open
-        instead (:meth:`_step_wait_locked`): while any group formed
-        before it has not resolved, and then, at a free device, for the
-        sessions that are about to come back."""
+        instead, absorbing arrivals, and closes by what the batcher
+        observes of the model's steps (:class:`_StepPace`), no option.
+        Where nearly every session is back within a launch's time: when
+        every group formed before it has been answered, and then, at
+        the free device, after a wait for the sessions that are about
+        to come back (:meth:`_step_wait_locked`). Where the sessions
+        take longer than that, the ones in the launch ahead will not
+        make the next launch anyway: behind a step launch of the same
+        model the group closes one measured close-to-launch time
+        before that launch is due off the device, at the latest the
+        moment its outputs are ready there, so that the device takes
+        the next launch with no gap and the launch ahead is read back
+        and answered meanwhile (:meth:`_early_wait_locked`). Behind
+        anything else (a prompt, another model's group, a group that
+        itself still waits for the device) it stays open until that
+        has been answered."""
         self._inner = inner
         self._capacity = max(1, int(capacity))
         self._ids = itertools.count(1)
@@ -384,11 +479,19 @@ class ContinuousBatchingChannel(BaseChannel):
             # that came in meanwhile, and the waits that ran out
             "step_holds": 0, "step_hold_s": 0.0,
             "step_hold_joined": 0, "step_hold_expired": 0,
+            # step groups closed while a launch was ahead of them, those
+            # of them closed by the event (the launch ahead's outputs
+            # ready on the device) and not by the prediction, and the
+            # steps of their key staged between such a close and the end
+            # of the launch ahead: what the early close cost
+            "step_early_closes": 0, "step_early_by_event": 0,
+            "step_early_missed": 0,
         }
         # groups formed and not yet resolved (answers handed back or
-        # failed): 0 means no launch is ahead of the next group on the
-        # device; when it last fell to 0, and when it last changed
-        self._launches_ahead = 0
+        # failed), oldest first: none means no launch is ahead of the
+        # next group on the device (_launches_ahead); when that list
+        # last emptied, and when it last changed
+        self._formed: list[_Formed] = []
         self._device_free_t = time.perf_counter()
         self._ahead_changed_t = self._device_free_t
         # (model, version) -> _StepPace; the wait in progress at a free
@@ -594,6 +697,14 @@ class ContinuousBatchingChannel(BaseChannel):
             now = time.perf_counter()
             if request.sequence_id:
                 self._observe_session_request_locked(request, session_step, now)
+            if session_step and any(
+                f.early and f.behind is not None and f.key == key
+                for f in self._formed
+            ):
+                # its group closed early and the launch ahead of that
+                # still runs: closed when that launch ends, the group
+                # would have taken this step
+                self._merge_stats["step_early_missed"] += 1
             item = (key, size, request, future, now)
             if not self._ready or self._edf_key(self._ready[-1]) <= self._edf_key(item):
                 # the common arrival (no deadline, or the latest one)
@@ -675,9 +786,11 @@ class ContinuousBatchingChannel(BaseChannel):
         """One dispatcher slot: acquire a permit, form the EDF head's
         group, submit. A group of any kind but session steps closes
         here the moment the permit is held and something is ready; a
-        group of session steps closes when :meth:`_step_wait_locked`
-        says so (no launch ahead of it, and nobody worth waiting for),
-        and keeps absorbing arrivals in the ready set until then.
+        group of session steps closes when :meth:`_head_wait_locked`
+        says so (behind a step launch that is about to leave the
+        device, :meth:`_early_wait_locked`; or nothing ahead of it and
+        nobody worth waiting for, :meth:`_step_wait_locked`), and keeps
+        absorbing arrivals in the ready set until then.
         Returns True when the loop should exit (close() requested and
         the ready set is drained: close() waits for nobody). Any
         unexpected error fails the formed group's futures, releases the
@@ -715,8 +828,7 @@ class ContinuousBatchingChannel(BaseChannel):
                     self._merge_stats["merged_frames"] += frames
                     self._merge_occupancy[frames] += 1
                     self._active_slots += 1
-                    self._launches_ahead += 1
-                    self._ahead_changed_t = time.perf_counter()
+                    slot = self._formed_locked(group[0][0], len(group))
                 elif self._dispatch_stop:
                     self._inflight.release()
                     return True
@@ -724,7 +836,7 @@ class ContinuousBatchingChannel(BaseChannel):
                 self._inflight.release()
                 return False
 
-            def run(g=group, t_submit=time.perf_counter()):
+            def run(g=group, free_slot=slot, t_submit=time.perf_counter()):
                 t_run = time.perf_counter()
                 with self._ready_cv:
                     self._decomp["n"] += 1
@@ -743,20 +855,9 @@ class ContinuousBatchingChannel(BaseChannel):
                 # staged, compute enqueued on the inner channel) — the
                 # dispatcher can then form the next batch against
                 # device occupancy while this group's readback/split
-                # still runs. Exactly-once: the finally covers groups
-                # whose launch never happened (errors before dispatch).
-                released = [False]
-
-                def free_slot():
-                    if released[0]:
-                        return
-                    released[0] = True
-                    with self._ready_cv:
-                        self._slot_occupancy[self._active_slots] += 1
-                        self._active_slots -= 1
-                        self._merge_stats["launch_frees"] += 1
-                    self._inflight.release()
-
+                # still runs. Exactly-once (_launched): the finally
+                # covers groups whose launch never happened (errors
+                # before dispatch).
                 try:
                     # (t_staged, request, future): the staging timestamp
                     # rides along so each member gets its own merge_wait
@@ -772,14 +873,14 @@ class ContinuousBatchingChannel(BaseChannel):
                             it[3].set_exception(e)
                 finally:
                     free_slot()
-                    self._group_resolved(g, t_run)
+                    self._group_resolved(g, t_run, free_slot)
 
             try:
                 self._exec.submit(run)
             except RuntimeError as e:  # executor shut down mid-close
                 with self._ready_cv:
                     self._active_slots -= 1
-                    self._launches_ahead -= 1
+                    self._unform_locked(slot, time.perf_counter())
                 self._inflight.release()
                 for it in group:
                     if not it[3].done():
@@ -825,11 +926,18 @@ class ContinuousBatchingChannel(BaseChannel):
 
     # -- when a group of session steps closes ----------------------------------
 
+    @property
+    def _launches_ahead(self) -> int:
+        return len(self._formed)
+
     def _head_wait_locked(self) -> float | None:
         """Seconds until the dispatcher should look again, or None: form
         the EDF head's group now. Only a head that is a session step
-        ever waits with something ready; the wait at a FREE device is
-        what ``step_holds`` and its three companions count."""
+        ever waits with something ready: behind a launch
+        (:meth:`_early_wait_locked`; ``step_early_closes`` and its two
+        companions count the groups that closed there), or at a FREE
+        device (:meth:`_step_wait_locked`), which is what
+        ``step_holds`` and its three companions count."""
         if not self._ready:
             return 0.1
         key = self._ready[0][0]
@@ -837,11 +945,12 @@ class ContinuousBatchingChannel(BaseChannel):
         wait_s, missing = None, 0
         if key[0] == "__session_step__":
             if self._launches_ahead:
-                # a launch is ahead on the device: closing now would fix
-                # the group's members one launch early and buy nothing
-                # (a step ships four bytes a session); its resolution
-                # notifies
-                return 0.1
+                # a launch is ahead: the group stays open, absorbing
+                # arrivals, until that launch is about to leave the
+                # device or, where that is not the batcher's to
+                # foresee, until it has been answered (each event of
+                # the launch ahead notifies)
+                return self._early_wait_locked(key, now)
             wait_s, missing = self._step_wait_locked(key, now)
         hold = self._hold
         if hold is not None and (wait_s is None or hold[1] != key):
@@ -911,29 +1020,166 @@ class ContinuousBatchingChannel(BaseChannel):
         wait_s = min(self._device_free_t, max(missing)) + pace.launch_s - now
         return (wait_s if wait_s > 0 else None), len(missing)
 
-    def _group_resolved(self, group, t_run: float) -> None:
-        """A formed group is off the device: its answers are handed back
-        (or it failed). One launch fewer is ahead of the next group, and
-        a group of session steps leaves behind how long it took and
+    def _early_wait_locked(self, key, now: float) -> float | None:
+        """A launch is ahead and the EDF head is a session step of the
+        model of ``key``: seconds until the dispatcher should look
+        again, or None where the group closes now, BEHIND the launch
+        that runs, so that its stage and launch are done when the
+        device is ready to take it.
+
+        That is only where everything ahead is step launches of the
+        same model, at most one of them still on the device and that
+        one enqueued there (no second group closes behind one that
+        itself still waits for the device), and where the sessions IN
+        the launch ahead are not worth waiting for: they would not be
+        back within a launch's time (:meth:`_StepPace.back_within_s`;
+        where they would, the group stays open until the launch ahead is
+        answered and then waits at the free device,
+        :meth:`_step_wait_locked`). A launch that waited for them would
+        answer the members that are ready AND the sessions ahead at
+        once, and a return grows with the sessions answered together
+        (their callers share an interpreter lock on each side), so the
+        returns observed count in the proportion of that many sessions
+        to those the observed returns followed (the model's mean
+        sessions a step launch, or the sessions ahead if they are
+        more): two cohorts that take turns stay two (each would be back
+        in time alone, both together would not), while a straggler
+        behind a launch that holds everybody else is no reason to split
+        them. The group then closes ``lead``
+        before the launch ahead is DUE off the device: ``due`` is that
+        launch's start on the device plus the model's mean device time
+        of a step launch, ``lead`` the mean time from a step group's
+        close to its launch enqueued, both the batcher's own
+        observations (:class:`_StepPace`). The prediction's latest
+        bound is an event: the group closes at the latest when the
+        launch ahead's outputs are ready on the device, before their
+        readback and before any of its answers is handed back. A step
+        staged between an early close and that event misses one launch
+        (``step_early_missed``), which is why the lead is no longer
+        than the time a close takes to reach the device. Anywhere else
+        (a prompt or another model's group ahead, an inner channel that
+        cannot say when a launch left the device) the group stays open
+        until the launch ahead has been answered."""
+        stay = 0.1  # every event of the launch ahead notifies
+        pace = self._step_pace.get(key[1:])
+        if pace is not None:
+            pace.forget_stale(now)
+        if (
+            pace is None
+            or pace.device_s is None
+            or pace.return_s is None
+            or any(f.key != key for f in self._formed)
+        ):
+            return stay
+        ahead = sum(f.members for f in self._formed)
+        together = (self._members_locked(key) + ahead) / max(ahead, pace.cohort)
+        if pace.back_within_s() * max(1.0, together) < pace.launch_s:
+            return stay
+        flying = [f for f in self._formed if f.ready_t is None]
+        if not flying:
+            return None  # the event: everything ahead has left the device
+        if len(flying) > 1:
+            return stay
+        start = flying[0].start_t()
+        if start is None:
+            return stay
+        wait_s = start + pace.device_s - pace.lead_s - now
+        return wait_s if wait_s > 0 else None
+
+    def _formed_locked(self, key, members: int) -> _Formed:
+        """A group of ``members`` requests under ``key`` has just closed:
+        its record, behind the groups formed before it."""
+        now = time.perf_counter()
+        last = self._formed[-1] if self._formed else None
+        early = bool(self._formed) and key[0] == "__session_step__"
+        slot = _Formed(
+            self, key, members, now,
+            behind=last if last is not None and last.ready_t is None else None,
+            early=early,
+        )
+        if early:
+            self._merge_stats["step_early_closes"] += 1
+            if slot.behind is None:
+                self._merge_stats["step_early_by_event"] += 1
+        self._formed.append(slot)
+        self._ahead_changed_t = now
+        return slot
+
+    def _launched(self, slot: _Formed) -> None:
+        """``slot``'s group is enqueued on the inner channel (or resolves
+        without a launch): its execution slot frees, once."""
+        with self._ready_cv:
+            if slot.launched_t is not None:
+                return
+            slot.launched_t = time.perf_counter()
+            self._slot_occupancy[self._active_slots] += 1
+            self._active_slots -= 1
+            self._merge_stats["launch_frees"] += 1
+            if self._ready:
+                # a step group behind it can now tell when it is due
+                self._ready_cv.notify_all()
+        self._inflight.release()
+
+    def _off_device(self, slot: _Formed) -> None:
+        """The inner channel says ``slot``'s outputs are ready on the
+        device (their readback is still to come)."""
+        with self._ready_cv:
+            now = time.perf_counter()
+            start = slot.start_t()
+            if slot.ready_t is None and start is not None:
+                slot.device_s = now - start
+            self._off_device_locked(slot, now)
+
+    def _off_device_locked(self, slot: _Formed, now: float) -> None:
+        if slot.ready_t is not None:
+            return
+        slot.ready_t = now
+        for f in self._formed:
+            if f.behind is slot:
+                f.behind, f.clear_t = None, now
+        if self._ready:
+            # with nothing ready the dispatcher has nothing to decide,
+            # and a wake-up here would only contend with the handback
+            self._ready_cv.notify_all()
+
+    def _unform_locked(self, slot: _Formed, now: float) -> None:
+        """``slot``'s group is resolved: off the device at the latest
+        now, and no longer ahead of anything."""
+        self._off_device_locked(slot, now)
+        self._formed.remove(slot)
+        self._ahead_changed_t = now
+        if not self._formed:
+            self._device_free_t = now
+
+    def _group_resolved(self, group, t_run: float, slot: _Formed) -> None:
+        """A formed group's answers are handed back (or it failed): one
+        launch fewer is ahead of the next group, and a group of session
+        steps leaves behind how long it took (its close to its launch
+        enqueued; its time on the device, where the inner channel said
+        when that ended; its dispatch, or the end on the device of the
+        launch it was enqueued behind, to its answers handed back) and
         which sessions may come back for more."""
         key = group[0][0]
         with self._ready_cv:
             now = time.perf_counter()
-            self._launches_ahead -= 1
-            self._ahead_changed_t = now
-            if self._launches_ahead == 0:
-                self._device_free_t = now
+            self._unform_locked(slot, now)
             answered = key[0] == "__session_step__" and [
                 it[2]
                 for it in group
                 if it[3].done() and it[3].exception() is None
             ]
             if answered:
+                # a launch enqueued behind another did not hold the
+                # device while it queued there: T leaves that time out
+                took = now - max(t_run, slot.clear_t or 0.0)
                 pace = self._step_pace.get(key[1:])
                 if pace is None:
-                    pace = self._step_pace[key[1:]] = _StepPace(now - t_run)
+                    pace = self._step_pace[key[1:]] = _StepPace(took)
                 else:
-                    pace.launch_s = _running_mean(pace.launch_s, now - t_run)
+                    pace.launch_s = _running_mean(pace.launch_s, took)
+                pace.observe_launch(
+                    len(answered), slot.launched_t - slot.closed_t, slot.device_s
+                )
                 for request in answered:
                     if not request.sequence_end:
                         pace.answered[request.sequence_id] = now
@@ -1180,12 +1426,31 @@ class ContinuousBatchingChannel(BaseChannel):
             )
             if free_slot is not None:
                 free_slot()
+            self._await_device(fut, free_slot)
             return fut.result()
         finally:
             t_dev_end = time.perf_counter()
             with self._ready_cv:
                 self._decomp["stage_s"] += t_disp - t_stage0
                 self._decomp["device_s"] += t_dev_end - t_disp
+
+    @staticmethod
+    def _await_device(fut, slot) -> None:
+        """A launch of session steps whose future can say when its
+        outputs are ready ON THE DEVICE (``wait_device``: before their
+        readback, which ``result()`` pays): wait for that on this
+        thread, which would wait in ``result()`` anyway, and tell the
+        group's record, so that a step group behind this launch can
+        close before this one's answers are copied, split and handed
+        back."""
+        wait = getattr(fut, "wait_device", None)
+        if wait is None or not getattr(slot, "steps", False):
+            return
+        try:
+            wait()
+        except Exception:
+            return  # result() raises it; the resolution says the rest
+        slot.off_device()
 
     def _count_merged(self, merged: dict, record, t_stage0, t_disp) -> None:
         """``merged_bytes`` and the ``batch_merge`` span: a device batch
@@ -1426,6 +1691,7 @@ class ContinuousBatchingChannel(BaseChannel):
             fut = self._inner.do_inference_async(request)
             if free_slot is not None:
                 free_slot()  # launched: slot frees before the readback
+            self._await_device(fut, free_slot)
             future.set_result(fut.result())
         except Exception as e:
             future.set_exception(e)
@@ -1478,6 +1744,10 @@ class ContinuousBatchingChannel(BaseChannel):
                 # the times behind step_holds, of the model whose step
                 # group resolved last
                 out["step_launch_ms"] = round(pace.launch_s * 1e3, 3)
+                if pace.device_s is not None:
+                    # the times behind step_early_closes
+                    out["step_device_ms"] = round(pace.device_s * 1e3, 3)
+                    out["step_lead_ms"] = round(pace.lead_s * 1e3, 3)
                 if pace.return_s is not None:
                     out["step_return_ms"] = round(pace.return_s * 1e3, 3)
                     out["step_return_dev_ms"] = round(pace.return_dev_s * 1e3, 3)
