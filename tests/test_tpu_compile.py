@@ -248,6 +248,92 @@ def test_sdar_launches_update_the_served_cache_in_place(one_chip, launch):
     assert not [line for line in text.splitlines() if f"= {whole}" in line and " copy(" in line]
 
 
+def _ling_launch(one_chip, model: dict, slots: int, slot_len: int, launch: dict, monkeypatch):
+    """One launch shape of ``family: bailing_hybrid`` compiled as
+    ``ParamLauncher`` launches it: weights and the three cache arrays as
+    arguments, the cache donated and row-major on both sides, the KDA
+    core as on the chip (the Pallas kernel where the head size fits).
+    Returns the executable's text and the configuration."""
+    from jax.experimental.layout import Format, Layout
+    from triton_client_tpu.models import ling
+    from triton_client_tpu.ops import delta_attention
+    from triton_client_tpu.pipelines import lm
+
+    monkeypatch.setattr(delta_attention, "on_chip", lambda: True)
+    cfg = ling.LingConfig.from_dict(model)
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    weights = placed(jax.eval_shape(lambda: ling.stack_layers(ling.init_params(jax.random.PRNGKey(0), cfg), cfg)))
+    cache = placed(jax.eval_shape(lambda: ling.empty_cache(cfg, slots, slot_len)))
+    row_major = jax.tree_util.tree_map(
+        lambda x: Format(Layout(major_to_minor=tuple(range(x.ndim))), one_chip), cache)
+    ((kind, size),) = launch.items()
+    inputs = placed({k: jnp.asarray(v) for k, v in lm.launch_inputs(kind, size).items()})
+    device_fn = lm.make_device_fn.__wrapped__(ling, cfg)  # traced here, with the probe steered: not the memoized one
+
+    def run(inputs, weights, cache):
+        out = dict(device_fn(inputs, {"weights": weights, lm.STATE_KEY: cache}))
+        return out, out.pop(lm.STATE_KEY)
+
+    return jax.jit(
+        run, donate_argnums=(2,), in_shardings=(None, None, row_major), out_shardings=(None, row_major),
+    ).lower(inputs, weights, cache).compile().as_text(), cfg
+
+
+def _ling_config() -> dict:
+    import json
+    import pathlib
+
+    return json.loads(
+        (pathlib.Path(__file__).resolve().parents[1] / "benchmarks/configs/ling3flash-ep8-l13.json").read_text())
+
+
+@pytest.mark.parametrize("launch", ({"extend": 128}, {"extend": 256}, {"step": 8}))
+def test_ling_launch_kinds_lower_at_the_tiny_preset(one_chip, launch, monkeypatch):
+    """Both launch kinds of the benchmark configuration's rehearsal (heads
+    of 16 values: the chunkwise form in plain XLA, ``kernel_fits`` says
+    no), the scan over periods with its inner scan over KDA layers."""
+    doc = _ling_config()
+    model = {**doc["model"], **doc["rehearsal"]["model"]}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _ling_launch(one_chip, model, 8, slot_len, launch, monkeypatch)
+    ((kind, size),) = launch.items()
+    assert f"f32[{size if kind == 'step' else 1},{cfg.vocab_size}]" in text
+
+
+def test_lm_kda_chunk_lowers_at_the_served_head_size(one_chip):
+    """The Pallas kernel of an extend launch of ``examples/ling3_ep8``:
+    4,096 positions of 32 heads of 128 values, a head's state resident
+    across its 64 chunks."""
+    from triton_client_tpu.ops import delta_attention
+
+    t, h, d = 4096, 32, 128
+    assert delta_attention.kernel_fits(d) and not delta_attention.kernel_fits(16)
+    text = _compile(
+        lambda q, k, v, g, beta, s0: delta_attention.extend(q, k, v, g, beta, s0, kernel=True), one_chip,
+        *[((t, h, d), jnp.float32)] * 4, ((t, h), jnp.float32), ((h, d, d), jnp.float32),
+    )
+    assert "tpu_custom_call" in text and "lm_kda_chunk" in text
+
+
+@pytest.mark.parametrize("launch", ({"extend": 1024}, {"step": 8}))
+def test_ling_launches_update_the_three_caches_in_place(one_chip, launch, monkeypatch):
+    """At the served widths and the served slots (one dense layer and one
+    period of a KDA and an MLA layer of the 13) the latent rows, the
+    recurrent state and the convolution tails are donated together and
+    keep their row-major layouts through both scans: no launch begins or
+    ends with a copy of any of them."""
+    model = {**_ling_config()["model"], "num_hidden_layers": 3, "layer_types": ["kda", "kda", "mla"]}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _ling_launch(one_chip, model, 8, slot_len, launch, monkeypatch)
+    assert ("lm_kda_chunk/pallas_call" in text) == ("extend" in launch)  # the kernel, under its own name
+    for whole in (f"bf16[1,8,{slot_len},640]", "f32[2,8,32,128,128]", "bf16[2,8,36864]"):
+        assert whole in text
+        assert not [line for line in text.splitlines() if f"= {whole}" in line and " copy(" in line]
+
+
 def test_nms_pallas_lowers(one_chip):
     from triton_client_tpu.ops.pallas_nms import nms_pallas
 

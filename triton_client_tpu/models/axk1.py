@@ -77,7 +77,7 @@ class AXK1Config:
     intermediate_size: int = 18432
     moe_intermediate_size: int = 2048
     num_attention_heads: int = 64
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536  # None (``null``): no query compression, ``q = x W_q`` with no query norm
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -116,6 +116,8 @@ class AXK1Config:
         cfg = cls(**doc)
         if cfg.topk_method not in ("none", "noaux_tc"):
             raise ValueError(f"axk1 model config: topk_method {cfg.topk_method!r} (known: none, noaux_tc)")
+        if cfg.index_topk and not cfg.q_lora_rank:
+            raise ValueError("axk1 model config: the indexer's queries are made from the compressed query: q_lora_rank")
         yarn = rope.YarnConfig(
             dim=cfg.qk_rope_head_dim,
             theta=float(cfg.rope_theta),
@@ -176,6 +178,20 @@ def _mlp(key, d, f, lead=()):
     }
 
 
+def _query_params(key_a, key_b, cfg) -> dict:
+    """The query side of latent attention: compressed through
+    ``q_lora_rank`` values and normalised there, or, where the
+    configuration states none, one matrix ``q``."""
+    d, width = cfg.hidden_size, cfg.num_attention_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if not cfg.q_lora_rank:
+        return {"q": _normal(key_a, (d, width), d**-0.5)}
+    return {
+        "q_a": _normal(key_a, (d, cfg.q_lora_rank), d**-0.5),
+        "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
+        "q_b": _normal(key_b, (cfg.q_lora_rank, width), cfg.q_lora_rank**-0.5),
+    }
+
+
 def init_params(key, cfg: AXK1Config) -> dict:
     """The program's own initialisation (an entry without a weights
     file serves it): the layout a ``weights.msgpack`` has, layer by
@@ -190,9 +206,7 @@ def init_params(key, cfg: AXK1Config) -> dict:
             "norm1": jnp.ones((d,), jnp.float32),
             "norm2": jnp.ones((d,), jnp.float32),
             "attn": {
-                "q_a": _normal(k[0], (d, cfg.q_lora_rank), d**-0.5),
-                "q_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
-                "q_b": _normal(k[1], (cfg.q_lora_rank, h * qk), cfg.q_lora_rank**-0.5),
+                **_query_params(k[0], k[1], cfg),
                 "kv_a": _normal(k[2], (d, cfg.cache_width), d**-0.5),
                 "kv_norm": jnp.ones((cfg.kv_lora_rank,), jnp.float32),
                 "kv_b": _normal(
@@ -259,25 +273,34 @@ def stack_layers(tree: dict, cfg, one_program: bool = False) -> dict:
     n_dense = cfg.first_k_dense_replace
     dense = [layers.pop(str(i)) for i in range(n_dense)]
     rest = [layers.pop(str(i)) for i in range(n_dense, cfg.num_hidden_layers)]
-    stacked = None
-    if rest:
-        stack = jax.jit(lambda *parts: jnp.stack(parts)) if one_program else (lambda *parts: jnp.stack(parts))
-        flat = [jax.tree_util.tree_flatten(layer) for layer in rest]
-        treedef = flat[0][1]
-        columns = [list(leaves) for leaves, _ in flat]
-        del rest, flat  # the columns alone hold the per-layer leaves now
-        out = []
-        for j in range(len(columns[0])):
-            out.append(stack(*[col[j] for col in columns]))
-            if one_program:
-                jax.block_until_ready(out[-1])  # a tracer (shapes alone) has nothing to wait for
-            for col in columns:
-                col[j] = None
-        stacked = jax.tree_util.tree_unflatten(treedef, out)
     return {
         "embed": tree["embed"], "head": tree["head"],
-        "final_norm": tree["final_norm"], "dense": dense, "moe": stacked,
+        "final_norm": tree["final_norm"], "dense": dense, "moe": stack_group(rest, one_program),
     }
+
+
+_stack_in_one_program = jax.jit(lambda *parts: jnp.stack(parts))  # one for the module: an entry built again compiles no stack anew
+
+
+def stack_group(rest: list, one_program: bool = False):
+    """Layers of one kind stacked leaf by leaf on a leading axis
+    (:func:`stack_layers` says why a leaf at a time); EMPTIES ``rest``.
+    None for no layer."""
+    if not rest:
+        return None
+    stack = _stack_in_one_program if one_program else (lambda *parts: jnp.stack(parts))
+    flat = [jax.tree_util.tree_flatten(layer) for layer in rest]
+    treedef = flat[0][1]
+    columns = [list(leaves) for leaves, _ in flat]
+    del flat, rest[:]  # the columns alone hold the per-layer leaves now
+    out = []
+    for j in range(len(columns[0])):
+        out.append(stack(*[col[j] for col in columns]))
+        if one_program:
+            jax.block_until_ready(out[-1])  # a tracer (shapes alone) has nothing to wait for
+        for col in columns:
+            col[j] = None
+    return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def empty_cache(cfg: AXK1Config, slots: int, slot_len: int):
@@ -356,8 +379,11 @@ def _attention(cfg, p, x, kv, ik, layer, slots, positions, valid, cos, sin):
     h, nope, rp = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rank, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
     bf = jnp.bfloat16
-    qr = _rms(x @ p["q_a"], p["q_norm"], eps).astype(bf)
-    q = (qr @ p["q_b"]).reshape(b, n, h, nope + rp)
+    if "q" in p:  # no query compression (``q_lora_rank: null``)
+        qr, q = None, (x @ p["q"]).reshape(b, n, h, nope + rp)
+    else:
+        qr = _rms(x @ p["q_a"], p["q_norm"], eps).astype(bf)
+        q = (qr @ p["q_b"]).reshape(b, n, h, nope + rp)
     q_nope = q[..., :nope]
     q_rope = rope.apply_rope(
         q[..., nope:].astype(jnp.float32), cos[:, :, None], sin[:, :, None]
@@ -394,6 +420,8 @@ def _attention(cfg, p, x, kv, ik, layer, slots, positions, valid, cos, sin):
                 q_nope[0], q_rope[0], rows, positions[0], kv_b,
                 cfg.softmax_scale, nope, select,
             )[None]
+    if "gate" in p:  # one sigmoid gate a head on the attention output (models/ling.py)
+        out = out * jax.nn.sigmoid((x @ p["gate"]).astype(jnp.float32)).astype(bf)[..., None]
     return out.reshape(b, n, -1) @ p["o"], kv, ik
 
 

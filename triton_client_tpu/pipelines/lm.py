@@ -4,11 +4,16 @@ Builds the served form of a ``family: axk1`` or ``family: deepseek_v32``
 repository entry (one model module for both, models/axk1.py: what an
 entry's ``model`` block switches on decides, not the family's name) or
 of a ``family: sdar_moe`` entry (models/sdar.py: grouped-query attention
-under a block mask, generation by diffusion over blocks):
+under a block mask, generation by diffusion over blocks) or of a
+``family: bailing_hybrid`` entry (models/ling.py: Kimi Delta Attention
+layers with a latent-attention layer among every few; the contract of a
+session with a RECURRENT STATE is further down):
 the device program ``device_fn(inputs, params)``, the
 ``params`` it takes as launcher ARGUMENTS (``weights`` and, under
 ``cache``, the latent cache, with an indexer a dict of it and the index
-keys, for ``sdar_moe`` the keys and values a head, that the channel
+keys, for ``sdar_moe`` the keys and values a head, for
+``bailing_hybrid`` three arrays: latent rows, the recurrent state and
+the convolution tails, that the channel
 donates into each launch and takes back from its
 outputs: gigabytes of weights cannot be constants of an HLO module, and
 the cache never crosses to the host),
@@ -49,6 +54,22 @@ an extend of ``n mod B != 0``; a block request of another width than B,
 or of a session whose length is no multiple of B. Which positions a pass
 reveals, and in how many passes, is the client's, as sampling is.
 
+The contract of a session of a model WITH STATE (``family:
+bailing_hybrid``; the requests are the first contract's, unchanged).
+What a slot holds: rows of the latent cache in the MLA layers, which
+grow with the session, and in every KDA layer one recurrent state
+(``[heads, d_v, d_k]`` float32) and the last three rows that entered the
+short convolution, which do not: a slot's bytes no longer follow its
+length (``TokenSessions.stats``: ``session_state_bytes`` beside
+``session_cache_tokens``). When it is zeroed: never by a launch of its
+own; a row admitted at position 0 (``sequence_start``, a slot reused
+after ``sequence_end`` or the TTL) reads a zero state and tail INSIDE
+the launch it joins, from ``positions``. What a failed launch costs: one
+refused before dispatch leaves state and length as they were; one that
+failed after dispatch has overwritten the state of its rows, so its
+sessions end and their next request is refused with that reason
+(``lm_state_lost``; docs/OPERATIONS.md).
+
 Everything is read from the entry's ``config.yaml``: ``model`` (the
 published sizes and this chip's share, ``precision``), ``pipeline``
 (``slot_len``, ``max_tokens``, ``session_ttl_s``), ``max_batch_size``
@@ -64,7 +85,7 @@ import numpy as np
 
 from triton_client_tpu.channel.base import InferRequest
 from triton_client_tpu.config import ModelSpec, TensorSpec
-from triton_client_tpu.models import axk1, sdar
+from triton_client_tpu.models import axk1, ling, sdar
 from triton_client_tpu.runtime import precision as precision_policy
 from triton_client_tpu.runtime.repository import RegisteredModel
 from triton_client_tpu.runtime.sessions import TokenSessions
@@ -161,7 +182,7 @@ def _int8_rounded(w):
 #: ``init_params``, ``abstract_params``, ``stack_layers``, ``empty_cache``
 #: and ``extend`` (models/sdar.py also has ``block``). The ONE place a
 #: family is tied to a module; runtime/disk_repository.py reads its keys
-MODULES = {"axk1": axk1, "deepseek_v32": axk1, "sdar_moe": sdar}
+MODULES = {"axk1": axk1, "deepseek_v32": axk1, "sdar_moe": sdar, "bailing_hybrid": ling}
 
 
 @functools.lru_cache(maxsize=None)
@@ -229,6 +250,8 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
         ttl_s=float(pipe.get("session_ttl_s", 60.0)),
         index_topk=index_topk, layers=cfg.num_hidden_layers,
         index_cache_bytes=index_cache_bytes, block=block,
+        # a model whose layers hold a recurrent state says what one session's takes (models/ling.py)
+        state_bytes=cfg.state_bytes() if hasattr(cfg, "state_bytes") else 0,
     )
     device_fn = make_device_fn(model, cfg)
     program = jax.jit(device_fn)

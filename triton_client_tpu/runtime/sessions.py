@@ -525,6 +525,21 @@ class TokenSessions:
     The cache itself never passes through here: it stays on the device,
     donated from launch to launch by the channel.
 
+    A model whose layers hold a RECURRENT STATE beside rows of a cache
+    (``state_bytes`` > 0: models/ling.py) changes what a slot is. Rows
+    beyond a session's length are masked, so a slot described by a length
+    alone is reused without touching the device; a state is read whole by
+    the next token. So: a row admitted at position 0 is counted in
+    :meth:`open` (``lm_state_resets``; a further many-token turn counts
+    in ``lm_state_carries``) and the launch itself reads a zero state for
+    it, from ``positions`` (no extra launch, no host copy: slot reuse
+    after ``sequence_end`` and after the TTL goes the same way); a launch
+    refused BEFORE dispatch (:meth:`abort`) leaves state and length as
+    they were, as for any model; a launch that FAILED after dispatch
+    (:meth:`close` without outputs) has already overwritten the state of
+    its rows, which no length can take back: its sessions END, and their
+    next request is refused with that reason (``lm_state_lost``).
+
     One request of a stream at a time: a later request of a stream whose
     earlier one is still in flight waits its turn in :meth:`open`.
     """
@@ -548,13 +563,19 @@ class TokenSessions:
         layers: int = 1,
         index_cache_bytes: int = 0,
         block: int = 0,
+        state_bytes: int = 0,
     ) -> None:
         """``index_topk``: the positions a token of the model attends to
         at most (0: all), ``layers`` its layers, ``index_cache_bytes``
         what its index keys take beside the latent cache (a gauge): what
         the counters ``lm_keys_visible`` / ``lm_keys_selected`` need.
         ``block``: the tokens a block request of the model carries (0:
-        the model has one-token steps and no block operation)."""
+        the model has one-token steps and no block operation).
+        ``state_bytes``: what ONE session's recurrent state takes on the
+        device whatever its length (0: the model holds none, and a slot
+        is its length)."""
+        self._state_bytes = int(state_bytes)
+        self._lost: dict = {}  # stream -> why it holds no slot any more, while the stream may still ask
         self.slot_len = int(slot_len)
         self.max_tokens = int(max_tokens)
         self._block = int(block)
@@ -575,6 +596,7 @@ class TokenSessions:
             "lm_keys_visible": 0, "lm_keys_selected": 0,
             "lm_block_launches": 0, "lm_block_rows": 0,
             "lm_block_commit_rows": 0, "lm_tokens_committed": 0,
+            "lm_state_resets": 0, "lm_state_carries": 0, "lm_state_lost": 0,
             "created_total": 0, "ended_total": 0,
             "outgrown_total": 0, "unknown_total": 0,
         }
@@ -730,9 +752,12 @@ class TokenSessions:
                 if not start:
                     self._counters["unknown_total"] += 1
                     raise SessionLimitError(
+                        self._lost.get(stream_id) or
                         f"stream '{stream_id}' holds no cache slot here (never "
                         "started, ended, or reclaimed): send sequence_start"
                     )
+                if self._lost:
+                    self._lost.pop(stream_id, None)
                 self._pool.make_room_locked(now)
                 slot = _Slot(stream_id=stream_id, state=self._free.pop(), created=now)
                 self._pool.slots[stream_id] = slot
@@ -760,6 +785,11 @@ class TokenSessions:
             slot.last_used = now
             ticket.rows.append((stream_id, moved, restarted, end))
             ticket.context += position
+            if self._state_bytes:
+                if position == 0:  # the launch reads a zero state for this row, whatever the slot holds
+                    self._counters["lm_state_resets"] += 1
+                elif ticket.kind == "lm_prefill":  # a further turn: the chunkwise form starts from the slot's state
+                    self._counters["lm_state_carries"] += 1
             if self._block:
                 # a position may attend to every position up to the end of its own block, in every layer
                 ends = (np.arange(position, position + n, dtype=np.int64) // self._block + 1) * self._block
@@ -784,12 +814,18 @@ class TokenSessions:
         return outputs
 
     def abort(self, ticket: TokenLaunch) -> None:
+        """The launch was refused before it reached the device: lengths
+        (and a model's recurrent state) are as they were."""
         self.close(ticket, None, failed=True)
 
     def close(self, ticket: TokenLaunch, host_outputs=None, failed: bool = False) -> None:
         """The launch resolved: drop its rows' references, free the
         slots of streams that ended, count. A failed launch takes its
-        rows' lengths back (a restarted stream is freed)."""
+        rows' lengths back (a restarted stream is freed). Where the model
+        holds a recurrent state and the launch failed AFTER dispatch (no
+        outputs, and not :meth:`abort`), the state of its rows is
+        overwritten and no length takes that back: its sessions end."""
+        lost = self._state_bytes and host_outputs is None and not failed
         failed = failed or host_outputs is None
         with self._turn:
             for stream_id, n, restarted, end in ticket.rows:
@@ -800,6 +836,16 @@ class TokenSessions:
                 if failed:
                     slot.length -= n
                     end = end or restarted
+                    if lost and not end:
+                        end = True
+                        self._counters["lm_state_lost"] += 1
+                        while len(self._lost) >= 4 * self._pool.max:  # streams that never asked again
+                            self._lost.pop(next(iter(self._lost)))
+                        self._lost[stream_id] = (
+                            f"stream '{stream_id}': its recurrent state was lost in a launch that failed after "
+                            f"dispatch (the state of its {slot.length} cached positions is overwritten and "
+                            "cannot be taken back): the session has ended, send sequence_start"
+                        )
                 if end:
                     slot.ended = True
                     if not failed:
@@ -854,6 +900,7 @@ class TokenSessions:
                 "session_cache_slot_len": self.slot_len,
                 "session_cache_slots_in_use": len(slots),
                 "session_cache_tokens": sum(s.length for s in slots),
+                "session_state_bytes": self._state_bytes * len(slots),
                 "session_index_cache_bytes": self._index_cache_bytes,
                 "expired_total": self._pool.expired,
                 "reclaimed_total": self._pool.reclaimed,
