@@ -334,6 +334,29 @@ def test_ling_launches_update_the_three_caches_in_place(one_chip, launch, monkey
         assert not [line for line in text.splitlines() if f"= {whole}" in line and " copy(" in line]
 
 
+def test_ling_step_launch_reads_single_experts_in_place(one_chip, monkeypatch):
+    """At the served widths (the dense layer and one whole period of the
+    13 layers: five KDA layers under the inner scan, an MLA layer under
+    the outer one) the step launch yields no layer's experts: no copy,
+    slice or fusion whose result is ``[64, 2560, 768]``; what it slices
+    from the stacks is ONE expert at (layer, expert), inside the fusion
+    of the product that reads it. A layer's slice handed to the loop over
+    the chosen experts would be written out first, 757 MB a layer."""
+    import re
+
+    model = {**_ling_config()["model"], "num_hidden_layers": 7, "layer_types": ["kda"] * 6 + ["mla"]}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _ling_launch(one_chip, model, 8, slot_len, {"step": 8}, monkeypatch)
+    e, d, f = cfg.experts_here, cfg.hidden_size, cfg.moe_intermediate_size
+    whole = re.compile(rf"= bf16\[(1,)?{e},({d},{f}|{f},{d})\]\S* (copy|fusion|dynamic-slice|bitcast)\(")
+    assert not [line for line in text.splitlines() if whole.search(line)]
+    assert text.count(f"dynamic_slice_sizes={{1,1,{d},{f}}}") >= 4  # gate and up, under either scan
+    for stack in (f"bf16[5,{e},{d},{f}]", f"bf16[1,{e},{d},{f}]"):  # a fused slice: the stack in, one matrix out
+        assert [line for line in text.splitlines()
+                if line.startswith("%fused_computation") and f": {stack}" in line and f"-> bf16[{d},{f}]" in line]
+
+
 def test_nms_pallas_lowers(one_chip):
     from triton_client_tpu.ops.pallas_nms import nms_pallas
 

@@ -315,10 +315,13 @@ def _kda(cfg, p, x, state, conv, layer, slots, positions, lengths):
     return out.astype(bf).reshape(b, n, h * d) @ p["o"], state, conv
 
 
-def _feed(cfg, p, hidden, valid):
+def _feed(cfg, p, hidden, valid, at=None):
     """A layer's second half: the dense SwiGLU, or the routed experts
-    held here beside the shared one. Returns the stream and the rows
-    each held expert saw (None for a dense layer)."""
+    held here beside the shared one (with ``at``, ``p["experts"]`` are
+    the stacks of the layer's group and ``at`` its place in them: a step
+    launch then reads single experts from the stack, ops/experts.py).
+    Returns the stream and the rows each held
+    expert saw (None for a dense layer)."""
     x32 = axk1._rms(hidden, p["norm2"], cfg.rms_norm_eps)
     x = x32.astype(jnp.bfloat16)
     if "mlp" in p:
@@ -329,7 +332,7 @@ def _feed(cfg, p, hidden, valid):
         cfg.norm_topk_prob, bias=p["router_bias"], n_group=cfg.n_group, topk_group=cfg.topk_group,
     )
     y, rows = experts_op.routed_experts(
-        x.reshape(b * n, d), valid.reshape(-1), idx, gates, p["experts"], cfg.expert_offset, cfg.expert_chunk_rows,
+        x.reshape(b * n, d), valid.reshape(-1), idx, gates, p["experts"], cfg.expert_offset, cfg.expert_chunk_rows, at,
     )
     return hidden + y.reshape(b, n, d) + axk1._swiglu(x, p["shared"]).astype(jnp.float32), rows
 
@@ -347,16 +350,16 @@ def extend(cfg: LingConfig, weights: dict, cache: dict, tokens, slots, positions
     cos, sin = rope.rope_tables(pos, cfg.yarn)
     bf, eps = jnp.bfloat16, cfg.rms_norm_eps
 
-    def kda_layer(p, hidden, cache, i):
+    def kda_layer(p, hidden, cache, i, at=None):
         x = axk1._rms(hidden, p["norm1"], eps).astype(bf)
         a, state, conv = _kda(cfg, p["attn"], x, cache["state"], cache["conv"], i, slots, positions, lengths)
-        hidden, rows = _feed(cfg, p, hidden + a.astype(jnp.float32), valid)
+        hidden, rows = _feed(cfg, p, hidden + a.astype(jnp.float32), valid, at)
         return hidden, {**cache, "state": state, "conv": conv}, rows
 
-    def mla_layer(p, hidden, cache, i):
+    def mla_layer(p, hidden, cache, i, at=None):
         x = axk1._rms(hidden, p["norm1"], eps).astype(bf)
         a, latent, _ = axk1._attention(cfg, p["attn"], x, cache["latent"], None, i, slots, pos, valid, cos, sin)
-        hidden, rows = _feed(cfg, p, hidden + a.astype(jnp.float32), valid)
+        hidden, rows = _feed(cfg, p, hidden + a.astype(jnp.float32), valid, at)
         return hidden, {**cache, "latent": latent}, rows
 
     hidden = weights["embed"][tokens].astype(jnp.float32)
@@ -369,23 +372,28 @@ def extend(cfg: LingConfig, weights: dict, cache: dict, tokens, slots, positions
             hidden, cache, _ = mla_layer(p, hidden, cache, mla_at)
             mla_at += 1
     periods, inner = cfg.periods, cfg.period - 1
+    # a group's experts go to each of its layers WHOLE beside the layer's place in them: sliced by the scan
+    # like the rest, a layer's experts would be written out before the loop over the chosen ones could read one
+    apart = lambda group: ({n: leaf for n, leaf in group.items() if n != "experts"}, group["experts"])
+    (kda, kda_experts), (mla, mla_experts) = apart(weights["kda"]), apart(weights["mla"])
 
     def period(carry, xs):
-        mla, j = xs
+        p_mla, j = xs
 
         def one(carry, i):
             # a layer of the KDA stack by its place: the stack itself stays where it is (as ``xs`` of this
             # inner scan the outer one would first copy a period's layers out of it, gigabytes a launch)
-            p = jax.tree_util.tree_map(lambda a: jax.lax.dynamic_index_in_dim(a, j * inner + i, 0, False), weights["kda"])
-            hidden, cache, rows = kda_layer(p, *carry, kda_at + j * inner + i)
+            at = j * inner + i
+            p = jax.tree_util.tree_map(lambda a: jax.lax.dynamic_index_in_dim(a, at, 0, False), kda)
+            hidden, cache, rows = kda_layer({**p, "experts": kda_experts}, *carry, kda_at + at, at)
             return (hidden, cache), rows
 
         carry, kda_rows = jax.lax.scan(one, carry, jnp.arange(inner, dtype=jnp.int32))
-        hidden, cache, mla_rows = mla_layer(mla, *carry, mla_at + j)
+        hidden, cache, mla_rows = mla_layer({**p_mla, "experts": mla_experts}, *carry, mla_at + j, j)
         return (hidden, cache), jnp.concatenate([kda_rows, mla_rows[None]])
 
     (hidden, cache), expert_rows = jax.lax.scan(
-        period, (hidden, cache), (weights["mla"], jnp.arange(periods, dtype=jnp.int32)),
+        period, (hidden, cache), (mla, jnp.arange(periods, dtype=jnp.int32)),
     )
     last = jnp.clip(lengths - 1, 0, n - 1)
     final = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]

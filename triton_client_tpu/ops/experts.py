@@ -9,7 +9,11 @@ dropped whatever the imbalance: the token-slots routed here are sorted
 by expert and go through a grouped product (``jax.lax.ragged_dot``, one
 group an expert) in chunks of :data:`CHUNK_ROWS` rows, as many chunks
 as the launch's routing needs. A launch of a few tokens (a step launch)
-runs every held expert over every token and weights by the gates.
+that is given the LAYERS' stacks and the layer's place in them reads
+each held expert that some valid row of it chose, once, from its place
+in the stack, runs all rows through it and weights by the gates: an
+expert no row chose is not read. Given a layer's own experts it runs
+every one of them over every row.
 """
 
 from __future__ import annotations
@@ -18,11 +22,13 @@ import jax
 import jax.numpy as jnp
 
 CHUNK_ROWS = 1024
-#: launches of at most this many tokens (a step launch) run every held
-#: expert over every token instead: such a launch is bound by reading the
-#: experts' weights whichever way, and a plain product reads a layer's
-#: experts in place where the grouped one has them copied out of the
-#: layers' stack first (5.3 ms a matrix a launch: my chip run, PR 29)
+#: launches of at most this many tokens (a step launch) take plain
+#: products instead: such a launch is bound by reading experts' weights,
+#: and a plain product reads them in place where the grouped one has a
+#: layer's experts copied out of the layers' stack first (5.3 ms a matrix
+#: a launch: my chip run, PR 29). Given the stacks, the launch loops over
+#: the held experts its rows chose (3.9 of 64 a layer at four rows of
+#: examples/ling3_ep8), each sliced at (layer, expert) inside its product
 DENSE_TOKENS = 64
 
 
@@ -63,18 +69,21 @@ def _swiglu_grouped(rows, experts, sizes):
     )
 
 
-def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows: int = CHUNK_ROWS):
+def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows: int = CHUNK_ROWS, layer=None):
     """``x [T, D]`` bfloat16, ``valid [T]`` (pad tokens route nowhere),
     ``idx``/``gates [T, k]`` from :func:`route`, ``experts`` the held
-    experts' ``gate``/``up [E, D, F]`` and ``down [E, F, D]``. Returns
-    the held experts' sum ``[T, D]`` float32 and the rows each expert
-    saw ``[E]`` int32. ``chunk_rows``: the token-slots one grouped
-    product takes; the launch runs as many as the rows routed HERE
-    fill, so a launch whose expected rows equal ``chunk_rows`` takes one
-    pass or two as the seed's router falls (models/axk1.py
-    ``expert_chunk_rows``)."""
+    experts' ``gate``/``up [E, D, F]`` and ``down [E, F, D]``; with
+    ``layer`` (an index, traced or not) they are the LAYERS' stacks
+    ``[L, E, D, F]`` / ``[L, E, F, D]`` and this layer's experts are read
+    from their place in them, which is what lets a launch of at most
+    ``DENSE_TOKENS`` tokens read the chosen experts alone. Returns the held experts' sum ``[T, D]``
+    float32 and the rows each expert saw ``[E]`` int32. ``chunk_rows``:
+    the token-slots one grouped product takes; the launch runs as many
+    as the rows routed HERE fill, so a launch whose expected rows equal
+    ``chunk_rows`` takes one pass or two as the seed's router falls
+    (models/axk1.py ``expert_chunk_rows``)."""
     t, k = idx.shape
-    held = experts["gate"].shape[0]
+    held = experts["gate"].shape[-3]
     local = idx - expert_offset
     here = (local >= 0) & (local < held) & valid[:, None]
     if t <= DENSE_TOKENS:
@@ -86,12 +95,31 @@ def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows
             ),
             axis=1,
         )
-        act = jax.nn.silu(jnp.einsum("td,edf->etf", x, experts["gate"])) * jnp.einsum(
-            "td,edf->etf", x, experts["up"]
-        )
-        y = jnp.einsum("etf,efd->etd", act, experts["down"]).astype(jnp.float32)
+        if layer is None:
+            # a layer's own experts: every one of them over every row. The products read the layer in place
+            # (its slice of a scan fuses into them); a loop over single experts would have the slice written
+            # out first and cost twice this (29.7 ms against 13.2 at 12 x 64 experts: my chip run, PR 45)
+            act = jax.nn.silu(jnp.einsum("td,edf->etf", x, experts["gate"])) * jnp.einsum(
+                "td,edf->etf", x, experts["up"]
+            )
+            y = jnp.einsum("etf,efd->etd", act, experts["down"]).astype(jnp.float32)
+            rows = jnp.sum(weight > 0, axis=0, dtype=jnp.int32)
+            return jnp.einsum("etd,te->td", y, weight), rows
         rows = jnp.sum(weight > 0, axis=0, dtype=jnp.int32)
-        return jnp.einsum("etd,te->td", y, weight), rows
+        chosen = jnp.argsort(rows == 0, stable=True).astype(jnp.int32)  # the experts some row chose, ascending, first
+
+        def add_expert(i, acc):
+            e = chosen[i]
+            # one expert's matrix at (layer, e) of the stack, sliced inside the product that reads it
+            at = lambda a: jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1, *a.shape[2:]))[0, 0]
+            act = jax.nn.silu(x @ at(experts["gate"])) * (x @ at(experts["up"]))
+            y = (act @ at(experts["down"])).astype(jnp.float32)
+            return acc + y * jax.lax.dynamic_slice_in_dim(weight, e, 1, axis=1)
+
+        acc = jnp.zeros((t, x.shape[1]), jnp.float32)
+        return jax.lax.fori_loop(0, jnp.sum(rows > 0, dtype=jnp.int32), add_expert, acc), rows
+    if layer is not None:
+        experts = {name: jax.lax.dynamic_index_in_dim(a, layer, 0, False) for name, a in experts.items()}
     key = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(key, stable=True)
     token = (order // k).astype(jnp.int32)

@@ -597,6 +597,7 @@ class TokenSessions:
             "lm_block_launches": 0, "lm_block_rows": 0,
             "lm_block_commit_rows": 0, "lm_tokens_committed": 0,
             "lm_state_resets": 0, "lm_state_carries": 0, "lm_state_lost": 0,
+            "lm_step_experts_chosen": 0, "lm_step_experts_held": 0,
             "created_total": 0, "ended_total": 0,
             "outgrown_total": 0, "unknown_total": 0,
         }
@@ -881,6 +882,11 @@ class TokenSessions:
                     self._expert_rows = (
                         total if self._expert_rows is None else self._expert_rows + total
                     )
+                    if ticket.kind == "lm_step":
+                        # the held experts some row of a step launch chose: all that a program which
+                        # hands ops/experts.py the layers' stacks reads of them (models/ling.py)
+                        self._counters["lm_step_experts_chosen"] += int(np.count_nonzero(total))
+                        self._counters["lm_step_experts_held"] += total.size
 
     def end(self, stream_id: str) -> None:
         """Explicitly end a session (server drain, client abort)."""
