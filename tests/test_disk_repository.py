@@ -147,7 +147,7 @@ def test_examples_tree_parses():
 
     root = pathlib.Path("examples")
     dirs = sorted(p for p in root.iterdir() if (p / "config.yaml").exists())
-    assert len(dirs) == 18
+    assert len(dirs) == 19
     for d in dirs:
         doc = load_yaml(str(d / "config.yaml"))
         if doc["family"] == "ensemble":

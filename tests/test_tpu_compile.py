@@ -399,6 +399,78 @@ def _launcher_text(model_dir, inputs, one_chip):
     return model, _compile_launcher(TPUChannel(repo), model, structs).as_text()
 
 
+def _smallthinker_launch(one_chip, model: dict, slots: int, slot_len: int, launch: dict):
+    """One launch shape of ``family: smallthinker`` compiled as
+    ``ParamLauncher`` launches it: weights and the two key/value caches
+    as arguments, the caches donated and row-major on both sides.
+    Returns the executable's text and the configuration."""
+    from jax.experimental.layout import Format, Layout
+    from triton_client_tpu.models import smallthinker
+    from triton_client_tpu.pipelines import lm
+
+    cfg = smallthinker.Config.from_dict(model)
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    weights = placed(jax.eval_shape(
+        lambda: smallthinker.stack_layers(smallthinker.init_params(jax.random.PRNGKey(0), cfg), cfg)))
+    cache = placed(jax.eval_shape(lambda: smallthinker.empty_cache(cfg, slots, slot_len)))
+    row_major = jax.tree_util.tree_map(
+        lambda x: Format(Layout(major_to_minor=tuple(range(x.ndim))), one_chip), cache)
+    ((kind, size),) = launch.items()
+    inputs = placed({k: jnp.asarray(v) for k, v in lm.launch_inputs(kind, size).items()})
+    device_fn = lm.make_device_fn(smallthinker, cfg)
+
+    def run(inputs, weights, cache):
+        out = dict(device_fn(inputs, {"weights": weights, lm.STATE_KEY: cache}))
+        return out, out.pop(lm.STATE_KEY)
+
+    return jax.jit(
+        run, donate_argnums=(2,), in_shardings=(None, None, row_major), out_shardings=(None, row_major),
+    ).lower(inputs, weights, cache).compile().as_text(), cfg
+
+
+def _smallthinker_config() -> dict:
+    import json
+    import pathlib
+
+    return json.loads(
+        (pathlib.Path(__file__).resolve().parents[1] / "benchmarks/configs/smallthinker21b-ep1-l12.json").read_text())
+
+
+@pytest.mark.parametrize("launch", ({"extend": 32}, {"extend": 64}, {"step": 8}))
+def test_smallthinker_launch_kinds_lower_at_the_tiny_preset(one_chip, launch):
+    """Both launch kinds of the benchmark configuration's rehearsal: the
+    scan over periods with its inner scan over window layers, the ring."""
+    doc = _smallthinker_config()
+    model = {**doc["model"], **doc["rehearsal"]["model"]}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _smallthinker_launch(one_chip, model, 16, slot_len, launch)
+    ((kind, size),) = launch.items()
+    assert f"f32[{size if kind == 'step' else 1},{cfg.vocab_size}]" in text
+
+
+@pytest.mark.parametrize("launch", ({"extend": 2048}, {"step": 16}))
+def test_smallthinker_launches_update_both_geometries_in_place(one_chip, launch):
+    """At the served widths (one period of the three, an eighth of the
+    vocabulary) both key/value caches keep their row-major layout through
+    the scans: no launch begins or ends with a copy of the full layers'
+    rows or of the window layers' rings, and none copies a layer's
+    experts out of the stack whole for a step launch."""
+    model = {**_smallthinker_config()["model"], "num_hidden_layers": 4, "vocab_size": 18992}
+    model["layer_types"] = model["layer_types"][:4]
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _smallthinker_launch(one_chip, model, 16, slot_len, launch)
+    width = cfg.num_key_value_heads * cfg.head_dim
+    for whole in (f"bf16[1,16,{slot_len},{width}]", f"bf16[3,16,{cfg.window_ring},{width}]"):
+        assert whole in text
+        assert not [line for line in text.splitlines() if f"= {whole}" in line and " copy(" in line]
+    if "step" in launch:
+        experts = f"bf16[{cfg.moe_num_primary_experts},{cfg.hidden_size},{cfg.moe_ffn_hidden_size}]"
+        assert not [line for line in text.splitlines() if f"= {experts}" in line]
+
+
 @pytest.fixture()
 def tpu_route(monkeypatch):
     """``fused: auto`` asks ``jax.default_backend()``, which says cpu

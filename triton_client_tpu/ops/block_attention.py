@@ -36,6 +36,28 @@ kv_heads)``. Two forms, one a launch kind:
     sessions and 48 layers; gathering them into one array copies each
     slot first.
 
+With ``block`` 1 the block mask is the causal one, and models/smallthinker.py
+takes the same row layout and :func:`prefill_attention` for its FULL
+layers. Its WINDOW layers (a position reads the ``window`` latest
+positions, its own among them) keep a session's rows in a RING: position
+``p`` lies at row ``p % rows`` of a slot of ``rows`` < ``slot_len`` rows
+(:func:`write_ring`), which positions, never contents, give a meaning to:
+a row whose position is out of a query's sight is masked, whatever an
+earlier lap or an earlier session left in it.
+
+  * :func:`prefill_attention` with ``window``: the loop over key blocks
+    starts at the FIRST block some query of the launch may see and
+    finds block ``j`` (absolute) at row ``j * KEY_BLOCK % rows``; a ring of
+    ``window`` + the launch's tokens - 1 rows or more still holds every
+    key the launch may see after the launch has written its own;
+  * :func:`step_attention`: ONE token of each of several sessions,
+    already written, against its slot's rows IN PLACE, ring or not, in
+    :func:`block_attention`'s one batched product over the rows as they
+    lie (no copy of a slot per session per layer: gathering the launch's
+    slots copied each first, PR 39): row ``r`` of a slot at position ``p``
+    holds position ``p - (p - r) % rows``, in sight if that is not
+    negative and inside the window.
+
 Scores, softmax and the mask are float32; products read bfloat16.
 """
 
@@ -83,60 +105,94 @@ def write_span(kv, layer, slot, start, k, v):
     return {"k": keys, "v": values}, (key_rows, value_rows)
 
 
-def prefill_attention(q, rows, positions, block: int, scale: float):
+def write_ring(kv, layer, slot, start, k, v):
+    """:func:`write_span` into a slot that is a RING: position ``p`` goes
+    to row ``p % rows``, and a span that runs past the ring's last row
+    goes on at its first (a span holds at most ``rows`` tokens). Pad
+    tokens at the span's end are written too: the rows they take held
+    positions that are out of every later query's sight."""
+
+    def put(cache, new):
+        new = new.reshape(new.shape[0], -1).astype(cache.dtype)
+        n, (ring, width) = new.shape[0], cache.shape[2:]
+        assert n <= ring, "a span fits the ring"
+        rows = jax.lax.dynamic_slice(cache, (layer, slot, 0, 0), (1, 1, ring, width))[0, 0]
+        at = start % ring
+        wide = jax.lax.dynamic_update_slice(jnp.concatenate([rows, jnp.zeros_like(new)]), new, (at, 0))
+        # the rows that ran past the ring's end are its head's
+        head = jnp.where((jnp.arange(n) < at + n - ring)[:, None], wide[ring:], wide[:n])
+        rows = jax.lax.dynamic_update_slice(wide[:ring], head, (0, 0))
+        return jax.lax.dynamic_update_slice(cache, rows[None, None], (layer, slot, 0, 0)), rows
+
+    keys, key_rows = put(kv["k"], k)
+    values, value_rows = put(kv["v"], v)
+    return {"k": keys, "v": values}, (key_rows, value_rows)
+
+
+def prefill_attention(q, rows, positions, block: int, scale: float, window: int = 0):
     """``q [n, H, d]`` (n new tokens of one session, ``positions [n]``
     ascending by one), ``rows`` its slot's ``(keys, values)`` ``[S, G *
     d]`` with the new tokens written. Returns ``[n, H * d]``.
 
     For each block of queries only the key blocks up to its last visible
     position (a loop whose length the positions decide): a prompt of 512
-    tokens reads 512 keys, not the slot's 2,048."""
+    tokens reads 512 keys, not the slot's 2,048. With ``window`` a query
+    at ``p`` reads positions ``p - window + 1`` to ``p``, ``rows`` are a
+    ring (:func:`write_ring`) and the loop starts at the first block the
+    query block's first query sees."""
     n, h, d = q.shape
     s_len, g = rows[0].shape[0], rows[0].shape[1] // d
     r = h // g
     qb, kb = min(n, QUERY_BLOCK), math.gcd(s_len, KEY_BLOCK)
     assert n % qb == 0, "the launch's tokens fill whole query blocks"
     last_visible = (positions // block + 1) * block - 1
+    sight = (last_visible, jnp.maximum(positions - (window - 1), 0)) if window else (last_visible,)
     keys, values = (jnp.moveaxis(a.reshape(s_len, g, d), 0, 1) for a in rows)  # [G, S, d]
 
     def one(args):
-        qq, limit = args  # [qb, H, d], [qb]
+        qq, limit, *floor = args  # [qb, H, d], [qb] and, under a window, the first visible position [qb]
         # a key/value head's queries side by side: rows (head in group, query)
         qq = jnp.moveaxis(qq.reshape(qb, g, r, d), 0, 2).reshape(g, r * qb, d)
         limit_rows = jnp.tile(limit, r)
+        floor_rows = jnp.tile(floor[0], r) if window else None
 
         def take(j, carry):
             top, total, acc = carry
             lo = j * kb
+            at = lo % s_len if window else lo  # where the ring holds block j
             scores = jnp.einsum(
-                "gqd,gkd->gqk", qq, jax.lax.dynamic_slice_in_dim(keys, lo, kb, axis=1),
+                "gqd,gkd->gqk", qq, jax.lax.dynamic_slice_in_dim(keys, at, kb, axis=1),
                 preferred_element_type=jnp.float32,
             ) * scale
             keep = (lo + jnp.arange(kb))[None, None, :] <= limit_rows[None, :, None]
+            if window:
+                keep &= (lo + jnp.arange(kb))[None, None, :] >= floor_rows[None, :, None]
             scores = jnp.where(keep, scores, -jnp.inf)
             new_top = jnp.maximum(top, scores.max(axis=-1))
             w = jnp.exp(scores - new_top[..., None])
             shrink = jnp.exp(top - new_top)
             acc = acc * shrink[..., None] + jnp.einsum(
                 "gqk,gkd->gqd", w.astype(values.dtype),
-                jax.lax.dynamic_slice_in_dim(values, lo, kb, axis=1),
+                jax.lax.dynamic_slice_in_dim(values, at, kb, axis=1),
                 preferred_element_type=jnp.float32,
             )
             return new_top, total * shrink + w.sum(axis=-1), acc
 
-        # key 0 is visible to every query, so the first block leaves no row empty
-        blocks = jnp.clip(limit[-1] // kb + 1, 1, s_len // kb)
+        # key 0 is visible to every query, so the first block leaves no row empty (under a window a row
+        # whose first blocks are all out of its sight keeps the state's finite floor until one is not)
+        first = floor[0][0] // kb if window else 0
+        blocks = limit[-1] // kb + 1 if window else jnp.clip(limit[-1] // kb + 1, 1, s_len // kb)
         state = (
             jnp.full((g, r * qb), -1e30, jnp.float32),
             jnp.zeros((g, r * qb), jnp.float32),
             jnp.zeros((g, r * qb, d), jnp.float32),
         )
-        _, total, acc = jax.lax.fori_loop(0, blocks, take, state)
+        _, total, acc = jax.lax.fori_loop(first, blocks, take, state)
         out = (acc / total[..., None]).reshape(g, r, qb, d)
         return jnp.moveaxis(out, 2, 0).reshape(qb, h * d).astype(values.dtype)
 
     split = lambda a: a.reshape(n // qb, qb, *a.shape[1:])
-    return jax.lax.map(one, (split(q), split(last_visible))).reshape(n, h * d)
+    return jax.lax.map(one, (split(q), *map(split, sight))).reshape(n, h * d)
 
 
 def block_attention(q, k, v, kv, layer, slots, positions, scale: float):
@@ -171,3 +227,34 @@ def block_attention(q, k, v, kv, layer, slots, positions, scale: float):
     # a row of key/value head g keeps that head's lanes of its output
     out = jnp.einsum("rgqhd,gh->rgqd", out.reshape(rows, g, r * b, g, d), jnp.eye(g, dtype=out.dtype))
     return jnp.moveaxis(out.reshape(rows, g, r, b, d), 3, 1).reshape(rows, b, h * d).astype(dtype)
+
+
+def step_attention(q, kv, layer, slots, positions, scale: float, window: int = 0):
+    """``q [R, H, d]``: ONE token of each of R sessions at ``positions
+    [R]``, its key and value already written to row ``positions[r] %
+    rows`` of slot ``slots[r]`` (a pad row's slot is the slot COUNT: it
+    is dropped). Each row reads its slot's rows in place, a ring or a
+    whole slot alike: row ``s`` holds the position ``age`` = ``(p - s) %
+    rows`` back from ``p``, in sight if that position is not negative
+    and, with ``window``, among the latest ``window``. Returns ``[R, H * d]``."""
+    rows, h, d = q.shape
+    n_slots, s_len, width = kv["k"].shape[1:]
+    g = width // d
+    r = h // g
+    dtype = kv["k"].dtype
+    layer_of = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)  # [slots, S, G * d]
+    # the queries of key/value head g, rows (g, head in group), zero outside g's lanes (block_attention)
+    qs = q.reshape(rows, g, r, 1, d) * jnp.eye(g, dtype=q.dtype)[:, None, :, None]  # [R, G, r, G, d]
+    place = lambda a: jnp.zeros((n_slots, *a.shape[1:]), a.dtype).at[slots].set(a, mode="drop")
+    qs, at = place(qs.reshape(rows, h, width)), place(positions)
+    age = (at[:, None] - jnp.arange(s_len)[None, :]) % s_len  # [slots, S]
+    keep = age <= at[:, None]
+    if window:
+        keep &= age < window
+    scores = jnp.einsum("sqc,skc->sqk", qs, layer_of(kv["k"]), preferred_element_type=jnp.float32) * scale
+    w = jax.nn.softmax(jnp.where(keep[:, None, :], scores, -jnp.inf), axis=-1)  # a row's own position is in sight
+    out = jnp.einsum("sqk,skc->sqc", w.astype(dtype), layer_of(kv["v"]), preferred_element_type=jnp.float32)
+    out = out[jnp.minimum(slots, n_slots - 1)]  # [R, H, G * d]
+    # a row of key/value head g keeps that head's lanes of its output
+    out = jnp.einsum("rgqhd,gh->rgqd", out.reshape(rows, g, r, g, d), jnp.eye(g, dtype=out.dtype))
+    return out.reshape(rows, h * d).astype(dtype)

@@ -14,6 +14,12 @@ each held expert that some valid row of it chose, once, from its place
 in the stack, runs all rows through it and weights by the gates: an
 expert no row chose is not read. Given a layer's own experts it runs
 every one of them over every row.
+
+An expert is a GATED product, ``act(x W_gate) * (x W_up)`` through
+``W_down``, and ``act`` is the layer's (``activation`` of
+:func:`routed_experts`): SiLU, the default, for ``family: axk1``,
+``deepseek_v32``, ``sdar_moe`` and ``bailing_hybrid`` (SwiGLU); ReLU for
+``family: smallthinker`` (its ReLU-gated experts).
 """
 
 from __future__ import annotations
@@ -61,15 +67,17 @@ def route(x, router, top_k: int, scale: float, normalise: bool = True,
     return idx, top * scale
 
 
-def _swiglu_grouped(rows, experts, sizes):
+def _gated_grouped(rows, experts, sizes, activation):
+    """The gated product of ``rows``, sorted by expert, one group an expert."""
     dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
-    act = jax.nn.silu(dot(rows, experts["gate"])) * dot(rows, experts["up"])
+    act = activation(dot(rows, experts["gate"])) * dot(rows, experts["up"])
     return jax.lax.ragged_dot(
         act, experts["down"], sizes, preferred_element_type=jnp.float32
     )
 
 
-def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows: int = CHUNK_ROWS, layer=None):
+def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows: int = CHUNK_ROWS, layer=None,
+                   activation=jax.nn.silu):
     """``x [T, D]`` bfloat16, ``valid [T]`` (pad tokens route nowhere),
     ``idx``/``gates [T, k]`` from :func:`route`, ``experts`` the held
     experts' ``gate``/``up [E, D, F]`` and ``down [E, F, D]``; with
@@ -81,7 +89,8 @@ def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows
     the token-slots one grouped product takes; the launch runs as many
     as the rows routed HERE fill, so a launch whose expected rows equal
     ``chunk_rows`` takes one pass or two as the seed's router falls
-    (models/axk1.py ``expert_chunk_rows``)."""
+    (models/axk1.py ``expert_chunk_rows``). ``activation``: what the gate's
+    product passes through (module docstring)."""
     t, k = idx.shape
     held = experts["gate"].shape[-3]
     local = idx - expert_offset
@@ -99,7 +108,7 @@ def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows
             # a layer's own experts: every one of them over every row. The products read the layer in place
             # (its slice of a scan fuses into them); a loop over single experts would have the slice written
             # out first and cost twice this (29.7 ms against 13.2 at 12 x 64 experts: my chip run, PR 45)
-            act = jax.nn.silu(jnp.einsum("td,edf->etf", x, experts["gate"])) * jnp.einsum(
+            act = activation(jnp.einsum("td,edf->etf", x, experts["gate"])) * jnp.einsum(
                 "td,edf->etf", x, experts["up"]
             )
             y = jnp.einsum("etf,efd->etd", act, experts["down"]).astype(jnp.float32)
@@ -112,7 +121,7 @@ def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows
             e = chosen[i]
             # one expert's matrix at (layer, e) of the stack, sliced inside the product that reads it
             at = lambda a: jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1, *a.shape[2:]))[0, 0]
-            act = jax.nn.silu(x @ at(experts["gate"])) * (x @ at(experts["up"]))
+            act = activation(x @ at(experts["gate"])) * (x @ at(experts["up"]))
             y = (act @ at(experts["down"])).astype(jnp.float32)
             return acc + y * jax.lax.dynamic_slice_in_dim(weight, e, 1, axis=1)
 
@@ -138,7 +147,7 @@ def routed_experts(x, valid, idx, gates, experts, expert_offset: int, chunk_rows
         g = jax.lax.dynamic_slice(gate, (lo,), (chunk,))
         # the part of each expert's run of rows that falls in this chunk
         in_chunk = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
-        y = _swiglu_grouped(x[rows], experts, in_chunk)
+        y = _gated_grouped(x[rows], experts, in_chunk, activation)
         # rows past the last group are not the product's to define
         return acc.at[rows].add(jnp.where(g[:, None] > 0, y * g[:, None], 0.0))
 

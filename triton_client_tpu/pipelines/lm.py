@@ -7,13 +7,18 @@ of a ``family: sdar_moe`` entry (models/sdar.py: grouped-query attention
 under a block mask, generation by diffusion over blocks) or of a
 ``family: bailing_hybrid`` entry (models/ling.py: Kimi Delta Attention
 layers with a latent-attention layer among every few; the contract of a
-session with a RECURRENT STATE is further down):
+session with a RECURRENT STATE is further down) or of a ``family:
+smallthinker`` entry (models/smallthinker.py: window layers with rotary
+positions to one full layer without, a router that reads the layer's
+input, ReLU-gated experts; the contract of a session whose layers keep
+rows in TWO GEOMETRIES is further down):
 the device program ``device_fn(inputs, params)``, the
 ``params`` it takes as launcher ARGUMENTS (``weights`` and, under
 ``cache``, the latent cache, with an indexer a dict of it and the index
 keys, for ``sdar_moe`` the keys and values a head, for
 ``bailing_hybrid`` three arrays: latent rows, the recurrent state and
-the convolution tails, that the channel
+the convolution tails, for ``smallthinker`` the keys and values of the
+full layers and of the window layers' rings, that the channel
 donates into each launch and takes back from its
 outputs: gigabytes of weights cannot be constants of an HLO module, and
 the cache never crosses to the host),
@@ -70,6 +75,20 @@ failed after dispatch has overwritten the state of its rows, so its
 sessions end and their next request is refused with that reason
 (``lm_state_lost``; docs/OPERATIONS.md).
 
+The contract of a session whose layers keep rows in TWO GEOMETRIES
+(``family: smallthinker``; the requests are the first contract's,
+unchanged). What a slot holds: in every full layer a row a position up
+to ``slot_len``, in every window layer a ring of ``window_ring`` rows
+(the window and the longest extend launch). A session longer than the
+ring loses nothing it may still read: a window layer's query reads the
+latest ``sliding_window_size`` positions, and those are in the ring. The
+limit is ``slot_len`` positions (``SessionLimitError`` past it), whatever
+the ring. A slot is reused without touching the device: positions, not
+contents, decide what a query sees. ``TokenSessions.stats`` gives the
+cache in bytes by geometry (``session_cache_bytes``,
+``session_cache_bytes_in_use``) and ``lm_keys_read`` beside
+``lm_keys_visible``.
+
 Everything is read from the entry's ``config.yaml``: ``model`` (the
 published sizes and this chip's share, ``precision``), ``pipeline``
 (``slot_len``, ``max_tokens``, ``session_ttl_s``), ``max_batch_size``
@@ -85,7 +104,7 @@ import numpy as np
 
 from triton_client_tpu.channel.base import InferRequest
 from triton_client_tpu.config import ModelSpec, TensorSpec
-from triton_client_tpu.models import axk1, ling, sdar
+from triton_client_tpu.models import axk1, ling, sdar, smallthinker
 from triton_client_tpu.runtime import precision as precision_policy
 from triton_client_tpu.runtime.repository import RegisteredModel
 from triton_client_tpu.runtime.sessions import TokenSessions
@@ -180,9 +199,12 @@ def _int8_rounded(w):
 
 #: family -> the model module that serves it: its ``Config``,
 #: ``init_params``, ``abstract_params``, ``stack_layers``, ``empty_cache``
-#: and ``extend`` (models/sdar.py also has ``block``). The ONE place a
-#: family is tied to a module; runtime/disk_repository.py reads its keys
-MODULES = {"axk1": axk1, "deepseek_v32": axk1, "sdar_moe": sdar, "bailing_hybrid": ling}
+#: and ``extend`` (models/sdar.py also has ``block``; a ``Config`` may state
+#: ``state_bytes``, models/ling.py, or ``row_geometries``, models/smallthinker.py).
+#: The ONE place a family is tied to a module; runtime/disk_repository.py reads its keys
+MODULES = {
+    "axk1": axk1, "deepseek_v32": axk1, "sdar_moe": sdar, "bailing_hybrid": ling, "smallthinker": smallthinker,
+}
 
 
 @functools.lru_cache(maxsize=None)
@@ -252,6 +274,8 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
         index_cache_bytes=index_cache_bytes, block=block,
         # a model whose layers hold a recurrent state says what one session's takes (models/ling.py)
         state_bytes=cfg.state_bytes() if hasattr(cfg, "state_bytes") else 0,
+        # one whose layers keep rows in more than one geometry says which (models/smallthinker.py)
+        geometries=cfg.row_geometries(slot_len) if hasattr(cfg, "row_geometries") else (),
     )
     device_fn = make_device_fn(model, cfg)
     program = jax.jit(device_fn)

@@ -489,6 +489,7 @@ class TokenLaunch:
     context: int = 0  # positions cached before the launch, summed over its sessions
     keys_visible: int = 0  # over its tokens: the positions each may attend to ...
     keys_selected: int = 0  # ... and those it reads (the model's ``index_topk`` at most)
+    keys_read: int = 0  # ... and, of a model with row geometries, those its windows leave it
     answers: int = 0  # rows of the answer that are some session's: one a session, a block launch's one a token
     commit_rows: int = 0  # of a block launch's sessions, those that wrote their block
 
@@ -499,6 +500,26 @@ class TokenLaunch:
         if self.kind == "lm_block":
             attrs["commit_rows"] = self.commit_rows
         return self.kind, attrs
+
+
+@dataclasses.dataclass(frozen=True)
+class RowGeometry:
+    """One geometry of the rows a session's slot keeps: the ``layers``
+    that share it, the ``rows`` a slot has in each of them, the positions
+    back a query of theirs reads, its own among them (``window``; 0: all,
+    and a row a position) and a row's bytes. With a window the rows are a
+    ring (models/smallthinker.py): a session holds ``min(length, rows)``
+    of them, and a token at position ``p`` reads ``min(p + 1, window)``."""
+
+    name: str
+    layers: int
+    rows: int
+    window: int
+    row_bytes: int
+
+    def held(self, length: int) -> int:
+        """Bytes a session of ``length`` positions holds in this geometry."""
+        return self.layers * min(int(length), self.rows) * self.row_bytes
 
 
 class TokenSessions:
@@ -540,6 +561,16 @@ class TokenSessions:
     its rows, which no length can take back: its sessions END, and their
     next request is refused with that reason (``lm_state_lost``).
 
+    A model whose layers keep rows in MORE THAN ONE GEOMETRY
+    (``geometries``: models/smallthinker.py's full and window layers)
+    is admitted by positions all the same (``slot_len``: what its
+    longest geometry holds); a ring must hold its window and the longest
+    extend launch, which the constructor checks. What changes is the
+    account: ``lm_keys_read`` beside ``lm_keys_visible`` (what a window
+    leaves of a token's keys), and the cache in BYTES by geometry
+    (``session_cache_bytes``, ``session_cache_bytes_in_use``), since the
+    share of ``slot_len`` that sessions hold says nothing of a ring.
+
     One request of a stream at a time: a later request of a stream whose
     earlier one is still in flight waits its turn in :meth:`open`.
     """
@@ -564,6 +595,7 @@ class TokenSessions:
         index_cache_bytes: int = 0,
         block: int = 0,
         state_bytes: int = 0,
+        geometries: tuple = (),
     ) -> None:
         """``index_topk``: the positions a token of the model attends to
         at most (0: all), ``layers`` its layers, ``index_cache_bytes``
@@ -573,7 +605,17 @@ class TokenSessions:
         the model has one-token steps and no block operation).
         ``state_bytes``: what ONE session's recurrent state takes on the
         device whatever its length (0: the model holds none, and a slot
-        is its length)."""
+        is its length). ``geometries``: :class:`RowGeometry`'s fields for
+        each geometry of rows a slot keeps (empty: one row a position in
+        every layer, which ``layers`` counts)."""
+        self._geometries = tuple(RowGeometry(*g) for g in geometries)
+        launch = token_bucket(int(max_tokens))
+        for g in self._geometries:
+            if g.window and g.rows < g.window + launch - 1:
+                raise ValueError(
+                    f"the {g.name} layers keep a ring of {g.rows} rows: under a window of {g.window} and extend "
+                    f"launches of up to {launch} tokens it takes {g.window + launch - 1}"
+                )
         self._state_bytes = int(state_bytes)
         self._lost: dict = {}  # stream -> why it holds no slot any more, while the stream may still ask
         self.slot_len = int(slot_len)
@@ -593,7 +635,7 @@ class TokenSessions:
             "lm_tokens_prefill": 0, "lm_tokens_step": 0,
             "lm_prefill_launches": 0, "lm_step_launches": 0,
             "lm_step_sessions": 0, "lm_context_prefill": 0,
-            "lm_keys_visible": 0, "lm_keys_selected": 0,
+            "lm_keys_visible": 0, "lm_keys_selected": 0, "lm_keys_read": 0,
             "lm_block_launches": 0, "lm_block_rows": 0,
             "lm_block_commit_rows": 0, "lm_tokens_committed": 0,
             "lm_state_resets": 0, "lm_state_carries": 0, "lm_state_lost": 0,
@@ -804,6 +846,8 @@ class TokenSessions:
             ticket.keys_selected += self._layers * int(
                 np.minimum(visible, self._index_topk).sum() if self._index_topk else visible.sum()
             )
+            for g in self._geometries:
+                ticket.keys_read += g.layers * int((np.minimum(visible, g.window) if g.window else visible).sum())
             return slot.state, position
 
     def advance(self, ticket: TokenLaunch, outputs):
@@ -857,6 +901,7 @@ class TokenSessions:
             if not failed and ticket.tokens:
                 self._counters["lm_keys_visible"] += ticket.keys_visible
                 self._counters["lm_keys_selected"] += ticket.keys_selected
+                self._counters["lm_keys_read"] += ticket.keys_read
                 if ticket.kind == "lm_step":
                     self._counters["lm_tokens_step"] += ticket.tokens
                     self._counters["lm_step_launches"] += 1
@@ -900,8 +945,16 @@ class TokenSessions:
     def stats(self) -> dict:
         with self._turn:
             slots = list(self._pool.slots.values())
+            by_geometry = {
+                g.name: {"allocated": self._pool.max * g.held(g.rows), "in_use": sum(g.held(s.length) for s in slots)}
+                for g in self._geometries
+            }
             return {
                 **self._counters,
+                # rows by geometry, in bytes: what is allocated and what live sessions hold of it
+                "session_cache_bytes": sum(g["allocated"] for g in by_geometry.values()),
+                "session_cache_bytes_in_use": sum(g["in_use"] for g in by_geometry.values()),
+                "session_cache_bytes_by_geometry": by_geometry,
                 "session_cache_slots": self._pool.max,
                 "session_cache_slot_len": self.slot_len,
                 "session_cache_slots_in_use": len(slots),
