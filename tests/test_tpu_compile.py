@@ -248,19 +248,23 @@ def test_sdar_launches_update_the_served_cache_in_place(one_chip, launch):
     assert not [line for line in text.splitlines() if f"= {whole}" in line and " copy(" in line]
 
 
-def _ling_launch(one_chip, model: dict, slots: int, slot_len: int, launch: dict, monkeypatch):
-    """One launch shape of ``family: bailing_hybrid`` compiled as
+def _ling_launch(one_chip, model: dict, slots: int, slot_len: int, launch: dict, monkeypatch,
+                 family: str = "bailing_hybrid"):
+    """One launch shape of ``family: bailing_hybrid`` (or of another
+    ``family`` whose layers attend over latent rows) compiled as
     ``ParamLauncher`` launches it: weights and the three cache arrays as
     arguments, the cache donated and row-major on both sides, the KDA
-    core as on the chip (the Pallas kernel where the head size fits).
+    core and a step's latent attention as on the chip (the Pallas
+    kernels, not their plain or interpreted forms).
     Returns the executable's text and the configuration."""
     from jax.experimental.layout import Format, Layout
-    from triton_client_tpu.models import ling
-    from triton_client_tpu.ops import delta_attention
+    from triton_client_tpu.ops import delta_attention, latent_attention
     from triton_client_tpu.pipelines import lm
 
     monkeypatch.setattr(delta_attention, "on_chip", lambda: True)
-    cfg = ling.LingConfig.from_dict(model)
+    monkeypatch.setattr(latent_attention, "on_chip", lambda: True)
+    ling = lm.MODULES[family]
+    cfg = ling.Config.from_dict(model)
     placed = lambda tree: jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
     weights = placed(jax.eval_shape(lambda: ling.stack_layers(ling.init_params(jax.random.PRNGKey(0), cfg), cfg)))
@@ -280,12 +284,11 @@ def _ling_launch(one_chip, model: dict, slots: int, slot_len: int, launch: dict,
     ).lower(inputs, weights, cache).compile().as_text(), cfg
 
 
-def _ling_config() -> dict:
+def _ling_config(name: str = "ling3flash-ep8-l13") -> dict:
     import json
     import pathlib
 
-    return json.loads(
-        (pathlib.Path(__file__).resolve().parents[1] / "benchmarks/configs/ling3flash-ep8-l13.json").read_text())
+    return json.loads((pathlib.Path(__file__).resolve().parents[1] / f"benchmarks/configs/{name}.json").read_text())
 
 
 @pytest.mark.parametrize("launch", ({"extend": 128}, {"extend": 256}, {"step": 8}))
@@ -355,6 +358,41 @@ def test_ling_step_launch_reads_single_experts_in_place(one_chip, monkeypatch):
     for stack in (f"bf16[5,{e},{d},{f}]", f"bf16[1,{e},{d},{f}]"):  # a fused slice: the stack in, one matrix out
         assert [line for line in text.splitlines()
                 if line.startswith("%fused_computation") and f": {stack}" in line and f"-> bf16[{d},{f}]" in line]
+
+
+@pytest.mark.parametrize("name, rows, cut", [
+    ("ling3flash-ep8-l13", 8, {"num_hidden_layers": 7, "layer_types": ["kda"] * 6 + ["mla"]}),
+    ("dsv32-ep32-l6", 8, {"num_hidden_layers": 2}),
+    ("axk1-ep16-l6", 16, {"num_hidden_layers": 2}),
+])
+def test_step_launches_read_latent_rows_in_place(one_chip, monkeypatch, name, rows, cut):
+    """At the served widths, slots and slot lengths of the three
+    configurations whose step launch runs ``absorbed_attention`` (a
+    dense layer and one layer, or one period, of each: 32, 128 and 64
+    heads): where the slot is long (62,720 and 34,048 positions) the
+    kernel ``lm_latent_decode`` lowers, and no op of the launch yields a
+    slot's rows: nothing of ``[slot_len, cache_row]`` (or of its latent
+    part) outside a fusion, where the parent's
+    ``dynamic-slice_bitcast_fusion`` wrote 80 MB a row out before
+    anything was multiplied. The slot of 4,352 positions is taken whole,
+    by the form it always had: no kernel, and the slice is there."""
+    import re
+
+    doc = _ling_config(name)
+    model = {**doc["model"], **cut}
+    slot_len = model.pop("slot_len")
+    model.pop("max_tokens")
+    text, cfg = _ling_launch(one_chip, model, doc["max_batch_size"], slot_len, {"step": rows}, monkeypatch, doc["family"])
+    block, layers = cfg.step_key_blocks(slot_len)
+    assert (block == slot_len) == (name == "axk1-ep16-l6") and slot_len % block == 0 and layers
+    whole = re.compile(rf"= bf16\[(1,)*{slot_len},({cfg.cache_row}|{cfg.kv_lora_rank})\]")
+    fused, written = False, []
+    for line in text.splitlines():
+        if line.endswith("{"):  # a computation opens: a fusion's body, or one whose ops each run
+            fused = line.lstrip("%").startswith("fused_computation")
+        elif not fused and whole.search(line) and " parameter(" not in line:
+            written.append(line.strip()[:160])
+    assert ("lm_latent_decode" in text) == (block < slot_len) == (not written)
 
 
 def test_nms_pallas_lowers(one_chip):

@@ -147,6 +147,12 @@ class AXK1Config:
         array agree on without being told (channel/staged.py)."""
         return -(-self.cache_width // 128) * 128
 
+    def step_key_blocks(self, slot_len: int) -> tuple:
+        """What a step launch's row fetches of its slot: the positions
+        it reads at a time (the device program's own rule) and the layers
+        that attend so (runtime/sessions.py counts by it)."""
+        return latent_attention.step_block(slot_len), self.num_hidden_layers
+
     @property
     def group_limited(self) -> bool:
         return self.topk_method == "noaux_tc"

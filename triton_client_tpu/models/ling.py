@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from triton_client_tpu.models import axk1
-from triton_client_tpu.ops import delta_attention
+from triton_client_tpu.ops import delta_attention, latent_attention
 from triton_client_tpu.ops import experts as experts_op
 from triton_client_tpu.ops import rope
 
@@ -168,6 +168,10 @@ class LingConfig:
         """What ``slots`` sessions' recurrent state and convolution tails take, whatever their lengths."""
         h, d = self.num_attention_heads, self.head_dim
         return self.kda_layers * slots * (h * d * d * 4 + (delta_attention.CONV_WIDTH - 1) * self.conv_width * 2)
+
+    def step_key_blocks(self, slot_len: int) -> tuple:
+        """As ``AXK1Config.step_key_blocks``: the MLA layers alone attend over a slot's rows."""
+        return latent_attention.step_block(slot_len), self.mla_layers
 
 
 Config = LingConfig  # what pipelines/lm.py asks of a model module

@@ -9,8 +9,9 @@ the same attention and the launch's shape picks one (models/axk1.py):
 
   * :func:`absorbed_attention` — one new token a session (a step
     launch): ``kv_b`` is folded into the query and the output, so every
-    head attends over the 576-wide cache rows themselves and a launch
-    reads each session's slot once;
+    head attends over the 576-wide cache rows themselves; a long slot is
+    read in place by one Pallas kernel, a block of positions at a time
+    and only as far as the session's position, a short one whole;
   * :func:`expanded_attention` — many new tokens of ONE session (an
     extend launch): the slot's latents are expanded to per-head keys
     and values once a launch, and blocks of queries go against the
@@ -50,10 +51,108 @@ KEY_BLOCK = 256
 SEGMENT_ROWS = 8192
 
 
-def _masked_softmax(scores, key_pos, query_pos):
-    """Softmax over keys at positions <= the query's own."""
-    keep = key_pos <= query_pos[..., None]
-    return jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+# The most positions of a slot that a step launch's row reads at a time. The sweep (my chip run, PR 49,
+# perf/profile_latent_step.py: the kernel alone over the layers that attend, ms at 3 / 4 sessions of 8 rows, 11 of 16
+# for A.X-K1; the whole-slot form it replaces read 3.19, 6.96 and 2.17 there and more inside a launch):
+#   slot 62,720 x 32 heads x 2 layers:  256 1.66/1.85, 640 1.20/1.43, 896 1.16/1.34, 1,280 1.19/1.27, 1,792 1.19/1.24,
+#                                       4,480 1.25/1.28, 6,272 1.20/1.35, 8,960 1.21/1.39, 12,544 1.36/1.52
+#   slot 34,048 x 128 heads x 6 layers: 256 2.96/3.34, 896 2.18/2.41, 1,792 2.04/2.14, 2,432 2.01/2.23,
+#                                       4,864 2.20/2.39, 17,024 5.13/5.23
+#   slot 4,352 x 64 heads x 6 layers:   256 1.38, 2,176 1.32, 4,352 (whole) 1.41 (served by the whole-slot form: below)
+# A short block fetches little past a row's position and pays a grid step (0.35 us) for every block of the slot, read
+# or not; a long one the reverse. The kernel runs at 300-380 GB/s of rows whatever the block: every row passes the
+# matrix unit twice (scores, values), which bounds it near 530.
+STEP_BLOCK = 2560
+
+
+def step_block(slot_len: int) -> int:
+    """Positions a step launch's row reads at a time, from the slot's
+    shape alone: the slot whole where it has at most
+    :data:`SEGMENT_ROWS` positions (as the extend form takes it), else
+    its largest part of whole 128-lane tiles that divides it and has at
+    most :data:`STEP_BLOCK` (a slot that no such part divides: whole)."""
+    fits = [n for n in range(128, STEP_BLOCK + 1, 128) if slot_len % n == 0]
+    return slot_len if slot_len <= SEGMENT_ROWS or not fits else max(fits)
+
+
+def on_chip() -> bool:
+    """The one probe :func:`absorbed_attention` asks: its kernel is
+    compiled for a TPU and interpreted elsewhere
+    (tests/test_tpu_compile.py steers it: the compiler is asked here,
+    where the backend says cpu)."""
+    return jax.default_backend() == "tpu"
+
+
+def _decode_kernel(layer_ref, slots_ref, pos_ref, q_ref, kv_ref, *refs, scale, block, rank, selected):
+    """One grid step: one row of the launch against one block of its
+    slot's positions, every head at once; the running softmax sits in
+    scratch while the row's blocks (the last grid axis) go by. A block
+    past the row's position is not computed (and, its index clamped to
+    the row's last, not fetched)."""
+    sel_ref, tau_ref = refs[:2] if selected else (None, None)
+    out_ref, top_ref, total_ref, acc_ref = refs[-4:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos, lo = pos_ref[b], j * block
+
+    @pl.when(j == 0)
+    def _():
+        top_ref[...] = jnp.full(top_ref.shape, -1e30, jnp.float32)
+        total_ref[...] = jnp.zeros(total_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def attend(edge: bool):
+        """``edge``: the block holds the row's position, so some of it lies past it."""
+        rows = kv_ref[...]  # [block, row]
+        scores = jax.lax.dot_general(
+            q_ref[...], rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        keep = lo + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) <= pos if edge else None
+        if selected:
+            chosen = sel_ref[...] >= tau_ref[...]
+            keep = chosen if keep is None else keep & chosen
+        if keep is not None:
+            scores = jnp.where(keep, scores, -jnp.inf)
+        values = rows[:, :rank]
+        if edge:
+            # what a slot holds past the row's position is not the row's: it must not reach the sum even times zero
+            seen = lo + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0) <= pos
+            values = jnp.where(seen, values, jnp.zeros_like(values))
+        top = top_ref[...]
+        new_top = jnp.maximum(top, jnp.max(scores, axis=1, keepdims=True))
+        # the weights as the values' product reads them, and their sum over those: what is divided in the end is
+        # then a mean of the values under weights that add up to one, whatever their rounding
+        w = jnp.exp(scores - new_top).astype(values.dtype)
+        shrink = jnp.exp(top - new_top)
+        total_ref[...] = total_ref[...] * shrink + jnp.sum(w.astype(jnp.float32), axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * shrink + jnp.dot(w, values, preferred_element_type=jnp.float32)
+        top_ref[...] = new_top
+
+    pl.when(lo + block - 1 <= pos)(lambda: attend(False))
+    pl.when((lo <= pos) & (pos < lo + block - 1))(lambda: attend(True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        out_ref[...] = (acc_ref[...] / total_ref[...]).astype(out_ref.dtype)
+
+
+def _whole_slot_attention(q, kv, layer, slots, positions, rank, scale, select):
+    """:func:`absorbed_attention` for a slot taken whole: the sessions
+    go one after another (``lax.map``), each slices its slot out of the
+    cache (5.6 MB at the served 4,352 positions), scores every position
+    and masks afterwards. ``q [B, H, row]``; returns ``[B, H, rank]``."""
+    key_pos = jnp.arange(kv.shape[2])
+
+    def one(args):
+        q_row, slot, pos, *picked = args  # [H, row]
+        rows = jax.lax.dynamic_slice(kv, (layer, slot, 0, 0), (1, 1, kv.shape[2], kv.shape[3]))[0, 0]
+        scores = jnp.einsum("hc,sc->hs", q_row, rows, preferred_element_type=jnp.float32) * scale
+        if picked:
+            index_scores, tau = picked
+            scores = jnp.where(index_scores >= tau, scores, -jnp.inf)
+        w = jax.nn.softmax(jnp.where(key_pos <= pos, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("hs,sc->hc", w.astype(rows.dtype), rows[:, :rank])
+
+    return jax.lax.map(one, (q, slots, positions, *(select or ())))
 
 
 def absorbed_attention(q_nope, q_rope, kv, layer, slots, positions, kv_b, scale, nope, select=None):
@@ -64,34 +163,72 @@ def absorbed_attention(q_nope, q_rope, kv, layer, slots, positions, kv_b, scale,
     ``[B, S]`` and thresholds ``[B]`` or None. Returns ``[B, H, v]``
     bfloat16.
 
-    The sessions go one after another (``lax.map``): each reads its own
-    slot in place with one dynamic slice, 5 MB at the served size. A
-    gather of the B slots into one array copied them first, row by row,
-    and took most of a step launch (8.2 ms for 8 slots of one layer: my
-    chip run, PR 29)."""
+    A slot of more than :data:`SEGMENT_ROWS` positions goes through one
+    Pallas kernel (``lm_latent_decode``; interpreted where there is no
+    TPU) whose grid is rows x the slot's blocks of :func:`step_block`
+    positions: each row reads its slot IN PLACE, a block at a time up
+    to the block that holds its position, with a running softmax over
+    the blocks, so nothing of the shape ``[S, row]`` is ever written out
+    and a row fetches no block past its position (a pad row, at position
+    0, fetches one). One whole slot sliced out a row wrote 80 MB out
+    before anything was multiplied (3.9 of a step launch's 14.9 ms at
+    62,720 positions: PERF.md section 6, PR 49). A shorter slot is taken
+    whole (:func:`_whole_slot_attention`): the kernel gives the served
+    slot of 4,352 positions 0.8 ms a launch back, and its one cell LOST
+    1.1-2.6% of throughput by it in four pairs of four, because its
+    batcher answers a shorter launch with more and smaller launches
+    (PERF.md section 6, PR 49; ROADMAP A14): the kernel waits there on
+    the batcher, as PR 45's form of the experts does. A gather of the B
+    slots into one array copied them first, row by row, and took most of
+    a step launch (8.2 ms for 8 slots of one layer: my chip run, PR 29)."""
     rank = kv_b.shape[0]
+    b, h = q_nope.shape[:2]
+    s_len, row = kv.shape[2:]
+    block = step_block(s_len)
+    assert s_len % block == 0, "the block divides the slot"
     q_lat = jnp.einsum("bhd,chd->bhc", q_nope, kv_b[..., :nope])
-    tail = kv.shape[-1] - rank - q_rope.shape[-1]  # a cache row's zero tail
+    tail = row - rank - q_rope.shape[-1]  # a cache row's zero tail
     q = jnp.concatenate(
         [q_lat, q_rope, jnp.zeros((*q_rope.shape[:-1], tail), q_rope.dtype)], axis=-1
     ).astype(kv.dtype)
-    key_pos = jnp.arange(kv.shape[2])
-
-    def one(args):
-        q_row, slot, pos, *picked = args  # [H, rank + rope]
-        rows = jax.lax.dynamic_slice(
-            kv, (layer, slot, 0, 0), (1, 1, kv.shape[2], kv.shape[3])
-        )[0, 0]
-        scores = jnp.einsum(
-            "hc,sc->hs", q_row, rows, preferred_element_type=jnp.float32
-        ) * scale
-        if picked:
-            index_scores, tau = picked
-            scores = jnp.where(index_scores >= tau, scores, -jnp.inf)
-        w = _masked_softmax(scores, key_pos, pos[None])
-        return jnp.einsum("hs,sc->hc", w.astype(rows.dtype), rows[:, :rank])
-
-    out_lat = jax.lax.map(one, (q, slots, positions, *(select or ())))
+    if block == s_len:
+        out_lat = _whole_slot_attention(q, kv, layer, slots, positions, rank, scale, select)
+        return jnp.einsum("bhc,chd->bhd", out_lat, kv_b[..., nope:])
+    # the row's last block, named again for the blocks after it so that the pipeline does not fetch them
+    at = lambda j, pos: jnp.minimum(j, pos // block)
+    in_specs = [
+        pl.BlockSpec((None, h, row), lambda i, j, layer, slots, pos: (i, 0, 0)),
+        pl.BlockSpec((None, None, block, row), lambda i, j, layer, slots, pos: (layer[0], slots[i], at(j, pos[i]), 0)),
+    ]
+    operands = [q, kv]
+    if select is not None:
+        index_scores, tau = select
+        in_specs += [
+            pl.BlockSpec((None, 1, block), lambda i, j, layer, slots, pos: (i, 0, at(j, pos[i]))),
+            pl.BlockSpec((None, 1, 1), lambda i, j, layer, slots, pos: (i, 0, 0)),
+        ]
+        operands += [index_scores[:, None, :], tau.astype(jnp.float32)[:, None, None]]
+    out_lat = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, block=block, rank=rank, selected=select is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, s_len // block),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, h, rank), lambda i, j, layer, slots, pos: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32), pltpu.VMEM((h, 1), jnp.float32), pltpu.VMEM((h, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), kv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=32 << 20
+        ),
+        interpret=not on_chip(),
+        name="lm_latent_decode",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
+        jnp.clip(positions, 0, s_len - 1).astype(jnp.int32), *operands,
+    )
     return jnp.einsum("bhc,chd->bhd", out_lat, kv_b[..., nope:])
 
 

@@ -200,7 +200,8 @@ def _int8_rounded(w):
 #: family -> the model module that serves it: its ``Config``,
 #: ``init_params``, ``abstract_params``, ``stack_layers``, ``empty_cache``
 #: and ``extend`` (models/sdar.py also has ``block``; a ``Config`` may state
-#: ``state_bytes``, models/ling.py, or ``row_geometries``, models/smallthinker.py).
+#: ``state_bytes``, models/ling.py, ``row_geometries``, models/smallthinker.py, or
+#: ``step_key_blocks``, models/axk1.py and models/ling.py).
 #: The ONE place a family is tied to a module; runtime/disk_repository.py reads its keys
 MODULES = {
     "axk1": axk1, "deepseek_v32": axk1, "sdar_moe": sdar, "bailing_hybrid": ling, "smallthinker": smallthinker,
@@ -276,6 +277,8 @@ def build_registered(doc: dict, name: str, version: str, weights=None) -> Regist
         state_bytes=cfg.state_bytes() if hasattr(cfg, "state_bytes") else 0,
         # one whose layers keep rows in more than one geometry says which (models/smallthinker.py)
         geometries=cfg.row_geometries(slot_len) if hasattr(cfg, "row_geometries") else (),
+        # one whose step launch reads a slot's latent rows by blocks says by which (ops/latent_attention.py)
+        step_keys=cfg.step_key_blocks(slot_len) if hasattr(cfg, "step_key_blocks") else (),
     )
     device_fn = make_device_fn(model, cfg)
     program = jax.jit(device_fn)

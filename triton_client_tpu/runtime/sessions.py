@@ -490,6 +490,8 @@ class TokenLaunch:
     keys_visible: int = 0  # over its tokens: the positions each may attend to ...
     keys_selected: int = 0  # ... and those it reads (the model's ``index_topk`` at most)
     keys_read: int = 0  # ... and, of a model with row geometries, those its windows leave it
+    step_keys_fetched: int = 0  # a step launch of a model that reads latent rows by blocks: positions fetched ...
+    step_keys_whole: int = 0  # ... and the positions of its rows' slots whole
     answers: int = 0  # rows of the answer that are some session's: one a session, a block launch's one a token
     commit_rows: int = 0  # of a block launch's sessions, those that wrote their block
 
@@ -596,6 +598,7 @@ class TokenSessions:
         block: int = 0,
         state_bytes: int = 0,
         geometries: tuple = (),
+        step_keys: tuple = (),
     ) -> None:
         """``index_topk``: the positions a token of the model attends to
         at most (0: all), ``layers`` its layers, ``index_cache_bytes``
@@ -607,7 +610,10 @@ class TokenSessions:
         device whatever its length (0: the model holds none, and a slot
         is its length). ``geometries``: :class:`RowGeometry`'s fields for
         each geometry of rows a slot keeps (empty: one row a position in
-        every layer, which ``layers`` counts)."""
+        every layer, which ``layers`` counts). ``step_keys``: the
+        positions a row of the model's step launch fetches of its slot
+        at a time and the layers that attend so (empty: the model's step
+        launch does not read by blocks, and the two counters stay 0)."""
         self._geometries = tuple(RowGeometry(*g) for g in geometries)
         launch = token_bucket(int(max_tokens))
         for g in self._geometries:
@@ -617,6 +623,7 @@ class TokenSessions:
                     f"launches of up to {launch} tokens it takes {g.window + launch - 1}"
                 )
         self._state_bytes = int(state_bytes)
+        self._step_key_block, self._step_key_layers = map(int, step_keys or (0, 0))
         self._lost: dict = {}  # stream -> why it holds no slot any more, while the stream may still ask
         self.slot_len = int(slot_len)
         self.max_tokens = int(max_tokens)
@@ -640,6 +647,7 @@ class TokenSessions:
             "lm_block_commit_rows": 0, "lm_tokens_committed": 0,
             "lm_state_resets": 0, "lm_state_carries": 0, "lm_state_lost": 0,
             "lm_step_experts_chosen": 0, "lm_step_experts_held": 0,
+            "lm_step_keys_fetched": 0, "lm_step_keys_whole": 0,
             "created_total": 0, "ended_total": 0,
             "outgrown_total": 0, "unknown_total": 0,
         }
@@ -695,6 +703,11 @@ class TokenSessions:
         if n == 1:
             rows = self._step_bucket(b)
             pad = rows - b
+            if self._step_key_block:
+                # every row of the launch's shape reads the blocks up to its position: a pad row's one
+                blocks = int((positions // self._step_key_block + 1).sum()) + pad
+                ticket.step_keys_fetched = self._step_key_layers * blocks * self._step_key_block
+                ticket.step_keys_whole = self._step_key_layers * rows * self.slot_len
             launch = {
                 "tokens": np.concatenate([tokens, np.zeros((pad, 1), np.int32)]),
                 "slots": np.concatenate([slots, np.zeros(pad, np.int32)]),
@@ -903,6 +916,8 @@ class TokenSessions:
                 self._counters["lm_keys_selected"] += ticket.keys_selected
                 self._counters["lm_keys_read"] += ticket.keys_read
                 if ticket.kind == "lm_step":
+                    self._counters["lm_step_keys_fetched"] += ticket.step_keys_fetched
+                    self._counters["lm_step_keys_whole"] += ticket.step_keys_whole
                     self._counters["lm_tokens_step"] += ticket.tokens
                     self._counters["lm_step_launches"] += 1
                     self._counters["lm_step_sessions"] += ticket.tokens
