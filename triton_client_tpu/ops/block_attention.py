@@ -17,9 +17,10 @@ compile, PR 39.) Query head ``j`` reads key/value head ``j // (heads /
 kv_heads)``. Two forms, one a launch kind:
 
   * :func:`prefill_attention`: many new tokens of ONE session, already
-    written to its slot: blocks of queries against the blocks of keys up
-    to their last visible position, with a running softmax, the slot's
-    rows split by head once a layer (2 MB);
+    written to its slot: tiles of queries against the blocks of keys up
+    to their last visible position, with a running softmax, in ONE Pallas
+    kernel (``lm_extend_attention``) that reads a head's key blocks out
+    of the slot's rows as they lie;
   * :func:`block_attention`: ONE block of each of several sessions
     against what their slots hold BEFORE the block plus the block itself,
     which need not be in the cache (a denoising pass writes nothing).
@@ -45,9 +46,9 @@ positions, its own among them) keep a session's rows in a RING: position
 a row whose position is out of a query's sight is masked, whatever an
 earlier lap or an earlier session left in it.
 
-  * :func:`prefill_attention` with ``window``: the loop over key blocks
-    starts at the FIRST block some query of the launch may see and
-    finds block ``j`` (absolute) at row ``j * KEY_BLOCK % rows``; a ring of
+  * :func:`prefill_attention` with ``window``: a tile's key blocks
+    start at the FIRST block some query of it may see, and block ``j``
+    (absolute) is found at row ``j * KEY_BLOCK % rows``; a ring of
     ``window`` + the launch's tokens - 1 rows or more still holds every
     key the launch may see after the launch has written its own;
   * :func:`step_attention`: ONE token of each of several sessions,
@@ -63,13 +64,18 @@ Scores, softmax and the mask are float32; products read bfloat16.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import latent_attention
 
 QUERY_BLOCK = 512
-KEY_BLOCK = 512
+KEY_BLOCK = 1024
 
 
 def write_rows(kv, layer, slots, where, k, v):
@@ -129,70 +135,149 @@ def write_ring(kv, layer, slot, start, k, v):
     return {"k": keys, "v": values}, (key_rows, value_rows)
 
 
+def _extend_kernel(first_ref, last_ref, start_ref, q_ref, k_ref, v_ref, o_ref, top_ref, total_ref, acc_ref,
+                   *, scale, block, window, kb, heads, d):
+    """One grid step: one key/value head, one tile of queries (the
+    ``heads`` query heads of its group, each ``[qb, d]``, side by side in
+    a row of ``q_ref``) against one block of ``kb`` keys; the running
+    softmax of every head sits in scratch while the tile's key blocks
+    (the last grid axis) go by. ``first_ref``/``last_ref``: the first
+    and last absolute key block some query of tile ``i`` sees,
+    ``start_ref`` its first query's position (they ascend by one). A
+    step past the last block does nothing, and has fetched nothing
+    (:func:`prefill_attention`'s index map). Only a block that straddles
+    the tile's limit or its window's floor is masked: the blocks between
+    are in every query's sight whole."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    qb = q_ref.shape[0]
+    first, last, p0 = first_ref[i], last_ref[i], start_ref[i]
+
+    @pl.when(j == 0)
+    def _():
+        top_ref[...] = jnp.full(top_ref.shape, -1e30, jnp.float32)  # finite: a row with no key in sight yet
+        total_ref[...] = jnp.zeros(total_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    lo = (first + j) * kb  # the block's first key's absolute position
+    limit_of = lambda p: p if block == 1 else (p // block + 1) * block - 1
+    whole = lo + kb - 1 <= limit_of(p0)  # the first query's limit is the tile's least
+    if window:
+        whole &= lo >= p0 + qb - window  # the last query's floor is the tile's highest
+
+    to_exponent = scale * math.log2(math.e)
+
+    def take(masked):
+        keys, values = k_ref[...], v_ref[...]
+        if masked:  # one mask a step: every head of the group shares it
+            pos = p0 + jax.lax.broadcasted_iota(jnp.int32, (qb, 1), 0)
+            key = lo + jax.lax.broadcasted_iota(jnp.int32, (1, kb), 1)
+            keep = key <= limit_of(pos)
+            if window:
+                keep &= key >= pos - (window - 1)
+        product = lambda h: jax.lax.dot_general(
+            q_ref[:, h * d:(h + 1) * d], keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ahead = product(0)
+        for h in range(heads):
+            # the next head's product is issued before this head's softmax, which the matrix unit then runs beside:
+            # in the heads' own order a layer took 3.55 ms where it takes 3.13 (2,048 tokens on 13k: PR 50)
+            scores, ahead = ahead, product(h + 1) if h + 1 < heads else None
+            if masked:
+                scores = jnp.where(keep, scores, -jnp.inf)
+            # the scale rides in the exponent's own multiply (2 ** (x log2 e)): the scores are not passed over for it
+            top = top_ref[h]  # in units of the exponent of 2
+            new_top = jnp.maximum(top, scores.max(axis=1, keepdims=True) * to_exponent)
+            w = jnp.exp2(scores * to_exponent - new_top)
+            shrink = jnp.exp2(top - new_top)
+            total_ref[h] = total_ref[h] * shrink + w.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * shrink + jnp.dot(w.astype(values.dtype), values, preferred_element_type=jnp.float32)
+            top_ref[h] = new_top
+
+    in_sight = first + j <= last
+    pl.when(in_sight & whole)(lambda: take(False))
+    pl.when(in_sight & jnp.logical_not(whole))(lambda: take(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        for h in range(heads):
+            o_ref[:, h * d:(h + 1) * d] = (acc_ref[h] / total_ref[h]).astype(o_ref.dtype)
+
+
 def prefill_attention(q, rows, positions, block: int, scale: float, window: int = 0):
     """``q [n, H, d]`` (n new tokens of one session, ``positions [n]``
     ascending by one), ``rows`` its slot's ``(keys, values)`` ``[S, G *
     d]`` with the new tokens written. Returns ``[n, H * d]``.
 
-    For each block of queries only the key blocks up to its last visible
-    position (a loop whose length the positions decide): a prompt of 512
-    tokens reads 512 keys, not the slot's 2,048. With ``window`` a query
-    at ``p`` reads positions ``p - window + 1`` to ``p``, ``rows`` are a
-    ring (:func:`write_ring`) and the loop starts at the first block the
-    query block's first query sees."""
+    One Pallas kernel (``lm_extend_attention``, under a window
+    ``lm_extend_attention_window``; interpreted where there is no TPU)
+    whose grid is key/value head x tile of :data:`QUERY_BLOCK` queries x
+    block of :data:`KEY_BLOCK` keys, the key blocks last and in turn: a
+    head's ``d`` lanes are a column block of a row, so a key block ``[kb,
+    d]`` is read straight out of ``rows`` (no split of the slot by head),
+    it serves the ``H / G`` query heads of its group one after another,
+    and scores, mask, maximum, exponential and sum stay in the chip's
+    fast memory. For each tile only the key blocks from the first to the
+    last some query of it sees (a prompt of 512 tokens reads 512 keys,
+    not the slot's 2,048): the steps past them compute nothing and name
+    the block already held, so they fetch nothing. With ``window`` a
+    query at ``p`` reads positions ``p - window + 1`` to ``p``, ``rows``
+    are a ring (:func:`write_ring`) and absolute key block ``j`` lies at
+    row ``j * kb % S``.
+
+    The running softmax in plain XLA that this replaced (a ``lax.map``
+    over query blocks around a ``fori_loop`` over key blocks, both 512)
+    was no slow path: XLA fuses a key block's mask and softmax into its
+    two products, and alone it took 36-63 us a (tile, key block) step of
+    19 us of products: 4.1 ms a full layer and 1.6 a window layer for
+    2,048 tokens on 13k of context, 18 of an extend launch's 134 ms at 7k
+    (not the 60 that ISSUE 50 reckoned). The kernel takes 3.1 and 1.4 ms
+    there, 54 us a step of 512 x 1,024 whose products are 38, and is the
+    faster at every served rung (PERF.md section 6, PR 50;
+    ``perf/profile_extend_attention.py`` keeps the loop). What is left
+    over the products is the scores' way through fast memory between
+    the maximum and the exponential (a quarter of a step: without the
+    maximum it ran in 2.8 ms for 3.8), not arithmetic: the exponential is
+    free beside it."""
     n, h, d = q.shape
     s_len, g = rows[0].shape[0], rows[0].shape[1] // d
     r = h // g
     qb, kb = min(n, QUERY_BLOCK), math.gcd(s_len, KEY_BLOCK)
     assert n % qb == 0, "the launch's tokens fill whole query blocks"
-    last_visible = (positions // block + 1) * block - 1
-    sight = (last_visible, jnp.maximum(positions - (window - 1), 0)) if window else (last_visible,)
-    keys, values = (jnp.moveaxis(a.reshape(s_len, g, d), 0, 1) for a in rows)  # [G, S, d]
-
-    def one(args):
-        qq, limit, *floor = args  # [qb, H, d], [qb] and, under a window, the first visible position [qb]
-        # a key/value head's queries side by side: rows (head in group, query)
-        qq = jnp.moveaxis(qq.reshape(qb, g, r, d), 0, 2).reshape(g, r * qb, d)
-        limit_rows = jnp.tile(limit, r)
-        floor_rows = jnp.tile(floor[0], r) if window else None
-
-        def take(j, carry):
-            top, total, acc = carry
-            lo = j * kb
-            at = lo % s_len if window else lo  # where the ring holds block j
-            scores = jnp.einsum(
-                "gqd,gkd->gqk", qq, jax.lax.dynamic_slice_in_dim(keys, at, kb, axis=1),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            keep = (lo + jnp.arange(kb))[None, None, :] <= limit_rows[None, :, None]
-            if window:
-                keep &= (lo + jnp.arange(kb))[None, None, :] >= floor_rows[None, :, None]
-            scores = jnp.where(keep, scores, -jnp.inf)
-            new_top = jnp.maximum(top, scores.max(axis=-1))
-            w = jnp.exp(scores - new_top[..., None])
-            shrink = jnp.exp(top - new_top)
-            acc = acc * shrink[..., None] + jnp.einsum(
-                "gqk,gkd->gqd", w.astype(values.dtype),
-                jax.lax.dynamic_slice_in_dim(values, at, kb, axis=1),
-                preferred_element_type=jnp.float32,
-            )
-            return new_top, total * shrink + w.sum(axis=-1), acc
-
+    tiles, blocks = n // qb, s_len // kb
+    starts, ends = (positions.reshape(tiles, qb)[:, at].astype(jnp.int32) for at in (0, -1))
+    last = ((ends // block + 1) * block - 1) // kb
+    if window:
+        first = jnp.maximum(starts - (window - 1), 0) // kb
+        # the most key blocks a tile's sight touches (its first and last may be the same rows of a small ring,
+        # each masked down to the positions it stands for)
+        steps = (window + qb + block - 3) // kb + 2
+    else:
         # key 0 is visible to every query, so the first block leaves no row empty (under a window a row
         # whose first blocks are all out of its sight keeps the state's finite floor until one is not)
-        first = floor[0][0] // kb if window else 0
-        blocks = limit[-1] // kb + 1 if window else jnp.clip(limit[-1] // kb + 1, 1, s_len // kb)
-        state = (
-            jnp.full((g, r * qb), -1e30, jnp.float32),
-            jnp.zeros((g, r * qb), jnp.float32),
-            jnp.zeros((g, r * qb, d), jnp.float32),
-        )
-        _, total, acc = jax.lax.fori_loop(first, blocks, take, state)
-        out = (acc / total[..., None]).reshape(g, r, qb, d)
-        return jnp.moveaxis(out, 2, 0).reshape(qb, h * d).astype(values.dtype)
-
-    split = lambda a: a.reshape(n // qb, qb, *a.shape[1:])
-    return jax.lax.map(one, (split(q), *map(split, sight))).reshape(n, h * d)
+        first, last, steps = jnp.zeros_like(starts), jnp.clip(last, 0, blocks - 1), blocks
+    # the tile's last block, named again for the steps after it so that the pipeline does not fetch them
+    at = lambda i, j, first, last: (first[i] + jnp.minimum(j, last[i] - first[i])) % blocks
+    tile = pl.BlockSpec((qb, r * d), lambda head, i, j, *_: (i, head))
+    key_block = pl.BlockSpec((kb, d), lambda head, i, j, first, last, _: (at(i, j, first, last), head))
+    return pl.pallas_call(
+        functools.partial(_extend_kernel, scale=scale, block=block, window=window, kb=kb, heads=r, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(g, tiles, steps),
+            in_specs=[tile, key_block, key_block],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((r, qb, 1), jnp.float32), pltpu.VMEM((r, qb, 1), jnp.float32),
+                pltpu.VMEM((r, qb, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, h * d), rows[1].dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=64 << 20
+        ),
+        interpret=not latent_attention.on_chip(),
+        name="lm_extend_attention_window" if window else "lm_extend_attention",  # the trace tells a launch's two apart
+    )(first, last, starts, q.reshape(n, h * d), *rows)
 
 
 def block_attention(q, k, v, kv, layer, slots, positions, scale: float):
