@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from triton_client_tpu.utils.compilation_cache import enable_persistent_cache
 
-enable_persistent_cache()  # perf/bench/entry share one compile bill
+enable_persistent_cache()  # perf/ and the entry points share one compile bill
 
 import jax
 import jax.numpy as jnp

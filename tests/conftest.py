@@ -9,7 +9,7 @@ forced here (env var for subprocesses, jax.config for this process,
 whatever the shell exported) and XLA_FLAGS, read lazily at backend
 init, asks for 8 virtual devices. Nothing here concerns libtpu: the
 only tests that load it describe a chip from inside their own fixture
-(tests/test_tpu_compile.py). Chip runs are not tests; they go through
+(tests/test_tpu_compile_*.py, tests/tpu_compile_support.py). Chip runs are not tests; they go through
 the chip tool as ``python chip_smoke.py``.
 """
 

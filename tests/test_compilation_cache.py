@@ -93,7 +93,7 @@ def test_no_entry_point_sets_a_cache_path_itself():
         if "jax_compilation_cache_dir" in path.read_text()
     ] + [
         name
-        for name in ("bench.py", "chip_smoke.py", "__graft_entry__.py")
+        for name in ("chip_smoke.py", "__graft_entry__.py")
         if "jax_compilation_cache_dir" in (REPO / name).read_text()
     ]
     assert offenders == ["triton_client_tpu/utils/compilation_cache.py"]

@@ -118,9 +118,8 @@ def test_anchor_class_unknown_key_fails(tmp_path):
 
 def test_kitti_pointpillars_capacity_yaml():
     """examples/pointpillar_wide serves the measured pp_capacity
-    configuration (perf/profile_capacity3d.py: 6.8x FLOPs, -18%
-    throughput) — the yaml must reproduce those hyperparameters on the
-    unchanged reference grid."""
+    configuration (6.8x FLOPs, -18% throughput) — the yaml must
+    reproduce those hyperparameters on the unchanged reference grid."""
     name, model_cfg, pipe_cfg = detect3d_from_yaml(
         "data/kitti_pointpillars_capacity.yaml"
     )

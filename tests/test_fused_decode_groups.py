@@ -9,7 +9,7 @@ what its keep mask implies. Interpret-mode Pallas on the CPU, small
 shapes (``max_nms`` 256, ``max_det`` 32); what Mosaic makes of the lane
 reductions is the chip's own and is held on the chip
 (``chip_smoke.py``, PERF.md), what it accepts by
-``tests/test_tpu_compile.py``.
+``tests/test_tpu_compile_detectors.py``.
 """
 
 import functools

@@ -1,13 +1,11 @@
 """loadgen client-protocol modes (round 5).
 
-``run_pool`` drives the serving benchmarks in all three client
-protocols (the reference's --streaming/--async flag surface,
-main.py:59-70, measured for real here by
-perf/profile_serving_modes.py). These tests pin the functional
+``run_pool`` drives a server in all three client protocols (the
+reference's --streaming/--async flag surface, main.py:59-70). These
+tests pin the functional
 contract of each mode against a live localhost server: requests
 complete, latencies are recorded per request, and results are
-numerically correct — so a protocol regression fails fast instead of
-silently zeroing a bench row.
+numerically correct — so a protocol regression fails fast.
 """
 
 import numpy as np

@@ -11,7 +11,7 @@ The contract under test, end to end:
     policy's declared ``map_budget`` RELATIVE to the f32 self-score
     (f32 scored against its own detections lands slightly under 1.0 —
     AP interpolation over tied confidences — so budgets floor against
-    that attainable ceiling, same form as perf/profile_precision.py);
+    that attainable ceiling);
   * selection — ``config.yaml model.precision`` per entry and the
     repository-wide ``serve --precision`` override both pick the same
     policy machinery;

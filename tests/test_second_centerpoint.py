@@ -373,8 +373,8 @@ def test_second_decode_topk_matches_full_decode_path():
 def test_non_divisible_grid_rejected_at_build():
     """A voxel size whose BEV grid doesn't divide the composed stride
     (e.g. 0.15 m over the 70.4x80 m KITTI range -> 469x533) must fail
-    loudly at init, not as a reshape error mid-trace
-    (perf/profile_second_grid.py found the silent variant)."""
+    loudly at init, not as a reshape error mid-trace (a grid sweep
+    found the silent variant)."""
     from triton_client_tpu.models.second import SECONDConfig, init_second
     from triton_client_tpu.ops.voxelize import VoxelConfig
 

@@ -300,8 +300,7 @@ class TestLiveShmServer:
 
 class TestLoadgen:
     def test_run_pool_closed_loop(self):
-        """The shared perf_analyzer-style driver (utils/loadgen) used
-        by bench.measure_serving and perf/profile_serving: pool runs,
+        """The perf_analyzer-style driver (utils/loadgen): pool runs,
         every thread drains before return, shm regions are gone."""
         from triton_client_tpu.utils.loadgen import run_pool
 

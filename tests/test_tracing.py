@@ -657,129 +657,3 @@ def test_trace_join_merges_files_onto_one_timeline(tmp_path, capsys):
     }
     assert reqs["router"]["ts"] == 0.0
     assert reqs["replica"]["ts"] == pytest.approx(1510.0)  # 10 + offset
-
-
-# -- bench_diff gate ----------------------------------------------------------
-
-
-class TestBenchDiff:
-    def _load(self):
-        import importlib.util
-        import os
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_diff",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                "perf", "bench_diff.py",
-            ),
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_regression_fails_improvement_passes(self):
-        bd = self._load()
-        base = {"m": {"metric": "m", "value": 100.0, "mfu": 0.10}}
-        ok = {"m": {"metric": "m", "value": 95.0, "mfu": 0.095}}
-        _lines, failures = bd.diff_rows(ok, base, threshold=0.10)
-        assert failures == []
-        bad = {"m": {"metric": "m", "value": 85.0, "mfu": 0.10}}
-        _lines, failures = bd.diff_rows(bad, base, threshold=0.10)
-        assert len(failures) == 1 and "throughput" in failures[0]
-        mfu_bad = {"m": {"metric": "m", "value": 120.0, "mfu": 0.05}}
-        _lines, failures = bd.diff_rows(mfu_bad, base, threshold=0.10)
-        assert len(failures) == 1 and "mfu" in failures[0]
-
-    def test_one_sided_metrics_do_not_gate(self):
-        bd = self._load()
-        lines, failures = bd.diff_rows(
-            {"new": {"metric": "new", "value": 1.0}},
-            {"old": {"metric": "old", "value": 1.0}},
-        )
-        assert failures == []
-        assert any("NEW" in ln for ln in lines)
-        assert any("baseline only" in ln for ln in lines)
-
-    def test_cli_exit_codes(self, tmp_path):
-        import subprocess
-        import sys
-
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps(
-            {"results": [{"metric": "m", "value": 100.0, "mfu": 0.10}]}
-        ))
-        fresh_ok = tmp_path / "ok.json"
-        fresh_ok.write_text(json.dumps(
-            {"results": [{"metric": "m", "value": 101.0, "mfu": 0.11}]}
-        ))
-        fresh_bad = tmp_path / "bad.json"
-        fresh_bad.write_text(json.dumps(
-            {"results": [{"metric": "m", "value": 50.0, "mfu": 0.10}]}
-        ))
-        import os
-
-        script = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "perf", "bench_diff.py",
-        )
-        ok = subprocess.run(
-            [sys.executable, script, str(fresh_ok), "--baseline", str(base)],
-            capture_output=True,
-        )
-        assert ok.returncode == 0
-        bad = subprocess.run(
-            [sys.executable, script, str(fresh_bad), "--baseline", str(base)],
-            capture_output=True,
-        )
-        assert bad.returncode == 1
-        assert b"REGRESSED" in bad.stdout or b"FAIL" in bad.stderr
-
-    def test_fused_stage_rows_load_and_gate(self, tmp_path):
-        """profile_fused --json rows (keyed by ``stage``) load under
-        synthetic fused_<stage> metrics and gate on speedup and
-        roofline_attained_ratio like any other row."""
-        bd = self._load()
-        doc = tmp_path / "fused.json"
-        doc.write_text(json.dumps({
-            "backend": "tpu",
-            "stages": [{
-                "stage": "voxelize_scatter", "ref_ms": 5.0,
-                "fused_ms": 2.0, "speedup": 2.5, "interpret": False,
-                "roofline_attained_ratio": 0.6,
-            }],
-        }))
-        rows = bd.load_rows(str(doc))
-        assert "fused_voxelize_scatter" in rows
-        base = dict(rows)
-        worse = {"fused_voxelize_scatter": dict(
-            rows["fused_voxelize_scatter"], speedup=1.2,
-            roofline_attained_ratio=0.3,
-        )}
-        _lines, failures = bd.diff_rows(worse, base, threshold=0.10)
-        assert len(failures) == 2
-        assert any("fused_speedup" in f for f in failures)
-        assert any("roofline_attained_ratio" in f for f in failures)
-
-    def test_interpret_and_route_change_report_but_never_gate(self):
-        """Interpreter timings are performance-false and a changed
-        fused_stages route is a different code path — both report
-        without failing the gate."""
-        bd = self._load()
-        base = {
-            "fused_decode_nms": {
-                "stage": "decode_nms", "speedup": 3.0, "interpret": True,
-            },
-            "m": {"metric": "m", "value": 100.0,
-                  "fused_stages": ["decode_nms"]},
-        }
-        fresh = {
-            "fused_decode_nms": {
-                "stage": "decode_nms", "speedup": 0.5, "interpret": True,
-            },
-            "m": {"metric": "m", "value": 40.0, "fused_stages": []},
-        }
-        lines, failures = bd.diff_rows(fresh, base, threshold=0.10)
-        assert failures == []
-        assert any("interpret" in ln for ln in lines)
-        assert any("fused route changed" in ln for ln in lines)

@@ -20,8 +20,7 @@
                 XLA's cost model (spec.extra, recorded at first
                 launch), arithmetic intensity vs the machine knee,
                 compute-/bandwidth-bound class, attainable-fps ceiling
-                next to the measured rate. Reads a live /snapshot URL
-                or a bench.py results JSON.
+                next to the measured rate. Reads a live /snapshot URL.
   trace-join  — merge several Chrome-trace exports (client / router /
                 replica trace-dump outputs) onto ONE timeline: each
                 source becomes its own pid row, shifted by an explicit
@@ -354,16 +353,15 @@ def roofline(argv=None) -> None:
     """Per-model roofline report: measured flops/bytes (XLA cost model,
     recorded into spec.extra at first launch), arithmetic intensity vs
     the machine knee, the binding ceiling, and the attainable-fps
-    ceiling next to the measured rate. Reads a live server's /snapshot
-    or a bench.py results JSON."""
+    ceiling next to the measured rate. Reads a live server's
+    /snapshot."""
     p = argparse.ArgumentParser(
         description="per-model roofline classification "
         "(compute- vs bandwidth-bound, attainable-fps ceiling)"
     )
     p.add_argument(
         "source", nargs="?", default="http://127.0.0.1:8002",
-        help="telemetry URL of a serving process (reads /snapshot) or "
-        "a bench.py results JSON file",
+        help="telemetry URL of a serving process (reads /snapshot)",
     )
     p.add_argument("--timeout", type=float, default=10.0)
     p.add_argument(
@@ -374,63 +372,35 @@ def roofline(argv=None) -> None:
     import json
     import urllib.request
 
+    url = args.source.rstrip("/") + "/snapshot"
+    with urllib.request.urlopen(url, timeout=args.timeout) as resp:
+        snap = json.load(resp)
+    device = snap.get("device")
     rows = []
-    device = None
-    if os.path.exists(args.source):
-        with open(args.source) as f:
-            doc = json.load(f)
-        device = doc.get("device")
-        # bench.py results: rows carry the roofline columns directly
-        for r in doc.get("rows") or doc.get("results") or []:
-            if not r.get("roofline_bound"):
-                continue
-            per_call = r.get("flops_per_call") or (
-                (r.get("flops_per_frame") or 0.0) * 1
-            )
-            rows.append(
-                {
-                    "model": r.get("metric", "?"),
-                    "precision": r.get("precision", "f32"),
-                    "flops": per_call,
-                    "bytes": r.get("bytes_per_call")
-                    or r.get("bytes_per_frame") or 0.0,
-                    "intensity": r.get("arithmetic_intensity", 0.0),
-                    "bound": r.get("roofline_bound", "unknown"),
-                    "attainable_fps": r.get("attainable_fps", 0.0),
-                    "measured_fps": r.get("value"),
-                    "attained_fraction": r.get("roofline_attained_ratio"),
-                }
-            )
-    else:
-        url = args.source.rstrip("/") + "/snapshot"
-        with urllib.request.urlopen(url, timeout=args.timeout) as resp:
-            snap = json.load(resp)
-        device = snap.get("device")
-        for m in snap.get("models") or []:
-            roof = m.get("roofline")
-            if not roof:
-                continue
-            rows.append(
-                {
-                    "model": f"{m['model']}:{m['version']}",
-                    "precision": roof.get("precision", "f32"),
-                    "flops": roof.get("flops", 0.0),
-                    "bytes": roof.get("bytes", 0.0),
-                    "intensity": roof.get("intensity", 0.0),
-                    "bound": roof.get("bound", "unknown"),
-                    "attainable_fps": roof.get("attainable_fps", 0.0),
-                    "measured_fps": roof.get("measured_fps"),
-                    "attained_fraction": roof.get("attained_fraction"),
-                }
-            )
+    for m in snap.get("models") or []:
+        roof = m.get("roofline")
+        if not roof:
+            continue
+        rows.append(
+            {
+                "model": f"{m['model']}:{m['version']}",
+                "precision": roof.get("precision", "f32"),
+                "flops": roof.get("flops", 0.0),
+                "bytes": roof.get("bytes", 0.0),
+                "intensity": roof.get("intensity", 0.0),
+                "bound": roof.get("bound", "unknown"),
+                "attainable_fps": roof.get("attainable_fps", 0.0),
+                "measured_fps": roof.get("measured_fps"),
+                "attained_fraction": roof.get("attained_fraction"),
+            }
+        )
     if args.json:
         print(json.dumps({"device": device, "rows": rows}, indent=2))
         return
     if not rows:
         raise SystemExit(
             "no roofline rows: models record measured flops/bytes at "
-            "their first launch (serve a request, then retry), and "
-            "bench JSON needs the roofline columns (rerun bench.py)"
+            "their first launch (serve a request, then retry)"
         )
     if device:
         from triton_client_tpu.obs.roofline import DEVICE_PEAKS

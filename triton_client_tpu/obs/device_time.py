@@ -15,8 +15,8 @@ construction) accrues into
     over elapsed wall × device count
     (``tpu_serving_device_utilization_ratio``),
   * live per-model MFU — achieved flops over the window against the
-    precision policy's peak (``tpu_serving_mfu{model}``), using the
-    same analytic flops / per-device peak accounting the bench records
+    precision policy's peak (``tpu_serving_mfu{model}``), from the
+    model's own flops and the per-device peak
     (``spec.extra["flops_per_call"]`` + ``extra["precision"]``).
 
 ``record`` runs on the resolve() readback path (executor threads,
@@ -30,9 +30,8 @@ import collections
 import threading
 import time
 
-# the single home of the per-chip peaks (bench.py reads the same
-# table), so served MFU, bench MFU, and the roofline ceiling all
-# divide by one denominator
+# the single home of the per-chip peaks, so served MFU and the
+# roofline ceiling divide by one denominator
 from triton_client_tpu.obs.roofline import peak_flops
 
 

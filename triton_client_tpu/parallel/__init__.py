@@ -4,9 +4,8 @@ The reference has no device parallelism at all (SURVEY.md section 2.10
 — one blocking RPC per frame, NCCL/MPI absent). This package supplies
 the TPU-native scale story: a named `jax.sharding.Mesh` (data / model /
 seq / pipe axes) with XLA collectives over ICI/DCN, batch sharding for
-multi-camera serving, ring + all-to-all sequence parallelism for long
-point clouds and BEV token grids, GPipe microbatch pipelining for deep
-stacks, and the sharded training step used for fine-tuning.
+multi-camera serving, and the sharded training step used for
+fine-tuning.
 """
 
 from triton_client_tpu.parallel.mesh import (
@@ -18,14 +17,4 @@ from triton_client_tpu.parallel.mesh import (
     batch_sharding,
     make_mesh,
     replicated,
-)
-from triton_client_tpu.parallel.pipeline import (
-    pipeline_apply,
-    stack_stage_params,
-)
-from triton_client_tpu.parallel.sequence import (
-    full_attention,
-    ring_attention,
-    sequence_parallel_pillar_canvas,
-    ulysses_attention,
 )

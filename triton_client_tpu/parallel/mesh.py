@@ -1,16 +1,14 @@
 """Mesh construction and canonical shardings.
 
 Axes:
-  data   — batch / camera-stream data parallelism (the BASELINE.json
-           "ensemble multi-camera over v5e-8" config maps cameras here)
+  data   — batch / camera-stream data parallelism (a multi-camera
+           ensemble maps cameras here)
   model  — tensor parallelism for wide layers (conv channel sharding,
            voxel-axis sharding for the 3D stack)
   seq    — sequence/context parallelism: the point/pillar/BEV-token
            axis for long point clouds (the reference's scale axis is
            MAX_NUMBER_OF_VOXELS=40000, data/kitti_dataset.yaml:66-70;
-           a full KITTI BEV canvas is 432x496 ≈ 214k tokens). Ring
-           attention and the distributed pillar scatter in
-           parallel/sequence.py ride this axis over ICI.
+           a full KITTI BEV canvas is 432x496 ≈ 214k tokens).
 
 On a single host this is `jax.devices()` reshaped; on multi-host the
 same code runs under `jax.distributed` with DCN-attached hosts, with

@@ -5,7 +5,7 @@ for the three flagship models on a v5e); jax's persistent cache keys
 serialized executables by HLO + backend, so a second process that
 finds the same directory pays only deserialization. Every entry point
 that compiles pipelines (``serve``, ``detect2d``, ``detect3d``,
-``bench.py``, ``chip_smoke.py``, the ``perf/`` scripts) calls
+``chip_smoke.py``, the ``perf/`` scripts) calls
 :func:`enable_persistent_cache` before its first compile.
 
 Where the cache lives is decided outside the program:

@@ -5,8 +5,7 @@ The role Triton's ``perf_analyzer`` plays in the reference's ecosystem
 N threads, each with its own channel, issuing one synchronous
 ModelInfer after another against a KServe v2 endpoint, with a
 warm-before-measure barrier so neither thread ramp nor first-request
-compiles bias the measured window. Used by ``bench.measure_serving``
-and ``perf/profile_serving.py`` so both measure the SAME protocol.
+compiles bias the measured window.
 
 Client lifecycle per thread:
   1. staggered connect + one warm request (staggering avoids N
